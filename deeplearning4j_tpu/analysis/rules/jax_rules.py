@@ -448,30 +448,27 @@ class RawMeshConstruction(Rule):
         "parallel/ bypasses all of that — the fit runs on a topology no "
         "operator can see and no validation ever checked. Route meshes "
         "through parallel.mesh (MeshSpec/make_mesh) and shard_map-style "
-        "steps through the parallel/ step factories. Exempt: tests/, the "
-        "parallel/ substrate package itself, and compat.py (the "
-        "version-shim that DEFINES the sanctioned shard_map wrapper). "
+        "steps through the parallel/ step factories. Exempt: tests/ and "
+        "the parallel/ substrate package itself. "
         "Ratchet-only via analysis/baseline.json for sites that "
         "genuinely cannot migrate.")
 
     def check(self, tree, lines, path) -> Iterator:
         p = path.replace("\\", "/")
         parts = p.split("/")
-        if "tests" in parts or "parallel" in parts \
-                or p.endswith("compat.py"):
+        if "tests" in parts or "parallel" in parts:
             return
         # names bound to the constructors by import: `from jax.sharding
         # import Mesh [as m]`, `from jax.experimental.shard_map import
-        # shard_map`, `from jax import shard_map`, and the repo idiom
-        # `from ..compat import shard_map`
+        # shard_map`, `from jax import shard_map`
         mesh_names: Set[str] = set()
         sm_names: Set[str] = set()
         jax_mods: Set[str] = {"jax"}
         # module aliases whose .shard_map attribute IS the constructor
         # (`from jax.experimental import shard_map as smod`,
-        # `import jax.experimental.shard_map as sm`, compat imports) — an
-        # unrelated object's own .shard_map method must NOT flag
-        sm_mods: Set[str] = {"compat"}
+        # `import jax.experimental.shard_map as sm`) — an unrelated
+        # object's own .shard_map method must NOT flag
+        sm_mods: Set[str] = set()
         # aliases of the jax.sharding MODULE itself (`import jax.sharding
         # as jsh`, `from jax import sharding [as x]`) — jsh.Mesh(...) is
         # just as raw as jax.sharding.Mesh(...)
@@ -484,13 +481,10 @@ class RawMeshConstruction(Rule):
                     if a.name == "Mesh" and mod.startswith("jax"):
                         mesh_names.add(bound)
                     elif a.name == "shard_map":
-                        if mod.startswith("jax") \
-                                or mod.split(".")[-1] == "compat":
+                        if mod.startswith("jax"):
                             sm_names.add(bound)
                         if mod == "jax.experimental":
                             sm_mods.add(bound)   # module, not function
-                    elif a.name == "compat":
-                        sm_mods.add(bound)
                     elif a.name == "sharding" and mod == "jax":
                         sharding_mods.add(bound)
             elif isinstance(node, ast.Import):
@@ -522,7 +516,7 @@ class RawMeshConstruction(Rule):
                     hit = "Mesh"          # jax.sharding.Mesh / jsh.Mesh
                 elif f.attr == "shard_map" and (
                         root in jax_mods or root in sm_mods):
-                    hit = "shard_map"     # compat.shard_map / jax.shard_map
+                    hit = "shard_map"     # jax.shard_map / smod.shard_map
             if hit is None:
                 continue
             yield self.finding(

@@ -1,73 +1,21 @@
-"""jax version-compatibility shims.
-
-The framework targets current jax APIs, but deployment containers pin older
-runtimes (the CI floor is jax 0.4.x). Every renamed/moved symbol the
-codebase relies on resolves here, in ONE place, so call sites stay written
-against the modern names:
-
-- ``shard_map``: top-level ``jax.shard_map`` (new) vs
-  ``jax.experimental.shard_map.shard_map`` (0.4.x); the new ``check_vma``
-  kwarg maps onto the old ``check_rep``.
-- ``enable_x64``: ``jax.enable_x64`` context manager (new) vs
-  ``jax.experimental.enable_x64`` (0.4.x).
-- ``set_cpu_devices``: ``jax_num_cpu_devices`` config (new) vs the
-  ``--xla_force_host_platform_device_count`` XLA flag (0.4.x). Must run
-  before the backend initializes, like both underlying mechanisms.
-"""
+"""Virtual CPU devices for tests, examples and the CPU-mesh workers."""
 from __future__ import annotations
 
 import os
 
 import jax
 
-try:
-    from jax import shard_map as _shard_map
-    _NEW_SHARD_MAP = True
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NEW_SHARD_MAP = False
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=None,
-              **kw):
-    """``jax.shard_map`` with the modern signature on every supported jax."""
-    if check_vma is not None:
-        kw["check_vma" if _NEW_SHARD_MAP else "check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-def enable_x64(enabled: bool = True):
-    """Context manager enabling 64-bit types (gradient checking)."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(enabled)
-    from jax.experimental import enable_x64 as _enable_x64
-    return _enable_x64(enabled)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as one flat dict on every supported jax
-    (0.4.x returns a one-dict-per-device list)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
 
 def set_cpu_devices(n: int):
     """Configure an ``n``-device virtual CPU backend. Call before any jax
-    computation (both mechanisms are read at backend initialization).
+    computation (the config is read at backend initialization).
 
     Any inherited ``--xla_force_host_platform_device_count`` is STRIPPED
     from ``XLA_FLAGS`` first: test runners export it for their own device
     count, subprocesses inherit the environment, and a stale flag would
-    either duplicate (0.4.x: relies on last-wins parsing) or fight the
-    ``jax_num_cpu_devices`` config (newer jax)."""
+    fight the ``jax_num_cpu_devices`` config."""
     flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
              if not f.startswith("--xla_force_host_platform_device_count=")]
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-    except AttributeError:  # jax 0.4.x: only the XLA flag exists
-        flags.append(f"--xla_force_host_platform_device_count={int(n)}")
     os.environ["XLA_FLAGS"] = " ".join(flags)
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", int(n))

@@ -65,11 +65,8 @@ def runtime_fingerprint() -> Dict[str, str]:
     a serialized XLA executable from another toolchain may load and then
     miscompute, so close does not count."""
     import jax
-    try:
-        from jax.extend.backend import get_backend
-        be = get_backend()
-    except ImportError:                      # older jax spelling
-        be = jax.lib.xla_bridge.get_backend()
+    from jax.extend.backend import get_backend
+    be = get_backend()
     return {"jax": str(jax.__version__), "backend": str(be.platform),
             "backend_version": str(be.platform_version)}
 
@@ -309,12 +306,10 @@ def try_install(served, path: str) -> bool:
     ``compile_cache_miss`` flight event with the reason — the caller
     (``ServedModel.warm``) then falls back to a live compile. Never
     raises: a bad artifact must cost a recompile, not an outage."""
+    import jax
+    from jax.experimental import serialize_executable as se
     from ..monitor.flightrec import get_flight_recorder
     try:
-        # inside the try ON PURPOSE: a jax build without the serializer
-        # is just another reason to fall back to a live warm, not a
-        # registration crash (the never-raises contract above)
-        from jax.experimental import serialize_executable as se
         # gate order matters: manifest checks BEFORE any entry
         # deserialization — a stale/tampered artifact is rejected without
         # unpickling a byte of its payload (_read_entries docstring)
@@ -325,7 +320,13 @@ def try_install(served, path: str) -> bool:
         kind = manifest.get("kind", "mln")
         for sig, (payload, (in_tree, out_tree)) in zip(
                 manifest["signatures"], entries):
-            loaded = se.deserialize_and_load(payload, in_tree, out_tree)
+            # export lowers every signature unsharded, so each entry is
+            # a one-device program for the default device; left to its
+            # default, the loader spreads it over every device of the
+            # backend and a multi-device host rejects the arguments
+            loaded = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=jax.devices()[:1])
             key = (tuple(int(d) for d in sig["shape"]),
                    str(sig["dtype"]), bool(sig["masked"]))
             aot[key] = _make_caller(loaded, kind)

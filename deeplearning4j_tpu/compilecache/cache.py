@@ -4,18 +4,22 @@ Every process in the fleet pays full XLA compile cost at start — visible
 in ``jit_compile_seconds``, in ``ModelRegistry.warm()``'s cold-start
 compiles, and in worker rejoin after ``scale_to()``. jax ships a
 persistent on-disk compilation cache that turns a recompile into a disk
-read, but it is off by default and its knobs have moved across jax
-versions; this module is the package's one compat-shimmed switch:
+read, but it is off by default; this module is the package's one switch:
 
-- :func:`enable` points jax's compilation cache at a directory (every
-  program cached, not just slow-to-compile ones) and registers a
-  ``jax.monitoring`` listener so cache hits/misses are observable.
-- :func:`maybe_enable` is the fleet seam: a no-op unless
-  ``DL4J_TPU_COMPILE_CACHE_DIR`` is set (tier-1 runs with it unset, so
-  the cache is off by default), called from ``ModelRegistry.register``
-  (serving replicas) and the paramserver join/rejoin path (workers) —
-  every process that is about to compile checks the dial once, so a
-  fleet shares one cache dir by exporting one env var.
+- :func:`enable` turns jax's compilation cache on for every program (not
+  just slow-to-compile ones) and registers a ``jax.monitoring`` listener
+  so cache hits/misses are observable. Where the cache lives, in order:
+  ``JAX_COMPILATION_CACHE_DIR`` (jax's own variable — when it is set the
+  cache is placed from outside and no code here sets another directory),
+  the directory the caller passes (``chip_smoke.py`` and ``bench.py
+  --one`` pass the fixed ``<checkout>/.jax_cache``), then the
+  ``DL4J_TPU_COMPILE_CACHE_DIR`` fleet dial.
+- :func:`maybe_enable` is the fleet seam: a no-op unless one of the two
+  variables is set (tier-1 runs with both unset, so the cache is off by
+  default), called from ``ModelRegistry.register`` (serving replicas) and
+  the paramserver join/rejoin path (workers) — every process that is
+  about to compile checks the dial once, so a fleet shares one cache dir
+  by exporting one env var.
 - :func:`take_persistent_hit` is jitwatch's claim protocol: a compile
   that was actually served from the disk cache is a *persistent* hit —
   fast, but still a jit-cache miss in-process — and the
@@ -42,16 +46,21 @@ from typing import Any, Dict, List, Optional
 
 log = logging.getLogger(__name__)
 
-__all__ = ["ENV_DIR", "enable", "maybe_enable", "enabled", "cache_dir",
-           "hits_count", "claim_persistent_hit", "suppress_events",
+__all__ = ["ENV_DIR", "JAX_ENV_DIR", "enable", "maybe_enable", "enabled",
+           "cache_dir", "hits_count", "claim_persistent_hit", "suppress_events",
            "persistent_cache_counts", "cache_stats", "gc_cache"]
 
 #: the fleet dial: one shared directory, exported to every worker and
 #: serving replica. Unset (the tier-1 default) = cache off.
 ENV_DIR = "DL4J_TPU_COMPILE_CACHE_DIR"
 
-#: jax.monitoring event names the listener counts (stable across the
-#: 0.4.x line; unknown names are simply never observed)
+#: jax's own variable for the same thing. It outranks everything here:
+#: jax reads it into ``jax_compilation_cache_dir`` itself, and
+#: :func:`enable` then only adds thresholds, the latch reset and the
+#: hit/miss listener.
+JAX_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+#: jax.monitoring event names the listener counts
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
@@ -105,25 +114,21 @@ def _install_listener() -> None:
         if _STATE["listener"]:
             return
         _STATE["listener"] = True
-    try:
-        from jax import monitoring
-        monitoring.register_event_listener(_on_event)
-    except Exception as e:
-        # compat shim: a jax build without the monitoring seam still gets
-        # the disk cache — only the hit/miss split degrades to zero
-        log.debug("compilecache: jax.monitoring unavailable (%r) — "
-                  "persistent hit/miss counts disabled", e)
+    from jax import monitoring
+    monitoring.register_event_listener(_on_event)
 
 
 def enable(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``cache_dir`` (or the
-    ``DL4J_TPU_COMPILE_CACHE_DIR`` env dial) and cache EVERY program —
-    min-compile-time / min-entry-size thresholds zeroed, because the
+    """Turn jax's persistent compilation cache on and cache EVERY program
+    — min-compile-time / min-entry-size thresholds zeroed, because the
     fleet's win is the *sum* of many small forward/pad programs, not one
-    big step. Idempotent; returns the active directory, or None when no
-    directory is configured (or this jax build lacks the cache knobs —
-    the compat contract is "no cache", never a crash)."""
-    d = cache_dir or os.environ.get(ENV_DIR)
+    big step. The directory is ``JAX_COMPILATION_CACHE_DIR`` when that is
+    set (jax already points its config there: nothing here sets another),
+    else ``cache_dir``, else the ``DL4J_TPU_COMPILE_CACHE_DIR`` dial.
+    Idempotent; returns the active directory, or None when none of the
+    three names one (or the one named cannot be created)."""
+    from_jax_env = os.environ.get(JAX_ENV_DIR)
+    d = from_jax_env or cache_dir or os.environ.get(ENV_DIR)
     if not d:
         return None
     d = os.path.abspath(d)
@@ -133,32 +138,22 @@ def enable(cache_dir: Optional[str] = None) -> Optional[str]:
         return d
     try:
         os.makedirs(d, exist_ok=True)
-        import jax
-        # thresholds FIRST, the dir LAST: the dir update is what arms
-        # the cache, so a jax build missing one of the (younger)
-        # threshold flags fails BEFORE anything is half-enabled — a
-        # partially-configured cache would serve disk hits jitwatch
-        # never attributes, the exact dishonesty this module removes
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_compilation_cache_dir", d)
-    except Exception as e:
-        # older jax without the flags, or an unwritable dir: the cache is
-        # an optimization — degrade loudly to live compiles
-        log.warning("compilecache: could not enable persistent cache at "
-                    "%s: %r", d, e)
+    except OSError as e:
+        # the cache is an optimization: an unusable directory degrades
+        # loudly to live compiles
+        log.warning("compilecache: cannot use %s: %r", d, e)
         return None
-    try:
-        # jax latches its cache decision at the FIRST compile: a process
-        # that already compiled anything (backend init, an eager net
-        # build) before this call would silently keep the cache OFF for
-        # its whole lifetime — reset the latch so the next compile
-        # re-reads the dir just configured. Private seam, so its absence
-        # (another jax line) merely loses late enabling, not the cache
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception as e:
-        log.debug("compilecache: cache-latch reset unavailable: %r", e)
+    import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if not from_jax_env:
+        jax.config.update("jax_compilation_cache_dir", d)
+    # jax latches its cache decision at the FIRST compile: a process
+    # that already compiled anything (backend init, an eager net build)
+    # before this call would silently keep the cache OFF for its whole
+    # lifetime — reset the latch so the next compile re-reads the config
+    from jax._src import compilation_cache as _cc
+    _cc.reset_cache()
     _install_listener()
     with _LOCK:
         _STATE["dir"] = d
@@ -168,13 +163,14 @@ def enable(cache_dir: Optional[str] = None) -> Optional[str]:
 
 
 def maybe_enable() -> Optional[str]:
-    """The fleet seam: :func:`enable` iff ``DL4J_TPU_COMPILE_CACHE_DIR``
-    is set. Cheap when unset (no jax import, one env read) so hot
-    registration/join paths can call it unconditionally."""
+    """The fleet seam: :func:`enable` iff ``JAX_COMPILATION_CACHE_DIR`` or
+    ``DL4J_TPU_COMPILE_CACHE_DIR`` is set. Cheap when neither is (no jax
+    import, two env reads) so hot registration/join paths can call it
+    unconditionally."""
     with _LOCK:
         if _STATE["dir"]:
             return _STATE["dir"]
-    if not os.environ.get(ENV_DIR):
+    if not (os.environ.get(JAX_ENV_DIR) or os.environ.get(ENV_DIR)):
         return None
     return enable()
 
@@ -239,7 +235,8 @@ def _artifact_paths(d: str) -> List[str]:
 
 def _resolve_dir(cache_dir: Optional[str]) -> Optional[str]:
     return (os.path.abspath(cache_dir) if cache_dir
-            else _STATE["dir"] or os.environ.get(ENV_DIR) or None)
+            else _STATE["dir"] or os.environ.get(JAX_ENV_DIR)
+            or os.environ.get(ENV_DIR) or None)
 
 
 def cache_stats(cache_dir: Optional[str] = None) -> Dict[str, Any]:

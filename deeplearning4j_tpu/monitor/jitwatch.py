@@ -16,8 +16,8 @@ both first-class monitor citizens:
   series), a ``compile/<name>`` tracer span (compiles appear on
   ``/trace`` and the merged fleet trace, parented under the step span
   they interrupted), and on-compile ``cost_analysis`` capture (flops /
-  bytes / peak memory per compiled variant, via ``compat.cost_analysis``
-  — the same numbers ``utils.profiling.step_cost`` reports).
+  bytes / peak memory per compiled variant — the same numbers
+  ``utils.profiling.step_cost`` reports).
 - the **retrace-storm detector**: ``RETRACE_THRESHOLD`` compiles of the
   same wrapper within ``RETRACE_WINDOW`` seconds records a health
   problem and a ``retrace_storm`` flight-recorder event naming the
@@ -633,22 +633,15 @@ def _capture_cost_now(jitted, name: str, sig_key: str, a_args, a_kwargs):
     memory_analysis. Best-effort by contract — sharded/exotic signatures
     that refuse the abstract re-lower simply report no cost."""
     compiled = jitted.lower(*a_args, **a_kwargs).compile()
-    from ..compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     cost = {"flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
-    try:
-        ma = compiled.memory_analysis()
-        peak = sum(float(getattr(ma, k, 0) or 0)
-                   for k in ("temp_size_in_bytes",
-                             "argument_size_in_bytes",
-                             "output_size_in_bytes"))
-        if peak:
-            cost["peak_memory_bytes"] = peak
-    # older jax builds lack Compiled.memory_analysis — the flops/bytes
-    # cost block above is still the full answer
-    except Exception:  # tpulint: disable=EXC001
-        pass
+    ma = compiled.memory_analysis()
+    peak = sum(float(getattr(ma, k, 0) or 0)
+               for k in ("temp_size_in_bytes", "argument_size_in_bytes",
+                         "output_size_in_bytes"))
+    if peak:
+        cost["peak_memory_bytes"] = peak
     get_jit_registry().note_cost(name, sig_key, cost)
 
 

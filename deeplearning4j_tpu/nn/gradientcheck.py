@@ -22,7 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..compat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 from ..monitor.jitwatch import monitored_jit
 
 log = logging.getLogger(__name__)

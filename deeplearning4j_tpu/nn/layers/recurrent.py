@@ -35,19 +35,10 @@ def _match_vma(z, ref):
     Under ``shard_map`` (ParallelWrapper local-SGD), batch inputs are
     device-varying while a ``jnp.zeros`` carry init is not; ``lax.scan``
     rejects the carry-type mismatch. Outside shard_map this is a no-op."""
-    try:
-        want = set(jax.typeof(ref).vma) - set(jax.typeof(z).vma)
-    except (AttributeError, TypeError):
-        # jax < typeof/vma (0.4.x), or a non-jax ref type: no varying-axis
-        # typing exists to satisfy, so the zero init is already fine
-        return z
+    want = set(jax.typeof(ref).vma) - set(jax.typeof(z).vma)
     if not want:
         return z
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(z, tuple(want), to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(z, tuple(want))
-    return z  # pre-vma jax (0.4.x): no varying-axis typing to satisfy
+    return jax.lax.pcast(z, tuple(want), to="varying")
 
 
 class _BaseLSTMImpl(LayerImpl):

@@ -25,7 +25,6 @@ Single-process testing uses the same code on a virtual device mesh.
 """
 from __future__ import annotations
 
-import logging
 from typing import Optional
 
 import numpy as np
@@ -35,8 +34,6 @@ from .sharding import DATA_AXIS, make_mesh
 from ..monitor.jitwatch import monitored_jit
 from .wrapper import ParallelWrapper, TrainingMode
 from .accumulation import EncodedGradientsAccumulator
-
-log = logging.getLogger(__name__)
 
 
 def initialize_distributed(coordinator_address: Optional[str] = None,
@@ -49,7 +46,7 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
     ``SharedTrainingMaster.java:469``). No-op when single-process.
 
     On the CPU backend (tests / virtual clusters) cross-process collectives
-    need the gloo transport — configured automatically when available.
+    ride jax's default gloo transport.
 
     FAILURE SEMANTICS: the cluster is fate-shared, like the reference's
     Spark stage — there is no in-framework elastic recovery (SURVEY.md §5:
@@ -66,25 +63,11 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
     ``test_killed_worker_fails_cleanly`` for the pinned behavior."""
     if coordinator_address is None:
         return False
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        # TPU backends use ICI/DCN natively — but log the skip so a
-        # renamed config flag can't silently disable CPU collectives
-        log.debug("gloo CPU-collectives config not applied", exc_info=True)
     kw = {}
     if heartbeat_timeout_s is not None:
         kw["heartbeat_timeout_seconds"] = int(heartbeat_timeout_s)
     if initialization_timeout_s is not None:
         kw["initialization_timeout"] = int(initialization_timeout_s)
-    import inspect
-    supported = set(inspect.signature(jax.distributed.initialize).parameters)
-    dropped = sorted(set(kw) - supported)
-    if dropped:  # older jax: runtime defaults apply (detection still works,
-        # just at the stock heartbeat cadence)
-        log.warning("jax.distributed.initialize does not support %s on this "
-                    "jax version; using runtime defaults", dropped)
-        kw = {k: v for k, v in kw.items() if k in supported}
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id, **kw)

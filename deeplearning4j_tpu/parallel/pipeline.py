@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..compat import shard_map
+from jax import shard_map
 from ..monitor.jitwatch import monitored_jit
 
 from .mesh import PIPELINE_AXIS, record_step, require_axes

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..compat import shard_map
+from jax import shard_map
 from ..monitor.jitwatch import monitored_jit
 
 from .mesh import record_step, require_axes
@@ -437,7 +437,7 @@ def sp_attend(q, k, v, axis: str, causal: bool, dropout_rate: float = 0.0,
     if rate > 0.0 and dropout_seed is None:
         raise ValueError("dropout_rate > 0 needs dropout_seed")
     flash_ok = (Tl % _fa.MIN_BLOCK == 0 and d <= 256
-                and (_fa._FORCE_INTERPRET or not _fa._interpret()))
+                and (_fa._FORCE_INTERPRET or _fa._on_tpu()))
     # dropout-free + head-divisible: Ulysses layout — 2 all_to_alls on ICI
     # and ONE full-sequence kernel beats the ring's n sequential launches
     # (dropout stays on the ring, whose global-coordinate PRNG is bit-equal

@@ -236,10 +236,5 @@ def data_parallel_tbptt_update_step(net, mesh: Mesh, axis: str = DATA_AXIS):
 
 def pvary(x, axis_names):
     """Mark ``x`` as device-varying over ``axis_names`` inside shard_map
-    (vma typing). Wraps ``lax.pcast(..., to='varying')`` with a fallback to
-    the older ``lax.pvary`` name."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, tuple(axis_names), to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, tuple(axis_names))
-    return x  # pre-vma jax (0.4.x): no varying-axis typing to satisfy
+    (vma typing)."""
+    return jax.lax.pcast(x, tuple(axis_names), to="varying")

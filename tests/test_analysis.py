@@ -778,7 +778,7 @@ def test_jax004_flags_raw_mesh_and_shard_map_calls():
     src = """
         import jax
         from jax.sharding import Mesh
-        from ..compat import shard_map
+        from jax import shard_map
         import numpy as np
 
         def build(devices, fn, mesh):
@@ -825,16 +825,15 @@ def test_jax004_exempts_substrate_tests_and_annotations():
     raw = """
         import numpy as np
         from jax.sharding import Mesh
-        from ..compat import shard_map
+        from jax import shard_map
 
         def build(devices, fn, mesh):
             m = Mesh(np.asarray(devices), ("data",))
             return m, shard_map(fn, mesh=mesh, in_specs=(), out_specs=())
         """
-    # the substrate package, compat.py and tests are exempt by design
+    # the substrate package and tests are exempt by design
     assert lint_src(raw, path="deeplearning4j_tpu/parallel/mesh.py") == []
     assert lint_src(raw, path="deeplearning4j_tpu/parallel/wrapper.py") == []
-    assert lint_src(raw, path="deeplearning4j_tpu/compat.py") == []
     assert lint_src(raw, path="tests/test_x.py") == []
     assert lint_src(raw, path="deeplearning4j_tpu/x.py") != []
     # a Mesh type ANNOTATION is not a construction — only calls flag
@@ -846,7 +845,7 @@ def test_jax004_exempts_substrate_tests_and_annotations():
         """
     assert lint_src(ann, path="deeplearning4j_tpu/x.py") == []
     # an unrelated object's own .shard_map method must not flag — only
-    # jax/compat module roots are constructors (review finding)
+    # jax module roots are constructors (review finding)
     own = """
         class Router:
             def shard_map(self, fn):

@@ -1,0 +1,109 @@
+"""What can be checked about the chip path from the CPU: ``chip_smoke.py``
+refuses to run without a TPU, the Pallas kernels refuse to run off-chip
+unless a test asked for interpret mode, and every kernel family still
+lowers to a Mosaic custom call at its bench shape (the cross-lowering
+check to run before a chip call — Mosaic's own compile only happens on
+the chip)."""
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import deeplearning4j_tpu.ops.flash_attention as fa
+import deeplearning4j_tpu.ops.lstm_cell as lk
+import deeplearning4j_tpu.ops.lstm_fused as lf
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_chip_smoke_refuses_cpu_naming_the_platform():
+    p = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=120,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode != 0
+    assert "'cpu'" in p.stderr and "not a TPU" in p.stderr
+    assert p.stdout == ""            # no result line
+
+
+def test_result_line_holds_exactly_ok_and_device():
+    sys.path.insert(0, ROOT)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(ROOT)
+    line = chip_smoke.result_line(
+        True, {"platform": "tpu", "kind": "TPU v5 lite", "count": 4,
+               "extra": "dropped"})
+    assert "\n" not in line
+    assert json.loads(line) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}}
+
+
+def test_kernel_call_off_chip_without_forced_interpret_raises():
+    assert not fa._FORCE_INTERPRET
+    q = jnp.zeros((1, 256, 2, 16), jnp.float32)
+    with pytest.raises(RuntimeError, match="backend 'cpu'"):
+        fa.flash_attention(q, q, q)
+    xp = jnp.zeros((8, 4, 4 * 128), jnp.float32)
+    rw = jnp.zeros((128, 4 * 128), jnp.float32)
+    z = jnp.zeros((8, 128), jnp.float32)
+    with pytest.raises(RuntimeError, match="backend 'cpu'"):
+        lk.lstm_scan(xp, rw, None, z, z)
+    with pytest.raises(RuntimeError, match="backend 'cpu'"):
+        lf.lstm_scan2(xp, rw, None, rw, jnp.zeros((4 * 128,)), rw, None,
+                      z, z, z, z)
+    # and nothing routes there by itself
+    assert not fa.supported(8192, 64, 0.0, None)
+    assert not lk.supported(64, 50, 512, "tanh", "sigmoid", weight_bytes=2)
+    assert not lf.supported2(64, 50, 512, weight_bytes=2)
+
+
+def _custom_calls(fn, *avals):
+    """``tpu_custom_call`` count of ``grad(fn)`` lowered FOR the TPU from
+    this CPU process."""
+    loss = lambda *a: jnp.sum(jax.tree_util.tree_leaves(fn(*a))[0]
+                              .astype(jnp.float32) ** 2)
+    traced = jax.jit(jax.grad(loss, argnums=tuple(range(len(avals))))
+                     ).trace(*avals)
+    return traced.lower(lowering_platforms=("tpu",)).as_text().count(
+        "tpu_custom_call")
+
+
+def test_every_kernel_family_lowers_for_tpu_at_bench_shape(monkeypatch):
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    sds = jax.ShapeDtypeStruct
+    # flash at bench_transformer_lm's attention shape: fwd + dq + dkv
+    qkv = [sds((4, 8192, 8, 64), jnp.bfloat16)] * 3
+    km = np.ones((4, 8192), np.float32)
+    assert fa.supported(8192, 64, 0.3, km)
+    assert _custom_calls(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True), *qkv) == 3
+    assert _custom_calls(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                           key_mask=jnp.asarray(km)),
+        *qkv) == 3
+    assert _custom_calls(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=False,
+                                           dropout_rate=0.3,
+                                           dropout_seed=7), *qkv) == 3
+    # the LSTM kernels at bench_graves_lstm's segment shape, bf16 weights
+    b, T, H = 64, 50, 512
+    xp = sds((b, T, 4 * H), jnp.float32)
+    w = sds((H, 4 * H), jnp.bfloat16)
+    st = sds((b, H), jnp.float32)
+    peep = (sds((H,), jnp.float32),) * 3
+    for stream in ("float32", "bfloat16"):
+        monkeypatch.setenv("DL4J_TPU_LSTM_STREAM_DTYPE", stream)
+        assert lk.supported(b, T, H, "tanh", "sigmoid", weight_bytes=2)
+        assert _custom_calls(lk.lstm_scan, xp, w, peep, st, st) == 2
+    assert lf.supported2(b, T, H, weight_bytes=2)     # bf16 streams only
+    assert _custom_calls(lf.lstm_scan2, xp, w, peep, w,
+                         sds((4 * H,), jnp.float32), w, peep,
+                         st, st, st, st) == 2

@@ -90,10 +90,52 @@ def test_claim_protocol_window_and_suppression():
 
 def test_maybe_enable_is_noop_without_the_dial(monkeypatch):
     monkeypatch.delenv(cc_cache.ENV_DIR, raising=False)
+    monkeypatch.delenv(cc_cache.JAX_ENV_DIR, raising=False)
     if cc_cache.enabled():
         pytest.skip("cache already enabled in this process")
     assert cc_cache.maybe_enable() is None
     assert cc_cache.cache_dir() is None
+
+
+def test_cache_directory_resolution_order(monkeypatch, tmp_path):
+    """Where the cache lives: JAX_COMPILATION_CACHE_DIR, then the
+    directory the caller passes (chip_smoke.py / bench.py --one: the fixed
+    <checkout>/.jax_cache), then the DL4J_TPU_COMPILE_CACHE_DIR dial. With
+    jax's own variable set the cache is placed from outside: no code
+    updates ``jax_compilation_cache_dir`` at all. jax's config and latch
+    are stubbed — the process-global cache state is not touched."""
+    import jax
+    from jax._src import compilation_cache as jcc
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setattr(jcc, "reset_cache", lambda: None)
+    monkeypatch.setattr(cc_cache, "_install_listener", lambda: None)
+    ext, fixed, dial = (str(tmp_path / n) for n in ("ext", "fixed", "dial"))
+    state = _cc_state()
+
+    def resolve(arg):
+        cc_cache._STATE["dir"] = None
+        updates.clear()
+        got = cc_cache.enable(arg)
+        return got, [v for k, v in updates
+                     if k == "jax_compilation_cache_dir"]
+
+    try:
+        monkeypatch.setenv(cc_cache.ENV_DIR, dial)
+        monkeypatch.setenv(cc_cache.JAX_ENV_DIR, ext)
+        assert resolve(fixed) == (ext, [])
+        assert ("jax_persistent_cache_min_compile_time_secs", 0) in updates
+        cc_cache._STATE["dir"] = None
+        assert cc_cache.maybe_enable() == ext       # the seam honours it too
+        monkeypatch.delenv(cc_cache.JAX_ENV_DIR)
+        assert resolve(fixed) == (fixed, [fixed])
+        assert resolve(None) == (dial, [dial])
+        monkeypatch.delenv(cc_cache.ENV_DIR)
+        assert resolve(None) == (None, [])
+        assert not os.path.exists(dial) or os.listdir(dial) == []
+    finally:
+        _restore(state)
 
 
 def test_jitwatch_splits_persistent_hits(monkeypatch):

@@ -350,10 +350,8 @@ def test_moe_sparse_dispatch_flops_drop():
     x = jnp.asarray(rng.normal(size=(n, F)), jnp.float32)
 
     def flops(impl):
-        from deeplearning4j_tpu.compat import cost_analysis
-
         fn = lambda params: impl.forward(params, {}, x, train=True)[0]
-        ca = cost_analysis(jax.jit(fn).lower(p).compile())
+        ca = jax.jit(fn).lower(p).compile().cost_analysis()
         return float(ca.get("flops", 0.0))
 
     fd, fs = flops(impl_d), flops(impl_s)
