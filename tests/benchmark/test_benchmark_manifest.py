@@ -1,0 +1,125 @@
+"""``BENCHMARK.json`` against the contract it was written to, and every cell's
+files found by the names in it: what the driver refuses before a single run,
+checked here at no chip time."""
+import json
+import os
+import re
+
+import pytest
+
+from benchmark import cells
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+MANIFEST = cells.load_manifest(ROOT)
+NAME = re.compile(r"[A-Za-z0-9][A-Za-z0-9_.\-]{0,63}\Z")
+#: a layer is a plain name too (it may start with '_'), and PERF.md §3 lists it
+LAYER = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}\Z")
+PERF_MD = open(os.path.join(ROOT, "PERF.md"), encoding="utf-8").read()
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+#: a width may never be listed as reduced
+WIDTH = re.compile(r"hidden|intermediate|latent|state|proj|_dim$|_rank$|head|"
+                   r"expan|experts_per|width")
+
+
+def test_top_level_keys_and_limits():
+    assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs",
+                             "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 << 10
+    assert 1 <= len(MANIFEST["paths"]) <= 16
+    assert len(MANIFEST["command"]) <= 32
+    assert MANIFEST["command"][1].startswith(MANIFEST["paths"][0] + "/")
+    rs = MANIFEST["run_seconds"]
+    assert isinstance(rs, int) and 1 <= rs <= 51
+    # a full check with all 24 cells has to fit into 43200 s
+    assert (2 + 14 * 24) * (rs + 60) + 24 * 180 + 1200 <= 43200
+    names = [x["name"] for key in ("configs", "workloads", "end_to_end",
+                                   "per_layer") for x in MANIFEST[key]]
+    assert all(NAME.match(n) for n in names), names
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        group = [x["name"] for x in MANIFEST[key]]
+        assert len(group) == len(set(group)), key
+
+
+def test_configs():
+    assert 1 <= len(MANIFEST["configs"]) <= 24
+    files = [c["file"] for c in MANIFEST["configs"]]
+    assert len(files) == len(set(files))
+    used = {w["config"] for w in MANIFEST["workloads"]}
+    for c in MANIFEST["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert c["name"] in used and len(c["why"]) <= 200
+        assert any(c["file"].startswith(p + "/") for p in MANIFEST["paths"])
+        doc = json.load(open(os.path.join(ROOT, c["file"])))
+        assert doc["name"] == c["name"] and doc["reduced"] == c["reduced"]
+        assert not [k for k in c["reduced"] if WIDTH.search(k)]
+
+
+def test_workloads():
+    cells_ = MANIFEST["workloads"]
+    assert 2 <= len(cells_) <= 24
+    pairs = [(w["config"], w["traffic"]) for w in cells_]
+    assert len(pairs) == len(set(pairs))
+    four = [w for w in cells_ if w["chips"] == 4]
+    assert len(four) <= max(1, len(cells_) // 4)
+    for w in cells_:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+
+
+def test_metrics():
+    e2e, per_layer = MANIFEST["end_to_end"], MANIFEST["per_layer"]
+    assert 1 <= len(e2e) <= 16 and 1 <= len(per_layer) <= 128
+    assert "setup_s" in {m["name"] for m in e2e}
+    known = {w["name"] for w in MANIFEST["workloads"]}
+    for m in e2e:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
+                                          "source"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    for m in per_layer:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+                                          "layer", "moves"}
+        assert m["source"] in SOURCES
+        assert LAYER.match(m["layer"]), m["layer"]
+        assert f"`{m['layer']}`" in PERF_MD, m["layer"]
+        assert m["moves"] in {x["name"] for x in e2e}
+        if m["name"].endswith("_roofline"):
+            assert m["unit"] == "%"
+    for m in e2e + per_layer:
+        assert m["better"] in ("higher", "lower")
+        assert set(m.get("workloads", [])) <= known
+
+
+@pytest.mark.parametrize("workload",
+                         [w["name"] for w in MANIFEST["workloads"]])
+def test_cell_files_resolve_by_name(workload):
+    cell = cells.load_cell(MANIFEST, ROOT, workload)
+    for rehearsal in (False, True):
+        c = cells.load_cell(MANIFEST, ROOT, workload, rehearse=rehearsal)
+        for key in ("batch", "feed", "pool", "entry", "run_ahead",
+                    "warmup_batches", "trace_batches"):
+            assert key in c.traffic, key
+        assert c.traffic["feed"] in ("resident", "hostfed")
+    # every cell reports setup_s, another end-to-end metric and a layer's
+    e2e = {m["name"] for m in cell.metrics["end_to_end"]}
+    assert "setup_s" in e2e and len(e2e) >= 2 and cell.metrics["per_layer"]
+    kind, _, what = cell.config["builder"].partition(":")
+    if kind == "zoo":
+        import deeplearning4j_tpu.models as zoo
+        assert hasattr(zoo, what)
+    else:
+        assert cells.module(*what.partition(":")[0].split(".")) is not None
+    for kind in ("reference", "opcount"):       # optional; named, they exist
+        if kind in cell.config:
+            assert cells.module(kind, cell.config[kind]) is not None
+    if cell.traffic["entry"] == "parallel_wrapper":
+        assert cell.chips == 4 and "one_device_seconds" in cell.traffic
+
+
+@pytest.mark.parametrize("metric",
+                         [m["name"] for m in MANIFEST["per_layer"]])
+def test_every_layer_metric_has_a_reader_of_its_name(metric):
+    reader = cells.module("layer_metrics", metric)
+    assert reader is not None and callable(reader.read)
+    assert reader.__doc__                  # says what it reads, and where
