@@ -148,6 +148,13 @@ def enable(cache_dir: Optional[str] = None) -> Optional[str]:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     if not from_jax_env:
         jax.config.update("jax_compilation_cache_dir", d)
+    # the program's scopes and kernel names are op metadata, which jax
+    # leaves out of the cache key by default: an entry compiled by a commit
+    # that named things otherwise (or not at all) then serves ITS names to
+    # this one's profiler traces and program texts. In the key, a trace
+    # shows the names of the code that ran; the price is that a checkout at
+    # another path, or with a line moved, compiles for itself.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     # jax latches its cache decision at the FIRST compile: a process
     # that already compiled anything (backend init, an eager net build)
     # before this call would silently keep the cache OFF for its whole

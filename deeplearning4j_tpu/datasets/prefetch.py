@@ -38,6 +38,10 @@ Monitor series (docs/OBSERVABILITY.md; all ride ``OP_TELEMETRY`` into
   blocked (the residual ETL the pipeline failed to hide).
 - ``input_bytes_total`` / ``input_batches_total`` counters — host bytes
   and batches fed through the pipeline.
+
+Spans, on the worker threads (``monitor/tracer.py``; host time):
+``input/transform`` (the user transform and the byte count) and
+``input/put_ahead`` (handing the batch to the runtime for transfer).
 """
 from __future__ import annotations
 
@@ -51,7 +55,7 @@ import numpy as np
 
 from .dataset import DataSet, DataSetIterator, MultiDataSet
 from .iterators import AsyncDataSetIterator
-from ..monitor import get_registry
+from ..monitor import get_registry, get_tracer
 from ..monitor.lockwatch import make_condition, make_lock
 
 log = logging.getLogger(__name__)
@@ -476,20 +480,25 @@ class PrefetchDataSetIterator(PrefetchIterator, DataSetIterator):
         return jnp.asarray(x)
 
     def _prepare(self, ds):
-        if self._user_transform is not None:
-            ds = self._user_transform(ds)
-        self._bytes_counter.inc(_host_nbytes(ds))
-        return ds
+        with get_tracer().span("input/transform", cat="input"):
+            if self._user_transform is not None:
+                ds = self._user_transform(ds)
+            self._bytes_counter.inc(_host_nbytes(ds))
+            return ds
 
     def _put_ahead(self, ds):
-        if self._cache_device and hasattr(ds, "device_arrays"):
-            # warm the base dataset's CacheMode.DEVICE cache ahead of the
-            # step; the fit loop's own device_arrays() call then hits it
-            ds.device_arrays()
+        # host time to hand the batch to the runtime, on the worker thread;
+        # the transfer itself is the runtime's and shows in the trace
+        with get_tracer().span("input/put_ahead", cat="input"):
+            if self._cache_device and hasattr(ds, "device_arrays"):
+                # warm the base dataset's CacheMode.DEVICE cache ahead of
+                # the step; the fit loop's own device_arrays() call then
+                # hits it
+                ds.device_arrays()
+                return ds
+            if isinstance(ds, (DataSet, MultiDataSet)):
+                return _device_view(ds, self._put)
             return ds
-        if isinstance(ds, (DataSet, MultiDataSet)):
-            return _device_view(ds, self._put)
-        return ds
 
     def batch(self):
         return self._base.batch()

@@ -48,23 +48,27 @@ across (``ParamServerMetrics``, ``PerformanceListener``/
   ``alerts_firing{rule=}`` gauge, ``GET /alerts``.
 
 The fit loops, transport channel, parameter-server client/server, and
-async dataset iterator are pre-instrumented against these globals. The
-per-iteration score fetch that instrumentation needs is a device→host
-VALUE fetch (the completion barrier rule from ``utils/profiling.py``);
-:func:`set_enabled` (False) turns the fit-loop instrumentation off for
-benchmarks that need maximally-async stepping with no listeners attached.
+async dataset iterator are pre-instrumented against these globals. A span
+is host time and holds no fetch (``monitor/tracer.py``): the fit loops'
+spans go to the profiler whatever :func:`set_enabled` says, and with the
+monitor on and no listener the per-iteration score reaches the registry and
+``/healthz`` through :class:`StepCompletions`, at most
+``StepCompletions.LAG`` steps after its dispatch and without the host ever
+waiting for the newest step. :func:`set_enabled` (False) turns the ring
+buffer and the fit loops' metric and health writes off.
 """
 from __future__ import annotations
 
-import os
-
+import collections
 import contextlib
+import time
 
 from .lockwatch import (InstrumentedLock, LockWatch, get_lockwatch,
                         make_lock, make_rlock, make_condition)
 from .registry import (MetricsRegistry, LatencyHistogram, Counter, Gauge,
                        Histogram, get_registry, render_prometheus_dump)
-from .tracer import SpanContext, Tracer, get_tracer, new_context
+from .tracer import (SpanContext, Tracer, enabled, get_tracer, new_context,
+                     set_enabled)
 from .health import (HealthState, get_health, TrainingHealthListener,
                      TrainingHealthError)
 from .flightrec import FlightRecorder, get_flight_recorder
@@ -109,42 +113,43 @@ __all__ = [
     "Incident", "IncidentRecorder", "get_incident_recorder",
     "abort_open_incidents", "load_bundle", "render_incident_text",
     "set_enabled", "enabled", "record_training_iteration", "step_span",
+    "spanned", "StepCompletions",
 ]
-
-#: fit-loop instrumentation switch — when False the containers skip the
-#: per-iteration value fetch (and all metric/health writes) unless
-#: listeners are attached, restoring fully-async dispatch. Defaults on
-#: (a bare fit populates /metrics and /healthz); flip per process with
-#: DL4J_TPU_MONITOR=0 or at runtime with set_enabled(False).
-_ENABLED = os.environ.get("DL4J_TPU_MONITOR", "1") not in ("0", "false", "")
-
-
-def set_enabled(value: bool):
-    global _ENABLED
-    _ENABLED = bool(value)
-
-
-def enabled() -> bool:
-    return _ENABLED
-
 
 @contextlib.contextmanager
 def step_span(iteration: int):
-    """The per-minibatch training span. The caller MUST perform its
-    device→host value fetch (``float(loss)``) inside this span so the span
-    measures the finished step, not its dispatch (value-fetch barrier rule,
-    ``utils/profiling.py``). Span close also samples the device-memory
-    gauges (throttled, AFTER the span ends so the sampling cost never
-    inflates the step duration) — the step boundary is where
-    donation/sharding decisions have just landed, so
-    ``device_memory_in_use_bytes`` tracks the working set step-by-step
-    (docs/OBSERVABILITY.md "Compilation & memory")."""
+    """The per-minibatch training span, around the DISPATCH of the jitted
+    step and nothing else: host time (``monitor/tracer.py``). Its
+    ``step_num`` makes it a ``StepTraceAnnotation``, so a profiler trace
+    groups the device ops it launched under it; the step's device time is
+    theirs. Span close also samples the device-memory gauges (throttled,
+    AFTER the span ends so the sampling cost never inflates the span) —
+    the step boundary is where donation/sharding decisions have just
+    landed, so ``device_memory_in_use_bytes`` tracks the working set
+    step-by-step (docs/OBSERVABILITY.md "Compilation & memory")."""
     try:
         with get_tracer().span("step", cat="train",
-                               iteration=int(iteration)) as ctx:
+                               step_num=int(iteration)) as ctx:
             yield ctx
     finally:
-        maybe_sample_device_memory()
+        if enabled():
+            maybe_sample_device_memory()
+
+
+def spanned(items, name: str, cat: str = "train"):
+    """``(item, seconds its pull took)`` for every item of ``items``, each
+    pull (the last, exhausted one too) under a span ``name``: the fit
+    loops' wait for the iterator, timed by the span itself (one clock)."""
+    items = iter(items)
+    tracer = get_tracer()
+    while True:
+        span = tracer.span(name, cat=cat)
+        with span:
+            try:
+                item = next(items)
+            except StopIteration:
+                return
+        yield item, span.seconds
 
 
 def record_training_iteration(model, iteration: int, score: float,
@@ -162,9 +167,72 @@ def record_training_iteration(model, iteration: int, score: float,
                     "examples consumed by fit").inc(batch_size)
     if step_ms is not None:
         reg.histogram("training_step_ms",
-                      "wall-clock per applied step, value-fetch "
-                      "barrier included").observe(step_ms)
+                      "interval between the completions of successive "
+                      "steps as the fit loop saw them (the first of a "
+                      "fit: since it began); input wait included"
+                      ).observe(step_ms)
     if etl_ms is not None:
         reg.histogram("training_etl_ms",
                       "host wait for the next minibatch").observe(etl_ms)
     get_health().record_iteration(iteration, score)
+
+
+class StepCompletions:
+    """What the fit loops know about steps they have dispatched and whose
+    loss they have not fetched yet. One per ``fit``; serves both containers
+    and the TBPTT path.
+
+    With listeners attached every step is resolved at once: a callback must
+    see the model as that step left it (``ParallelWrapper._resolve_score``
+    has the reasons). Without, a step is resolved once its loss
+    ``is_ready()``, or when more than :attr:`LAG` steps are pending, oldest
+    first, so the host never waits for the step it has just dispatched and
+    the device always has work queued behind the one waited for.
+    ``record_training_iteration``, ``/healthz`` liveness and NaN detection
+    therefore see step *n* at most ``LAG`` steps late; :meth:`drain` (epoch
+    end, halt, error path) brings them up to date. ``training_step_ms`` is
+    the interval between successive resolutions. The fetch happens under
+    the ``fit/resolve`` span, which is a wait and named as one."""
+
+    #: steps the host may run ahead of the newest loss it has fetched; the
+    #: benchmark's own ``run_ahead`` brake uses the same lag
+    LAG = 2
+
+    def __init__(self, model):
+        self._model = model
+        self._pending = collections.deque()
+        self._last_done = time.perf_counter()
+
+    def dispatched(self, loss, batch_size: int, etl_ms: float = None):
+        """Call after a step's dispatch, with ``iteration_count`` already
+        moved past it."""
+        model = self._model
+        if not (model.listeners or enabled()):
+            return
+        self._pending.append((loss, model.iteration_count - 1, batch_size,
+                              etl_ms))
+        self._resolve(all_of_them=bool(model.listeners))
+
+    def drain(self):
+        self._resolve(all_of_them=True)
+
+    def _resolve(self, all_of_them):
+        pending = self._pending
+
+        def due():
+            return pending and (all_of_them or len(pending) > self.LAG
+                                or pending[0][0].is_ready())
+
+        if not due():
+            return
+        with get_tracer().span("fit/resolve", cat="train"):
+            while due():
+                loss, iteration, batch_size, etl_ms = pending.popleft()
+                score = float(loss)     # device→host fetch: the wait
+                now = time.perf_counter()
+                record_training_iteration(
+                    self._model, iteration, score, batch_size=batch_size,
+                    step_ms=(now - self._last_done) * 1e3, etl_ms=etl_ms)
+                self._last_done = now
+                for lst in self._model.listeners:
+                    lst.iteration_done(self._model, iteration, score)

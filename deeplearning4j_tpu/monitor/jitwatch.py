@@ -1000,9 +1000,10 @@ def _pipeline_block(snap) -> Dict[str, Any]:
         step_total = step["mean_ms"] * step["n"]
         out["etl_ms_total"] = round(etl_total, 3)
         out["step_ms_total"] = round(step_total, 3)
-        if etl_total + step_total > 0:
-            out["etl_fraction"] = round(
-                etl_total / (etl_total + step_total), 4)
+        # training_step_ms is the interval between completions, input
+        # wait included: the fraction is etl over it (1 at most)
+        if step_total > 0:
+            out["etl_fraction"] = round(min(1.0, etl_total / step_total), 4)
     return out
 
 

@@ -9,13 +9,22 @@ dump in Perfetto / ``chrome://tracing``. When jax is importable, every span
 also nests a ``jax.profiler.TraceAnnotation`` so host spans line up with
 device traces captured through ``utils.profiling.trace``.
 
-Timing honesty: jitted dispatch is asynchronous, so a span around a bare
-dispatch measures *dispatch*, not the step. A span means the step only
-when a completion barrier sits inside it — ``jax.block_until_ready`` or a
-device→host value fetch (``float(loss)`` / ``np.asarray``). The fit-loop
-instrumentation keeps its ``float(loss)`` fetch INSIDE the step span for
-exactly this reason; spans you place around your own jitted calls must
-close on a barrier of their own to mean anything.
+One span, two sinks. The ``TraceAnnotation`` is entered on EVERY span: it
+costs ~0.3 µs while no profiler session runs and lands the span on the
+profiler's clock, next to the device's ops, while one does — whatever the
+monitor switch says. The ring-buffer event (two ids, a dict, a lock) is
+written only while ``monitor.enabled()``; that switch is the one there is.
+
+The rule: a span is HOST time. Dispatch is asynchronous, so a span around a
+jitted call measures its dispatch, and that is what it is for: the host work
+needed to keep the device fed. Never put a device→host fetch
+(``float(loss)``, ``np.asarray``, ``block_until_ready``) in a span to make
+it "mean the step": that serialises host and device (−14 % on ResNet50,
+PERF.md §6). Device time comes from the device trace (the ops that ran
+under the ``step`` span's ``step_num``), or, live, from the lagged
+completion the fit loops record (``monitor.StepCompletions``). The two
+spans that ARE waits say so by name, ``fit/resolve`` and
+``pw/resolve_score``, and no metric counts them as host work.
 
 Trace-context propagation: every span carries a ``trace_id`` shared with
 its whole causal chain and a fresh ``span_id``; :meth:`Tracer.current_span`
@@ -27,7 +36,6 @@ one chain across processes (docs/OBSERVABILITY.md "Fleet observability").
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import os
 import random
@@ -36,7 +44,26 @@ import time
 from collections import deque
 from typing import Dict, List, NamedTuple, Optional
 
-__all__ = ["SpanContext", "Tracer", "get_tracer", "new_context"]
+__all__ = ["SpanContext", "Tracer", "get_tracer", "new_context",
+           "set_enabled", "enabled"]
+
+#: the monitor's one switch (``monitor.set_enabled`` / ``monitor.enabled``;
+#: it lives here because the tracer is the lowest module that reads it).
+#: While False no span writes the ring buffer and the fit loops skip their
+#: metric and health writes unless listeners are attached; the profiler
+#: annotations are not switched. Defaults on (a bare fit populates /metrics,
+#: /healthz and /trace); flip per process with DL4J_TPU_MONITOR=0 or at
+#: runtime with set_enabled(False).
+_ENABLED = os.environ.get("DL4J_TPU_MONITOR", "1") not in ("0", "false", "")
+
+
+def set_enabled(value: bool):
+    global _ENABLED
+    _ENABLED = bool(value)
+
+
+def enabled() -> bool:
+    return _ENABLED
 
 
 class SpanContext(NamedTuple):
@@ -81,6 +108,56 @@ _UNRESOLVED = object()
 _ANNOTATION = _UNRESOLVED
 
 
+class _Span:
+    """The context manager :meth:`Tracer.span` returns."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_parent", "_args", "_ann",
+                 "_ctx", "_stack", "_start", "seconds")
+
+    def __init__(self, tracer, name, cat, parent, step_num, args):
+        self._tracer, self._name, self._cat = tracer, name, cat
+        self._parent, self._args = parent, args
+        self.seconds = None
+        ann_cls = _trace_annotation()
+        if ann_cls is None:
+            self._ann = None
+        elif step_num is None:
+            self._ann = ann_cls(name)
+        else:       # what jax.profiler.StepTraceAnnotation adds to the name
+            self._ann = ann_cls(name, _r=1, step_num=step_num)
+            args["step_num"] = step_num
+
+    def __enter__(self) -> SpanContext:
+        if self._ann is not None:
+            self._ann.__enter__()
+        stack = self._stack = self._tracer._stack()
+        up = self._parent if self._parent is not None else (
+            stack[-1] if stack else None)
+        self._ctx = SpanContext(up.trace_id if up else _new_id(), _new_id(),
+                                up.span_id if up else 0)
+        stack.append(self._ctx)
+        self._start = time.perf_counter()
+        return self._ctx
+
+    def __exit__(self, *exc):
+        self.seconds = time.perf_counter() - self._start
+        tracer, ctx = self._tracer, self._ctx
+        self._stack.pop()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if _ENABLED:
+            ev = {"name": self._name, "cat": self._cat, "ph": "X",
+                  "ts": (self._start - tracer._t0) * 1e6,
+                  "dur": self.seconds * 1e6,
+                  "pid": os.getpid(), "tid": threading.get_ident()}
+            ev["args"] = {"trace_id": f"{ctx.trace_id:x}",
+                          "span_id": f"{ctx.span_id:x}", **self._args}
+            if ctx.parent_span_id:
+                ev["args"]["parent_span_id"] = f"{ctx.parent_span_id:x}"
+            tracer._append(ev)
+        return False
+
+
 class Tracer:
     """Bounded ring buffer of completed host spans.
 
@@ -114,39 +191,23 @@ class Tracer:
         stack = self._stack()
         return stack[-1] if stack else None
 
-    @contextlib.contextmanager
     def span(self, name: str, cat: str = "host",
-             parent: Optional[SpanContext] = None, **args):
-        """Record one span around the enclosed block; yields the span's
-        :class:`SpanContext`. ``args`` become the trace event's ``args``
-        (must be JSON-serializable scalars). The trace/parent IDs come from
-        the innermost open span on this thread, or from ``parent`` — pass a
-        context that arrived over the wire to join a REMOTE trace."""
-        ann_cls = _trace_annotation()
-        ann = ann_cls(name) if ann_cls is not None else None
-        if ann is not None:
-            ann.__enter__()
-        stack = self._stack()
-        up = parent if parent is not None else (stack[-1] if stack else None)
-        ctx = SpanContext(up.trace_id if up else _new_id(), _new_id(),
-                          up.span_id if up else 0)
-        stack.append(ctx)
-        start = time.perf_counter()
-        try:
-            yield ctx
-        finally:
-            dur = time.perf_counter() - start
-            stack.pop()
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            ev = {"name": name, "cat": cat, "ph": "X",
-                  "ts": (start - self._t0) * 1e6, "dur": dur * 1e6,
-                  "pid": os.getpid(), "tid": threading.get_ident()}
-            ev["args"] = {"trace_id": f"{ctx.trace_id:x}",
-                          "span_id": f"{ctx.span_id:x}", **args}
-            if ctx.parent_span_id:
-                ev["args"]["parent_span_id"] = f"{ctx.parent_span_id:x}"
-            self._append(ev)
+             parent: Optional[SpanContext] = None,
+             step_num: Optional[int] = None, **args) -> "_Span":
+        """One span of HOST time around the enclosed block: ``with
+        tracer.span(...) as ctx`` yields the span's :class:`SpanContext`,
+        and the returned object holds the span's ``seconds`` once it has
+        closed (the fit loops time their input wait with it: one clock).
+        Always enters the profiler annotation (recorded only while a
+        profiler session runs); writes the ring-buffer event only while
+        ``monitor.enabled()``. ``args`` become the ring event's ``args``
+        (must be JSON-serializable scalars). ``step_num`` makes the
+        annotation a ``StepTraceAnnotation``, so the profiler's own tools
+        group device ops by step; the ring event carries it among its
+        ``args``. The trace/parent IDs come from the innermost open span on
+        this thread, or from ``parent`` — pass a context that arrived over
+        the wire to join a REMOTE trace."""
+        return _Span(self, name, cat, parent, step_num, args)
 
     def record_complete(self, name: str, start: float, dur: float,
                         cat: str = "host",
@@ -159,7 +220,10 @@ class Tracer:
         parents a request's queue-wait span under the REQUEST's context,
         not the scheduler thread's), else under the innermost OPEN span on
         this thread (a compile detected mid-step nests under the step
-        span); either way it does not touch the context stack itself."""
+        span); either way it does not touch the context stack itself.
+        Like every write to the ring, only while ``monitor.enabled()``."""
+        if not _ENABLED:
+            return
         up = parent if parent is not None else self.current_span()
         ctx = SpanContext(up.trace_id if up else _new_id(), _new_id(),
                           up.span_id if up else 0)

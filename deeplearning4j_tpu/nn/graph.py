@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import contextlib
 import logging
-import time
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -72,8 +71,8 @@ class ComputationGraph:
         self.epoch_count = 0
         self.listeners: List = []
         self.score_ = float("nan")
-        self.last_etl_ms = 0.0
         self.halt_requested = False  # TrainingHealthListener "halt" action
+        self._completions = _mon.StepCompletions(self)   # fit starts its own
         self._rng = None
         self._jit_step = None
         self._jit_ext_step = None
@@ -152,19 +151,24 @@ class ComputationGraph:
             in_names = conf.vertex_inputs[name]
             xs = [acts[i] for i in in_names]
             if isinstance(v, Layer):
-                x = xs[0]
-                pre = conf.input_preprocessors.get(name)
-                if pre is not None:
-                    x = pre(x, ctx)
                 # propagate the mask of the (single) input chain
                 m = masks.get(in_names[0])
                 impl = self.impls[name]
-                p_n = impl.noised_params(params[name], train, keys.get(name))
-                y, ns = impl.forward(p_n, states[name], x, train=train,
-                                     rng=keys.get(name), mask=m, ctx=ctx)
-                if impl.save_output:
-                    # tag for the remat policy (identity outside jax.checkpoint)
-                    y = checkpoint_name(y, "dl4j_act")
+                # the vertex's name names its ops in the device trace
+                # (op_name metadata only: the compiled program is the same)
+                with jax.named_scope(name):
+                    x = xs[0]
+                    pre = conf.input_preprocessors.get(name)
+                    if pre is not None:
+                        x = pre(x, ctx)
+                    p_n = impl.noised_params(params[name], train,
+                                             keys.get(name))
+                    y, ns = impl.forward(p_n, states[name], x, train=train,
+                                         rng=keys.get(name), mask=m, ctx=ctx)
+                    if impl.save_output:
+                        # tag for the remat policy (identity outside
+                        # jax.checkpoint)
+                        y = checkpoint_name(y, "dl4j_act")
                 new_states[name] = ns
                 acts[name] = y
                 masks[name] = m
@@ -173,7 +177,9 @@ class ComputationGraph:
                 # vertices (ElementWise/Merge) carry the residual spine, and
                 # an unsaved spine would recompute-chain through every
                 # upstream block during the backward pass
-                acts[name] = checkpoint_name(v.forward(xs, ctx), "dl4j_act")
+                with jax.named_scope(name):
+                    acts[name] = checkpoint_name(v.forward(xs, ctx),
+                                                 "dl4j_act")
                 masks[name] = v.propagate_mask([masks.get(i) for i in in_names])
         return acts, new_states, masks, ctx
 
@@ -198,14 +204,17 @@ class ComputationGraph:
                 x = pre(x, ctx)
             mask = lm if lm is not None else (masks.get(in_name) if x.ndim == 3
                                               else None)
-            total = total + impl.loss_on(params[out_name], states[out_name], x,
-                                         lbl, mask=mask, train=train, rng=rng)
+            with jax.named_scope("loss"):
+                total = total + impl.loss_on(params[out_name],
+                                             states[out_name], x, lbl,
+                                             mask=mask, train=train, rng=rng)
             if hasattr(impl, "update_state"):
                 xs = jax.lax.stop_gradient(x)
                 new_states[out_name] = impl.update_state(states[out_name], xs, lbl)
         reg = 0.0
-        for name, impl in self.impls.items():
-            reg = reg + impl.regularization(params[name])
+        with jax.named_scope("loss"):
+            for name, impl in self.impls.items():
+                reg = reg + impl.regularization(params[name])
         aux = ctx.get("aux_loss", 0.0)  # e.g. MoE load balancing
         return total + reg + aux, (new_states, ctx.get("rnn_state_out"))
 
@@ -236,10 +245,12 @@ class ComputationGraph:
             if grads_reduce is not None:
                 grads, loss, new_states = grads_reduce(grads, loss,
                                                        new_states)
-            if not minimize:
-                grads = _tm(lambda g: -g, grads)
-            grads = normalize_gradients(grads, gn_mode, gn_thresh)
-            updates, new_upd = self.updater.apply(upd_state, grads, iteration)
+            with jax.named_scope("updater"):
+                if not minimize:
+                    grads = _tm(lambda g: -g, grads)
+                grads = normalize_gradients(grads, gn_mode, gn_thresh)
+                updates, new_upd = self.updater.apply(upd_state, grads,
+                                                      iteration)
             return updates, new_states, new_upd, loss, rnn_out
 
         return core
@@ -252,8 +263,10 @@ class ComputationGraph:
             updates, new_states, new_upd, loss, rnn_out = core(
                 params, states, upd_state, iteration, rng, inputs, labels,
                 input_masks, label_masks, rnn_state_in)
-            new_params = _tm(lambda p, u: p - u.astype(p.dtype), params, updates)
-            new_params = self._apply_constraints(new_params)
+            with jax.named_scope("updater"):
+                new_params = _tm(lambda p, u: p - u.astype(p.dtype), params,
+                                 updates)
+                new_params = self._apply_constraints(new_params)
             if with_rnn_state:
                 rnn_out = (_tm(jax.lax.stop_gradient, rnn_out)
                            if rnn_out else rnn_out)
@@ -346,19 +359,18 @@ class ComputationGraph:
         # halt would silently truncate every later fit to a single batch
         self.halt_requested = False
         _mon.get_health().clear_halt()
+        done = self._completions = _mon.StepCompletions(self)
         try:
             for _ in range(epochs):
                 for lst in self.listeners:
                     lst.on_epoch_start(self, self.epoch_count)
                 with _mon.get_tracer().span("epoch", cat="train",
                                             epoch=self.epoch_count):
-                    t_etl = time.perf_counter()
-                    for ds in it:
-                        self.last_etl_ms = (time.perf_counter() - t_etl) * 1e3
-                        self._fit_batch(ds)
+                    for ds, waited in _mon.spanned(it, "fit/next_batch"):
+                        self._fit_batch(ds, etl_ms=waited * 1e3)
                         if self.halt_requested:
                             break
-                        t_etl = time.perf_counter()
+                    done.drain()
                 for lst in self.listeners:
                     lst.on_epoch_end(self, self.epoch_count)
                 self.epoch_count += 1
@@ -372,6 +384,10 @@ class ComputationGraph:
             # before the exception unwinds out of fit
             from ..optimize.listeners import dispatch_training_error
             dispatch_training_error(self, self.listeners, e)
+            # the steps dispatched before the failure still count; a fetch
+            # that fails in turn must not hide ``e``
+            with contextlib.suppress(Exception):
+                done.drain()
             raise
         finally:
             if own_pipeline:
@@ -385,10 +401,37 @@ class ComputationGraph:
                             None if ds.features_mask is None else [ds.features_mask],
                             None if ds.labels_mask is None else [ds.labels_mask])
 
-    def _fit_batch(self, ds, single_iteration=False):
+    def _fit_batch(self, ds, single_iteration=False, etl_ms=None):
         """One minibatch. ``single_iteration=True`` applies exactly ONE
         optimizer update even under ``iterations(n)`` (ParallelWrapper
         tail-batch fallback — see MultiLayerNetwork._fit_batch)."""
+        with _mon.get_tracer().span("fit/prepare", cat="train"):
+            inputs, labels, fms, lms = self._batch_streams(ds)
+            self.last_batch_size = int(inputs[0].shape[0])
+            tbptt = (self.conf.backprop_type == BackpropType.TruncatedBPTT
+                     and all(x.ndim == 3 for x in inputs)
+                     and inputs[0].shape[1] > self.conf.tbptt_fwd_length)
+            if not tbptt:
+                step = self._ensure_step(single_iteration=single_iteration)
+                it = jnp.asarray(self.iteration_count, jnp.int32)
+                rng = self._next_rng()
+        if tbptt:
+            self._fit_tbptt(inputs, labels, fms, lms,
+                            single_iteration=single_iteration)
+            return
+        # dispatch only: a span is host time, the fetch is StepCompletions'
+        with _mon.step_span(self.iteration_count):
+            self.params, self.states, self.updater_state, loss = step(
+                self.params, self.states, self.updater_state, it, rng,
+                inputs, labels, fms, lms)
+        self.score_ = loss
+        self.iteration_count += (1 if single_iteration
+                                 else _n_iterations(self.gc))
+        self._completions.dispatched(loss, self.last_batch_size, etl_ms)
+
+    def _batch_streams(self, ds):
+        """``(inputs, labels, feature masks, label masks)`` of ``ds`` as the
+        step takes them: tuples of device arrays, masks None where absent."""
         if isinstance(ds, DataSet):
             if self.gc.cache_mode == CacheMode.DEVICE:
                 # cache on the CALLER's DataSet — _as_multi builds a fresh
@@ -404,55 +447,20 @@ class ComputationGraph:
                       else jnp.asarray(ds.features_mask))
                 lm = (None if ds.labels_mask is None
                       else jnp.asarray(ds.labels_mask))
-            inputs, labels = (f,), (l,)
-            fms = None if fm is None else (fm,)
-            lms = None if lm is None else (lm,)
-        elif self.gc.cache_mode == CacheMode.DEVICE:
-            inputs, labels, fms, lms = self._as_multi(ds).device_arrays()
-        else:
-            mds = self._as_multi(ds)
-            inputs = tuple(jnp.asarray(f) for f in mds.features)
-            labels = tuple(jnp.asarray(l) for l in mds.labels)
-            fms = (None if mds.features_masks is None
-                   else tuple(None if m is None else jnp.asarray(m)
-                              for m in mds.features_masks))
-            lms = (None if mds.labels_masks is None
-                   else tuple(None if m is None else jnp.asarray(m)
-                              for m in mds.labels_masks))
-        if (self.conf.backprop_type == BackpropType.TruncatedBPTT
-                and all(x.ndim == 3 for x in inputs)
-                and inputs[0].shape[1] > self.conf.tbptt_fwd_length):
-            self._fit_tbptt(inputs, labels, fms, lms,
-                            single_iteration=single_iteration)
-            return
-        step = self._ensure_step(single_iteration=single_iteration)
-        it = jnp.asarray(self.iteration_count, jnp.int32)
-        self.last_batch_size = int(inputs[0].shape[0])
-        observe = bool(self.listeners) or _mon.enabled()
-        score = None
-        t0 = time.perf_counter()
-        # span only when observing: without the float(loss) barrier inside
-        # it, a span would record dispatch time and be worse than no data
-        with (_mon.step_span(self.iteration_count) if observe
-              else contextlib.nullcontext()):
-            self.params, self.states, self.updater_state, loss = step(
-                self.params, self.states, self.updater_state, it,
-                self._next_rng(), inputs, labels, fms, lms)
-            if observe:
-                # device→host VALUE fetch: the completion barrier that makes
-                # the span (and step_ms) measure the step, not its dispatch
-                score = float(loss)
-        self.score_ = loss
-        self.iteration_count += (1 if single_iteration
-                                 else _n_iterations(self.gc))
-        if observe:
-            _mon.record_training_iteration(
-                self, self.iteration_count - 1, score,
-                batch_size=self.last_batch_size,
-                step_ms=(time.perf_counter() - t0) * 1e3,
-                etl_ms=self.last_etl_ms)
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration_count - 1, score)
+            return ((f,), (l,), None if fm is None else (fm,),
+                    None if lm is None else (lm,))
+        if self.gc.cache_mode == CacheMode.DEVICE:
+            return self._as_multi(ds).device_arrays()
+        mds = self._as_multi(ds)
+        inputs = tuple(jnp.asarray(f) for f in mds.features)
+        labels = tuple(jnp.asarray(l) for l in mds.labels)
+        fms = (None if mds.features_masks is None
+               else tuple(None if m is None else jnp.asarray(m)
+                          for m in mds.features_masks))
+        lms = (None if mds.labels_masks is None
+               else tuple(None if m is None else jnp.asarray(m)
+                          for m in mds.labels_masks))
+        return inputs, labels, fms, lms
 
     def _ensure_tbptt_scan_step(self, single_iteration=False):
         cache = getattr(self, "_jit_tbptt_scan", None)

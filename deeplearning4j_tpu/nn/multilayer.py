@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import contextlib
 import logging
-import time
 from functools import partial
 from typing import List, Optional
 
@@ -145,12 +144,16 @@ def _run_tbptt(net, f, l, fm, lm, single_iteration):
                     else jnp.broadcast_to(x, (S,) + x.shape))
 
         scan_step = net._ensure_tbptt_scan_step(single_iteration)
-        it0 = jnp.asarray(net.iteration_count, jnp.int32)
-        (net.params, net.states, net.updater_state, loss) = scan_step(
-            net.params, net.states, net.updater_state, it0, net._next_rng(),
-            _map_streams(stack, f), _map_streams(stack_lbl, l),
-            _map_streams(stack, fm), _map_streams(stack, lm),
-            net._init_rnn_state(b))
+        with _mon.get_tracer().span("fit/prepare", cat="train"):
+            it0 = jnp.asarray(net.iteration_count, jnp.int32)
+            rng = net._next_rng()
+            streams = (_map_streams(stack, f), _map_streams(stack_lbl, l),
+                       _map_streams(stack, fm), _map_streams(stack, lm))
+            rnn0 = net._init_rnn_state(b)
+        with _mon.step_span(net.iteration_count):
+            (net.params, net.states, net.updater_state, loss) = scan_step(
+                net.params, net.states, net.updater_state, it0, rng,
+                *streams, rnn0)
         # one iteration per TBPTT segment × iterations(n) applied per
         # segment (reference increments iterationCount per applied update,
         # so Adam bias correction and lr schedules see each one)
@@ -160,23 +163,22 @@ def _run_tbptt(net, f, l, fm, lm, single_iteration):
         rnn_state = net._init_rnn_state(int(first.shape[0]))
         for start in range(0, T, L):
             sl = slice(start, min(start + L, T))
-            it = jnp.asarray(net.iteration_count, jnp.int32)
-            (net.params, net.states, net.updater_state, loss,
-             rnn_state) = step(
-                net.params, net.states, net.updater_state, it,
-                net._next_rng(),
-                _map_streams(lambda x: x[:, sl], f),
-                _map_streams(lambda x: x[:, sl] if x.ndim == 3 else x, l),
-                _map_streams(lambda x: x[:, sl], fm),
-                _map_streams(lambda x: x[:, sl], lm), rnn_state)
+            with _mon.get_tracer().span("fit/prepare", cat="train"):
+                it = jnp.asarray(net.iteration_count, jnp.int32)
+                rng = net._next_rng()
+                streams = (
+                    _map_streams(lambda x: x[:, sl], f),
+                    _map_streams(lambda x: x[:, sl] if x.ndim == 3 else x, l),
+                    _map_streams(lambda x: x[:, sl], fm),
+                    _map_streams(lambda x: x[:, sl], lm))
+            with _mon.step_span(net.iteration_count):
+                (net.params, net.states, net.updater_state, loss,
+                 rnn_state) = step(
+                    net.params, net.states, net.updater_state, it, rng,
+                    *streams, rnn_state)
             net.iteration_count += n_applied
     net.score_ = loss
-    if net.listeners or _mon.enabled():
-        score = float(loss)  # device→host value fetch: completion barrier
-        _mon.record_training_iteration(net, net.iteration_count - 1, score,
-                                       batch_size=int(first.shape[0]))
-        for lst in net.listeners:
-            lst.iteration_done(net, net.iteration_count - 1, score)
+    net._completions.dispatched(loss, int(first.shape[0]))
 
 
 class MultiLayerNetwork:
@@ -193,8 +195,8 @@ class MultiLayerNetwork:
         self.listeners: List = []
         self.score_ = float("nan")
         self.last_batch_size = 0
-        self.last_etl_ms = 0.0
         self.halt_requested = False  # TrainingHealthListener "halt" action
+        self._completions = _mon.StepCompletions(self)   # fit starts its own
         self._rng = None
         self._jit_step = None
         self._jit_tbptt_step = None
@@ -282,16 +284,21 @@ class MultiLayerNetwork:
             # take the per-layer path below unchanged.
             if (i + 1 < end and self.conf.preprocessor(i + 1) is None
                     and self._lstm_pair_fusable(i, x, fmask, train)):
-                x = self._fused_lstm_forward(params, x, train, keys[i],
-                                             ctx, i)
+                with jax.named_scope(str(i)):    # the pair, under its first
+                    x = self._fused_lstm_forward(params, x, train, keys[i],
+                                                 ctx, i)
                 i += 2
                 continue
-            p_i = impl.noised_params(params[str(i)], train, keys[i])
-            x, ns = impl.forward(p_i, states[str(i)], x, train=train,
-                                 rng=keys[i], mask=fmask, ctx=ctx)
-            if impl.save_output:
-                # tag for the remat policy (identity outside jax.checkpoint)
-                x = checkpoint_name(x, "dl4j_act")
+            # the layer's index names its ops in the device trace (op_name
+            # metadata only: the compiled program is the same)
+            with jax.named_scope(str(i)):
+                p_i = impl.noised_params(params[str(i)], train, keys[i])
+                x, ns = impl.forward(p_i, states[str(i)], x, train=train,
+                                     rng=keys[i], mask=fmask, ctx=ctx)
+                if impl.save_output:
+                    # tag for the remat policy (identity outside
+                    # jax.checkpoint)
+                    x = checkpoint_name(x, "dl4j_act")
             new_states[str(i)] = ns
             i += 1
         return x, new_states, ctx
@@ -396,16 +403,18 @@ class MultiLayerNetwork:
         if not hasattr(out_impl, "loss_on"):
             raise ValueError(f"Last layer {type(out_impl).__name__} is not an "
                              f"output layer")
-        loss = out_impl.loss_on(params[str(n - 1)], states[str(n - 1)], x, l,
-                                mask=mask, train=train, rng=rng)
+        with jax.named_scope("loss"):
+            loss = out_impl.loss_on(params[str(n - 1)], states[str(n - 1)],
+                                    x, l, mask=mask, train=train, rng=rng)
         if hasattr(out_impl, "update_state"):
             # e.g. CenterLossOutputLayer EMA centers — updated outside AD
             xs = jax.lax.stop_gradient(x)
             new_states[str(n - 1)] = out_impl.update_state(states[str(n - 1)],
                                                            xs, l)
         reg = 0.0
-        for i, impl in enumerate(self.impls):
-            reg = reg + impl.regularization(params[str(i)])
+        with jax.named_scope("loss"):
+            for i, impl in enumerate(self.impls):
+                reg = reg + impl.regularization(params[str(i)])
         # activation-dependent auxiliary losses (e.g. MoE load balancing)
         # accumulate in ctx during the forward pass
         aux = ctx.get("aux_loss", 0.0)
@@ -446,10 +455,12 @@ class MultiLayerNetwork:
             if grads_reduce is not None:
                 grads, loss, new_states = grads_reduce(grads, loss,
                                                        new_states)
-            if not minimize:
-                grads = _tm(lambda g: -g, grads)
-            grads = normalize_gradients(grads, gn_mode, gn_thresh)
-            updates, new_upd = self.updater.apply(upd_state, grads, iteration)
+            with jax.named_scope("updater"):
+                if not minimize:
+                    grads = _tm(lambda g: -g, grads)
+                grads = normalize_gradients(grads, gn_mode, gn_thresh)
+                updates, new_upd = self.updater.apply(upd_state, grads,
+                                                      iteration)
             return updates, new_states, new_upd, loss, rnn_out
 
         return core
@@ -466,9 +477,10 @@ class MultiLayerNetwork:
             updates, new_states, new_upd, loss, rnn_out = core(
                 params, states, upd_state, iteration, rng, f, l, fm, lm,
                 rnn_state_in)
-            new_params = _tm(lambda p, u: p - u.astype(p.dtype), params,
-                             updates)
-            new_params = self._apply_constraints(new_params)
+            with jax.named_scope("updater"):
+                new_params = _tm(lambda p, u: p - u.astype(p.dtype), params,
+                                 updates)
+                new_params = self._apply_constraints(new_params)
             if with_rnn_state:
                 rnn_out = _tm(jax.lax.stop_gradient, rnn_out) if rnn_out else rnn_out
                 return new_params, new_states, new_upd, loss, rnn_out
@@ -589,19 +601,18 @@ class MultiLayerNetwork:
         # halt would silently truncate every later fit to a single batch
         self.halt_requested = False
         _mon.get_health().clear_halt()
+        done = self._completions = _mon.StepCompletions(self)
         try:
             for epoch in range(epochs):
                 for lst in self.listeners:
                     lst.on_epoch_start(self, self.epoch_count)
                 with _mon.get_tracer().span("epoch", cat="train",
                                             epoch=self.epoch_count):
-                    t_etl = time.perf_counter()
-                    for ds in it:
-                        self.last_etl_ms = (time.perf_counter() - t_etl) * 1e3
-                        self._fit_batch(ds)
+                    for ds, waited in _mon.spanned(it, "fit/next_batch"):
+                        self._fit_batch(ds, etl_ms=waited * 1e3)
                         if self.halt_requested:
                             break
-                        t_etl = time.perf_counter()
+                    done.drain()
                 for lst in self.listeners:
                     lst.on_epoch_end(self, self.epoch_count)
                 self.epoch_count += 1
@@ -615,58 +626,52 @@ class MultiLayerNetwork:
             # before the exception unwinds out of fit
             from ..optimize.listeners import dispatch_training_error
             dispatch_training_error(self, self.listeners, e)
+            # the steps dispatched before the failure still count; a fetch
+            # that fails in turn must not hide ``e``
+            with contextlib.suppress(Exception):
+                done.drain()
             raise
         finally:
             if own_pipeline:
                 it.shutdown()   # no prefetch worker outlives its fit
         return self
 
-    def _fit_batch(self, ds: DataSet, single_iteration=False):
+    def _fit_batch(self, ds: DataSet, single_iteration=False, etl_ms=None):
         """One minibatch. ``single_iteration=True`` applies exactly ONE
         optimizer update even when ``iterations(n)`` scans are configured —
         the ParallelWrapper tail-batch fallback needs update-count parity
-        with its sharded dispatches (masks and TBPTT routing preserved)."""
-        if self.gc.cache_mode == CacheMode.DEVICE:
-            f, l, fm, lm = ds.device_arrays()
-        else:
-            f = jnp.asarray(ds.features)
-            l = jnp.asarray(ds.labels)
-            fm = (None if ds.features_mask is None
-                  else jnp.asarray(ds.features_mask))
-            lm = (None if ds.labels_mask is None
-                  else jnp.asarray(ds.labels_mask))
-        self.last_batch_size = int(f.shape[0])
-        if (self.conf.backprop_type == BackpropType.TruncatedBPTT and f.ndim == 3
-                and f.shape[1] > self.conf.tbptt_fwd_length):
+        with its sharded dispatches (masks and TBPTT routing preserved).
+        ``etl_ms``: what ``fit`` waited for ``ds`` (``fit/next_batch``)."""
+        with _mon.get_tracer().span("fit/prepare", cat="train"):
+            if self.gc.cache_mode == CacheMode.DEVICE:
+                f, l, fm, lm = ds.device_arrays()
+            else:
+                f = jnp.asarray(ds.features)
+                l = jnp.asarray(ds.labels)
+                fm = (None if ds.features_mask is None
+                      else jnp.asarray(ds.features_mask))
+                lm = (None if ds.labels_mask is None
+                      else jnp.asarray(ds.labels_mask))
+            self.last_batch_size = int(f.shape[0])
+            tbptt = (self.conf.backprop_type == BackpropType.TruncatedBPTT
+                     and f.ndim == 3
+                     and f.shape[1] > self.conf.tbptt_fwd_length)
+            if not tbptt:
+                step = self._ensure_step(single_iteration=single_iteration)
+                it = jnp.asarray(self.iteration_count, jnp.int32)
+                rng = self._next_rng()
+        if tbptt:
             self._fit_tbptt(f, l, fm, lm, single_iteration=single_iteration)
             return
-        step = self._ensure_step(single_iteration=single_iteration)
-        it = jnp.asarray(self.iteration_count, jnp.int32)
-        observe = bool(self.listeners) or _mon.enabled()
-        score = None
-        t0 = time.perf_counter()
-        # span only when observing: without the float(loss) barrier inside
-        # it, a span would record dispatch time and be worse than no data
-        with (_mon.step_span(self.iteration_count) if observe
-              else contextlib.nullcontext()):
+        # dispatch only: a span is host time, the fetch is StepCompletions'
+        with _mon.step_span(self.iteration_count):
             self.params, self.states, self.updater_state, loss = step(
-                self.params, self.states, self.updater_state, it,
-                self._next_rng(), f, l, fm, lm)
-            if observe:
-                # device→host VALUE fetch: the completion barrier that makes
-                # the span (and step_ms) measure the step, not its dispatch
-                score = float(loss)
+                self.params, self.states, self.updater_state, it, rng,
+                f, l, fm, lm)
         self.score_ = loss
         self.iteration_count += (1 if single_iteration
                                  else _n_iterations(self.gc))
-        if observe:
-            _mon.record_training_iteration(
-                self, self.iteration_count - 1, score,
-                batch_size=self.last_batch_size,
-                step_ms=(time.perf_counter() - t0) * 1e3,
-                etl_ms=self.last_etl_ms)
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration_count - 1, score)
+        self._completions.dispatched(loss, self.last_batch_size, etl_ms)
 
     def _fit_tbptt(self, f, l, fm, lm, single_iteration=False):
         """Truncated BPTT (reference ``doTruncatedBPTT``): split time into

@@ -292,6 +292,7 @@ def _fwd(q, k, v, km, seed, causal, scale, rate):
         scratch_shapes=[_scratch((blk, 8)), _scratch((blk, 8)),
                         _scratch((blk, d))],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*operands)
 
 
@@ -449,6 +450,7 @@ def dq_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[_scratch((blk, d))],
         interpret=_interpret(),
+        name="flash_dq",
     )(*ops)
 
 
@@ -496,6 +498,7 @@ def dkv_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         scratch_shapes=[_scratch((blk, d)), _scratch((blk, d))],
         interpret=_interpret(),
+        name="flash_dkv",
     )(*ops)
 
 

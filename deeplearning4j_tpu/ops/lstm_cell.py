@@ -224,6 +224,7 @@ def _fwd(xp, rw, peep, h0, c0, mask, save_reserve=True):
         out_shape=tuple(out_shape),
         scratch_shapes=[_scratch((b, H)), _scratch((b, H))],
         interpret=_interpret(),
+        name="lstm_cell_fwd",
     )(*ops)
     if save_reserve:
         return res
@@ -389,6 +390,7 @@ def _bwd_call(dy, gates, cseq, rwt, peep, mask, c0, dhT, dcT):
         scratch_shapes=[_scratch((b, H)), _scratch((b, H)),
                         _scratch((8, H))],
         interpret=_interpret(),
+        name="lstm_cell_bwd",
     )(*ops)
 
 

@@ -238,6 +238,7 @@ def _fwd2(xp, rw1, w2, b2, rw2, peep, h0, save_reserve=True):
         out_shape=tuple(out_shape),
         scratch_shapes=[_scratch((b, H))] * 4,
         interpret=_interpret(),
+        name="lstm_fused_fwd",
     )(*ops)
     if save_reserve:
         return res
@@ -385,6 +386,7 @@ def _bwd2_call(dy, g1, c1seq, g2, c2seq, rw1t, w2t, rw2t, peep, c0, dhcT):
                    jax.ShapeDtypeStruct((8, H), f32)),
         scratch_shapes=[_scratch((b, H))] * 4 + [_scratch((8, H))],
         interpret=_interpret(),
+        name="lstm_fused_bwd",
     )(*ops)
 
 

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
+from ..monitor import get_tracer, spanned
 from ..monitor.jitwatch import monitored_jit
 
 from .mesh import MODEL_AXIS, MeshSpec, record_step, require_axes
@@ -406,11 +407,11 @@ class ParallelWrapper:
             loss, rnn_state = seg_step(itc, key, f_c, l_c, fm_c, lm_c,
                                        rnn_state)
             net.iteration_count += 1
-        self.last_score = float(loss)
+        self.last_score = self._fetch_score(loss)
         net.score_ = loss
         self.iteration_count += 1
         for lst in net.listeners:
-            lst.iteration_done(net, net.iteration_count - 1, float(loss))
+            lst.iteration_done(net, net.iteration_count - 1, self.last_score)
 
     def _fit_sync_tbptt(self, f, l, fm, lm):
         """TBPTT over the fused-psum sharded step."""
@@ -550,12 +551,14 @@ class ParallelWrapper:
         the data axis; everything else replicates."""
         net = self.net
         put = lambda t: _tm(lambda x: put_replicated(x, self.mesh), t)
-        par, upd = composed_specs(net, self.mesh, tp_rules=self.tp_rules,
-                                  shard_update=self.weight_update_sharding,
-                                  shard_params=self.fsdp)
-        net.params = put_sharded_tree(net.params, par)
-        net.states = put(net.states)
-        net.updater_state = put_sharded_tree(net.updater_state, upd)
+        with get_tracer().span("pw/place_model", cat="train"):
+            par, upd = composed_specs(
+                net, self.mesh, tp_rules=self.tp_rules,
+                shard_update=self.weight_update_sharding,
+                shard_params=self.fsdp)
+            net.params = put_sharded_tree(net.params, par)
+            net.states = put(net.states)
+            net.updater_state = put_sharded_tree(net.updater_state, upd)
 
     def _resolve_score(self, pending):
         """Resolve a deferred ``(loss, iteration_idx)`` score fetch. The
@@ -576,11 +579,17 @@ class ParallelWrapper:
         if pending is None:
             return
         loss, idx = pending
-        v = float(loss)
-        self.last_score = v
+        self.last_score = v = self._fetch_score(loss)
         net = self.net
         for lst in net.listeners:
             lst.iteration_done(net, idx, v)
+
+    @staticmethod
+    def _fetch_score(loss):
+        """The device→host fetch of a step's loss, under the span that
+        names it as the wait it is (it lasts about a step)."""
+        with get_tracer().span("pw/resolve_score", cat="train"):
+            return float(loss)
 
     def _fit_sync(self, it):
         """AVERAGING freq=1 / SHARED_GRADIENTS: fused psum step per global
@@ -604,7 +613,7 @@ class ParallelWrapper:
         self._device_put_model()
         pending = None
         try:
-            for group in self._batch_groups(it):
+            for group, _ in spanned(self._batch_groups(it), "pw/group"):
                 if group is None:
                     continue  # tail handled unsharded by _batch_groups
                 f, l, fm, lm = self._global_batch(group)
@@ -613,11 +622,12 @@ class ParallelWrapper:
                     self._resolve_score(prev)
                     self._fit_sync_tbptt(f, l, fm, lm)
                     continue
-                itc = jnp.asarray(net.iteration_count, jnp.int32)
-                key = put_replicated(net._next_rng(), self.mesh)
-                net.params, net.states, net.updater_state, loss = step(
-                    net.params, net.states, net.updater_state, itc, key, f, l,
-                    fm, lm)
+                with self._step_span():
+                    itc = jnp.asarray(net.iteration_count, jnp.int32)
+                    key = put_replicated(net._next_rng(), self.mesh)
+                    net.params, net.states, net.updater_state, loss = step(
+                        net.params, net.states, net.updater_state, itc, key,
+                        f, l, fm, lm)
                 net.score_ = loss
                 net.iteration_count += 1
                 self.iteration_count += 1
@@ -632,6 +642,13 @@ class ParallelWrapper:
         finally:
             prev, pending = pending, None
             self._resolve_score(prev)
+
+    def _step_span(self):
+        """``pw/step``: the dispatch of one sharded step with the iteration
+        scalar and the key it takes; ``step_num`` as the containers'
+        ``step`` span has it."""
+        return get_tracer().span("pw/step", cat="train",
+                                 step_num=self.net.iteration_count)
 
     def _batch_groups(self, it):
         """Yield groups of iterator batches (reference round-robin dispatch):
@@ -742,25 +759,27 @@ class ParallelWrapper:
             self.accumulator = EncodedGradientsAccumulator()
         update_step, apply_step = self._ensure_shared_steps()
         self._device_put_model()
-        for group in self._batch_groups(it):
+        for group, _ in spanned(self._batch_groups(it), "pw/group"):
             if group is None:
                 continue
             f, l, fm, lm = self._global_batch(group)
             if self._tbptt_applicable(f):
                 self._fit_shared_tbptt(f, l, fm, lm, apply_step)
                 continue
-            itc = jnp.asarray(net.iteration_count, jnp.int32)
-            key = put_replicated(net._next_rng(), self.mesh)
-            update, net.states, net.updater_state, loss = update_step(
-                net.params, net.states, net.updater_state, itc, key, f, l,
-                fm, lm)
+            with self._step_span():
+                itc = jnp.asarray(net.iteration_count, jnp.int32)
+                key = put_replicated(net._next_rng(), self.mesh)
+                update, net.states, net.updater_state, loss = update_step(
+                    net.params, net.states, net.updater_state, itc, key, f,
+                    l, fm, lm)
             self._apply_encoded(apply_step, update)
-            self.last_score = float(loss)
+            self.last_score = self._fetch_score(loss)
             net.score_ = loss
             net.iteration_count += 1
             self.iteration_count += 1
             for lst in net.listeners:
-                lst.iteration_done(net, net.iteration_count - 1, float(loss))
+                lst.iteration_done(net, net.iteration_count - 1,
+                                   self.last_score)
 
     def _apply_encoded(self, apply_step, update):
         """Host hop: encode (residual kept) → apply the decoded quantized
@@ -806,21 +825,23 @@ class ParallelWrapper:
             # TBPTT segments count as extra optimizer iterations per micro-
             # batch (mirror of the trace-time predicate in one_micro)
             n_seg = self._stacked_n_segments(fs)
-            itc = jnp.asarray(net.iteration_count, jnp.int32)
-            key = put_replicated(net._next_rng(), self.mesh)
-            t0 = time.perf_counter()
-            net.params, net.states, net.updater_state, loss = step(
-                net.params, net.states, net.updater_state, itc, key, fs, ls,
-                fms, lms)
+            with self._step_span():
+                itc = jnp.asarray(net.iteration_count, jnp.int32)
+                key = put_replicated(net._next_rng(), self.mesh)
+                t0 = time.perf_counter()
+                net.params, net.states, net.updater_state, loss = step(
+                    net.params, net.states, net.updater_state, itc, key, fs,
+                    ls, fms, lms)
             # value fetch = completion barrier
-            self.last_score = float(loss)
+            self.last_score = self._fetch_score(loss)
             self.averaging_ms = (time.perf_counter() - t0) * 1e3
             net.iteration_count += self.averaging_frequency * n_seg
             self.iteration_count += self.averaging_frequency
             net.score_ = loss
             if self.report_score_after_averaging:
                 for lst in net.listeners:
-                    lst.iteration_done(net, net.iteration_count - 1, float(loss))
+                    lst.iteration_done(net, net.iteration_count - 1,
+                                       self.last_score)
         if pending:
             log.info("Dropping %d tail micro-batches (< averaging_frequency)",
                      len(pending))
@@ -838,7 +859,9 @@ class ParallelWrapper:
         iterator batches skip the host→device transfer entirely — the
         reference's ``CacheMode.DEVICE`` semantics (`nn/conf/CacheMode.java`)
         applied to the ParallelWrapper dispatch path."""
-        return self._cached_sharded((), batches, self._global_batch_uncached)
+        with get_tracer().span("pw/global_batch", cat="train"):
+            return self._cached_sharded((), batches,
+                                        self._global_batch_uncached)
 
     def _cached_sharded(self, prefix, batches, build):
         """LRU device-batch cache shared by the sync and local-SGD paths.

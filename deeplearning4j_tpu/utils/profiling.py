@@ -21,8 +21,13 @@ This module covers *device* traces and per-step timing; the process-wide
 metrics/span/health layer lives in ``deeplearning4j_tpu/monitor/`` (one
 ``MetricsRegistry`` scraped at ``GET /metrics``, a host-side span tracer
 exporting Chrome trace JSON, and a NaN/divergence/stall watchdog) — see
-docs/OBSERVABILITY.md. The completion-barrier rule stated on
-:class:`StepTimerListener` applies to the monitor's spans identically.
+docs/OBSERVABILITY.md. The monitor's spans are HOST time and hold no fetch
+(``monitor/tracer.py``): under a trace taken here, the program's spans
+(``fit/next_batch``, ``fit/prepare``, ``step`` with its ``step_num``, …)
+sit on the profiler's clock beside the device's ops, whose time is the
+step's. The completion barrier :class:`StepTimerListener` and
+:class:`ProfilerListener` rely on is the eager ``float(loss)`` the fit
+loops keep for as long as a listener is attached.
 """
 from __future__ import annotations
 
@@ -80,10 +85,10 @@ class ProfilerListener(TrainingListener):
             self._active = True
             self._until = iteration + self.num_iterations
         elif self._active and iteration >= self._until:
-            # completion barrier: the fit loops evaluate float(loss) — a
-            # device→host VALUE fetch of this step's output — before
-            # dispatching listeners, so the traced step has already finished
-            # when we get here.
+            # completion barrier: with a listener attached (this one) the
+            # fit loops fetch float(loss) — a device→host VALUE fetch of
+            # this step's output — before dispatching listeners, so the
+            # traced step has already finished when we get here.
             self.close()
 
     def close(self):
@@ -114,9 +119,11 @@ class StepTimerListener(TrainingListener):
     Dispatch is asynchronous: a timed window has to close on
     ``jax.block_until_ready`` or on a device→host value fetch
     (``np.asarray`` / ``float()`` of a result), or it measures the enqueue.
-    The fit loops evaluate ``float(loss)`` before dispatching
-    ``iteration_done``, so the score this listener receives IS post-barrier
-    — timing here is honest by construction. User code timing its own
+    With a listener attached the fit loops evaluate ``float(loss)`` right
+    after each dispatch and before ``iteration_done`` (without one the fetch
+    lags, ``monitor.StepCompletions``), so the score this listener receives
+    IS post-barrier — timing here is honest by construction, and attaching
+    it is what makes the loop synchronous. User code timing its own
     steps outside a listener must close its window the same way (on the
     v5e both barriers close a window at the same time — PERF.md "Bring-up
     on the chip")."""

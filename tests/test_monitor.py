@@ -349,7 +349,10 @@ class TestEndpoints:
             assert 'paramserver_pull_ms_count{role="client"}' in text
             assert 'paramserver_push_ms_count{role="server"}' in text
             assert 'paramserver_pull_ms_bucket{role="client",le=' in text
-            assert "dataset_next_ms_count" in text
+            # the fit loop wraps its iterator in PrefetchDataSetIterator,
+            # whose wait is this series (dataset_next_ms is
+            # AsyncDataSetIterator's own)
+            assert "input_wait_seconds_count" in text
 
             with _get(port, "/healthz") as r:
                 h = json.loads(r.read())
@@ -360,6 +363,8 @@ class TestEndpoints:
                 doc = json.loads(r.read())
             names = {e["name"] for e in doc["traceEvents"]}
             assert "step" in names and "ps/pull" in names
+            assert {"epoch", "fit/next_batch", "fit/prepare", "fit/resolve",
+                    "input/transform", "input/put_ahead"} <= names
         finally:
             srv_ui.stop()
 
