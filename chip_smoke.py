@@ -24,7 +24,7 @@ Legs:
   B  every Pallas kernel family compiled by Mosaic (``interpret=False``)
      and held to its oracle at the bench shape — flash attention forward
      and gradients plain / masked / with dropout (``perf_flash_check``),
-     ``lstm_cell`` with f32 and bf16 streams and ``lstm_fused``
+     ``lstm_cell`` with an f32 and a bf16 reserve and ``lstm_fused``
      (``perf_lstm``) — then one ``fit`` each of the TransformerLM and the
      char-RNN bench configs, whose compiled step must hold the custom call.
   C  (more than one device) leg A's model through ``ParallelWrapper`` over

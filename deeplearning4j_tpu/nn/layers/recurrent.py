@@ -9,6 +9,15 @@ body only does the [b, H]×[H, 4H] recurrent gemm plus elementwise gate math.
 Backward-through-time is AD of the scan (no hand-written BPTT).
 
 Sequence layout is [batch, time, features] (reference: [b, features, T]).
+Where the persistent Pallas kernel applies (``ops/lstm_cell.supported``) the
+LSTM layers hand it their streams TIME-major and in the gemms' own dtype:
+the narrow input [b, T, nIn] is swapped once, in the compute dtype, and
+projected to ``xw`` [T, b, 4H] (no upcast, no bias: the kernel adds the f32
+bias and sums its gradient), and ``ys`` [T, b, H] comes back in the layer's
+activation dtype and is swapped once. Which streams follow the compute
+dtype and which the ``DL4J_TPU_LSTM_STREAM_DTYPE`` knob (the reserve only)
+is in ``ops/lstm_cell.py``'s docstring. The scan path below is unchanged:
+batch-major projection, f32 carries, bias added in XLA.
 Gate order in the fused 4H dimension is i, f, o, g matching the reference's
 IFOG convention (``LSTMParamInitializer``). Param keys: "W" (input weights
 [nIn, 4H]), "RW" (recurrent [H, 4H]), "b" ([4H]); Graves peepholes "pi","pf","po".
@@ -85,16 +94,11 @@ class _BaseLSTMImpl(LayerImpl):
             x = jnp.flip(x, axis=1)
             mask = jnp.flip(mask, axis=1) if mask is not None else None
         ad = acc_dtype(self.compute_dtype)
-        # hoisted input projection: [b*T, nIn] @ [nIn, 4H] on the MXU
-        xp = (x.reshape(b * T, -1).astype(self.compute_dtype)
-              @ params["W"].astype(self.compute_dtype)).astype(ad)
-        xp = xp.reshape(b, T, 4 * H) + params["b"].astype(ad)
         if h0c0 is None:
             h0 = jnp.zeros((b, H), ad)
             c0 = jnp.zeros((b, H), ad)
         else:
             h0, c0 = h0c0
-        h0, c0 = _match_vma(h0, xp), _match_vma(c0, xp)
         peep = ((params["pi"], params["pf"], params["po"])
                 if self.peepholes else None)
         # recurrent weights ride in COMPUTE dtype (bf16 policy): the
@@ -111,10 +115,33 @@ class _BaseLSTMImpl(LayerImpl):
         gate_name = getattr(c, "gate_activation", "sigmoid")
         if _lk.supported(b, T, H, self.activation_name, str(gate_name),
                          weight_bytes=jnp.dtype(rw.dtype).itemsize):
-            y, (hT, cT) = _lk.lstm_scan(xp, rw, peep, h0, c0, mask)
+            # the kernels are time-major, so swap the NARROW input ([b, T,
+            # nIn], compute dtype) and project it time-major: no [·, ·, 4H]
+            # tensor is transposed, upcast or biased in XLA, and under AD
+            # dW = x_tmᵀ·dz and dx = dz·Wᵀ come out time-major too. Where b
+            # fills whole tiles (a multiple of 16 in bf16) both reshapes
+            # are views. Between stacked LSTM layers this swap and the
+            # previous layer's swap-back are an inverse pair that XLA
+            # cancels. The bias goes to the kernel, which adds it in f32.
+            cd = self.compute_dtype
+            x_tm = jnp.swapaxes(x.astype(cd), 0, 1)
+            xw = (x_tm.reshape(T * b, -1)
+                  @ params["W"].astype(cd)).reshape(T, b, 4 * H)
+            h0, c0 = _match_vma(h0, xw), _match_vma(c0, xw)
+            ys, (hT, cT) = _lk.lstm_scan(
+                xw, params["b"], rw, peep, h0, c0,
+                None if mask is None else jnp.swapaxes(mask, 0, 1),
+                out_dtype=self.out_dtype)
+            y = jnp.swapaxes(ys, 0, 1)
             if reverse:
                 y = jnp.flip(y, axis=1)
-            return y.astype(self.out_dtype), (hT, cT)
+            return y, (hT, cT)
+
+        # hoisted input projection: [b*T, nIn] @ [nIn, 4H] on the MXU
+        xp = (x.reshape(b * T, -1).astype(self.compute_dtype)
+              @ params["W"].astype(self.compute_dtype)).astype(ad)
+        xp = xp.reshape(b, T, 4 * H) + params["b"].astype(ad)
+        h0, c0 = _match_vma(h0, xp), _match_vma(c0, xp)
 
         def step(carry, inp):
             h, cc = carry

@@ -3,7 +3,7 @@ recurrent weights VMEM-RESIDENT across the whole sequence.
 
 Why: the container LSTM (``nn/layers/recurrent.py``) hoists the input
 projection out of the scan (one big MXU gemm), but the remaining sequential
-chain ``z_t = xp_t + h @ RW`` re-streams ``RW [H, 4H]`` from HBM every
+chain ``z_t = xw_t + b + h @ RW`` re-streams ``RW [H, 4H]`` from HBM every
 timestep: at char-RNN shapes (H=512 → 2 MB bf16) that is T × 2 MB per layer
 per direction, and the step is weight-bandwidth-bound at ~1% MFU — exactly
 the workload the reference dedicates ``CudnnLSTMHelper.java`` (persistent
@@ -18,17 +18,30 @@ Backward is the standard LSTM BPTT, hand-written (the cuDNN-helper pattern
 the repo already uses for flash attention: custom kernel behind the same
 layer math, ``lax.scan`` path as the always-available oracle/fallback):
 the forward saves the post-activation gates [T, b, 4H] and the cell
-sequence (cuDNN "reserve space"), the reverse kernel carries (dh, dc) and
-emits per-step pre-activation gradients dz [T, b, 4H]; everything
-batched-over-time (dW, dRW, dx, db, h_prev) happens OUTSIDE as single MXU
-gemms. Supports the Graves peephole variant (``pi/pf/po``) and per-step
-[b] sequence masks — both GravesLSTM semantics from the reference
-(``GravesLSTM.java``, ``LSTMHelpers.java:206-212``).
+sequence (cuDNN "reserve space"), the reverse kernel carries (dh, dc),
+emits per-step pre-activation gradients dz [T, b, 4H] and sums them into
+the bias gradient db; everything else batched-over-time (dW, dRW, dx,
+h_prev) happens OUTSIDE as single MXU gemms. Supports the Graves peephole
+variant (``pi/pf/po``) and per-step [b] sequence masks — both GravesLSTM
+semantics from the reference (``GravesLSTM.java``,
+``LSTMHelpers.java:206-212``).
 
-Layout: time-major [T, b, ...] inside the kernels (grid walks T); the
-public :func:`lstm_scan` takes the layer's batch-major arrays. f32
-accumulation throughout; tanh cell activation and sigmoid gates (the
-``supported()`` contract — other activations fall back to the scan).
+Layout and dtypes — the contract with the one call site, the kernel branch
+of ``recurrent._BaseLSTMImpl._run``: everything is time-major [T, b, ...]
+(the grid walks T), at :func:`lstm_scan` as inside the kernels, so the
+caller swaps its NARROW input ([b, T, nIn]) once and no [·, ·, 4H] tensor
+is ever transposed. The streamed operands travel in the dtype of the gemms
+on their other side and are widened / narrowed in-kernel: ``xw`` (the
+projection, bias NOT added) in the dtype its gemm wrote, ``ys`` in the
+layer's activation dtype, ``dy`` as AD hands it over (``ys``'s dtype),
+``dz`` in ``xw``'s dtype (it is ``xw``'s cotangent and the operand of the
+three gradient gemms). Under the bf16 policy that is bf16 for all four,
+under f32 compute f32; nobody sets it. The bias rides as a resident f32
+row and is added in-kernel; ``db`` is summed in-kernel from the f32 ``dz``.
+The RESERVE (gates, cseq) alone follows ``DL4J_TPU_LSTM_STREAM_DTYPE``
+(f32 by default). h/c state, gate math and all accumulation are f32
+throughout; tanh cell activation and sigmoid gates (the ``supported()``
+contract — other activations fall back to the scan).
 """
 from __future__ import annotations
 
@@ -49,15 +62,25 @@ def _sig(x):
     return jax.nn.sigmoid(x)
 
 
+def _pad8(*rows):
+    """Up to 8 rows [n] → f32 [8, n], zero below them: small per-feature
+    vectors (the bias, the peepholes) as one resident tile."""
+    return jnp.pad(jnp.stack(rows).astype(jnp.float32),
+                   ((0, 8 - len(rows)), (0, 0)))
+
+
 def _stream_dtype():
-    """Dtype of the HBM-streamed per-step tensors (xp in, ys/gates/cseq
-    reserve out, dz out): ``DL4J_TPU_LSTM_STREAM_DTYPE`` = ``float32``
-    (default) or ``bfloat16``. bf16 halves the dominant HBM traffic of the
-    sequential chain (the cuDNN reserve-space convention stores the
-    compute dtype) at a small recompute-precision cost in the backward;
-    h/c state and all gate math stay f32 regardless. TRACE-TIME knob, same
-    caveat as ``DL4J_TPU_LSTM_UNROLL``: set it before the first step of a
-    config."""
+    """Dtype of the RESERVE the forward saves for the backward (gates,
+    cseq), and of nothing else: ``DL4J_TPU_LSTM_STREAM_DTYPE`` = ``float32``
+    (default) or ``bfloat16``. bf16 halves the reserve's HBM traffic (the
+    cuDNN reserve-space convention stores the compute dtype) at a small
+    recompute-precision cost in the backward, and is what admits
+    ``lstm_fused`` at the char-RNN shape; h/c state and all gate math stay
+    f32 regardless. The other streams (xw, ys, dy, dz) follow their
+    operands' dtypes (module docstring). The VMEM budgets below still count
+    every stream at this width: at the f32 default that is an upper bound.
+    TRACE-TIME knob, same caveat as ``DL4J_TPU_LSTM_UNROLL``: set it before
+    the first step of a config."""
     import os
     v = os.environ.get("DL4J_TPU_LSTM_STREAM_DTYPE", "float32")
     return jnp.bfloat16 if v in ("bfloat16", "bf16") else jnp.float32
@@ -96,7 +119,7 @@ def _unroll_factor(T: int, b: int, H: int, weight_bytes: int) -> int:
 
 
 # ------------------------------------------------------------------ forward
-def _fwd_kernel(xp_ref, rw_ref, peep_ref, m_ref, h0_ref, c0_ref,
+def _fwd_kernel(xw_ref, b_ref, rw_ref, peep_ref, m_ref, h0_ref, c0_ref,
                 ys_ref, gates_ref, cseq_ref, hc_ref,
                 h_s, c_s, *, nb, H, peep, U):
     """One grid step processes U consecutive timesteps (statically
@@ -118,14 +141,18 @@ def _fwd_kernel(xp_ref, rw_ref, peep_ref, m_ref, h0_ref, c0_ref,
     # multi-pass f32 algorithm, and the resident footprint halves. h/c stay
     # f32 in scratch (accumulation dtype); only the gemm operand is cast.
     rw = rw_ref[...]
+    bias = b_ref[0]                                       # [4H] f32
     if peep:
         pi = peep_ref[0].astype(jnp.float32)              # [H]
         pf = peep_ref[1].astype(jnp.float32)
         po = peep_ref[2].astype(jnp.float32)
     for u in range(U):
-        z = xp_ref[u].astype(jnp.float32) + jax.lax.dot_general(
-            h.astype(rw.dtype), rw, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [b, 4H]
+        # (xw + b) first, then + h·RW: the order the layer's XLA bias add
+        # followed by the kernel's add used to give, so z keeps its bits
+        z = (xw_ref[u].astype(jnp.float32) + bias[None, :]) \
+            + jax.lax.dot_general(
+                h.astype(rw.dtype), rw, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [b, 4H]
         zi, zf, zo, zg = (z[:, :H], z[:, H:2 * H], z[:, 2 * H:3 * H],
                           z[:, 3 * H:])
         if peep:
@@ -158,14 +185,15 @@ def _fwd_kernel(xp_ref, rw_ref, peep_ref, m_ref, h0_ref, c0_ref,
         hc_ref[1] = c.astype(hc_ref.dtype)
 
 
-def _fwd(xp, rw, peep, h0, c0, mask, save_reserve=True):
-    """xp: [T, b, 4H] (input projection + bias), rw: [H, 4H], peep: [8, H]
-    or None, h0/c0: [b, H], mask: [T, b, 8] or None →
-    (ys [T, b, H], gates [T, b, 4H], cseq [T, b, H], hcT [2, b, H]);
+def _fwd(xw, bias, rw, peep, h0, c0, mask, ys_dtype, save_reserve=True):
+    """xw: [T, b, 4H] (input projection, no bias, any float dtype), bias:
+    [4H] f32, rw: [H, 4H], peep: [8, H] or None, h0/c0: [b, H], mask:
+    [T, b, 8] or None → (ys [T, b, H] in ``ys_dtype``, gates [T, b, 4H],
+    cseq [T, b, H] (both in the reserve dtype), hcT [2, b, H] f32);
     ``save_reserve=False`` (inference primal) omits the gates/cseq reserve
     outputs entirely — no dead HBM writes on the non-training path — and
     returns (ys, None, None, hcT)."""
-    T, b, H4 = xp.shape
+    T, b, H4 = xw.shape
     H = H4 // 4
     U = _unroll_factor(T, b, H, jnp.dtype(rw.dtype).itemsize)
     nb = T // U
@@ -174,10 +202,11 @@ def _fwd(xp, rw, peep, h0, c0, mask, save_reserve=True):
     const3 = lambda t: (0, 0, 0)
     const2 = lambda t: (0, 0)
     specs = [
-        _vspec((U, b, H4), lambda t: (t, 0, 0)),          # xp (streamed)
+        _vspec((U, b, H4), lambda t: (t, 0, 0)),          # xw (streamed)
+        _vspec((8, H4), const2),                          # bias (row 0)
         _vspec((H, H4), const2),                          # rw (resident)
     ]
-    ops = [xp, rw]
+    ops = [xw, _pad8(bias), rw]
     if peep is not None:
         specs.append(_vspec((8, H), const2))              # peepholes
         ops.append(peep)
@@ -189,9 +218,9 @@ def _fwd(xp, rw, peep, h0, c0, mask, save_reserve=True):
     ops += [h0, c0]
 
     def shim(*refs):
-        n_in = 2 + int(peep is not None) + int(has_mask) + 2
+        n_in = 3 + int(peep is not None) + int(has_mask) + 2
         ins, rest = refs[:n_in], refs[n_in:]
-        pos = 2
+        pos = 3
         peep_ref = ins[pos] if peep is not None else None
         pos += int(peep is not None)
         m_ref = ins[pos] if has_mask else None
@@ -201,12 +230,13 @@ def _fwd(xp, rw, peep, h0, c0, mask, save_reserve=True):
         else:
             (ys_ref, hc_ref, h_s, c_s), gates_ref, cseq_ref = rest, None, \
                 None
-        return kern(ins[0], ins[1], peep_ref, m_ref, ins[pos], ins[pos + 1],
-                    ys_ref, gates_ref, cseq_ref, hc_ref, h_s, c_s)
+        return kern(ins[0], ins[1], ins[2], peep_ref, m_ref, ins[pos],
+                    ins[pos + 1], ys_ref, gates_ref, cseq_ref, hc_ref,
+                    h_s, c_s)
 
-    sd = _stream_dtype()          # reserve stream dtype (policy knob)
+    sd = _stream_dtype()          # reserve dtype (policy knob)
     out_specs = [_vspec((U, b, H), lambda t: (t, 0, 0))]  # ys
-    out_shape = [jax.ShapeDtypeStruct((T, b, H), xp.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((T, b, H), ys_dtype)]
     if save_reserve:
         out_specs += [
             _vspec((U, b, H4), lambda t: (t, 0, 0)),      # gates (reserve)
@@ -235,19 +265,20 @@ def _fwd(xp, rw, peep, h0, c0, mask, save_reserve=True):
 # ----------------------------------------------------------------- backward
 def _bwd_kernel(dy_ref, gates_ref, cseq_ref, cprev_ref, rwt_ref, peep_ref,
                 m_ref, c0_ref, dhT_ref, dcT_ref,
-                dz_ref, dh0_ref, dc0_ref, dpeep_ref,
-                dh_s, dc_s, dp_s, *, nb, H, peep, U):
+                dz_ref, dh0_ref, dc0_ref, dpeep_ref, db_ref,
+                dh_s, dc_s, dp_s, db_s, *, nb, H, peep, U):
     """Reverse BPTT, U timesteps per grid step (statically unrolled, walked
     u = U-1 … 0 inside the block). ``cprev_ref`` streams the PREVIOUS
     block of the c sequence — in-block u > 0 takes c_{t-1} from the local
     block, u == 0 takes it from ``cprev_ref[U-1]`` (or c0 at the sequence
-    start)."""
+    start). ``db_s`` [8, 4H] collects the bias gradient Σ_t Σ_b dz."""
     t = pl.program_id(0)          # walks 0..nb-1; blocks indexed nb-1-t
 
     @pl.when(t == 0)
     def _():
         dh_s[:] = dhT_ref[...].astype(jnp.float32)
         dc_s[:] = dcT_ref[...].astype(jnp.float32)
+        db_s[:] = jnp.zeros_like(db_s)
         if peep:
             dp_s[:] = jnp.zeros_like(dp_s)
 
@@ -259,6 +290,7 @@ def _bwd_kernel(dy_ref, gates_ref, cseq_ref, cprev_ref, rwt_ref, peep_ref,
         po = peep_ref[2].astype(jnp.float32)
     dh_carry = dh_s[:]
     dc_carry = dc_s[:]
+    db = db_s[:]
     for u in reversed(range(U)):
         gts = gates_ref[u].astype(jnp.float32)
         i, f, o, g = (gts[:, :H], gts[:, H:2 * H], gts[:, 2 * H:3 * H],
@@ -315,21 +347,30 @@ def _bwd_kernel(dy_ref, gates_ref, cseq_ref, cprev_ref, rwt_ref, peep_ref,
             dh_prev = dh_prev + (1.0 - m) * dh_tot
             dc_prev = dc_prev + (1.0 - m) * dc_tot
         dz_ref[u] = dz.astype(dz_ref.dtype)
+        # bias gradient from the f32 dz, before the stream rounds it: row r
+        # of db sums the batch rows ≡ r (mod 8) — whole-tile adds per step,
+        # the 8 rows are summed once, outside
+        for k in range(0, dz.shape[0], 8):
+            db = db + dz[k:k + 8]
         dh_carry, dc_carry = dh_prev, dc_prev
     dh_s[:] = dh_carry
     dc_s[:] = dc_carry
+    db_s[:] = db
 
     @pl.when(t == nb - 1)
     def _():
         dh0_ref[...] = dh_carry.astype(dh0_ref.dtype)
         dc0_ref[...] = dc_carry.astype(dc0_ref.dtype)
+        db_ref[...] = db
         if peep:
             dpeep_ref[...] = dp_s[:].astype(dpeep_ref.dtype)
         else:
             dpeep_ref[...] = jnp.zeros(dpeep_ref.shape, dpeep_ref.dtype)
 
 
-def _bwd_call(dy, gates, cseq, rwt, peep, mask, c0, dhT, dcT):
+def _bwd_call(dy, gates, cseq, rwt, peep, mask, c0, dhT, dcT, dz_dtype):
+    """→ (dz [T, b, 4H] in ``dz_dtype``, dh0, dc0 [b, H], dpeep [8, H],
+    db [8, 4H]: partial sums, see the kernel), all but dz in f32."""
     T, b, H = dy.shape
     H4 = 4 * H
     U = _unroll_factor(T, b, H, jnp.dtype(rwt.dtype).itemsize)
@@ -371,7 +412,6 @@ def _bwd_call(dy, gates, cseq, rwt, peep, mask, c0, dhT, dcT):
         return kern(ins[0], ins[1], ins[2], ins[3], ins[4], peep_ref, m_ref,
                     ins[pos], ins[pos + 1], ins[pos + 2], *rest)
 
-    sd = _stream_dtype()          # dz rides the stream-dtype policy too
     f32 = jnp.float32
     return pl.pallas_call(
         shim,
@@ -382,53 +422,56 @@ def _bwd_call(dy, gates, cseq, rwt, peep, mask, c0, dhT, dcT):
             _vspec((b, H), const2),                       # dh0
             _vspec((b, H), const2),                       # dc0
             _vspec((8, H), const2),                       # dpeep
+            _vspec((8, H4), const2),                      # db (8 partials)
         ),
-        out_shape=(jax.ShapeDtypeStruct((T, b, H4), sd),
+        out_shape=(jax.ShapeDtypeStruct((T, b, H4), dz_dtype),
                    jax.ShapeDtypeStruct((b, H), f32),
                    jax.ShapeDtypeStruct((b, H), f32),
-                   jax.ShapeDtypeStruct((8, H), f32)),
+                   jax.ShapeDtypeStruct((8, H), f32),
+                   jax.ShapeDtypeStruct((8, H4), f32)),
         scratch_shapes=[_scratch((b, H)), _scratch((b, H)),
-                        _scratch((8, H))],
+                        _scratch((8, H)), _scratch((8, H4))],
         interpret=_interpret(),
         name="lstm_cell_bwd",
     )(*ops)
 
 
 # ------------------------------------------------------------- public entry
-@functools.partial(jax.custom_vjp, nondiff_argnums=())
-def _lstm(xp, rw, peep, h0, c0, mask):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _lstm(ys_dtype, xw, bias, rw, peep, h0, c0, mask):
     # primal (inference) path: no reserve tensors — the BPTT residuals are
     # only materialized by _lstm_fwd under differentiation
-    ys, _, _, hc = _fwd(xp, rw, peep, h0, c0, mask, save_reserve=False)
+    ys, _, _, hc = _fwd(xw, bias, rw, peep, h0, c0, mask, ys_dtype,
+                        save_reserve=False)
     return ys, hc[0], hc[1]
 
 
-def _lstm_fwd(xp, rw, peep, h0, c0, mask):
-    ys, gates, cseq, hc = _fwd(xp, rw, peep, h0, c0, mask)
-    return (ys, hc[0], hc[1]), (rw, peep, h0, c0, mask, ys, gates, cseq)
+def _lstm_fwd(ys_dtype, xw, bias, rw, peep, h0, c0, mask):
+    ys, gates, cseq, hc = _fwd(xw, bias, rw, peep, h0, c0, mask, ys_dtype)
+    # xw itself is not needed again; its (empty) slice carries its dtype,
+    # which is dz's, to the backward
+    return (ys, hc[0], hc[1]), (xw[:0], rw, peep, h0, c0, mask, ys, gates,
+                                cseq)
 
 
-def _lstm_bwd(res, grads):
-    rw, peep, h0, c0, mask, ys, gates, cseq = res
+def _lstm_bwd(ys_dtype, res, grads):
+    xw0, rw, peep, h0, c0, mask, ys, gates, cseq = res
     dy, dhT, dcT = grads
-    T, b, H = dy.shape
-    dy = dy.astype(jnp.float32)
     rwt = jnp.swapaxes(rw, 0, 1)
-    dz, dh0, dc0, dpeep = _bwd_call(dy, gates, cseq, rwt, peep, mask,
-                                    c0.astype(jnp.float32),
-                                    dhT.astype(jnp.float32),
-                                    dcT.astype(jnp.float32))
+    dz, dh0, dc0, dpeep, db = _bwd_call(dy, gates, cseq, rwt, peep, mask,
+                                        c0.astype(jnp.float32),
+                                        dhT.astype(jnp.float32),
+                                        dcT.astype(jnp.float32), xw0.dtype)
     # batched-over-time pieces as single MXU gemms (outside the kernel):
-    # z_t = xp_t + h_{t-1} @ RW  →  dxp = dz,  dRW = Σ_t h_{t-1}ᵀ dz_t
+    # z_t = xw_t + b + h_{t-1} @ RW  →  dxw = dz,  dRW = Σ_t h_{t-1}ᵀ dz_t.
+    # ys and dz already are what the gemm reads (the compute dtype under
+    # the bf16 policy), f32 accumulation
     h_prev = jnp.concatenate([h0.astype(ys.dtype)[None], ys[:-1]], axis=0)
-    # batched gemm in the weight dtype (bf16 policy), f32 accumulation
-    drw = jnp.einsum("tbh,tbg->hg", h_prev.astype(rw.dtype),
-                     dz.astype(rw.dtype),
+    drw = jnp.einsum("tbh,tbg->hg", h_prev, dz,
                      preferred_element_type=jnp.float32).astype(rw.dtype)
-    dxp = dz                                              # z = xp + h @ RW
     dpeep_out = None if peep is None else dpeep.astype(peep.dtype)
     dmask = None if mask is None else jnp.zeros_like(mask)
-    return (dxp, drw, dpeep_out, dh0, dc0, dmask)
+    return (dz, db.sum(axis=0), drw, dpeep_out, dh0, dc0, dmask)
 
 
 _lstm.defvjp(_lstm_fwd, _lstm_bwd)
@@ -470,36 +513,35 @@ def supported(b: int, T: int, H: int, activation: str,
             and H % 128 == 0 and b % 8 == 0 and T >= 1)
 
 
-def lstm_scan(xp, rw, peep, h0, c0, mask=None):
-    """Persistent-LSTM sequence step. ``xp``: [b, T, 4H] hoisted input
-    projection (+bias), ``rw``: [H, 4H], ``peep``: (pi, pf, po) tuple or
-    None, ``h0``/``c0``: [b, H], ``mask``: [b, T] (1 = real step, values in
-    [0, 1]) or None. The mask is NON-differentiable (the custom_vjp returns
-    a zero cotangent for it); callers differentiating through a soft mask
-    must stop_gradient it on their fallback path too (recurrent.py does).
-    Returns (ys [b, T, H] in the stream dtype — f32 unless
-    ``DL4J_TPU_LSTM_STREAM_DTYPE=bfloat16`` — and (hT, cT) in f32) — a
-    drop-in for the ``lax.scan`` recurrent loop with the weight stream
-    eliminated."""
-    b, T, H4 = xp.shape
-    H = H4 // 4
-    xp_tm = jnp.swapaxes(xp, 0, 1)                        # time-major
-    pk = None
-    if peep is not None:
-        pk = jnp.zeros((8, H), jnp.float32)
-        pk = pk.at[0].set(peep[0].astype(jnp.float32))
-        pk = pk.at[1].set(peep[1].astype(jnp.float32))
-        pk = pk.at[2].set(peep[2].astype(jnp.float32))
+def lstm_scan(xw, bias, rw, peep, h0, c0, mask=None, out_dtype=None):
+    """Persistent-LSTM sequence step, TIME-MAJOR. ``xw``: [T, b, 4H] hoisted
+    input projection WITHOUT the bias, in the dtype its gemm produced
+    (bf16 under the mixed-precision policy, f32 under f32 compute);
+    ``bias``: [4H], added in-kernel in f32; ``rw``: [H, 4H]; ``peep``:
+    (pi, pf, po) tuple or None; ``h0``/``c0``: [b, H]; ``mask``: [T, b]
+    (1 = real step, values in [0, 1]) or None. The mask is
+    NON-differentiable (the custom_vjp returns a zero cotangent for it);
+    callers differentiating through a soft mask must stop_gradient it on
+    their fallback path too (recurrent.py does).
+
+    Returns (ys [T, b, H] in ``out_dtype`` — the layer's activation dtype;
+    ``xw.dtype`` when None — and (hT, cT) in f32) — a drop-in for the
+    ``lax.scan`` recurrent loop with the weight stream eliminated. Under AD
+    ``xw``'s cotangent dz comes back in ``xw.dtype``, ``bias``'s is summed
+    in-kernel from the f32 dz. No stream dtype is a setting: ``xw``, ``ys``,
+    ``dy`` and ``dz`` follow the operands; only the reserve (gates, cseq)
+    follows ``DL4J_TPU_LSTM_STREAM_DTYPE``."""
+    T, b, _ = xw.shape
+    pk = None if peep is None else _pad8(*peep)
     mk = None
     if mask is not None:
-        mk = jnp.broadcast_to(
-            jnp.swapaxes(jnp.asarray(mask, jnp.float32), 0, 1)[..., None],
-            (T, b, 8))
-    # xp (the accumulated input projection) rides the STREAM dtype policy
-    # (f32 default; DL4J_TPU_LSTM_STREAM_DTYPE=bfloat16 halves the per-step
-    # HBM stream — gate math stays f32 in-kernel either way); RW rides in
-    # its caller dtype (bf16 under the mixed-precision policy) so the
-    # recurrent gemm runs the MXU's native bf16 pass with f32 accumulation
-    ys, hT, cT = _lstm(xp_tm.astype(_stream_dtype()), rw, pk,
-                       h0.astype(jnp.float32), c0.astype(jnp.float32), mk)
-    return jnp.swapaxes(ys, 0, 1), (hT, cT)
+        mk = jnp.broadcast_to(jnp.asarray(mask, jnp.float32)[..., None],
+                              (T, b, 8))
+    # RW rides in its caller dtype (bf16 under the mixed-precision policy)
+    # so the recurrent gemm runs the MXU's native bf16 pass with f32
+    # accumulation; h/c state and the gate math are f32 in-kernel whatever
+    # the streams' dtypes
+    f32 = jnp.float32
+    ys, hT, cT = _lstm(jnp.dtype(out_dtype or xw.dtype), xw, bias.astype(f32),
+                       rw, pk, h0.astype(f32), c0.astype(f32), mk)
+    return ys, (hT, cT)

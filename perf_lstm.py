@@ -89,6 +89,7 @@ def micro(batch=64, width=512, tbptt=50):
     rng = np.random.default_rng(0)
     xp = jnp.asarray(rng.normal(size=(T, b, 4 * H)), jnp.float32)
     rw = jnp.asarray(rng.normal(size=(H, 4 * H)) / np.sqrt(H), jnp.bfloat16)
+    bias = jnp.zeros((4 * H,), jnp.float32)
     h0 = jnp.zeros((b, H), jnp.float32)
     c0 = jnp.zeros((b, H), jnp.float32)
 
@@ -125,23 +126,24 @@ def micro(batch=64, width=512, tbptt=50):
           f"({tb/T*1e6:6.1f} us/timestep)")
 
     # c. persistent fwd kernel, one segment (training fwd w/ reserve)
-    fwd_j = jax.jit(lambda x, r, h, c: lc._fwd(x, r, None, h, c, None)[0])
+    fwd_j = jax.jit(lambda x, r, h, c: lc._fwd(x, bias, r, None, h, c, None,
+                                               x.dtype)[0])
     tc = timeit(fwd_j, xp, rw, h0, c0)
     print(f"c. persistent fwd kernel:      {tc*1e3:8.3f} ms "
           f"({tc/T*1e6:6.1f} us/timestep)")
 
-    # d. lstm_scan fwd+bwd
-    xp_bm = jnp.swapaxes(xp, 0, 1)
+    # d. lstm_scan fwd+bwd (time-major, as the layer calls it)
     grad_j = jax.jit(jax.grad(lambda x, r: jnp.sum(
-        lc.lstm_scan(x, r, None, h0, c0)[0]), argnums=(0, 1)))
-    td = timeit(grad_j, xp_bm, rw)
+        lc.lstm_scan(x, bias, r, None, h0, c0)[0]), argnums=(0, 1)))
+    td = timeit(grad_j, xp, rw)
     print(f"d. lstm_scan fwd+bwd:          {td*1e3:8.3f} ms "
           f"({td/T*1e6:6.1f} us/timestep)")
 
-    # e. fwd kernel with bf16 xp stream (halves streamed bytes): a big win
-    # here means the step is HBM-stream-bound, not latency-bound
+    # e. fwd kernel with bf16 xw / ys streams (what the bf16 policy hands
+    # it): a big win here means the step is HBM-stream-bound, not
+    # latency-bound
     te = timeit(fwd_j, xp.astype(jnp.bfloat16), rw, h0, c0)
-    print(f"e. fwd kernel, bf16 xp:        {te*1e3:8.3f} ms "
+    print(f"e. fwd kernel, bf16 xw/ys:     {te*1e3:8.3f} ms "
           f"({te/T*1e6:6.1f} us/timestep)")
 
     # f. inference fwd (save_reserve=False: no gates/cseq HBM writes)
@@ -207,14 +209,15 @@ def _measure_one(env, batch, width, tbptt, seq_len, timeout=900):
 
 
 def stream_ab(batch=64, width=512, tbptt=50, seq_len=200):
-    """A/B DL4J_TPU_LSTM_STREAM_DTYPE (f32 vs bf16 streams) x unroll caps.
-    bf16 halves the per-step HBM stream AND doubles the unroll the VMEM
-    budget admits — if the chain is stream-bound this is the 2x lever.
+    """A/B DL4J_TPU_LSTM_STREAM_DTYPE (f32 vs bf16 RESERVE: gates, cseq;
+    the other streams follow the compute dtype whatever it says) x unroll
+    caps. bf16 halves the reserve's HBM stream AND doubles the unroll the
+    VMEM budget admits — if the chain is stream-bound this is the 2x lever.
     Trace-time knobs -> fresh subprocess per cell; U candidates divide
     tbptt=50 (the kernel decrements non-divisors, which would silently
     re-measure a duplicate point)."""
     print(f"{'config':>16} {'U':>4} {'chars/s':>12}")
-    # under bf16 streams the fused two-layer kernel engages at the
+    # under a bf16 reserve the fused two-layer kernel engages at the
     # char-RNN shape (lstm_fused.supported2 VMEM budget) — the +nofuse
     # rows isolate its contribution from the stream-dtype win
     cells = [("float32", 2, {}),
@@ -315,9 +318,11 @@ def kernel_check(batch=64, width=512, tbptt=50, vocab=80,
     """The LSTM kernels against the repo's own oracle, the ``lax.scan``
     path of ``nn/layers/recurrent.py``, on one TBPTT segment of the
     char-RNN bench config: the network's loss and every parameter gradient
-    through ``lstm_cell`` with f32 streams, ``lstm_cell`` with bf16 streams
-    and ``lstm_fused`` (bf16 streams admit it), each compared with the
-    same network routed to the scan. The route is read off the lowered
+    through ``lstm_cell`` with an f32 reserve, ``lstm_cell`` with a bf16
+    reserve and ``lstm_fused`` (a bf16 reserve admits it), each compared
+    with the same network routed to the scan. The layer hands ``lstm_cell``
+    its other streams (xw, ys, dy, dz) in the config's compute dtype, bf16,
+    in both variants. The route is read off the lowered
     program — 4, 4 and 2 ``tpu_custom_call``s — so a variant cannot pass by
     quietly taking the oracle's path. Returns {variant: {...}}; raises
     AssertionError past :data:`CHECK_TOL`."""

@@ -36,14 +36,17 @@ def _pair_inputs(b=8, T=6, H=128, peep=False, seed=0):
 
 
 def _compose(xp1, rw1, p1, w2, b2, rw2, p2, h01, c01, h02, c02):
-    """Per-layer reference: layer 1 kernel, hoisted xp2 gemm, layer 2
-    kernel — exactly what the unfused container does."""
-    ys1, (h1T, c1T) = lk.lstm_scan(xp1, rw1, p1, h01, c01)
-    b, T, H = ys1.shape
-    xp2 = (ys1.astype(jnp.float32).reshape(b * T, H) @ w2
-           ).reshape(b, T, 4 * H) + b2
-    ys2, (h2T, c2T) = lk.lstm_scan(xp2, rw2, p2, h02, c02)
-    return ys2, (h1T, c1T), (h2T, c2T)
+    """Per-layer reference: layer 1 kernel, hoisted xw2 gemm, layer 2
+    kernel — exactly what the unfused container does: time-major between
+    the two (``xp1`` carries layer 1's bias already, so its kernel gets a
+    zero one; layer 2's ``b2`` goes to its kernel)."""
+    ys1, (h1T, c1T) = lk.lstm_scan(
+        jnp.swapaxes(xp1, 0, 1), jnp.zeros_like(b2), rw1, p1, h01, c01)
+    T, b, H = ys1.shape
+    xw2 = (ys1.astype(jnp.float32).reshape(T * b, H) @ w2
+           ).reshape(T, b, 4 * H)
+    ys2, (h2T, c2T) = lk.lstm_scan(xw2, b2, rw2, p2, h02, c02)
+    return jnp.swapaxes(ys2, 0, 1), (h1T, c1T), (h2T, c2T)
 
 
 @pytest.mark.parametrize("peep", [False, True])

@@ -44,7 +44,8 @@ QKV = [SDS((1, 256, 2, 64), jnp.float32)] * 3
 
 
 @pytest.mark.parametrize("entry,avals,kernels", [
-    (lk.lstm_scan, (XP, W, PEEP, ST, ST),
+    (lk.lstm_scan, (SDS((T, B, 4 * H), jnp.float32),
+                    SDS((4 * H,), jnp.float32), W, PEEP, ST, ST),
      ("lstm_cell_fwd", "lstm_cell_bwd")),
     (lf.lstm_scan2, (XP, W, PEEP, W, SDS((4 * H,), jnp.float32), W, PEEP,
                      ST, ST, ST, ST),
