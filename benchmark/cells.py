@@ -6,11 +6,12 @@ A configuration file (``configs/<name>.json``) holds ``builder``
 (``zoo:<Class>`` of ``deeplearning4j_tpu.models`` or
 ``file:<module>:<function>`` under this directory), ``builder_kwargs``,
 ``global_conf`` (attributes set on the configuration's ``global_conf``),
-``features`` / ``labels`` (how a batch is drawn), ``unit``, ``reference`` and
-``opcount`` (module names under ``reference/`` and ``opcount/``; left out,
-that part of the check or that metric is left out), ``correct_sample`` and
-the record of its origin (``source``, ``published``, ``reduced``,
-``assumed``). A traffic file (``traffic/<name>.json``) holds ``batch``,
+``features`` / ``labels`` (how a batch is drawn: their ``kind``s name the
+module ``batches/<features.kind>__<labels.kind>.py``), ``unit``,
+``reference`` and ``opcount`` (module names under ``reference/`` and
+``opcount/``; left out, that part of the check or that metric is left out),
+``correct_sample`` and the record of its origin (``source``, ``published``,
+``reduced``, ``assumed``). A traffic file (``traffic/<name>.json``) holds ``batch``,
 ``seq_len`` (sequences only), ``feed`` (``resident`` | ``hostfed``),
 ``pool`` (distinct batches cycled), ``entry`` (``fit`` |
 ``parallel_wrapper``), ``run_ahead`` (how many handed-out batches the
@@ -25,6 +26,7 @@ import importlib
 import importlib.util
 import json
 import os
+import pkgutil
 
 import numpy as np
 
@@ -118,7 +120,8 @@ def load_cell(manifest, root, workload, rehearse=False):
 
 def module(kind, name):
     """The module ``<kind>/<name>.py`` of this directory (``layer_metrics``,
-    ``opcount``, ``reference``, ``builders``), or None if there is none."""
+    ``opcount``, ``reference``, ``builders``, ``batches``), or None if there
+    is none."""
     full = f"{PACKAGE}.{kind}.{name}"
     if importlib.util.find_spec(full) is None:
         return None
@@ -158,33 +161,18 @@ def build_net(cell, seed):
 
 
 # ------------------------------------------------------------------- data
-def _one_hot(ids, classes):
-    out = np.zeros(ids.shape + (classes,), np.float32)
-    np.put_along_axis(out.reshape(-1, classes), ids.reshape(-1, 1), 1.0,
-                      axis=1)
-    return out
-
-
 def make_batches(config, seed, n, batch, seq_len=None):
     """``n`` distinct batches as the configuration's ``features`` /
-    ``labels`` describe them, drawn from ``seed``: float32 on the host, as
-    a user's iterator would hand them over."""
-    from deeplearning4j_tpu.datasets.dataset import DataSet
-
-    rng = np.random.default_rng([int(seed), 0xDA7A])
+    ``labels`` describe them, drawn from ``seed`` by the module
+    ``batches/<features.kind>__<labels.kind>.py``: on the host, as a user's
+    iterator would hand them over."""
     feats, labels = config["features"], config["labels"]
-    if feats["kind"] == "normal" and labels["kind"] == "one_hot":
-        shape = (batch,) + tuple(feats["shape"])
-        classes = int(labels["classes"])
-        return [DataSet(rng.standard_normal(shape, dtype=np.float32),
-                        _one_hot(rng.integers(0, classes, batch), classes))
-                for _ in range(n)]
-    if (feats["kind"] == "one_hot_sequence"
-            and labels["kind"] == "next_in_sequence"):
-        vocab = int(feats["vocab"])
-        ids = rng.integers(0, vocab, size=(n, batch, int(seq_len) + 1))
-        f = _one_hot(ids[:, :, :-1], vocab)
-        l = _one_hot(ids[:, :, 1:], vocab)
-        return [DataSet(f[i], l[i]) for i in range(n)]
-    raise SystemExit(f"no generator for features {feats['kind']!r} with "
-                     f"labels {labels['kind']!r}")
+    kind = module("batches", f"{feats['kind']}__{labels['kind']}")
+    if kind is None:
+        from benchmark import batches
+        found = sorted(m.name for m in pkgutil.iter_modules(batches.__path__))
+        raise SystemExit(f"no generator for features {feats['kind']!r} with "
+                         f"labels {labels['kind']!r} under batches/ (found: "
+                         f"{', '.join(found)})")
+    rng = np.random.default_rng([int(seed), 0xDA7A])
+    return kind.draw(rng, feats, labels, n, batch, seq_len)

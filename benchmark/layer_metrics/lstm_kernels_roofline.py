@@ -1,9 +1,10 @@
 """The Pallas LSTM kernels' share of their roofline in per cent: the least
 time the chip could take for what they need per step (the larger of
 operations over peak FLOP/s and bytes over peak HBM bytes/s, from shapes,
-``opcount/<config>.py: kernel_work``) over their device time per step. Above
-100 means the kernels' streams did not come from HBM (XLA may park them in
-on-chip memory between ops)."""
+``opcount/<config>.py: kernel_work``) over their device time per step. Only
+bytes that have to cross HBM count, so the share cannot pass 100: where a
+kernel's streams are values inside a program, which the compiler may keep on
+the chip, ``kernel_work`` counts none and the floor is the MXU's."""
 from benchmark.layer_metrics import pallas_ms_per_step
 
 

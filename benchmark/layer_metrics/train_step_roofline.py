@@ -1,7 +1,9 @@
 """The step program's share of its roofline in per cent: the least time the
 chip could take for what a step needs (the larger of needed operations over
 peak FLOP/s and needed bytes over peak bytes/s, ``opcount/<config>.py``)
-over the time the device spent in the step program (``device_step_ms``)."""
+over the time the device spent in the step program (``device_step_ms``).
+Needed bytes are those no program could keep out of HBM, so the share cannot
+pass 100."""
 from benchmark.layer_metrics import device_step_ms
 
 

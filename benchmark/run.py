@@ -173,8 +173,10 @@ def one_device(cell, seed, counters, seconds, note):
 
 @contextlib.contextmanager
 def _monitor_off():
-    """The fit loops fetch the loss after every step while the monitor is on
-    (its default) or a listener is attached: the window has neither."""
+    """With the monitor on (its default) the fit loops fetch every step's
+    loss at most two steps late (``monitor.StepCompletions``, lag 2, never
+    the newest step's) and write its metrics; with a listener attached they
+    fetch it at once. The window has neither: nothing is fetched."""
     import deeplearning4j_tpu.monitor as monitor
 
     was = monitor.enabled()

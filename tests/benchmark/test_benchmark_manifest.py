@@ -110,6 +110,9 @@ def test_cell_files_resolve_by_name(workload):
         assert hasattr(zoo, what)
     else:
         assert cells.module(*what.partition(":")[0].split(".")) is not None
+    for c in (cell, cells.load_cell(MANIFEST, ROOT, workload, rehearse=True)):
+        assert callable(cells.module("batches", "{}__{}".format(
+            c.config["features"]["kind"], c.config["labels"]["kind"])).draw)
     for kind in ("reference", "opcount"):       # optional; named, they exist
         if kind in cell.config:
             assert cells.module(kind, cell.config[kind]) is not None
@@ -123,3 +126,31 @@ def test_every_layer_metric_has_a_reader_of_its_name(metric):
     reader = cells.module("layer_metrics", metric)
     assert reader is not None and callable(reader.read)
     assert reader.__doc__                  # says what it reads, and where
+
+
+#: device time per step of the step program and of its Pallas kernels, ms, as
+#: the ledger's newest lines have them (PR 24, change side)
+MEASURED_MS = {"resnet50_b256_resident": (95.483, None),
+               "charrnn_b64_t5000_tbptt50_pool20": (52.289, 19.944),
+               "resnet50_pw4_b1024_resident": (100.46, None)}
+
+
+@pytest.mark.parametrize("workload", sorted(MEASURED_MS))
+def test_no_floor_is_above_what_the_chip_has_done(workload):
+    """A roofline share over 100 % is a count of work that is not needed: the
+    least times from ``opcount/`` lie under the times the ledger holds."""
+    from benchmark import device
+    cell = cells.load_cell(MANIFEST, ROOT, workload)
+    opcount = cells.module("opcount", cell.config["opcount"])
+    peaks = device.peaks("TPU v5 lite")
+    step_ms, kernel_ms = MEASURED_MS[workload]
+
+    def least_ms(work):
+        return 1e3 * max(work["flops"] / peaks["flops_bf16"],
+                         work["bytes"] / peaks["hbm_bytes_per_s"])
+    step = opcount.step_work(cell.config, cell.traffic)
+    assert 0.25 * step_ms < least_ms(step) <= step_ms   # batch is per chip
+    if kernel_ms is not None:
+        kernels = opcount.kernel_work(cell.config, cell.traffic)
+        assert 0.25 * kernel_ms < least_ms(kernels) <= kernel_ms
+        assert kernels["flops"] < step["flops"]
