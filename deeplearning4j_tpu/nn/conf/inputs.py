@@ -17,7 +17,8 @@ from typing import Optional
 from .serde import register
 
 __all__ = ["InputType", "InputTypeFeedForward", "InputTypeRecurrent",
-           "InputTypeConvolutional", "InputTypeConvolutionalFlat"]
+           "InputTypeLoopedRecurrent", "InputTypeConvolutional",
+           "InputTypeConvolutionalFlat"]
 
 
 @register
@@ -37,6 +38,14 @@ class InputTypeRecurrent:
 
     def arity(self):
         return self.size
+
+
+@register
+@dataclasses.dataclass
+class InputTypeLoopedRecurrent(InputTypeRecurrent):
+    """A sequence activation after each of ``passes`` passes of a looped
+    stack, stacked on a leading axis: [passes, b, T, size]."""
+    passes: int = 1
 
 
 @register

@@ -21,7 +21,8 @@ from typing import Any, List, Optional, Tuple
 
 from .serde import register
 from .inputs import (InputTypeConvolutional, InputTypeConvolutionalFlat,
-                     InputTypeFeedForward, InputTypeRecurrent)
+                     InputTypeFeedForward, InputTypeLoopedRecurrent,
+                     InputTypeRecurrent)
 
 __all__ = [
     "Layer", "BaseLayer", "FeedForwardLayer", "DenseLayer", "ConvolutionLayer",
@@ -35,7 +36,8 @@ __all__ = [
     "OutputLayer", "RnnOutputLayer", "LossLayer", "CenterLossOutputLayer",
     "AutoEncoder", "VariationalAutoencoder", "GlobalPoolingLayer",
     "Yolo2OutputLayer", "FrozenLayer", "ConvolutionMode", "SelfAttentionLayer",
-    "MoEDenseLayer",
+    "MoEDenseLayer", "RMSNorm", "GatedDenseLayer", "LoopedBlockStack",
+    "LoopLMOutputLayer",
 ]
 
 
@@ -141,6 +143,25 @@ class FeedForwardLayer(BaseLayer):
 class DenseLayer(FeedForwardLayer):
     """Fully connected layer (reference ``nn/conf/layers/DenseLayer.java``)."""
     has_bias: bool = True
+
+
+@register
+@dataclasses.dataclass
+class GatedDenseLayer(FeedForwardLayer):
+    """Gated feed-forward block (SwiGLU with the default activation):
+    ``(act(x Wgate) * (x Wup)) Wdown`` with ``n_hidden`` units between two
+    ``n_in`` → ``n_out`` sides. Net-new vs the 0.9.x reference; applied per
+    position, so [b, F] and [b, T, F] both pass through as they are."""
+    n_hidden: Optional[int] = None
+    activation: Optional[str] = "silu"
+
+    def get_output_type(self, index, input_type):
+        if isinstance(input_type, InputTypeRecurrent):
+            return InputTypeRecurrent(self.n_out, input_type.timeseries_length)
+        return InputTypeFeedForward(self.n_out)
+
+    def preprocessor_for(self, input_type):
+        return None
 
 
 @register
@@ -441,6 +462,17 @@ class LayerNormalization(FeedForwardLayer):
 
 @register
 @dataclasses.dataclass
+class RMSNorm(LayerNormalization):
+    """Root-mean-square normalization over the feature dim with a learned
+    gain and no bias: ``x / sqrt(mean(x^2) + eps) * gain``, the statistics in
+    float32 (Zhang, Sennrich 2019; the norm of the decoder-only families
+    after 2023). Net-new like :class:`LayerNormalization`, and stateless and
+    per-position like it."""
+    eps: float = 1e-6
+
+
+@register
+@dataclasses.dataclass
 class LocalResponseNormalization(Layer):
     """Reference ``nn/conf/layers/LocalResponseNormalization.java``."""
     k: float = 2.0
@@ -602,6 +634,46 @@ class SelfAttentionLayer(BaseRecurrentLayer):
     #: cross-segment TBPTT attention; static so the cached step keeps one
     #: compiled shape. Streams beyond this length roll over the tail.
     stream_max_length: int = 512
+    #: rotary position embedding base; None (today's behaviour) rotates
+    #: nothing. Applied to q and k over the whole head dim, pairing
+    #: (i, i + head_dim/2), at the tokens' global positions.
+    rope_theta: Optional[float] = None
+    #: False drops the output projection's bias (the only bias the layer has)
+    has_bias: bool = True
+
+
+@register
+@dataclasses.dataclass
+class LoopedBlockStack(BaseRecurrentLayer):
+    """``num_blocks`` decoder blocks applied ``num_passes`` times with ONE set
+    of weights (a looped / universal transformer; Ouro's LoopLM). A block is
+    sandwich-normed: ``a = x + RMSNorm(Attn(RMSNorm(x)))``,
+    ``y = a + RMSNorm(SwiGLU(RMSNorm(a)))`` with rotary causal attention and
+    no biases; after every pass over the blocks comes a final RMSNorm, and
+    its output both feeds the next pass and is handed on. The output is the
+    ``num_passes`` normed states stacked [R, b, T, n_out], which
+    :class:`LoopLMOutputLayer` reads.
+
+    The blocks' weights are stacked leaf by leaf ``[num_blocks, ...]`` (one
+    leaf per kind of weight) and run under ``lax.scan``; the training step
+    keeps each block application's input and recomputes the rest in the
+    backward pass."""
+    num_blocks: int = 1
+    num_passes: int = 1
+    num_heads: int = 4
+    head_dim: Optional[int] = None
+    n_hidden: Optional[int] = None
+    eps: float = 1e-6
+    rope_theta: Optional[float] = 10000.0
+
+    def get_output_type(self, index, input_type):
+        t = input_type.timeseries_length if isinstance(input_type, InputTypeRecurrent) else None
+        return InputTypeLoopedRecurrent(self.n_out, t, self.num_passes)
+
+    def set_n_in(self, input_type, override=False):
+        super().set_n_in(input_type, override)
+        if self.n_out is None:
+            self.n_out = self.n_in
 
 
 @register
@@ -628,6 +700,23 @@ class RnnOutputLayer(OutputLayer):
         if isinstance(input_type, InputTypeFeedForward):
             return FeedForwardToRnnPreProcessor()
         return None
+
+
+@register
+@dataclasses.dataclass
+class LoopLMOutputLayer(RnnOutputLayer):
+    """Output layer of a looped language model: reads the [R, b, T, n_in]
+    states of a :class:`LoopedBlockStack`, applies the one head to every
+    pass, and weighs the passes' next-token losses by a learned exit gate
+    (Ouro's stage-I objective): per token ``lambda_t = sigmoid(h_t . w + b)``,
+    ``p_t = lambda_t * prod_{j<t}(1 - lambda_j)`` with the last pass taking
+    the remainder, and the loss ``sum_t p_t * xent_t - entropy_weight *
+    H(p)``, reduced as ``sparse_mcxent`` reduces. Labels are integer ids
+    [b, T]. Inference returns the last pass's softmax."""
+    loss: str = "sparse_mcxent"
+    activation: Optional[str] = "softmax"
+    has_bias: bool = False
+    entropy_weight: float = 0.05
 
 
 @register

@@ -28,7 +28,7 @@ from .conf import BackpropType, CacheMode, GradientNormalization
 from ..monitor.jitwatch import monitored_jit
 from .conf.graph import ComputationGraphConfiguration
 from .conf.layers import Layer
-from .conf.inputs import InputTypeConvolutional
+from .conf.inputs import InputTypeConvolutional, InputTypeLoopedRecurrent
 from jax.ad_checkpoint import checkpoint_name
 
 from .layers import impl_for
@@ -202,8 +202,12 @@ class ComputationGraph:
             pre = conf.input_preprocessors.get(out_name)
             if pre is not None:
                 x = pre(x, ctx)
-            mask = lm if lm is not None else (masks.get(in_name) if x.ndim == 3
-                                              else None)
+            # a sequence's [b, T] mask follows [b, T, F] activations and a
+            # looped stack's [R, b, T, F] (InputTypeLoopedRecurrent)
+            looped = isinstance((self._types or {}).get(in_name),
+                                InputTypeLoopedRecurrent)
+            mask = lm if lm is not None else (
+                masks.get(in_name) if x.ndim == 3 or looped else None)
             with jax.named_scope("loss"):
                 total = total + impl.loss_on(params[out_name],
                                              states[out_name], x, lbl,
@@ -309,6 +313,14 @@ class ComputationGraph:
         n_iter = 1 if single_iteration else _n_iterations(self.gc)
         if n_iter > 1:
             step = _scan_iterations(step, n_iter, with_rnn_state=with_rnn_state)
+        looped = sum(getattr(i, "block_applications", 0)
+                     for i in self.impls.values())
+        if looped:
+            _mon.get_registry().gauge(
+                "looped_block_applications",
+                "Block applications per step of the network's looped stacks "
+                "(passes x blocks), set when the step is built",
+                network="cg").set(looped)
         return monitored_jit(step, name="cg/step",
                              donate_argnums=(0, 2))
 

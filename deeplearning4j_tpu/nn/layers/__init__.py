@@ -15,4 +15,5 @@ from . import variational  # noqa: F401
 from . import objdetect  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe  # noqa: F401
+from . import looped  # noqa: F401
 from . import wrapper  # noqa: F401

@@ -84,6 +84,21 @@ def _dense_attention(q, k, v, visible, compute_dtype, dropout_rate=0.0,
                       preferred_element_type=pet_dtype(compute_dtype))
 
 
+def rope(x, theta, start=0):
+    """Rotary position embedding of ``x`` [b, T, h, d] at positions
+    ``start`` .. ``start + T - 1``: the pair (i, i + d/2) of every head is
+    rotated by ``position * theta ** (-2 i / d)`` (the rotate-half pairing).
+    Angles and rotation in float32; returned in ``x``'s dtype."""
+    T, half = x.shape[1], x.shape[-1] // 2
+    inv_freq = 1.0 / (float(theta) ** (jnp.arange(half, dtype=jnp.float32)
+                                       / half))
+    angle = (start + jnp.arange(T, dtype=jnp.float32))[:, None] * inv_freq
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 @implements("SelfAttentionLayer")
 class SelfAttentionImpl(LayerImpl):
     def _dims(self):
@@ -101,8 +116,9 @@ class SelfAttentionImpl(LayerImpl):
             "Wk": self._init_w(k2, (c.n_in, h * d), c.n_in, h * d),
             "Wv": self._init_w(k3, (c.n_in, h * d), c.n_in, h * d),
             "Wo": self._init_w(k4, (h * d, c.n_out), h * d, c.n_out),
-            "b": self._init_b((c.n_out,)),
         }
+        if c.has_bias:
+            params["b"] = self._init_b((c.n_out,))
         return params, {}
 
     #: training forward is scan-free — the stream state must not disable
@@ -187,6 +203,14 @@ class SelfAttentionImpl(LayerImpl):
                  if ctx is not None and idx is not None else None)
         from ...parallel.sequence import current_sp_axis
         sp_axis = current_sp_axis()
+        if c.rope_theta is not None:
+            if sp_axis is not None:
+                raise ValueError(
+                    "SelfAttentionLayer: rope_theta under a sequence-parallel "
+                    "step is not supported (a shard does not know its "
+                    "global positions)")
+            start = 0 if carry is None else carry[3]
+            q, k = rope(q, c.rope_theta, start), rope(k, c.rope_theta, start)
         if carry is not None:
             o, new_carry = self._cached_attention(
                 q, k, v, carry, cd, key_mask=mask,
@@ -216,5 +240,7 @@ class SelfAttentionImpl(LayerImpl):
             o = mha(q, k, v, c.causal, cd, c.dropout_rate, rng, train,
                     key_mask=mask)
         o = o.reshape(b, T, h * d)
-        y = o @ params["Wo"].astype(o.dtype) + params["b"].astype(o.dtype)
+        y = o @ params["Wo"].astype(o.dtype)
+        if "b" in params:
+            y = y + params["b"].astype(o.dtype)
         return self.activation(y).astype(self.out_dtype), state

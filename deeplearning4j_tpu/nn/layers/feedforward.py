@@ -1,5 +1,5 @@
-"""Feed-forward layer implementations: Dense, Activation, Dropout, Embedding,
-AutoEncoder.
+"""Feed-forward layer implementations: Dense, GatedDense, Activation, Dropout,
+Embedding, AutoEncoder.
 
 TPU-native equivalents of reference ``nn/layers/feedforward/`` +
 ``nn/layers/BaseLayer.java`` (dense preOutput/activate) — gemms hit the MXU via a
@@ -40,6 +40,28 @@ class DenseImpl(LayerImpl):
         if "b" in params:
             z = z + params["b"].astype(z.dtype)
         return self.activation(z).astype(self.out_dtype), state
+
+
+@implements("GatedDenseLayer")
+class GatedDenseImpl(LayerImpl):
+    """``(act(x Wgate) * (x Wup)) Wdown`` (SwiGLU with ``silu``): three gemms
+    in the compute dtype, the gate's activation and the product on the
+    gemms' outputs."""
+
+    def init(self, rng):
+        c = self.conf
+        h = int(c.n_hidden)
+        kg, ku, kd = jax.random.split(rng, 3)
+        return {"Wgate": self._init_w(kg, (c.n_in, h), c.n_in, h),
+                "Wup": self._init_w(ku, (c.n_in, h), c.n_in, h),
+                "Wdown": self._init_w(kd, (h, c.n_out), h, c.n_out)}, {}
+
+    def forward(self, params, state, x, train=False, rng=None, mask=None, ctx=None):
+        x = self.maybe_dropout(x, train, rng)
+        cd = self.compute_dtype
+        hidden = (self.activation(_dot(x, params["Wgate"], cd))
+                  * _dot(x, params["Wup"], cd))
+        return _dot(hidden, params["Wdown"], cd).astype(self.out_dtype), state
 
 
 @implements("ActivationLayer")

@@ -1,4 +1,5 @@
-"""Normalization implementations: BatchNormalization, LocalResponseNormalization.
+"""Normalization implementations: BatchNormalization, LayerNormalization,
+RMSNorm, LocalResponseNormalization.
 
 TPU-native equivalents of reference ``nn/layers/normalization/{BatchNormalization,
 LocalResponseNormalization}.java`` (cuDNN helper hooks at
@@ -118,6 +119,34 @@ class LayerNormImpl(LayerImpl):
         inv = jax.lax.rsqrt(var + self.conf.eps)
         y = (xs - mean) * inv
         y = (y * params["gain"].astype(sd) + params["bias"].astype(sd))
+        return y.astype(x.dtype), state
+
+    def regularization(self, params):
+        return 0.0  # norm params free of l1/l2, like BN
+
+
+def rms_norm(x, gain, eps, stat_dtype):
+    """``x / sqrt(mean(x^2, last axis) + eps) * gain`` with the statistics
+    and the product in ``stat_dtype``; returned in ``stat_dtype``."""
+    xs = x.astype(stat_dtype)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xs), axis=-1, keepdims=True) + eps)
+    return xs * inv * gain.astype(stat_dtype)
+
+
+@implements("RMSNorm")
+class RMSNormImpl(LayerImpl):
+    """Per-position RMS normalization with a learned gain (see the config
+    class); float32 statistics under a bfloat16 compute policy, one
+    elementwise kernel around one f32-accumulated moment."""
+
+    save_output = False  # elementwise given the moment: recompute
+
+    def init(self, rng):
+        return {"gain": host_full((self.conf.n_out,), 1, self.dtype)}, {}
+
+    def forward(self, params, state, x, train=False, rng=None, mask=None, ctx=None):
+        y = rms_norm(x, params["gain"], self.conf.eps,
+                     acc_dtype(self.compute_dtype))
         return y.astype(x.dtype), state
 
     def regularization(self, params):

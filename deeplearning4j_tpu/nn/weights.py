@@ -37,6 +37,10 @@ def _np_rng(rng):
     return np.random.default_rng([int(x) for x in arr])
 
 
+#: elements ``_normal`` samples at a time (1 MB of float64)
+_CHUNK = 1 << 17
+
+
 def _normal(rng, shape, dtype, scale=1.0, shift=0.0):
     """Sampling, scaling and shifting all happen host-side in the eager path:
     an eager device multiply/add would compile one tiny program per distinct
@@ -44,8 +48,21 @@ def _normal(rng, shape, dtype, scale=1.0, shift=0.0):
     g = _np_rng(rng)
     if g is None:
         return jax.random.normal(rng, shape, dtype) * scale + shift
-    return jnp.asarray(
-        (g.standard_normal(size=shape) * scale + shift).astype(dtype))
+    # a cache-sized piece at a time, in place, the same stream and the same
+    # float64 arithmetic as one call over the whole shape: drawn whole, a
+    # leaf of 100M elements (a 49,152-word embedding) goes through main
+    # memory in three float64 temporaries of its size
+    out = np.empty(shape, np.dtype(dtype))
+    flat = out.reshape(-1)
+    buf = np.empty(min(_CHUNK, flat.size))
+    for i in range(0, flat.size, _CHUNK):
+        part = flat[i:i + _CHUNK]
+        draw = buf[:part.size]
+        g.standard_normal(out=draw)
+        draw *= scale
+        draw += shift
+        part[...] = draw
+    return jnp.asarray(out)
 
 
 def _uniform(rng, shape, dtype, lo, hi):

@@ -100,6 +100,22 @@ class TestWeightInit:
         expected_std = np.sqrt(2.0 / 1500)
         assert abs(float(jnp.std(w)) - expected_std) < 0.1 * expected_std
 
+    @pytest.mark.parametrize("chunk", [7, 1 << 17])
+    def test_normal_sampled_in_chunks_is_the_one_call_stream(self, chunk,
+                                                             monkeypatch):
+        """Leaves are sampled a piece at a time (``weights._CHUNK``):
+        the values are those of one ``standard_normal`` call over the whole
+        shape, scaled and cast as before, to the bit."""
+        from deeplearning4j_tpu.nn import weights
+        monkeypatch.setattr(weights, "_CHUNK", chunk)
+        rng = jax.random.PRNGKey(3)
+        got = weights._normal(rng, (5, 4, 3), jnp.float32, scale=0.3,
+                              shift=0.1)
+        whole = (weights._np_rng(rng).standard_normal(size=(5, 4, 3)) * 0.3
+                 + 0.1).astype(np.float32)
+        np.testing.assert_array_equal(got, whole)
+        assert got.dtype == jnp.float32 and got.shape == (5, 4, 3)
+
     def test_zero_ones_identity(self):
         rng = jax.random.PRNGKey(0)
         assert np.all(np.asarray(init_weight(rng, (3, 3), 3, 3, WeightInit.ZERO)) == 0)

@@ -1,0 +1,423 @@
+"""The looped language model's layers (``RMSNorm``, rotary
+``SelfAttentionLayer``, ``GatedDenseLayer``, ``LoopedBlockStack``,
+``LoopLMOutputLayer``) at tiny widths on the CPU, float32: against the plain
+reference (``benchmark/reference/ouro_2_6b.py``), against the same model
+unrolled into the package's own layers, and against themselves with the
+scan, the checkpoint and the kernels swapped for their plain forms."""
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import ouro_2_6b as ref
+from deeplearning4j_tpu import Adam, NeuralNetConfiguration
+from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.monitor import get_registry
+from deeplearning4j_tpu.nn.conf.graph import (ComputationGraphConfiguration,
+                                              ElementWiseVertex)
+from deeplearning4j_tpu.nn.conf.layers import (EmbeddingSequenceLayer,
+                                               GatedDenseLayer,
+                                               LoopedBlockStack,
+                                               LoopLMOutputLayer, RMSNorm,
+                                               RnnOutputLayer,
+                                               SelfAttentionLayer)
+from deeplearning4j_tpu.nn.graph import ComputationGraph
+from deeplearning4j_tpu.nn.layers import impl_for
+from deeplearning4j_tpu.nn.layers.attention import mha, rope
+from deeplearning4j_tpu.nn.layers.looped import ATTN_KEYS, FFN_KEYS
+from deeplearning4j_tpu.nn.layers.output import exit_distribution
+from deeplearning4j_tpu.nn.losses import get_loss
+from deeplearning4j_tpu.ops import flash_attention as fa
+
+V, D, F, HEADS, HEAD_DIM, L, T = 40, 32, 48, 4, 8, 2, 12
+THETA, EPS = 1e6, 1e-6
+
+
+def _builder(seed=3):
+    return (NeuralNetConfiguration.builder().seed(seed)
+            .updater(Adam(learning_rate=1e-3)).activation("identity")
+            .graph_builder().add_inputs("ids")
+            .add_layer("embed", EmbeddingSequenceLayer(n_in=V, n_out=D),
+                       "ids"))
+
+
+def looped_conf(passes, beta=0.05, seed=3):
+    return (_builder(seed)
+            .add_layer("stack", LoopedBlockStack(
+                n_in=D, n_out=D, num_blocks=L, num_passes=passes,
+                num_heads=HEADS, head_dim=HEAD_DIM, n_hidden=F, eps=EPS,
+                rope_theta=THETA), "embed")
+            .add_layer("out", LoopLMOutputLayer(n_in=D, n_out=V,
+                                                entropy_weight=beta), "stack")
+            .set_outputs("out").build())
+
+
+def shaken(net, scale=0.2, seed=0):
+    """Gains start at 1 and the gate's bias at 0: move every leaf so that
+    each takes part."""
+    rng = np.random.default_rng(seed)
+    net.params = jax.tree_util.tree_map(
+        lambda x: x + scale * jnp.asarray(rng.standard_normal(x.shape),
+                                          x.dtype), net.params)
+    return net
+
+
+def sample(batch=2, seed=1):
+    ids = np.random.default_rng(seed).integers(0, V, (batch, T + 1),
+                                               dtype=np.int32)
+    return DataSet(np.ascontiguousarray(ids[:, :-1]),
+                   np.ascontiguousarray(ids[:, 1:]))
+
+
+def assert_trees_close(a, b, tol):
+    """Leaf by leaf, ||x - y|| <= tol ||y||."""
+    la, ta = jax.tree_util.tree_flatten_with_path(a)
+    lb, tb = jax.tree_util.tree_flatten_with_path(b)
+    assert ta == tb
+    for (path, x), (_, y) in zip(la, lb):
+        err = float(jnp.linalg.norm(x - y) / jnp.linalg.norm(y))
+        assert err <= tol, (jax.tree_util.keystr(path), err)
+
+
+# ------------------------------------------------------- against the reference
+@pytest.mark.parametrize("passes", [1, 2, 4])
+def test_system_matches_the_reference_leaf_by_leaf(passes):
+    net = shaken(ComputationGraph(looped_conf(passes)).init())
+    ds = sample()
+    grads, loss = net.compute_gradient_and_score(ds)
+    ref_loss, ref_grads = jax.value_and_grad(ref.loss)(
+        net.params, jnp.asarray(ds.features), jnp.asarray(ds.labels),
+        heads=HEADS, passes=passes, theta=THETA, eps=EPS, beta=0.05)
+    assert abs(loss - float(ref_loss)) <= 1e-4 * abs(float(ref_loss))
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(ref_grads)):
+        if passes == 1 and "['out']['gate_" in jax.tree_util.keystr(path):
+            # the one pass takes all: the gate moves nothing, on either side
+            assert not np.any(np.asarray(g)) and not np.any(np.asarray(r))
+            continue
+        err = float(jnp.linalg.norm(g - r) / jnp.linalg.norm(r))
+        assert err <= 1e-4, (jax.tree_util.keystr(path), err)
+
+
+@pytest.mark.parametrize("part", ["rms_norm", "rope", "gated_ffn"])
+def test_each_new_layer_against_the_reference(part):
+    rng = np.random.default_rng(2)
+    gc = looped_conf(1).global_conf
+    x = jnp.asarray(rng.standard_normal((2, T, D)), jnp.float32)
+    if part == "rms_norm":
+        impl = impl_for(RMSNorm(n_in=D, n_out=D, eps=EPS), gc)
+        gain = jnp.asarray(rng.standard_normal(D), jnp.float32)
+        got, _ = impl.forward({"gain": gain}, {}, x)
+        want = ref.rms_norm(x, gain, EPS)
+    elif part == "rope":
+        q = x.reshape(2, T, HEADS, HEAD_DIM)
+        got, want = rope(q, THETA), ref.rope(q, THETA)
+        # theta and the pairing, written out for one position and pair
+        t, i, half = 5, 1, HEAD_DIM // 2
+        a = t * THETA ** (-i / half)
+        np.testing.assert_allclose(
+            got[0, t, 0, [i, i + half]],
+            [q[0, t, 0, i] * np.cos(a) - q[0, t, 0, i + half] * np.sin(a),
+             q[0, t, 0, i + half] * np.cos(a) + q[0, t, 0, i] * np.sin(a)],
+            rtol=1e-5)
+    else:
+        impl = impl_for(GatedDenseLayer(n_in=D, n_out=D, n_hidden=F), gc)
+        p, _ = impl.init(jax.random.PRNGKey(0))
+        assert set(p) == {"Wgate", "Wup", "Wdown"}
+        got, _ = impl.forward(p, {}, x)
+        want = (jax.nn.silu(x @ p["Wgate"]) * (x @ p["Wup"])) @ p["Wdown"]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_rotary_scores_depend_on_the_distance_only():
+    rng = np.random.default_rng(4)
+    q = jnp.asarray(rng.standard_normal(HEAD_DIM), jnp.float32)
+    k = jnp.asarray(rng.standard_normal(HEAD_DIM), jnp.float32)
+
+    def score(tq, tk):      # the same two vectors placed at tq and tk
+        at = lambda v, t: rope(jnp.broadcast_to(v, (1, 24, 1, HEAD_DIM)),
+                               100.0)[0, t, 0]
+        return float(at(q, tq) @ at(k, tk))
+
+    assert score(9, 4) == pytest.approx(score(20, 15), rel=1e-4)
+    assert score(9, 4) == pytest.approx(score(5, 0), rel=1e-4)
+    assert abs(score(9, 4) - score(9, 5)) > 1e-3
+
+
+# --------------------------------------------------- against its own plain forms
+def test_one_pass_without_entropy_is_the_plain_next_token_loss():
+    net = shaken(ComputationGraph(looped_conf(1, beta=0.0)).init())
+    ds = sample()
+    states, _ = net.impls["stack"].forward(
+        net.params["stack"], {},
+        net.impls["embed"].forward(net.params["embed"], {},
+                                   jnp.asarray(ds.features))[0], train=True)
+    assert states.shape == (1, 2, T, D)
+    plain = get_loss("sparse_mcxent")(jnp.asarray(ds.labels),
+                                      states[0] @ net.params["out"]["W"],
+                                      "softmax", None)
+    assert net.score(ds, training=True) == pytest.approx(float(plain),
+                                                         rel=1e-6)
+
+
+def unrolled_conf(passes, seed=3):
+    """The same model from the package's single layers: ``passes`` x L
+    blocks, vertex by vertex, a final norm after every L, and a plain
+    next-token head on the last state."""
+    g, prev = _builder(seed), "embed"
+    norm = lambda: RMSNorm(n_in=D, n_out=D, eps=EPS)
+    for t in range(passes):
+        for l in range(L):
+            b = f"p{t}b{l}"
+            g = (g.add_layer(f"{b}-g1", norm(), prev)
+                 .add_layer(f"{b}-attn", SelfAttentionLayer(
+                     n_in=D, n_out=D, num_heads=HEADS, head_dim=HEAD_DIM,
+                     causal=True, rope_theta=THETA, has_bias=False), f"{b}-g1")
+                 .add_layer(f"{b}-g2", norm(), f"{b}-attn")
+                 .add_vertex(f"{b}-a", ElementWiseVertex(op="add"), prev,
+                             f"{b}-g2")
+                 .add_layer(f"{b}-g3", norm(), f"{b}-a")
+                 .add_layer(f"{b}-ffn", GatedDenseLayer(n_in=D, n_out=D,
+                                                        n_hidden=F), f"{b}-g3")
+                 .add_layer(f"{b}-g4", norm(), f"{b}-ffn")
+                 .add_vertex(f"{b}-y", ElementWiseVertex(op="add"), f"{b}-a",
+                             f"{b}-g4"))
+            prev = f"{b}-y"
+        g = g.add_layer(f"p{t}-gf", norm(), prev)
+        prev = f"p{t}-gf"
+    return (g.add_layer("out", RnnOutputLayer(
+        n_in=D, n_out=V, activation="softmax", loss="sparse_mcxent",
+        has_bias=False), prev).set_outputs("out").build())
+
+
+def test_looped_stack_is_the_unrolled_graph_with_tied_weights():
+    passes = 2
+    looped = shaken(ComputationGraph(looped_conf(passes, beta=0.0)).init())
+    # a shut gate hands the whole weight to the last pass: the looped loss is
+    # then the plain loss of h^R, which the unrolled graph computes
+    looped.params["out"]["gate_W"] = jnp.zeros(D)
+    looped.params["out"]["gate_b"] = jnp.full((1,), -50.0)
+    stack = looped.params["stack"]
+    net = ComputationGraph(unrolled_conf(passes)).init()
+    net.params["embed"] = looped.params["embed"]
+    net.params["out"] = {"W": looped.params["out"]["W"]}
+    for t in range(passes):
+        for l in range(L):
+            b = f"p{t}b{l}"
+            for i in (1, 2, 3, 4):
+                net.params[f"{b}-g{i}"] = {"gain": stack[f"g{i}"][l]}
+            net.params[f"{b}-attn"] = {k: stack[k][l] for k in ATTN_KEYS}
+            net.params[f"{b}-ffn"] = {k: stack[k][l] for k in FFN_KEYS}
+        net.params[f"p{t}-gf"] = {"gain": stack["gf"]}
+    ds = sample()
+    grads, loss = looped.compute_gradient_and_score(ds)
+    copies, plain = net.compute_gradient_and_score(ds)
+    assert loss == pytest.approx(plain, rel=1e-5)
+    tied = {k: jnp.stack([sum(copies[f"p{t}b{l}-{v}"][k]
+                              for t in range(passes)) for l in range(L)])
+            for v, keys in (("attn", ATTN_KEYS), ("ffn", FFN_KEYS))
+            for k in keys}
+    for i in (1, 2, 3, 4):
+        tied[f"g{i}"] = jnp.stack([sum(copies[f"p{t}b{l}-g{i}"]["gain"]
+                                       for t in range(passes))
+                                   for l in range(L)])
+    tied["gf"] = sum(copies[f"p{t}-gf"]["gain"] for t in range(passes))
+    assert_trees_close(grads["stack"], tied, 1e-5)
+    assert_trees_close(grads["embed"], copies["embed"], 1e-5)
+    assert_trees_close(grads["out"]["W"], copies["out"]["W"], 1e-5)
+
+
+def test_scan_over_stacked_leaves_is_a_loop_over_the_blocks():
+    net = shaken(ComputationGraph(looped_conf(3)).init())
+    impl, p = net.impls["stack"], net.params["stack"]
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((2, T, D)),
+                    jnp.float32)
+    states, _ = impl.forward(p, {}, x)
+    h, want = x, []
+    for _ in range(3):
+        for l in range(L):
+            h = impl.block({k: v[l] for k, v in p.items() if k != "gf"}, h)
+        h = ref.rms_norm(h, p["gf"], EPS)
+        want.append(h)
+    assert_trees_close(states, jnp.stack(want), 1e-5)
+    assert len(p) == 12 and all(v.shape[0] == L for k, v in p.items()
+                                if k != "gf")
+    # each stacked leaf is one draw at the per-block fan-in and fan-out
+    drawn = ComputationGraph(looped_conf(3)).init().params["stack"]
+    for k in ATTN_KEYS + FFN_KEYS:
+        assert float(jnp.std(drawn[k])) == pytest.approx(
+            (2 / sum(drawn[k].shape[1:])) ** 0.5, rel=0.1), k
+        assert not np.allclose(drawn[k][0], drawn[k][1]), k
+
+
+def test_block_checkpoint_changes_no_number(monkeypatch):
+    ds = sample()
+    on = shaken(ComputationGraph(looped_conf(2)).init())
+    g_on, l_on = on.compute_gradient_and_score(ds)
+    # the checkpoint is there: the recomputed forward is in the backward
+    text = str(jax.make_jaxpr(lambda p: on._loss_fn(
+        p, on.states, [jnp.asarray(ds.features)], [jnp.asarray(ds.labels)],
+        None, None, True, None)[0])(on.params))
+    assert "checkpoint" in text or "remat" in text
+    # and without it (the stack's and the head's) every number is the same
+    monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: f)
+    off = shaken(ComputationGraph(looped_conf(2)).init())
+    text = str(jax.make_jaxpr(lambda p: off._loss_fn(
+        p, off.states, [jnp.asarray(ds.features)], [jnp.asarray(ds.labels)],
+        None, None, True, None)[0])(off.params))
+    assert "checkpoint" not in text and "remat" not in text
+    g_off, l_off = off.compute_gradient_and_score(ds)
+    assert l_on == pytest.approx(l_off, rel=1e-6)
+    assert_trees_close(g_on, g_off, 1e-6)
+
+
+def test_exit_distribution_sums_to_one_and_the_last_pass_takes_the_rest():
+    z = jnp.asarray(np.random.default_rng(6).standard_normal((4, 3, 5)) * 3,
+                    jnp.float32)
+    p, log_p = exit_distribution(z)
+    lam = jax.nn.sigmoid(z)
+    np.testing.assert_allclose(p.sum(0), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(p[0], lam[0], rtol=1e-6)
+    np.testing.assert_allclose(p[-1], jnp.prod(1 - lam[:-1], axis=0),
+                               rtol=1e-4)
+    np.testing.assert_allclose(p, ref.exit_distribution(lam), rtol=1e-4,
+                               atol=1e-7)
+    np.testing.assert_allclose(jnp.exp(log_p), p, rtol=1e-6)
+    # one pass exits with certainty, a saturated gate gives no NaN
+    np.testing.assert_array_equal(exit_distribution(z[:1])[0], 1.0)
+    sat, log_sat = exit_distribution(jnp.asarray([[200.0], [0.0], [0.0]]))
+    assert np.isfinite(np.asarray(sat * log_sat)).all()
+
+
+# ----------------------------------------------------------------- attention
+@pytest.fixture
+def interpret(monkeypatch):
+    monkeypatch.setattr(fa, "_FORCE_INTERPRET", True)
+
+
+def test_rotary_attention_through_the_flash_kernel_matches_dense(interpret,
+                                                                 monkeypatch):
+    rng = np.random.default_rng(7)
+    Tk = 2 * fa.MIN_BLOCK
+    gc = looped_conf(1).global_conf
+    impl = impl_for(SelfAttentionLayer(
+        n_in=D, n_out=D, num_heads=2, head_dim=16, causal=True,
+        rope_theta=THETA, has_bias=False), gc)
+    p, _ = impl.init(jax.random.PRNGKey(1))
+    x = jnp.asarray(rng.standard_normal((1, Tk, D)), jnp.float32)
+    assert fa.supported(Tk, 16, 0.0, None)
+    flash, _ = impl.forward(p, {}, x)
+    monkeypatch.setattr(fa, "_FORCE_INTERPRET", False)   # the dense path
+    assert not fa.supported(Tk, 16, 0.0, None)
+    dense, _ = impl.forward(p, {}, x)
+    np.testing.assert_allclose(flash, dense, rtol=2e-4, atol=2e-5)
+
+
+def test_attention_without_rotary_and_with_bias_is_bit_equal_to_before():
+    rng = np.random.default_rng(8)
+    gc = looped_conf(1).global_conf
+    conf = SelfAttentionLayer(n_in=D, n_out=D, num_heads=HEADS, causal=True)
+    assert conf.rope_theta is None and conf.has_bias is True
+    impl = impl_for(conf, gc)
+    p, _ = impl.init(jax.random.PRNGKey(2))
+    assert set(p) == {"Wq", "Wk", "Wv", "Wo", "b"}
+    p["b"] = jnp.asarray(rng.standard_normal(D), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((2, T, D)), jnp.float32)
+    got, _ = impl.forward(p, {}, x)
+
+    def before(p, x):       # the forward as it stood before this layer grew
+        b, t, _ = x.shape
+        q, k, v = ((x @ p[w].astype(x.dtype)).reshape(b, t, HEADS, D // HEADS)
+                   for w in ("Wq", "Wk", "Wv"))
+        o = mha(q, k, v, True, jnp.float32, 0.0, None, False, key_mask=None)
+        o = o.reshape(b, t, D)
+        return o @ p["Wo"].astype(o.dtype) + p["b"].astype(o.dtype)
+
+    np.testing.assert_array_equal(got, before(p, x))
+
+
+def test_rotary_streaming_matches_the_full_sequence():
+    conf = (_builder()
+            .add_layer("attn", SelfAttentionLayer(
+                n_in=D, n_out=D, num_heads=HEADS, causal=True,
+                rope_theta=100.0, has_bias=False, stream_max_length=T),
+                "embed")
+            .add_layer("out", RnnOutputLayer(n_in=D, n_out=V,
+                                             activation="softmax",
+                                             loss="sparse_mcxent"), "attn")
+            .set_outputs("out").build())
+    net = ComputationGraph(conf).init()
+    ids = sample().features
+    full = net.output(ids)
+    net.rnn_clear_previous_state()
+    parts = [net.rnn_time_step(ids[:, s:s + 4, None])
+             for s in range(0, T, 4)]
+    np.testing.assert_allclose(jnp.concatenate(parts, axis=1), full,
+                               rtol=1e-4, atol=1e-6)
+
+
+# -------------------------------------------------------- through the container
+def test_trains_through_fit_and_round_trips_through_json():
+    conf = looped_conf(2)
+    again = ComputationGraphConfiguration.from_json(conf.to_json())
+    assert again.to_json() == conf.to_json()
+    assert isinstance(again.vertices["stack"], LoopedBlockStack)
+    assert again.vertices["out"].entropy_weight == 0.05
+    net = ComputationGraph(again).init()
+    ds = sample()
+    first = net.score(ds, training=True)
+    for _ in range(5):
+        net.fit(ds)
+    assert float(net.score_) < first
+    assert net.output(ds.features).shape == (2, T, V)
+    np.testing.assert_allclose(net.output(ds.features).sum(-1), 1.0,
+                               rtol=1e-5)
+    gauge = get_registry().snapshot()["looped_block_applications"]
+    assert [r["value"] for r in gauge if r["labels"] == {"network": "cg"}] \
+        == [2 * L]
+
+
+def test_the_looped_output_layer_takes_no_other_loss():
+    conf = looped_conf(2)
+    conf.vertices["out"].loss = "mse"
+    with pytest.raises(ValueError, match="no other loss"):
+        ComputationGraph(conf).init()
+
+
+def test_a_sequence_mask_reaches_the_looped_loss():
+    net = shaken(ComputationGraph(looped_conf(2)).init())
+    ds = sample()
+    mask = np.ones((2, T), np.float32)
+    mask[:, T // 2:] = 0
+    masked = DataSet(ds.features, ds.labels, labels_mask=mask)
+    half = DataSet(ds.features[:, :T // 2], ds.labels[:, :T // 2])
+    # causal: the first half's loss does not see the second half
+    assert net.score(masked, training=True) == pytest.approx(
+        net.score(half, training=True), rel=1e-5)
+
+
+def test_the_scopes_name_the_ops_of_the_step():
+    net = ComputationGraph(looped_conf(2)).init()
+    ds = sample()
+    text = jax.jit(net._raw_step(False)).lower(
+        net.params, net.states, net.updater_state, jnp.int32(0),
+        net._next_rng(), (jnp.asarray(ds.features),),
+        (jnp.asarray(ds.labels),), None, None).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+
+    def named(*parts):      # an op whose op_name holds the parts in order
+        pattern = re.compile(".*".join(re.escape(p) for p in parts))
+        return any(pattern.search(n) for n in names)
+
+    for sub in ("attn", "ffn"):
+        assert named("jvp(stack)/", "/blocks/", f"/{sub}"), sub
+        assert named("transpose(jvp(stack))/", "/blocks/", f"/{sub}"), sub
+        # the recomputed forward, inside the backward pass
+        assert named("transpose(jvp(stack))/", "/blocks/",
+                     "/rematted_computation/", sub), sub
+    assert named("jvp(stack)/", "/final_norm")
+    for sub in ("head", "exit_gate"):
+        assert named("jvp(loss)/", sub) and named("transpose(jvp(loss))/", sub)
+    assert named("transpose(jvp(loss))/", "head/", "/rematted_computation")
