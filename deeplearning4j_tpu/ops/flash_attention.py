@@ -7,18 +7,35 @@ Pallas TPU kernels — online softmax over K/V blocks streamed through VMEM,
 O(T) memory, with the standard FlashAttention-2 backward (recompute
 probabilities per block from the saved log-sum-exp instead of storing them).
 
-Streaming structure: every kernel runs on a 3-D grid (bh, out-block,
-in-block) whose innermost dimension walks the streamed blocks; the
-BlockSpec index maps stage exactly ONE 128-row block of each operand into
-VMEM per grid step (no full-sequence VMEM residency — T is bounded by HBM,
-not VMEM), and the running accumulators (m/l/acc, dq, dk/dv) live in VMEM
-scratch that persists across the innermost grid sweep: initialized at the
-first in-block, written out at the last.
+Tiling: every kernel cuts the query side into ``block_q`` rows and the key
+side into ``block_k`` rows, chosen per kernel from what the call can see
+(:func:`pick_blocks`: lengths, head size, operand dtype; a VMEM reckoning
+and divisibility bound them). A Mosaic grid step costs ~0.35 µs whatever it
+holds (v5e), so the edges decide how often a call pays it: at bh 32, T 4096,
+d 128 a causal call walked 32,768 steps of 128 × 128 for 0.7 ms of products
+(11 ms a call) before the edges came from the shape.
+
+Walk: the grid's leading dimension is batch×heads. Not causal, the other
+two are (out-block, in-block) and the innermost walks the streamed blocks.
+Causal, they collapse into ONE dimension over the list of visible (q block,
+k block) pairs (:func:`causal_pairs`), whose tables arrive by scalar
+prefetch and drive the index maps: a block above the diagonal is never a
+grid step, and only a block that straddles the diagonal builds the causal
+mask (blocks wholly below it take the unmasked body). The BlockSpec index
+maps stage one block of each operand into VMEM per step (no full-sequence
+VMEM residency — T is bounded by HBM, not VMEM), and the running
+accumulators (m/l/acc, dq, dk/dv) live in VMEM scratch that persists across
+a row's steps: initialized at its first pair, written out at its last.
 
 Layout: kernels work on [bh, T, d] (batch×heads flattened); the public
 :func:`flash_attention` takes the layer's [b, T, h, d] and
 transposes/reshapes at the boundary (XLA fuses these). f32 accumulation
 throughout; inputs/outputs keep the caller's dtype (bf16 on TPU).
+
+What engaged is visible: each kernel's ``name=`` carries its edges
+(``flash_fwd_q512_k512``; the device trace's op names), and the registry
+gauge ``flash_grid_steps{kernel}`` holds the steps of its grid per call, set
+when the call is traced.
 
 Used automatically by ``SelfAttentionLayer`` when applicable (TPU backend,
 T divisible by the 128 block; [b, T] key-padding masks AND attention-
@@ -40,45 +57,117 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# q/k block edge. 128 is the MXU lane-aligned minimum; LARGER blocks divide
-# the sequential grid-step count quadratically (grid = bh * (T/B)^2), which
-# is what bounds throughput at head_dim 64 (each 128x64x128 dot is ~2 MFLOP
-# of MXU work against fixed per-step DMA/launch latency). BLOCK is the CAP:
-# each kernel call picks the largest 128-multiple <= BLOCK that divides its
-# T (:func:`pick_block`), so odd-length-but-lane-aligned sequences degrade
-# to a smaller block instead of losing the flash path. IMPORT-TIME knob:
-# DL4J_TPU_FLASH_BLOCK must be set before the first import (same trace-time
-# caveat as DL4J_TPU_LSTM_UNROLL, read once here so behavior is
-# predictable); snapped to the 128 grid — a non-multiple would mis-tile
-# every BlockSpec.
-import os as _os
+#: the MXU lane-aligned minimum edge, and the grid every edge snaps to
 MIN_BLOCK = 128
-try:
-    BLOCK = max(MIN_BLOCK,
-                int(_os.environ.get("DL4J_TPU_FLASH_BLOCK", "128")))
-except ValueError:  # pragma: no cover - malformed override
-    BLOCK = MIN_BLOCK
-BLOCK -= BLOCK % MIN_BLOCK
 _NEG = -1e30
 
+#: scoped VMEM every flash ``pallas_call`` asks Mosaic for: a quarter of the
+#: v5e's 128 MiB (the default, 16 MiB, is under :func:`vmem_bytes`'
+#: reckoning of the swept tiles); :func:`pick_blocks` keeps the edges under it
+VMEM_LIMIT = 32 * 2 ** 20
 
-def pick_block(T: int, d: int) -> int:
-    """Largest 128-multiple <= the BLOCK cap that divides ``T``, bounded by
-    a VMEM budget covering BOTH the [blk, d] operand tiles (blk*d <= 64k
-    elements) and the dominant in-kernel [blk, blk] f32 intermediates
-    (s/p/keep: 12*blk^2 bytes <= 8 MB, which caps picks at 768; at d=128
-    the operand term caps at 512 first, at d=256 at 256). Dropout
-    coordinates hash GLOBAL positions, so forward/backward kernels may
-    legally pick different blocks without changing any semantics."""
-    cap = min(BLOCK, T)
-    cap -= cap % MIN_BLOCK
-    while cap > MIN_BLOCK and (cap * d > 65536
-                               or 12 * cap * cap > 8 * 2 ** 20):
-        cap -= MIN_BLOCK
-    for b in range(cap, MIN_BLOCK, -MIN_BLOCK):
-        if T % b == 0:
-            return b
-    return MIN_BLOCK
+#: per kernel: the edges (block_q, block_k) the sweep found best. Swept on
+#: the v5e, causal, bf16, no mask, no dropout
+#: (``perf_flash_check.py blocksweep``; the table is in PERF.md): at bh 32,
+#: T 4096, d 128 over block_q 128..1024 × block_k 128..2048, and at d 64 for
+#: T 4096 and 8192 over 256..1024 × 256..2048, 1024 × 1024 was the fastest
+#: of every kernel at every shape (ms per call at d 128: forward 1.42
+#: against 10.42 at 128 × 128, dq 1.77 against 9.16, dk/dv 2.20 against
+#: 11.80). Past it the k edge loses again (1024 × 2048: 1.74 / 2.16 / 2.59):
+#: more of the walk is diagonal blocks, whose hidden cells are computed.
+_EDGES = {"flash_fwd": (1024, 1024), "flash_dq": (1024, 1024),
+          "flash_dkv": (1024, 1024)}
+
+
+def vmem_bytes(kernel: str, block_q: int, block_k: int, d: int, dtype) -> int:
+    """VMEM one grid step of ``kernel`` holds at these edges, reckoned from
+    its tiles as Mosaic lays them out (last dimension padded to 128 lanes;
+    every streamed tile twice, for the pipeline's double buffer): the
+    [block, d] operand and output tiles, the [block, 8] f32 statistics, the
+    f32 accumulators in scratch, and the [block_q, block_k] intermediates the
+    body names — f32 ones (s, p; dp, ds too in the backward) plus the copies
+    cast to the operand dtype for the MXU. An upper bound: Mosaic streams the
+    elementwise chain and holds fewer of the intermediates whole (the three
+    kernels at 1024 × 1024, bf16, d 128 compile for the v5e under 16 MiB
+    and are refused at 8 for 8.6 / 8.4 / 9.7 MB, where this reckons 15.5 /
+    24 / 27)."""
+    item = jnp.dtype(dtype).itemsize
+    lanes = -(-d // 128) * 128
+    row, col = block_q * lanes, block_k * lanes       # elements of a tile
+    stat_q, stat_k = block_q * 128 * 4, block_k * 128 * 4
+    if kernel == "flash_fwd":
+        streamed = (2 * row + 2 * col) * item + stat_k + stat_q  # q o k v km lse
+        scratch = 2 * stat_q + row * 4                           # m l acc
+        n_f32, casts = 2, 1                                      # s p | p
+    elif kernel == "flash_dq":
+        streamed = (3 * row + 2 * col) * item + stat_k + 2 * stat_q
+        scratch = row * 4                                        # dq
+        n_f32, casts = 4, 1                                      # s p dp ds | ds
+    else:
+        streamed = (2 * row + 4 * col) * item + stat_k + 2 * stat_q
+        scratch = 2 * col * 4                                    # dk dv
+        n_f32, casts = 4, 2                                      # | pd ds
+    return (2 * streamed + scratch
+            + block_q * block_k * (4 * n_f32 + item * casts))
+
+
+def _divisors(T: int, cap: int):
+    """128-multiples <= cap that divide ``T``, largest first."""
+    return [b for b in range(min(cap, T) // MIN_BLOCK * MIN_BLOCK, 0,
+                             -MIN_BLOCK) if T % b == 0]
+
+
+def pick_blocks(kernel: str, Tq: int, Tk: int, d: int, dtype):
+    """(block_q, block_k) for one call of ``kernel`` (``flash_fwd``,
+    ``flash_dq``, ``flash_dkv``) from what the call can see: among the
+    128-multiples that divide ``Tq`` and ``Tk`` and are no larger than the
+    edges the sweep found best for the kernel (:data:`_EDGES`), the pair of
+    the largest tile whose step fits :data:`VMEM_LIMIT` by
+    :func:`vmem_bytes` (of two tiles of one area, the one with more query
+    rows). The two lengths are tiled apart, and a length that no larger edge
+    divides falls back to smaller ones down to 128, so every 128-multiple
+    takes the flash path. Dropout coordinates hash GLOBAL positions, so the
+    three kernels may pick different edges without changing any decision."""
+    cap_q, cap_k = _EDGES[kernel]
+    fits = [(bq, bk) for bq in _divisors(Tq, cap_q)
+            for bk in _divisors(Tk, cap_k)
+            if vmem_bytes(kernel, bq, bk, d, dtype) <= VMEM_LIMIT]
+    return max(fits, key=lambda e: (e[0] * e[1], e[0]),
+               default=(MIN_BLOCK, MIN_BLOCK))
+
+
+def _edges(kernel, Tq, Tk, d, dtype, block_q, block_k):
+    """The chooser's edges, each overridden where the caller gave one (the
+    sweep of ``perf_flash_check.py`` and the tests' multi-block grids)."""
+    bq, bk = block_q, block_k
+    if not (bq and bk):
+        pq, pk = pick_blocks(kernel, Tq, Tk, d, dtype)
+        bq, bk = bq or pq, bk or pk
+    if Tq % bq or Tk % bk or bq % MIN_BLOCK or bk % MIN_BLOCK:
+        raise ValueError(f"{kernel}: edges ({bq}, {bk}) must be multiples of "
+                         f"{MIN_BLOCK} that divide ({Tq}, {Tk})")
+    return bq, bk
+
+
+def causal_pairs(nq: int, nk: int, block_q: int, block_k: int,
+                 k_major: bool = False):
+    """The causal walk as four int32 tables, one entry per grid step:
+    (outer block, inner block, first of its row?, last of its row?), rows in
+    order. A (q block, k block) pair is visible where the k block starts no
+    later than the q block ends. ``k_major`` False: a row is a q block and
+    its visible k blocks (forward, dq); True: a k block and its visible q
+    blocks (dk/dv). A k block past the last query (``Tk`` > ``Tq``) sees
+    none; it keeps the last q block as its one step, where the diagonal's
+    mask hides every cell, so that its zeros are still written."""
+    vis = (np.arange(nk)[None, :] * block_k
+           <= (np.arange(nq)[:, None] + 1) * block_q - 1)
+    if k_major:
+        vis = vis.T.copy()
+        vis[~vis.any(axis=1), -1] = True
+    outer, inner = np.nonzero(vis)
+    edge = outer[1:] != outer[:-1]
+    return tuple(a.astype(np.int32) for a in (
+        outer, inner, np.r_[True, edge], np.r_[edge, True]))
 
 # ---------------------------------------------------------------- dropout RNG
 # Counter-based hash PRNG for attention-probability dropout INSIDE the
@@ -122,19 +211,20 @@ def _keep_from_coords(seed, bh, qpos, kpos, rate):
     return (u >= rate).astype(jnp.float32)
 
 
-def _block_keep(seed_ref, bh, qi, kj, rate, blk):
-    """[blk, blk] keep mask for attention block (bh, qi, kj). The SMEM
-    seed operand is [3] i32: (seed, q_offset, k_offset) — the offsets make
-    the hashed coordinates GLOBAL, so a kernel running on a ring shard
+def _block_keep(seed_ref, bh, qi, kj, rate, block_q, block_k):
+    """[block_q, block_k] keep mask for attention block (bh, qi, kj). The
+    SMEM seed operand is [3] i32: (seed, q_offset, k_offset) — the offsets
+    make the hashed coordinates GLOBAL, so a kernel running on a ring shard
     draws bit-identical decisions to a single kernel over the full
     sequence (``parallel.sequence.ring_flash_attention`` passes each ring
     step's shard offsets; single-device callers pass 0, 0). Hashing global
-    positions also makes the decisions independent of the block size the
+    positions also makes the decisions independent of the edges the
     calling kernel happened to pick."""
-    qpos = (seed_ref[1] + qi * blk
-            + lax.broadcasted_iota(jnp.int32, (blk, blk), 0))
-    kpos = (seed_ref[2] + kj * blk
-            + lax.broadcasted_iota(jnp.int32, (blk, blk), 1))
+    shape = (block_q, block_k)
+    qpos = (seed_ref[1] + qi * block_q
+            + lax.broadcasted_iota(jnp.int32, shape, 0))
+    kpos = (seed_ref[2] + kj * block_k
+            + lax.broadcasted_iota(jnp.int32, shape, 1))
     return _keep_from_coords(seed_ref[0], bh, qpos, kpos, rate)
 
 
@@ -170,50 +260,142 @@ def _scratch(shape, dtype=jnp.float32):
     return pltpu.VMEM(shape, dtype)
 
 
-def _when_visible(causal, cond, fn):
-    """Run ``fn`` only for visible blocks: always when not causal (static),
-    predicated on ``cond`` when causal."""
-    if causal:
-        pl.when(cond)(fn)
-    else:
-        fn()
-
-
-def _causal_mask(s, qi, kj, block):
-    Bq, Bk = s.shape
-    qpos = qi * block + jax.lax.broadcasted_iota(jnp.int32, (Bq, Bk), 0)
-    kpos = kj * block + jax.lax.broadcasted_iota(jnp.int32, (Bq, Bk), 1)
+def _causal_mask(s, qi, kj, block_q, block_k):
+    qpos = qi * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    kpos = kj * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(kpos <= qpos, s, _NEG)
 
 
-# ------------------------------------------------------------------ forward
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, nk, rate, has_km,
-                blk):
-    has_seed = rate > 0.0
-    km_ref = rest[0] if has_km else None
-    seed_ref = rest[int(has_km)] if has_seed else None
-    o_ref, lse_ref, m_s, l_s, acc_s = rest[int(has_km) + int(has_seed):]
-    bh, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+def _scores(q, k, km_ref, scale, masked, qi, kj):
+    """f32 [block_q, block_k] logits of one block pair. The matmul runs in
+    the SOURCE dtype (bf16 → native MXU pass) with f32 accumulation; the
+    scale moves after the dot so bf16 q is not pre-rounded by it. ``masked``
+    (static) applies the causal mask: asked for only on blocks that
+    straddle the diagonal."""
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    if masked:
+        s = _causal_mask(s, qi, kj, q.shape[0], k.shape[0])
+    if km_ref is not None:
+        s = jnp.where(km_ref[0, :, 0][None, :] > 0, s, _NEG)
+    return s
 
-    @pl.when(kj == 0)
+
+def _on_pair(causal, qi, kj, block_q, block_k, body):
+    """Run ``body(masked)`` on block pair (qi, kj). Every pair the grid
+    visits is visible; causal, the one that straddles the diagonal (its last
+    key lies past its first query) takes the masked body, one wholly below
+    it the unmasked: no iota, compare and select for a mask that hides
+    nothing."""
+    if not causal:
+        body(False)
+        return
+    straddles = (kj + 1) * block_k - 1 > qi * block_q
+    pl.when(straddles)(lambda: body(True))
+    pl.when(jnp.logical_not(straddles))(lambda: body(False))
+
+
+def _step(tabs, n_in):
+    """(bh, outer block, inner block, first of the row?, last?) of this grid
+    step: read from the prefetched pair tables of a causal walk, from the
+    grid's own coordinates otherwise."""
+    if tabs:
+        outer, inner, first, last = tabs
+        t = pl.program_id(1)
+        return (pl.program_id(0), outer[t], inner[t], first[t] == 1,
+                last[t] == 1)
+    i = pl.program_id(2)
+    return pl.program_id(0), pl.program_id(1), i, i == 0, i == n_in - 1
+
+
+def _split_refs(refs, causal, has_km, has_seed):
+    """(pair tables, (q_ref, k_ref, v_ref), km_ref, seed_ref, the rest) of a
+    kernel's flat argument list."""
+    n_tabs = 4 if causal else 0
+    tabs, refs = refs[:n_tabs], refs[n_tabs:]
+    lead, refs = refs[:3], refs[3:]
+    km_ref = refs[0] if has_km else None
+    seed_ref = refs[int(has_km)] if has_seed else None
+    return tabs, lead, km_ref, seed_ref, refs[int(has_km) + int(has_seed):]
+
+
+def _launch(kernel, body, bq, bk, bh, n_out, n_in, pairs, specs, operands,
+            out_specs, out_shape, scratch):
+    """One flash ``pallas_call``; returns the list of its outputs. ``specs``
+    / ``out_specs`` list (block shape, side) pairs with side "outer" or
+    "inner" (or a finished BlockSpec): the index maps follow the
+    walk — the prefetched ``pairs`` tables of a causal call on a (bh, pair)
+    grid, the grid's own (bh, out-block, in-block) otherwise. The name
+    carries the edges and the registry's ``flash_grid_steps`` the steps."""
+    if pairs:
+        grid = (bh, len(pairs[0]))
+        maps = {"outer": lambda i, t, o, n, *_: (i, o[t], 0),
+                "inner": lambda i, t, o, n, *_: (i, n[t], 0)}
+    else:
+        grid = (bh, n_out, n_in)
+        maps = {"outer": lambda i, o, n: (i, o, 0),
+                "inner": lambda i, o, n: (i, n, 0)}
+    spec = lambda s: (s if isinstance(s, pl.BlockSpec)
+                      else _vspec(s[0], maps[s[1]]))
+    name = f"{kernel}_q{bq}_k{bk}"
+    from ..monitor import get_registry     # here: the package imports ops
+    get_registry().gauge(
+        "flash_grid_steps",
+        "Grid steps of one call of a flash-attention kernel at the edges in "
+        "its name, set when the call is traced", kernel=name
+    ).set(math.prod(grid))
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(pairs), grid=grid,
+            in_specs=[spec(s) for s in specs],
+            out_specs=[spec(s) for s in out_specs],
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        interpret=_interpret(),
+        name=name,
+    )(*(jnp.asarray(t) for t in pairs), *operands)
+
+
+def _operands(q_side, k_side, bq, bk, q, k, v, km, seed, rate, bwd=()):
+    """(specs, operands) of a kernel's inputs in the order its body unpacks
+    them: q, k, v, the key mask and the dropout seed where there are any,
+    then ``bwd`` = (do, delta, lse) for the backward kernels. q-side tiles
+    follow ``q_side`` of the walk ("outer" / "inner"), k-side ``k_side``."""
+    d = q.shape[-1]
+    row, col = ((1, bq, d), q_side), ((1, bk, d), k_side)
+    specs, operands = [row, col, col], [q, k, v]
+    if km is not None:
+        specs.append(((1, bk, 8), k_side))
+        operands.append(km)
+    if rate > 0.0:
+        specs.append(_smem_spec())
+        operands.append(seed)
+    if bwd:
+        stat = ((1, bq, 8), q_side)
+        specs += [row, stat, stat]
+        operands += list(bwd)
+    return specs, operands
+
+
+# ------------------------------------------------------------------ forward
+def _fwd_kernel(*refs, causal, scale, nk, rate, has_km):
+    tabs, (q_ref, k_ref, v_ref), km_ref, seed_ref, rest = _split_refs(
+        refs, causal, has_km, rate > 0.0)
+    o_ref, lse_ref, m_s, l_s, acc_s = rest
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    bh, qi, kj, first, last = _step(tabs, nk)
+
+    @pl.when(first)
     def _():
         m_s[:] = jnp.full_like(m_s, _NEG)
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    def _compute():
-        # matmuls run in the SOURCE dtype (bf16 → native MXU pass) with f32
-        # accumulation via preferred_element_type; softmax stats stay f32.
-        # The scale moves after the dot so bf16 q is not pre-rounded by it.
-        q = q_ref[0]                                      # [Bq, d]
-        k = k_ref[0]                                      # [Bk, d]
+    def _compute(masked):
         v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, kj, blk)
-        if km_ref is not None:
-            s = jnp.where(km_ref[0, :, 0][None, :] > 0, s, _NEG)
+        s = _scores(q_ref[0], k_ref[0], km_ref, scale, masked, qi, kj)
         m = m_s[:, 0]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))       # [Bq]
         p = jnp.exp(s - m_new[:, None])
@@ -224,15 +406,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, nk, rate, has_km,
         l_s[:, 0] = l_s[:, 0] * alpha + jnp.sum(p, axis=-1)
         m_s[:, 0] = m_new
         if rate > 0.0:
-            keep = _block_keep(seed_ref, bh, qi, kj, rate, blk)
+            keep = _block_keep(seed_ref, bh, qi, kj, rate, bq, bk)
             p = p * keep * (1.0 / (1.0 - rate))
-        acc_s[:] = acc_s[:] * alpha[:, None] + jax.lax.dot_general(
+        acc_s[:] = acc_s[:] * alpha[:, None] + lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_visible(causal, kj <= qi, _compute)
+    _on_pair(causal, qi, kj, bq, bk, _compute)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(last)
     def _():
         # Rows whose visible keys were ALL masked never raise m above _NEG;
         # for them every p above was exp(_NEG - _NEG) = 1, so acc/l is a
@@ -249,257 +431,171 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, nk, rate, has_km,
             lse_ref.shape[1:])
 
 
-def _fwd(q, k, v, km, seed, causal, scale, rate):
-    """q/k/v: [bh, T, d], km: [bh, T, 8] key mask or None, seed: [3] i32
-    (seed, q_off, k_off — :func:`seed3`) or None (rate > 0) →
-    (o [bh, T, d], lse [bh, T, 8])."""
-    bh, T, d = q.shape
-    blk = pick_block(T, d)
-    nq = T // blk
-    kern = functools.partial(_fwd_kernel, causal=causal, scale=scale, nk=nq,
-                             rate=rate, has_km=km is not None, blk=blk)
-    if causal:
-        # invisible (kj > qj) steps clamp to the diagonal block: same index
-        # as the previous visible step → Pallas skips the DMA entirely
-        kv_idx = lambda i, qj, kj: (i, jnp.minimum(kj, qj), 0)
-    else:
-        kv_idx = lambda i, qj, kj: (i, kj, 0)
+def _fwd(q, k, v, km, seed, causal, scale, rate, block_q=None, block_k=None):
+    """q: [bh, Tq, d], k/v: [bh, Tk, d], km: [bh, Tk, 8] key mask or None,
+    seed: [3] i32 (seed, q_off, k_off — :func:`seed3`) or None (rate > 0) →
+    (o [bh, Tq, d], lse [bh, Tq, 8]). ``block_q`` / ``block_k`` override
+    :func:`pick_blocks`."""
+    bh, Tq, d = q.shape
+    Tk = k.shape[1]
+    bq, bk = _edges("flash_fwd", Tq, Tk, d, q.dtype, block_q, block_k)
+    nq, nk = Tq // bq, Tk // bk
+    kern = functools.partial(_fwd_kernel, causal=causal, scale=scale, nk=nk,
+                             rate=rate, has_km=km is not None)
     # lse is lane-padded to [bh, T, 8]: TPU block shapes need their last two
     # dims (8·k, 128·m) or full-dim; a (1, blk) slice of [bh, T] is
     # unlowerable. 8 f32 lanes per position is noise next to q/k/v
-    in_specs = [
-        _vspec((1, blk, d), lambda i, qj, kj: (i, qj, 0)),
-        _vspec((1, blk, d), kv_idx),
-        _vspec((1, blk, d), kv_idx),
-    ]
-    operands = [q, k, v]
-    if km is not None:
-        in_specs.append(_vspec((1, blk, 8), kv_idx))
-        operands.append(km)
-    if rate > 0.0:
-        in_specs.append(_smem_spec())
-        operands.append(seed)
-    return pl.pallas_call(
-        kern,
-        grid=(bh, nq, nq),
-        in_specs=in_specs,
-        out_specs=(
-            _vspec((1, blk, d), lambda i, qj, kj: (i, qj, 0)),
-            _vspec((1, blk, 8), lambda i, qj, kj: (i, qj, 0)),
-        ),
-        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((bh, T, 8), jnp.float32)),
-        scratch_shapes=[_scratch((blk, 8)), _scratch((blk, 8)),
-                        _scratch((blk, d))],
-        interpret=_interpret(),
-        name="flash_fwd",
-    )(*operands)
+    specs, operands = _operands("outer", "inner", bq, bk, q, k, v, km, seed,
+                                rate)
+    return _launch(
+        "flash_fwd", kern, bq, bk, bh, nq, nk,
+        causal_pairs(nq, nk, bq, bk) if causal else (),
+        specs, operands,
+        out_specs=[((1, bq, d), "outer"), ((1, bq, 8), "outer")],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((bh, Tq, 8), jnp.float32)],
+        scratch=[_scratch((bq, 8)), _scratch((bq, 8)), _scratch((bq, d))])
 
 
 # ----------------------------------------------------------------- backward
-def _dq_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, nk, rate,
-               has_km, blk):
-    has_seed = rate > 0.0
-    km_ref = rest[0] if has_km else None
-    seed_ref = rest[int(has_km)] if has_seed else None
-    do_ref, delta_ref, lse_ref, dq_ref, dq_s = \
-        rest[int(has_km) + int(has_seed):]
-    bh, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+def _dq_kernel(*refs, causal, scale, nk, rate, has_km):
+    tabs, (q_ref, k_ref, v_ref), km_ref, seed_ref, rest = _split_refs(
+        refs, causal, has_km, rate > 0.0)
+    do_ref, delta_ref, lse_ref, dq_ref, dq_s = rest
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    bh, qi, kj, first, last = _step(tabs, nk)
 
-    @pl.when(kj == 0)
+    @pl.when(first)
     def _():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    def _compute():
+    def _compute(masked):
         # source-dtype matmul operands (bf16 MXU pass), f32 accumulation —
         # same policy as the forward kernel; softmax/ds math stays f32
-        q = q_ref[0]
         do = do_ref[0]
         lse = lse_ref[0, :, 0]
         delta = delta_ref[0, :, 0]
         k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, kj, blk)
-        if km_ref is not None:
-            s = jnp.where(km_ref[0, :, 0][None, :] > 0, s, _NEG)
+        s = _scores(q_ref[0], k, km_ref, scale, masked, qi, kj)
         # s-guard: masked cells get p = 0 even on fully-masked rows, where
         # lse is the _NEG sentinel and exp(s - lse) would be exp(0) = 1
         p = jnp.where(s > _NEG * 0.5, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
         if rate > 0.0:
             # dP flows only through kept cells: dP = (do·vᵀ)·keep/(1-r);
             # delta already equals rowsum(P∘dP) = rowsum(do∘o) unchanged
-            keep = _block_keep(seed_ref, bh, qi, kj, rate, blk)
+            keep = _block_keep(seed_ref, bh, qi, kj, rate, bq, bk)
             dp = dp * keep * (1.0 / (1.0 - rate))
         ds = p * (dp - delta[:, None]) * scale
-        dq_s[:] = dq_s[:] + jax.lax.dot_general(
+        dq_s[:] = dq_s[:] + lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_visible(causal, kj <= qi, _compute)
+    _on_pair(causal, qi, kj, bq, bk, _compute)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(last)
     def _():
         dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, nq, rate,
-                has_km, blk):
-    has_seed = rate > 0.0
-    km_ref = rest[0] if has_km else None
-    seed_ref = rest[int(has_km)] if has_seed else None
-    do_ref, delta_ref, lse_ref, dk_ref, dv_ref, dk_s, dv_s = \
-        rest[int(has_km) + int(has_seed):]
-    bh, ki, qj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+def _dkv_kernel(*refs, causal, scale, nq, rate, has_km):
+    tabs, (q_ref, k_ref, v_ref), km_ref, seed_ref, rest = _split_refs(
+        refs, causal, has_km, rate > 0.0)
+    do_ref, delta_ref, lse_ref, dk_ref, dv_ref, dk_s, dv_s = rest
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    # the walk here is k-major: a row is a k block and its q blocks
+    bh, ki, qj, first, last = _step(tabs, nq)
 
-    @pl.when(qj == 0)
+    @pl.when(first)
     def _():
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    def _compute():
+    def _compute(masked):
         # source-dtype matmul operands (bf16 MXU pass), f32 accumulation
-        k = k_ref[0]
-        v = v_ref[0]
         q = q_ref[0]
         do = do_ref[0]
         lse = lse_ref[0, :, 0]
         delta = delta_ref[0, :, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qj, ki, blk)
-        if km_ref is not None:
-            s = jnp.where(km_ref[0, :, 0][None, :] > 0, s, _NEG)
+        s = _scores(q, k_ref[0], km_ref, scale, masked, qj, ki)
         # same s-guard as _dq_kernel (fully-masked rows: lse = _NEG)
         p = jnp.where(s > _NEG * 0.5,
                       jnp.exp(s - lse[:, None]), 0.0)    # [Bq, Bk]
         if rate > 0.0:
-            # same (bh, q-block, k-block) seeding as the fwd kernel: the
-            # grid here is (bh, k, q), so the id order swaps
-            keep = _block_keep(seed_ref, bh, qj, ki, rate, blk)
+            # the forward's decisions: same global (bh, q, k) coordinates
+            keep = _block_keep(seed_ref, bh, qj, ki, rate, bq, bk)
             pd = p * keep * (1.0 / (1.0 - rate))          # = drop(P)
         else:
             pd = p
-        dv_s[:] = dv_s[:] + jax.lax.dot_general(
+        dv_s[:] = dv_s[:] + lax.dot_general(
             pd.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
         if rate > 0.0:
             dp = dp * keep * (1.0 / (1.0 - rate))
         ds = p * (dp - delta[:, None]) * scale
-        dk_s[:] = dk_s[:] + jax.lax.dot_general(
+        dk_s[:] = dk_s[:] + lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_visible(causal, qj >= ki, _compute)
+    _on_pair(causal, qj, ki, bq, bk, _compute)
 
-    @pl.when(qj == nq - 1)
+    @pl.when(last)
     def _():
         dk_ref[0] = dk_s[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
 
 
 def dq_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
-             rate=0.0):
+             rate=0.0, block_q=None, block_k=None):
     """dq for one q-shard against one k/v block ([bh, Tq, d] × [bh, Tk, d]).
     ``delta``/``lse`` are the GLOBAL rowwise Δ and log-sum-exp ([bh, Tq, 8]
     lane-padded) — with them, per-block probabilities recompute exactly, so
     per-block gradients sum to the full-attention gradient. Used by the
     in-kernel backward below AND per ring step by
-    ``parallel.sequence.ring_flash_attention``."""
+    ``parallel.sequence.ring_flash_attention``. ``block_q`` / ``block_k``
+    override :func:`pick_blocks`; the two lengths are tiled apart."""
     bh, Tq, d = q.shape
-    # one block size must tile BOTH the q shard and the k/v block (the ring
-    # passes different lengths): pick on the gcd
-    blk = pick_block(math.gcd(Tq, k.shape[1]), d)
-    nq, nk = Tq // blk, k.shape[1] // blk
+    Tk = k.shape[1]
+    bq, bk = _edges("flash_dq", Tq, Tk, d, q.dtype, block_q, block_k)
+    nq, nk = Tq // bq, Tk // bk
     kern = functools.partial(_dq_kernel, causal=causal, scale=scale, nk=nk,
-                             rate=rate, has_km=km is not None, blk=blk)
-    if causal:
-        kv_idx = lambda i, qj, kj: (i, jnp.minimum(kj, qj), 0)
-    else:
-        kv_idx = lambda i, qj, kj: (i, kj, 0)
-    specs = [
-        _vspec((1, blk, d), lambda i, qj, kj: (i, qj, 0)),     # q
-        _vspec((1, blk, d), kv_idx),                           # k
-        _vspec((1, blk, d), kv_idx),                           # v
-    ]
-    ops = [q, k, v]
-    if km is not None:
-        specs.append(_vspec((1, blk, 8), kv_idx))              # key mask
-        ops.append(km)
-    if rate > 0.0:
-        specs.append(_smem_spec())
-        ops.append(seed)
-    specs += [
-        _vspec((1, blk, d), lambda i, qj, kj: (i, qj, 0)),     # do
-        _vspec((1, blk, 8), lambda i, qj, kj: (i, qj, 0)),     # delta
-        _vspec((1, blk, 8), lambda i, qj, kj: (i, qj, 0)),     # lse
-    ]
-    ops += [do, delta, lse]
-    return pl.pallas_call(
-        kern,
-        grid=(bh, nq, nk),
-        in_specs=specs,
-        out_specs=_vspec((1, blk, d), lambda i, qj, kj: (i, qj, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[_scratch((blk, d))],
-        interpret=_interpret(),
-        name="flash_dq",
-    )(*ops)
+                             rate=rate, has_km=km is not None)
+    specs, operands = _operands("outer", "inner", bq, bk, q, k, v, km, seed,
+                                rate, bwd=(do, delta, lse))
+    dq, = _launch(
+        "flash_dq", kern, bq, bk, bh, nq, nk,
+        causal_pairs(nq, nk, bq, bk) if causal else (),
+        specs, operands,
+        out_specs=[((1, bq, d), "outer")],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        scratch=[_scratch((bq, d))])
+    return dq
 
 
 def dkv_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
-              rate=0.0):
+              rate=0.0, block_q=None, block_k=None):
     """(dk, dv) for one k/v block against one q-shard; see :func:`dq_block`
-    for the global-``lse``/``delta`` contract."""
+    for the global-``lse``/``delta`` contract and the overrides."""
     bh, Tk, d = k.shape
-    blk = pick_block(math.gcd(q.shape[1], Tk), d)
-    nq, nk = q.shape[1] // blk, Tk // blk
+    Tq = q.shape[1]
+    bq, bk = _edges("flash_dkv", Tq, Tk, d, q.dtype, block_q, block_k)
+    nq, nk = Tq // bq, Tk // bk
     kern = functools.partial(_dkv_kernel, causal=causal, scale=scale, nq=nq,
-                             rate=rate, has_km=km is not None, blk=blk)
-    if causal:
-        q_idx = lambda i, kj, qj: (i, jnp.maximum(qj, kj), 0)
-    else:
-        q_idx = lambda i, kj, qj: (i, qj, 0)
-    specs = [
-        _vspec((1, blk, d), q_idx),                            # q
-        _vspec((1, blk, d), lambda i, kj, qj: (i, kj, 0)),     # k
-        _vspec((1, blk, d), lambda i, kj, qj: (i, kj, 0)),     # v
-    ]
-    ops = [q, k, v]
-    if km is not None:
-        specs.append(_vspec((1, blk, 8),
-                            lambda i, kj, qj: (i, kj, 0)))     # key mask
-        ops.append(km)
-    if rate > 0.0:
-        specs.append(_smem_spec())
-        ops.append(seed)
-    specs += [
-        _vspec((1, blk, d), q_idx),                            # do
-        _vspec((1, blk, 8), q_idx),                            # delta
-        _vspec((1, blk, 8), q_idx),                            # lse
-    ]
-    ops += [do, delta, lse]
-    return pl.pallas_call(
-        kern,
-        grid=(bh, nk, nq),
-        in_specs=specs,
-        out_specs=(
-            _vspec((1, blk, d), lambda i, kj, qj: (i, kj, 0)),
-            _vspec((1, blk, d), lambda i, kj, qj: (i, kj, 0)),
-        ),
-        out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)),
-        scratch_shapes=[_scratch((blk, d)), _scratch((blk, d))],
-        interpret=_interpret(),
-        name="flash_dkv",
-    )(*ops)
+                             rate=rate, has_km=km is not None)
+    specs, operands = _operands("inner", "outer", bq, bk, q, k, v, km, seed,
+                                rate, bwd=(do, delta, lse))
+    col = ((1, bk, d), "outer")
+    return _launch(
+        "flash_dkv", kern, bq, bk, bh, nk, nq,
+        causal_pairs(nq, nk, bq, bk, k_major=True) if causal else (),
+        specs, operands,
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch=[_scratch((bk, d)), _scratch((bk, d))])
 
 
 def rowwise_delta(do, o):
@@ -581,8 +677,14 @@ def _interpret() -> bool:
     return False
 
 
-#: below this sequence length the dense einsum is faster on-chip (measured:
-#: T=2048 dense 12.4 ms vs flash 14.1 ms; T=8192 dense 490 ms vs flash 65 ms)
+#: below this sequence length ``mha`` takes the dense einsum. One reading on
+#: an earlier backend at d 64 and 128 × 128 tiles set it (T 2048: dense 12.4
+#: ms, flash 14.1; T 8192: dense 490, flash 65) and no cell runs a shorter
+#: sequence that could guard a move. For whoever brings one, on the v5e at
+#: b2·h16·d128 bf16 causal with the edges of :func:`pick_blocks`, forward /
+#: forward and backward (PERF.md section 7, PR 29): T 4096 dense 16.1 /
+#: 33.0 ms, the kernels 1.43 / 5.43; T 2048 dense 4.24 / 8.59, the kernels
+#: 0.49 / 1.76: the crossing lies below T 2048.
 MIN_SEQ = 4096
 
 

@@ -7,7 +7,8 @@ leaves some query rows with no visible key, and with in-kernel dropout.
 ``chip_smoke.py`` imports :func:`check`; by hand:
 
     python perf_flash_check.py             # the transformer bench shape
-    python perf_flash_check.py blocksweep  # A/B DL4J_TPU_FLASH_BLOCK
+    python perf_flash_check.py blocksweep  # ms per call over (block_q, block_k)
+    python perf_flash_check.py picks       # the chooser's edges and the dense path
 """
 import os
 import time
@@ -121,77 +122,142 @@ def check(b=4, T=8192, h=8, d=64, oracle_heads=2, rate=0.3, seed=1234,
     return report
 
 
-def block_one():
-    """Child for blocksweep: time flash fwd and fwd+bwd at the transformer
-    bench's attention shapes (bench.py bench_transformer_lm: b=4, h=8,
-    T=8192, d=64 -> bh=32). The block size comes from DL4J_TPU_FLASH_BLOCK
-    (import-time knob — that is why each value needs a fresh process)."""
-    import json
+#: the sweep's shapes (bh, T, d) with the q and k edges tried at each: the
+#: looped-LM cell's attention (b2·h16·T4096·d128) over the whole grid, then
+#: d 64 at T 4096 and at the transformer bench's T 8192 (the shape
+#: ``MIN_SEQ``'s comment was first measured at) without the 128 edges, which
+#: the first shape rules out
+SWEEP = (((32, 4096, 128), (128, 256, 512, 1024), (128, 256, 512, 1024, 2048)),
+         ((32, 4096, 64), (256, 512, 1024), (256, 512, 1024, 2048)),
+         ((32, 8192, 64), (256, 512, 1024), (256, 512, 1024, 2048)))
 
-    from bench import _warm_time
+
+def _ms_per_call(fn, *args, iters=20):
+    """Mean ms per call of a compiled ``fn`` over ``iters`` queued calls,
+    closed by ``block_until_ready``: a fetch of the result (33 MB of dq at
+    the cell's shape) would add milliseconds of host link to every row."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters * 1e3
+
+
+def _time_kernels(bh, T, d, block_q=None, block_k=None):
+    """ms per call of flash_fwd, flash_dq, flash_dkv (causal, bf16, no mask,
+    no dropout) at [bh, T, d] with these edges (None: the chooser's)."""
     import deeplearning4j_tpu.ops.flash_attention as fa
 
     rng = np.random.default_rng(0)
-    b, T, h, d = 4, 8192, 8, 64
-    # the sweep must measure the cap it advertises: pick_block at these
-    # shapes has to resolve to exactly the exported cap
-    assert fa.pick_block(T, d) == fa.BLOCK, (fa.pick_block(T, d), fa.BLOCK)
+    q, k, v, do = (jnp.asarray(rng.normal(size=(bh, T, d)), jnp.bfloat16)
+                   for _ in range(4))
+    scale = 1.0 / float(np.sqrt(d))
+    kw = dict(block_q=block_q, block_k=block_k)
+    fwd = jax.jit(lambda q, k, v: fa._fwd(q, k, v, None, None, True, scale,
+                                          0.0, **kw))
+    o, lse = fwd(q, k, v)
+    delta = fa.rowwise_delta(do, o)
+    dq = jax.jit(lambda *a: fa.dq_block(*a, True, scale, **kw))
+    dkv = jax.jit(lambda *a: fa.dkv_block(*a, True, scale, **kw))
+    bwd_args = (q, k, v, None, do, delta, lse)
+    return {"fwd_ms": _ms_per_call(fwd, q, k, v),
+            "dq_ms": _ms_per_call(dq, *bwd_args),
+            "dkv_ms": _ms_per_call(dkv, *bwd_args)}
+
+
+def _time_dense(b, T, h, d):
+    """ms of the dense path's forward, and of forward + backward, at
+    [b, T, h, d] bf16 causal (``nn/layers/attention._dense_attention``: the
+    [b, h, T, T] f32 logits materialize, both halves of the square run)."""
+    from deeplearning4j_tpu.nn.layers.attention import _dense_attention
+
+    rng = np.random.default_rng(0)
     q, k, v = (jnp.asarray(rng.normal(size=(b, T, h, d)), jnp.bfloat16)
                for _ in range(3))
-    f = jax.jit(lambda a, b_, c: fa.flash_attention(a, b_, c, causal=True))
-    g = jax.jit(jax.grad(lambda a, b_, c: jnp.sum(
-        fa.flash_attention(a, b_, c, causal=True).astype(jnp.float32) ** 2),
-        argnums=(0, 1, 2)))
-    tf = _warm_time(f, q, k, v)
-    tg = _warm_time(g, q, k, v)
-    print(json.dumps({"block": fa.BLOCK, "fwd_ms": tf * 1e3,
-                      "fwdbwd_ms": tg * 1e3}))
+    vis = jnp.tril(jnp.ones((T, T), bool))[None, None]
+    dense = lambda q, k, v: _dense_attention(q, k, v, vis, jnp.bfloat16)
+    f = jax.jit(dense)
+    g = jax.jit(jax.grad(lambda *a: jnp.sum(
+        dense(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2)))
+    return {"dense_fwd_ms": _ms_per_call(f, q, k, v, iters=5),
+            "dense_fwdbwd_ms": _ms_per_call(g, q, k, v, iters=5)}
 
 
-def blocksweep():
-    """A/B DL4J_TPU_FLASH_BLOCK (import-time knob -> fresh subprocess per
-    value) at the transformer bench attention shapes. This parent never
-    initialises a backend: the chip belongs to one process at a time, and
-    each child needs it."""
+def blocksweep(grid=True,
+               out_path=os.path.join("chiprun_out", "flash_sweep.jsonl")):
+    """The table :func:`ops.flash_attention.pick_blocks` is made from, in
+    one process: per shape and pair of edges the ms per call of each kernel
+    and the TFLOP/s of the causal half's products it runs (2 forward, 3 in
+    dq, 4 in dk/dv, each T²·d FLOP a head), every row also appended to
+    ``out_path``; edges that do not divide T or that :func:`vmem_bytes`
+    puts past ``VMEM_LIMIT`` are left out, a pair Mosaic refuses is printed
+    as such. Then the chooser's own pick per shape and at T 2048, and the
+    dense path at the cell's shape and at T 2048 beside them (``grid``
+    False: these last rows alone)."""
     import json
-    import subprocess
-    import sys
 
-    print(f"{'block':>6} {'fwd_ms':>9} {'fwdbwd_ms':>10}")
-    # 1024 is excluded: pick_block's [blk,blk]-intermediate budget caps
-    # picks at 768, which doesn't divide T=8192 (block-one asserts the
-    # pick resolves to the advertised cap)
-    for blk in (128, 256, 512):
-        env = dict(os.environ, DL4J_TPU_FLASH_BLOCK=str(blk))
+    import deeplearning4j_tpu.ops.flash_attention as fa
+
+    os.makedirs(os.path.dirname(out_path), exist_ok=True)
+    products = {"fwd": 2, "dq": 3, "dkv": 4}
+
+    def row(bh, T, d, bq, bk):
+        rec = {"bh": bh, "T": T, "d": d, "block_q": bq, "block_k": bk}
         try:
-            p = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "block-one"],
-                capture_output=True, text=True, env=env, timeout=900)
-        except subprocess.TimeoutExpired:
-            print(f"{blk:>6} FAILED timeout", flush=True)
-            continue
-        line = None
-        for ln in reversed((p.stdout or "").splitlines()):
-            try:
-                line = json.loads(ln)
-                break
-            except ValueError:
-                continue
-        if p.returncode or not line:
-            print(f"{blk:>6} FAILED rc={p.returncode} "
-                  f"{(p.stderr or '')[-300:]}", flush=True)
-            continue
-        print(f"{blk:>6} {line['fwd_ms']:>9.1f} {line['fwdbwd_ms']:>10.1f}",
-              flush=True)
+            rec.update(_time_kernels(bh, T, d, bq, bk))
+        except Exception as e:  # noqa: BLE001 - Mosaic's refusal is the row
+            rec["refused"] = f"{type(e).__name__}: {str(e)[:200]}"
+        for n, c in products.items():
+            if f"{n}_ms" in rec:
+                rec[f"{n}_tflops"] = (c * bh * T * T * d
+                                      / (rec[f"{n}_ms"] * 1e-3) / 1e12)
+        with open(out_path, "a") as f:
+            f.write(json.dumps(rec) + "\n")
+        if "refused" in rec:
+            print(f"{bh:>3} {T:>5} {d:>4} {bq!s:>5} {bk!s:>5} "
+                  f"{rec['refused']}", flush=True)
+            return
+        print(f"{bh:>3} {T:>5} {d:>4} {bq!s:>5} {bk!s:>5} " + " ".join(
+            f"{rec[f'{n}_ms']:>8.3f} {rec[f'{n}_tflops']:>6.1f}"
+            for n in products), flush=True)
+
+    print(f"{'bh':>3} {'T':>5} {'d':>4} {'bq':>5} {'bk':>5} "
+          + " ".join(f"{n + '_ms':>8} {'TF/s':>6}" for n in products))
+    for (bh, T, d), edges_q, edges_k in SWEEP if grid else ():
+        for bq in edges_q:
+            for bk in edges_k:
+                if T % bq or T % bk or max(
+                        fa.vmem_bytes(kern, bq, bk, d, jnp.bfloat16)
+                        for kern in ("flash_fwd", "flash_dq", "flash_dkv")
+                        ) > fa.VMEM_LIMIT:
+                    continue
+                row(bh, T, d, bq, bk)
+    print("the chooser's own edges (block columns None):")
+    for bh, T, d in [shape for shape, _, _ in SWEEP] + [(32, 2048, 128)]:
+        print({kern: fa.pick_blocks(kern, T, T, d, jnp.bfloat16)
+               for kern in ("flash_fwd", "flash_dq", "flash_dkv")})
+        row(bh, T, d, None, None)
+    for T in (4096, 2048):
+        rec = {"b": 2, "T": T, "h": 16, "d": 128}
+        try:
+            rec.update(_time_dense(2, T, 16, 128))
+        except Exception as e:  # noqa: BLE001 - e.g. the logits do not fit
+            rec["refused"] = f"{type(e).__name__}: {str(e)[:200]}"
+        with open(out_path, "a") as f:
+            f.write(json.dumps(rec) + "\n")
+        print(rec, flush=True)
 
 
 if __name__ == "__main__":
     import sys
     cmd = sys.argv[1] if len(sys.argv) > 1 else "check"
-    if cmd == "blocksweep":
-        blocksweep()
-    elif cmd == "block-one":
-        block_one()
+    if cmd in ("blocksweep", "picks"):
+        print("backend:", jax.default_backend(),
+              jax.devices()[0].device_kind)
+        if jax.default_backend() != "tpu":
+            raise SystemExit("the sweep times the chip: no TPU here")
+        blocksweep(grid=cmd == "blocksweep")
     else:
         print("backend:", jax.default_backend())
         check()

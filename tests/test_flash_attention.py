@@ -321,29 +321,220 @@ def test_flash_key_mask_grads_match_dense():
 
 
 def test_pick_block_contract():
-    """pick_block: largest 128-multiple <= cap dividing T, bounded by the
-    blk*d <= 64k VMEM tile budget, floor 128. (The cap itself is the
-    import-time DL4J_TPU_FLASH_BLOCK knob, default 128.)"""
-    old = fa.BLOCK
+    """pick_blocks: per kernel the largest tile of 128-multiples that divide
+    Tq and Tk, no larger than the swept edges, whose step fits VMEM_LIMIT by
+    the module's own reckoning; the floor is 128 × 128. (The edges are no
+    knob: they follow from lengths, head size and operand dtype.)"""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    kernels = ("flash_fwd", "flash_dq", "flash_dkv")
+    pick = lambda *a: [fa.pick_blocks(k, *a) for k in kernels]
+    assert pick(8192, 8192, 64, bf16) == [(1024, 1024)] * 3
+    assert pick(4224, 4224, 64, bf16) == [(384, 384)] * 3   # 33*128: 384|
+    assert pick(4352, 4352, 64, bf16) == [(256, 256)] * 3   # 34*128: 256|
+    # VMEM: f32 operands at d 256 leave the forward its tile and cut the
+    # backward kernels' (four [bq, bk] f32 intermediates, wider tiles)
+    assert pick(8192, 8192, 256, f32) == [(1024, 1024), (1024, 512),
+                                          (1024, 512)]
+    assert pick(8192, 8192, 256, bf16) == [(1024, 1024)] * 3
+    assert pick(256, 256, 64, bf16) == [(256, 256)] * 3     # T under the edge
+    assert pick(128, 128, 64, bf16) == [(128, 128)] * 3
+    # the reckoning is what bounds, not the edge: every pick fits the limit,
+    # and a smaller limit cuts the backward kernels first
+    for k, (bq, bk) in zip(kernels, pick(8192, 8192, 256, f32)):
+        assert fa.vmem_bytes(k, bq, bk, 256, f32) <= fa.VMEM_LIMIT
+    assert fa.vmem_bytes("flash_dq", 1024, 1024, 256, f32) > fa.VMEM_LIMIT
+    old = fa.VMEM_LIMIT
     try:
-        fa.BLOCK = 512
-        assert fa.pick_block(8192, 64) == 512
-        assert fa.pick_block(4224, 64) == 384    # 33*128: 512∤, 384|
-        assert fa.pick_block(4352, 64) == 256    # 34*128: 512∤, 384∤, 256|
-        assert fa.pick_block(8192, 256) == 256   # VMEM: 512*256 > 64k
-        assert fa.pick_block(256, 64) == 256     # cap clamps to T
-        assert fa.pick_block(128, 64) == 128
-        fa.BLOCK = 1024
-        # 1024*64 fits the operand budget but 12*1024^2 blows the [blk,blk]
-        # intermediate budget -> capped at 768, which doesn't divide 8192,
-        # so the largest dividing 128-multiple <= 768 wins
-        assert fa.pick_block(8192, 64) == 512
-        assert fa.pick_block(8192, 128) == 512
-        assert fa.pick_block(768 * 4, 64) == 768
-        fa.BLOCK = 128
-        assert fa.pick_block(8192, 64) == 128    # default: unchanged path
+        fa.VMEM_LIMIT = 16 * 2 ** 20
+        assert pick(4096, 4096, 128, bf16) == [(1024, 1024), (1024, 512),
+                                               (1024, 512)]
+        fa.VMEM_LIMIT = 2 ** 20
+        assert pick(4096, 4096, 128, bf16) == [(128, 128)] * 3  # the floor
     finally:
-        fa.BLOCK = old
+        fa.VMEM_LIMIT = old
+    assert pick(768 * 4, 768 * 4, 64, bf16) == [(1024, 1024)] * 3
+    # q and k are tiled apart (the ring's shard against a longer k/v block)
+    assert pick(256, 4096, 128, bf16) == [(256, 1024)] * 3
+    assert pick(4096, 512, 128, bf16) == [(1024, 512)] * 3
+    # the looped-LM cell's shape gives the swept edges
+    assert pick(4096, 4096, 128, bf16) == [(1024, 1024)] * 3
+
+
+# ------------------------------------------------- tilings and the causal walk
+def _dense_bh(q, k, v, causal, km=None, keep=None, rate=0.0):
+    """Dense attention on the kernels' own [bh, T, d] layout, with their
+    conventions: causal on local positions, ``km`` [bh, Tk] hides keys,
+    ``keep`` [bh, Tq, Tk] drops normalized probabilities, a row with no
+    visible key outputs 0."""
+    s = jnp.einsum("bqd,bkd->bqk", q, k) / jnp.sqrt(float(q.shape[-1]))
+    vis = jnp.ones(s.shape, bool)
+    if causal:
+        vis &= jnp.tril(jnp.ones(s.shape[1:], bool))[None]
+    if km is not None:
+        vis &= km[:, None, :] > 0
+    p = jax.nn.softmax(jnp.where(vis, s, -1e30), axis=-1)
+    p = jnp.where(jnp.any(vis, axis=-1, keepdims=True), p, 0.0)
+    if keep is not None:
+        p = p * keep / (1.0 - rate)
+    return jnp.einsum("bqk,bkd->bqd", p, v)
+
+
+def _kernels_out_and_grads(q, k, v, km8, seed, causal, rate, block_q,
+                           block_k):
+    """(o, dq, dk, dv) of loss = sum(o²) through the three kernels at the
+    given edges."""
+    scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    kw = dict(block_q=block_q, block_k=block_k)
+    o, lse = fa._fwd(q, k, v, km8, seed, causal, scale, rate, **kw)
+    do = 2.0 * o
+    delta = fa.rowwise_delta(do, o)
+    dq = fa.dq_block(q, k, v, km8, do, delta, lse, causal, scale, seed, rate,
+                     **kw)
+    dk, dv = fa.dkv_block(q, k, v, km8, do, delta, lse, causal, scale, seed,
+                          rate, **kw)
+    return o, dq, dk, dv
+
+
+def _bh_inputs(bh, Tq, Tk, d, seed):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(bh, Tq, d)), jnp.float32),
+            jnp.asarray(rng.normal(size=(bh, Tk, d)), jnp.float32),
+            jnp.asarray(rng.normal(size=(bh, Tk, d)), jnp.float32))
+
+
+@pytest.mark.parametrize("variant", ["plain", "key_mask", "dropout"])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (128, 256),
+                                             (256, 128), (256, 256)])
+def test_every_tiling_matches_the_dense_oracle(block_q, block_k, causal,
+                                               variant):
+    """Forward and the three gradients on multi-block grids at T 512,
+    q and k edges apart: whatever the edges, the walk (visible pairs only,
+    the mask on diagonal blocks only) computes dense attention. Under
+    dropout the decisions hash global positions: on inputs whose arithmetic
+    is exact (q = 0, integer v, rate 1/2) the output is bit-equal to
+    another tiling's."""
+    bh, T, d, rate = 2, 512, 32, 0.5
+    q, k, v = _bh_inputs(bh, T, T, d, seed=41)
+    km = km8 = seed = keep = None
+    if variant == "key_mask":
+        km = np.ones((bh, T), np.float32)
+        km[0, :200] = 0.0          # a whole block and a half: rows left blind
+        km[1, 300::3] = 0.0
+        km = jnp.asarray(km)
+        km8 = jnp.broadcast_to(km[..., None], (bh, T, 8))
+    if variant == "dropout":
+        seed = fa.seed3(1234)
+        keep = fa.dropout_keep_mask(bh, T, T, 1234, rate)
+    use_rate = rate if variant == "dropout" else 0.0
+    got = _kernels_out_and_grads(q, k, v, km8, seed, causal, use_rate,
+                                 block_q, block_k)
+    loss = lambda q, k, v: jnp.sum(
+        _dense_bh(q, k, v, causal, km, keep, rate) ** 2)
+    want = (_dense_bh(q, k, v, causal, km, keep, rate),
+            *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+    for name, a, w in zip(("o", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=2e-3,
+                                   atol=2e-4, err_msg=name)
+    if variant == "dropout":
+        exact_v = jnp.round(v * 4.0)
+        run = lambda bq, bk: np.asarray(fa._fwd(
+            jnp.zeros_like(q), k, exact_v, None, seed, causal, 1.0, rate,
+            block_q=bq, block_k=bk)[0])
+        np.testing.assert_array_equal(run(block_q, block_k), run(512, 512))
+
+
+@pytest.mark.parametrize("Tq,Tk,block_q,block_k,causal", [
+    (256, 512, 128, 256, False), (512, 256, 256, 128, False),
+    (256, 512, 128, 256, True), (512, 256, 256, 128, True),
+    (256, 512, None, None, True)])
+def test_backward_blocks_at_unequal_lengths_and_edges(Tq, Tk, block_q,
+                                                      block_k, causal):
+    """dq_block / dkv_block on a q shard and a k/v block of different
+    lengths, each tiled by its own edge (the ring's use; no gcd of the two
+    lengths). Causal with Tk > Tq the k blocks past the last query see no
+    query and their dk, dv are zeros, written all the same."""
+    q, k, v = _bh_inputs(2, Tq, Tk, 32, seed=43)
+    got = _kernels_out_and_grads(q, k, v, None, None, causal, 0.0, block_q,
+                                 block_k)
+    loss = lambda q, k, v: jnp.sum(_dense_bh(q, k, v, causal) ** 2)
+    want = (_dense_bh(q, k, v, causal),
+            *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+    for name, a, w in zip(("o", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=2e-3,
+                                   atol=2e-4, err_msg=name)
+    if causal and Tk > Tq:
+        np.testing.assert_array_equal(np.asarray(got[2][:, Tq:]), 0.0)
+        np.testing.assert_array_equal(np.asarray(got[3][:, Tq:]), 0.0)
+
+
+@pytest.mark.parametrize("nq,nk,block_q,block_k", [
+    (4, 4, 128, 128), (8, 2, 128, 512), (2, 8, 512, 128), (3, 6, 256, 128),
+    (4, 1, 128, 512)])
+@pytest.mark.parametrize("k_major", [False, True])
+def test_causal_pair_table_holds_exactly_the_visible_pairs(nq, nk, block_q,
+                                                           block_k, k_major):
+    """One grid step per visible (q block, k block) pair and no other, rows
+    in order, each row's first and last step marked."""
+    outer, inner, first, last = fa.causal_pairs(nq, nk, block_q, block_k,
+                                                k_major=k_major)
+    visible = {(qi, kj) for qi in range(nq) for kj in range(nk)
+               if kj * block_k <= (qi + 1) * block_q - 1}
+    pairs = list(zip(outer.tolist(), inner.tolist()))
+    as_qk = [(i, o) for o, i in pairs] if k_major else pairs
+    assert len(set(as_qk)) == len(as_qk)
+    assert set(as_qk) == visible          # Tq == Tk: every row sees a pair
+    assert pairs == sorted(pairs)
+    for t, (o, _) in enumerate(pairs):
+        assert first[t] == (t == 0 or pairs[t - 1][0] != o)
+        assert last[t] == (t == len(pairs) - 1 or pairs[t + 1][0] != o)
+    assert sorted(set(outer.tolist())) == list(range(nk if k_major else nq))
+    assert all(a.dtype == np.int32 for a in (outer, inner, first, last))
+
+
+def test_a_k_block_past_the_last_query_keeps_one_masked_step():
+    """Tk > Tq, k-major: k blocks 2 and 3 start after the last query; each
+    keeps the last q block as its only step (the diagonal's mask hides all
+    of it), so its zeros are written."""
+    outer, inner, first, last = fa.causal_pairs(2, 4, 128, 128, k_major=True)
+    assert list(zip(outer.tolist(), inner.tolist())) == [
+        (0, 0), (0, 1), (1, 1), (2, 1), (3, 1)]
+    assert first.tolist() == [1, 0, 1, 1, 1]
+    assert last.tolist() == [0, 1, 1, 1, 1]
+
+
+def test_kernel_names_and_gauge_carry_the_edges():
+    """The tiling that engaged is in the kernels' names (the device trace's
+    op names) and in ``flash_grid_steps{kernel}``: steps per call, causal
+    ones counting visible pairs only."""
+    from deeplearning4j_tpu.monitor import get_registry
+
+    sds = jax.ShapeDtypeStruct((2, 512, 32), jnp.float32)
+    st = jax.ShapeDtypeStruct((2, 512, 8), jnp.float32)
+
+    def three(q, k, v, do, delta, lse):
+        scale, kw = 0.25, dict(block_q=128, block_k=256)
+        return (fa._fwd(q, k, v, None, None, True, scale, 0.0, **kw),
+                fa.dq_block(q, k, v, None, do, delta, lse, True, scale, **kw),
+                fa.dkv_block(q, k, v, None, do, delta, lse, False, scale,
+                             **kw))
+
+    jaxpr = str(jax.make_jaxpr(three)(sds, sds, sds, sds, st, st))
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert f"{kernel}_q128_k256" in jaxpr
+    steps = {r["labels"]["kernel"]: r["value"]
+             for r in get_registry().snapshot()["flash_grid_steps"]}
+    # visible pairs of 4 q blocks of 128 over 2 k blocks of 256: 1+1+2+2
+    assert steps["flash_fwd_q128_k256"] == 2 * 6
+    assert steps["flash_dq_q128_k256"] == 2 * 6
+    assert steps["flash_dkv_q128_k256"] == 2 * 2 * 4      # not causal: all
+    # the chooser's own edges reach the name the same way
+    fa._fwd(*_bh_inputs(1, 256, 256, 16, seed=0), None, None, True, 0.25, 0.0)
+    bq, bk = fa.pick_blocks("flash_fwd", 256, 256, 16, jnp.float32)
+    assert (bq, bk) == (256, 256)
+    steps = {r["labels"]["kernel"]: r["value"]
+             for r in get_registry().snapshot()["flash_grid_steps"]}
+    assert steps["flash_fwd_q256_k256"] == 1
 
 
 def test_flash_fully_masked_rows_zero():
