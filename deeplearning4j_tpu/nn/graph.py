@@ -9,22 +9,21 @@ init/topo-sort :394/:1190, ``fit(DataSetIterator)`` :863,
 As with MultiLayerNetwork, the architectural shift is whole-graph compilation:
 one jitted XLA computation covers forward over the cached topological order,
 loss on every output vertex, AD backward, gradient normalization, updater, and
-the parameter update, with params/updater state donated. External-errors
-training (the reference's externalEpsilons path, used to couple a graph to an
-outside loss) is ``fit_external_errors``: VJP of the outputs against caller
-epsilons inside the same jitted step.
+the parameter update, with params/updater state donated; that step and the
+``fit`` loop around it are ``nn/training.py``'s, shared with
+``MultiLayerNetwork``. External-errors training (the reference's
+externalEpsilons path, used to couple a graph to an outside loss) is
+``fit_external_errors``: VJP of the outputs against caller epsilons inside the
+same jitted step.
 """
 from __future__ import annotations
 
-import contextlib
-import logging
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .conf import BackpropType, CacheMode, GradientNormalization
 from ..monitor.jitwatch import monitored_jit
 from .conf.graph import ComputationGraphConfiguration
 from .conf.layers import Layer
@@ -32,15 +31,10 @@ from .conf.inputs import InputTypeConvolutional, InputTypeLoopedRecurrent
 from jax.ad_checkpoint import checkpoint_name
 
 from .layers import impl_for
-from .layers.base import remat_enabled, remat_policy
-from .multilayer import _n_iterations, _scan_iterations
-from ..datasets.dataset import (DataSet, MultiDataSet, DataSetIterator,
-                                ListDataSetIterator)
-from ..datasets.prefetch import wrap_for_training
+from .training import _TrainingBase, _device_arrays
+from ..datasets.dataset import MultiDataSet
 from ..optimize.updater import NetworkUpdater, normalize_gradients
-from .. import monitor as _mon
 
-log = logging.getLogger(__name__)
 _tm = jax.tree_util.tree_map
 
 
@@ -57,24 +51,13 @@ def fused_softmax_skip_set(conf, impls):
                      and n not in consumed)
 
 
-class ComputationGraph:
+class ComputationGraph(_TrainingBase):
+    _jit_prefix = "cg"
+
     def __init__(self, conf: ComputationGraphConfiguration):
-        self.conf = conf
-        self.gc = conf.global_conf
+        super().__init__(conf)
         self.topo: List[str] = conf.topological_order()
         self.impls: Dict[str, object] = {}
-        self.params = None
-        self.states = None
-        self.updater = None
-        self.updater_state = None
-        self.iteration_count = 0
-        self.epoch_count = 0
-        self.listeners: List = []
-        self.score_ = float("nan")
-        self.halt_requested = False  # TrainingHealthListener "halt" action
-        self._completions = _mon.StepCompletions(self)   # fit starts its own
-        self._rng = None
-        self._jit_step = None
         self._jit_ext_step = None
         self._jit_output = {}
         self._types = None
@@ -222,190 +205,7 @@ class ComputationGraph:
         aux = ctx.get("aux_loss", 0.0)  # e.g. MoE load balancing
         return total + reg + aux, (new_states, ctx.get("rnn_state_out"))
 
-    # ---------------------------------------------------------- train step
-    def _raw_update_core(self, grads_reduce=None):
-        """Shared step core (see MultiLayerNetwork._raw_update_core): returns
-        ``(updates, new_states, new_upd, loss, rnn_out)`` without applying.
-        ``grads_reduce``: optional cross-device reduction hook (same seam as
-        the MLN core — ``sequence_parallel_step`` uses it)."""
-        gn_mode = self.gc.gradient_normalization
-        gn_thresh = self.gc.gradient_normalization_threshold
-        minimize = self.gc.minimize
-
-        use_remat = remat_enabled(self.gc, self.impls.values())
-
-        def core(params, states, upd_state, iteration, rng, inputs, labels,
-                 input_masks, label_masks, rnn_state_in=None):
-            inputs = self._adapt_inputs(inputs)
-
-            def loss_fn(p):
-                return self._loss_fn(p, states, inputs, labels, input_masks,
-                                     label_masks, True, rng, rnn_state_in)
-
-            if use_remat:
-                loss_fn = jax.checkpoint(loss_fn, policy=remat_policy())
-            (loss, (new_states, rnn_out)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            if grads_reduce is not None:
-                grads, loss, new_states = grads_reduce(grads, loss,
-                                                       new_states)
-            with jax.named_scope("updater"):
-                if not minimize:
-                    grads = _tm(lambda g: -g, grads)
-                grads = normalize_gradients(grads, gn_mode, gn_thresh)
-                updates, new_upd = self.updater.apply(upd_state, grads,
-                                                      iteration)
-            return updates, new_states, new_upd, loss, rnn_out
-
-        return core
-
-    def _raw_step(self, with_rnn_state=False):
-        core = self._raw_update_core()
-
-        def step(params, states, upd_state, iteration, rng, inputs, labels,
-                 input_masks, label_masks, rnn_state_in=None):
-            updates, new_states, new_upd, loss, rnn_out = core(
-                params, states, upd_state, iteration, rng, inputs, labels,
-                input_masks, label_masks, rnn_state_in)
-            with jax.named_scope("updater"):
-                new_params = _tm(lambda p, u: p - u.astype(p.dtype), params,
-                                 updates)
-                new_params = self._apply_constraints(new_params)
-            if with_rnn_state:
-                rnn_out = (_tm(jax.lax.stop_gradient, rnn_out)
-                           if rnn_out else rnn_out)
-                return new_params, new_states, new_upd, loss, rnn_out
-            return new_params, new_states, new_upd, loss
-
-        return step
-
-    def _raw_update_step(self, with_rnn_state=False):
-        """Updater-transformed update without application — SHARED_GRADIENTS
-        wire seam (see MultiLayerNetwork._raw_update_step)."""
-        core = self._raw_update_core()
-
-        def step(params, states, upd_state, iteration, rng, inputs, labels,
-                 input_masks, label_masks, rnn_state_in=None):
-            updates, new_states, new_upd, loss, rnn_out = core(
-                params, states, upd_state, iteration, rng, inputs, labels,
-                input_masks, label_masks, rnn_state_in)
-            if with_rnn_state:
-                rnn_out = (_tm(jax.lax.stop_gradient, rnn_out)
-                           if rnn_out else rnn_out)
-                return updates, new_states, new_upd, loss, rnn_out
-            return updates, new_states, new_upd, loss
-
-        return step
-
-    def _apply_constraints(self, params):
-        from .conf.dropout import apply_constraints
-        out = dict(params)
-        for name in self.impls:
-            lc = self.conf.vertices[name]
-            cons = getattr(lc, "constraints", None) or \
-                getattr(getattr(lc, "inner", None), "constraints", None)
-            if cons:
-                out[name] = apply_constraints(cons, params[name])
-        return out
-
-    def _build_step(self, with_rnn_state, single_iteration=False):
-        step = self._raw_step(with_rnn_state=with_rnn_state)
-        n_iter = 1 if single_iteration else _n_iterations(self.gc)
-        if n_iter > 1:
-            step = _scan_iterations(step, n_iter, with_rnn_state=with_rnn_state)
-        looped = sum(getattr(i, "block_applications", 0)
-                     for i in self.impls.values())
-        if looped:
-            _mon.get_registry().gauge(
-                "looped_block_applications",
-                "Block applications per step of the network's looped stacks "
-                "(passes x blocks), set when the step is built",
-                network="cg").set(looped)
-        return monitored_jit(step, name="cg/step",
-                             donate_argnums=(0, 2))
-
-    def _ensure_step(self, single_iteration=False):
-        if single_iteration and _n_iterations(self.gc) > 1:
-            if getattr(self, "_jit_step_single", None) is None:
-                self._jit_step_single = self._build_step(
-                    with_rnn_state=False, single_iteration=True)
-            return self._jit_step_single
-        if self._jit_step is None:
-            self._jit_step = self._build_step(with_rnn_state=False)
-        return self._jit_step
-
-    def _ensure_tbptt_step(self, single_iteration=False):
-        if single_iteration and _n_iterations(self.gc) > 1:
-            if getattr(self, "_jit_tbptt_step_single", None) is None:
-                self._jit_tbptt_step_single = self._build_step(
-                    with_rnn_state=True, single_iteration=True)
-            return self._jit_tbptt_step_single
-        if getattr(self, "_jit_tbptt_step", None) is None:
-            self._jit_tbptt_step = self._build_step(with_rnn_state=True)
-        return self._jit_tbptt_step
-
-    def _init_rnn_state(self, batch):
-        state = {}
-        for name, impl in self.impls.items():
-            if hasattr(impl, "init_stream_state"):
-                state[name] = impl.init_stream_state(batch)
-        return state
-
-    def _next_rng(self):
-        self._rng, k = jax.random.split(self._rng)
-        return k
-
-    # ----------------------------------------------------------------- fit
-    def fit(self, data, labels=None, epochs=1):
-        """Train. Accepts DataSet/MultiDataSet, an iterator of either, or
-        (features, labels) arrays (reference ``fit`` overloads :863/:988)."""
-        if labels is not None:
-            data = DataSet(np.asarray(data), np.asarray(labels))
-        if isinstance(data, (DataSet, MultiDataSet)):
-            data = ListDataSetIterator([data])
-        # multi-worker prefetch + device-put-ahead (datasets/prefetch.py):
-        # see MultiLayerNetwork.fit
-        it, own_pipeline = wrap_for_training(
-            data, cache_device=self.gc.cache_mode == CacheMode.DEVICE)
-        # a new fit() supersedes a previous health halt — without this, one
-        # halt would silently truncate every later fit to a single batch
-        self.halt_requested = False
-        _mon.get_health().clear_halt()
-        done = self._completions = _mon.StepCompletions(self)
-        try:
-            for _ in range(epochs):
-                for lst in self.listeners:
-                    lst.on_epoch_start(self, self.epoch_count)
-                with _mon.get_tracer().span("epoch", cat="train",
-                                            epoch=self.epoch_count):
-                    for ds, waited in _mon.spanned(it, "fit/next_batch"):
-                        self._fit_batch(ds, etl_ms=waited * 1e3)
-                        if self.halt_requested:
-                            break
-                    done.drain()
-                for lst in self.listeners:
-                    lst.on_epoch_end(self, self.epoch_count)
-                self.epoch_count += 1
-                if self.halt_requested:
-                    log.warning("fit halted at epoch %d (halt_requested; see "
-                                "TrainingHealthListener)", self.epoch_count)
-                    break
-        except BaseException as e:
-            # error seam: listeners holding process-global resources (an
-            # active ProfilerListener trace window) must release them
-            # before the exception unwinds out of fit
-            from ..optimize.listeners import dispatch_training_error
-            dispatch_training_error(self, self.listeners, e)
-            # the steps dispatched before the failure still count; a fetch
-            # that fails in turn must not hide ``e``
-            with contextlib.suppress(Exception):
-                done.drain()
-            raise
-        finally:
-            if own_pipeline:
-                it.shutdown()   # no prefetch worker outlives its fit
-        return self
-
+    # --------------------------------- what nn/training.py asks of a container
     def _as_multi(self, ds):
         if isinstance(ds, MultiDataSet):
             return ds
@@ -413,88 +213,18 @@ class ComputationGraph:
                             None if ds.features_mask is None else [ds.features_mask],
                             None if ds.labels_mask is None else [ds.labels_mask])
 
-    def _fit_batch(self, ds, single_iteration=False, etl_ms=None):
-        """One minibatch. ``single_iteration=True`` applies exactly ONE
-        optimizer update even under ``iterations(n)`` (ParallelWrapper
-        tail-batch fallback — see MultiLayerNetwork._fit_batch)."""
-        with _mon.get_tracer().span("fit/prepare", cat="train"):
-            inputs, labels, fms, lms = self._batch_streams(ds)
-            self.last_batch_size = int(inputs[0].shape[0])
-            tbptt = (self.conf.backprop_type == BackpropType.TruncatedBPTT
-                     and all(x.ndim == 3 for x in inputs)
-                     and inputs[0].shape[1] > self.conf.tbptt_fwd_length)
-            if not tbptt:
-                step = self._ensure_step(single_iteration=single_iteration)
-                it = jnp.asarray(self.iteration_count, jnp.int32)
-                rng = self._next_rng()
-        if tbptt:
-            self._fit_tbptt(inputs, labels, fms, lms,
-                            single_iteration=single_iteration)
-            return
-        # dispatch only: a span is host time, the fetch is StepCompletions'
-        with _mon.step_span(self.iteration_count):
-            self.params, self.states, self.updater_state, loss = step(
-                self.params, self.states, self.updater_state, it, rng,
-                inputs, labels, fms, lms)
-        self.score_ = loss
-        self.iteration_count += (1 if single_iteration
-                                 else _n_iterations(self.gc))
-        self._completions.dispatched(loss, self.last_batch_size, etl_ms)
-
-    def _batch_streams(self, ds):
+    def _batch_streams(self, ds, cached=False):
         """``(inputs, labels, feature masks, label masks)`` of ``ds`` as the
         step takes them: tuples of device arrays, masks None where absent."""
-        if isinstance(ds, DataSet):
-            if self.gc.cache_mode == CacheMode.DEVICE:
-                # cache on the CALLER's DataSet — _as_multi builds a fresh
-                # wrapper per batch, so a wrapper-side cache would never hit
-                f, l, fm, lm = ds.device_arrays()
-            else:
-                # direct, not via _as_multi: MultiDataSet.__init__ calls
-                # np.asarray, which would pull a put-ahead (device-resident)
-                # batch straight back to the host
-                f = jnp.asarray(ds.features)
-                l = jnp.asarray(ds.labels)
-                fm = (None if ds.features_mask is None
-                      else jnp.asarray(ds.features_mask))
-                lm = (None if ds.labels_mask is None
-                      else jnp.asarray(ds.labels_mask))
-            return ((f,), (l,), None if fm is None else (fm,),
-                    None if lm is None else (lm,))
-        if self.gc.cache_mode == CacheMode.DEVICE:
-            return self._as_multi(ds).device_arrays()
-        mds = self._as_multi(ds)
-        inputs = tuple(jnp.asarray(f) for f in mds.features)
-        labels = tuple(jnp.asarray(l) for l in mds.labels)
-        fms = (None if mds.features_masks is None
-               else tuple(None if m is None else jnp.asarray(m)
-                          for m in mds.features_masks))
-        lms = (None if mds.labels_masks is None
-               else tuple(None if m is None else jnp.asarray(m)
-                          for m in mds.labels_masks))
-        return inputs, labels, fms, lms
+        streams = _device_arrays(ds, cached)
+        if isinstance(ds, MultiDataSet):
+            return streams
+        return tuple(None if a is None else (a,) for a in streams)
 
-    def _ensure_tbptt_scan_step(self, single_iteration=False):
-        cache = getattr(self, "_jit_tbptt_scan", None)
-        if cache is None:
-            cache = self._jit_tbptt_scan = {}
-        key = bool(single_iteration)
-        if key not in cache:
-            from .multilayer import _build_tbptt_scan
-            n_iter = 1 if single_iteration else _n_iterations(self.gc)
-            cache[key] = _build_tbptt_scan(self._raw_step(with_rnn_state=True),
-                                           n_iter)
-        return cache[key]
-
-    def _fit_tbptt(self, inputs, labels, fms, lms, single_iteration=False):
-        """Truncated BPTT over the DAG (reference CG ``doTruncatedBPTT``):
-        time is chunked to ``tbptt_fwd_length``; per-recurrent-vertex (h, c)
-        carries are detached between chunks. Equal segments run as ONE
-        fused ``lax.scan`` program (one device dispatch per minibatch — see
-        ``multilayer._build_tbptt_scan``); a ragged tail falls back to
-        per-segment dispatch."""
-        from .multilayer import _run_tbptt
-        _run_tbptt(self, inputs, labels, fms, lms, single_iteration)
+    def _layers(self):
+        """``(params key, layer conf, impl)``, in topological order."""
+        return ((name, self.conf.vertices[name], impl)
+                for name, impl in self.impls.items())
 
     # ------------------------------------------------------------- streaming
     def rnn_time_step(self, *inputs):
@@ -594,50 +324,6 @@ class ComputationGraph:
 
     feedForward = feed_forward
 
-    # ----------------------------------------------------------------- score
-    def score(self, ds=None, training=False):
-        if ds is None:
-            return float(self.score_)
-        mds = self._as_multi(ds)
-        inputs = tuple(jnp.asarray(f) for f in mds.features)
-        labels = tuple(jnp.asarray(l) for l in mds.labels)
-        fms = (None if mds.features_masks is None
-               else tuple(None if m is None else jnp.asarray(m)
-                          for m in mds.features_masks))
-        lms = (None if mds.labels_masks is None
-               else tuple(None if m is None else jnp.asarray(m)
-                          for m in mds.labels_masks))
-        key = (bool(training), fms is not None, lms is not None)
-        if not hasattr(self, "_jit_score"):
-            self._jit_score = {}
-        if key not in self._jit_score:
-            # jitted: early stopping / evaluative listeners call score every
-            # epoch — eager per-batch tracing would dominate evaluation on TPU
-            def score_fn(params, states, inputs, labels, fms, lms):
-                xs = self._adapt_inputs(inputs)
-                loss, _ = self._loss_fn(params, states, xs, labels, fms,
-                                        lms, training, None)
-                return loss
-            self._jit_score[key] = monitored_jit(score_fn,
-                                                 name="cg/score")
-        loss = self._jit_score[key](self.params, self.states, inputs, labels,
-                                    fms, lms)
-        return float(loss)
-
-    def compute_gradient_and_score(self, ds):
-        mds = self._as_multi(ds)
-        inputs = self._adapt_inputs([jnp.asarray(f) for f in mds.features])
-        labels = [jnp.asarray(l) for l in mds.labels]
-
-        def loss_fn(p):
-            loss, _ = self._loss_fn(p, self.states, inputs, labels, None, None,
-                                    True, None)
-            return loss
-
-        loss, grads = jax.value_and_grad(loss_fn)(self.params)
-        self.score_ = loss
-        return grads, float(loss)
-
     # ------------------------------------------------------------ evaluation
     def evaluate(self, iterator, output_idx=0):
         """Classification evaluation on output ``output_idx`` (reference
@@ -665,17 +351,6 @@ class ComputationGraph:
         return out
 
     paramTable = param_table
-
-    def num_params(self) -> int:
-        return sum(int(v.size) for v in jax.tree_util.tree_leaves(self.params))
-
-    numParams = num_params
-
-    def set_listeners(self, *listeners):
-        self.listeners = list(listeners)
-        return self
-
-    setListeners = set_listeners
 
     def summary(self) -> str:
         lines = [f"{'vertex':<32} {'type':<28} {'params':>10}"]

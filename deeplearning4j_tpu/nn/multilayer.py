@@ -11,195 +11,36 @@ parameter update — is ONE jitted XLA computation with params/updater-state/lay
 donated (the functional realization of the reference's in-place
 ``stepFunction.step``, ``StochasticGradientDescent.java:79``). Workspaces/CacheMode
 (§2.8 item 3) collapse into XLA buffer donation + executable caching, which jit
-gives us for free.
+gives us for free. That step and the ``fit`` loop around it are ``nn/training.py``'s,
+shared with ``ComputationGraph``; this module holds the forward pass and the loss.
 
 Training state (BN running stats, RNN streaming state) is explicit: ``states``
 pytree and the TBPTT carry, replacing the reference's mutable layer fields.
 """
 from __future__ import annotations
 
-import contextlib
-import logging
-from functools import partial
-from typing import List, Optional
-
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .conf import (MultiLayerConfiguration, BackpropType, CacheMode,
-                   GradientNormalization)
-from .conf.inputs import (InputTypeConvolutional, InputTypeConvolutionalFlat,
-                          InputTypeRecurrent)
+from .conf import MultiLayerConfiguration
+from .conf.inputs import InputTypeConvolutional
 from jax.ad_checkpoint import checkpoint_name
 
 from .layers import impl_for
-from .layers.base import remat_enabled, remat_policy
-from .layers.recurrent import _BaseLSTMImpl
-from ..datasets.dataset import DataSet, DataSetIterator, ListDataSetIterator
-from ..datasets.prefetch import wrap_for_training
-from ..optimize.updater import NetworkUpdater, normalize_gradients
-from .. import monitor as _mon
+from .training import _TrainingBase, _device_arrays
+from ..optimize.updater import NetworkUpdater
 from ..monitor.jitwatch import monitored_jit
-
-log = logging.getLogger(__name__)
 
 _tm = jax.tree_util.tree_map
 
 
-def _n_iterations(gc):
-    """Configured optimizer iterations per minibatch/segment (0.9.x
-    ``iterations`` config), with the legacy-config fallback in ONE place."""
-    return int(getattr(gc, "iterations", 1) or 1)
+class MultiLayerNetwork(_TrainingBase):
+    _jit_prefix = "mln"
 
-
-def _scan_iterations(step, n_iter, with_rnn_state=False):
-    """Wrap a train-step fn in a ``lax.scan`` running ``n_iter`` optimizer
-    iterations on the SAME minibatch inside one compiled program — the
-    TPU-native realization of the reference's 0.9.x ``iterations`` config
-    (``NeuralNetConfiguration.Builder.iterations``): small-model training
-    pays the dispatch latency once per n steps. Same signature as ``step``;
-    the iteration counter advances per scanned step and the rng is split so
-    dropout differs across iterations; returns the LAST loss (and, on the
-    TBPTT variant, the last rnn state — every iteration of a segment starts
-    from the same carried-in state, reference solver-per-segment
-    semantics)."""
-    def scanned(params, states, upd_state, iteration, rng, f, l, fm, lm,
-                rnn_state_in=None):
-        def body(carry, i):
-            params, states, upd_state, rng = carry
-            rng, key = jax.random.split(rng)
-            out = step(params, states, upd_state, iteration + i, key, f, l,
-                       fm, lm, rnn_state_in)
-            params, states, upd_state, loss = out[:4]
-            extra = out[4] if with_rnn_state else None
-            return (params, states, upd_state, rng), (loss, extra)
-        (params, states, upd_state, _), (losses, extras) = jax.lax.scan(
-            body, (params, states, upd_state, rng),
-            jnp.arange(n_iter, dtype=jnp.int32))
-        if with_rnn_state:
-            last_rnn = _tm(lambda x: x[-1], extras)
-            return params, states, upd_state, losses[-1], last_rnn
-        return params, states, upd_state, losses[-1]
-    return scanned
-
-
-def _build_tbptt_scan(step, n_iter):
-    """Jit a with-rnn-state train step into ONE program running the whole
-    TBPTT segment loop (``lax.scan`` over stacked segments, params/updater/
-    RNN state carried, segments detached by the step itself). Shared by
-    MultiLayerNetwork AND ComputationGraph so the two containers' fused
-    TBPTT semantics cannot drift. Inputs are segment-stacked pytrees
-    ``[S, ...]`` (tuples of streams for the graph container ride through
-    untouched — scan maps over every leaf's leading dim)."""
-    if n_iter > 1:
-        step = _scan_iterations(step, n_iter, with_rnn_state=True)
-
-    def scanned(params, states, upd, it0, rng, f_s, l_s, fm_s, lm_s, rnn0):
-        def body(carry, xs):
-            params, states, upd, rnn, s = carry
-            f_c, l_c, fm_c, lm_c = xs
-            params, states, upd, loss, rnn = step(
-                params, states, upd, it0 + s * n_iter,
-                jax.random.fold_in(rng, s), f_c, l_c, fm_c, lm_c, rnn)
-            return (params, states, upd, rnn, s + 1), loss
-
-        init = (params, states, upd, rnn0, jnp.asarray(0, jnp.int32))
-        (params, states, upd, _, _), losses = jax.lax.scan(
-            body, init, (f_s, l_s, fm_s, lm_s))
-        return params, states, upd, losses[-1]
-
-    return monitored_jit(scanned, name="nn/tbptt_scan",
-                         donate_argnums=(0, 2))
-
-
-def _map_streams(fn, x):
-    """Apply ``fn`` to every stream array — bare arrays (MultiLayerNetwork),
-    tuples of optional streams (ComputationGraph), None passthrough. Exactly
-    ``tree_map`` semantics; the alias names the intent at the call sites."""
-    return jax.tree_util.tree_map(fn, x)
-
-
-def _run_tbptt(net, f, l, fm, lm, single_iteration):
-    """The TBPTT dispatch loop shared by BOTH containers (reference
-    ``doTruncatedBPTT`` in `MultiLayerNetwork.java:1219` and
-    `ComputationGraph.java`): equal segments fuse into ONE scanned program
-    (segment stacking [b, T, ...] → [S, b, L, ...], rank-2 labels/static
-    streams broadcast over S); a ragged tail falls back to per-segment
-    dispatch with the (h, c) carries threaded on the host. Stream-shape
-    differences between the containers are confined to ``_map_streams``."""
-    conf, gc = net.conf, net.gc
-    first = f[0] if isinstance(f, tuple) else f
-    T = int(first.shape[1])
-    L = conf.tbptt_fwd_length
-    n_applied = 1 if single_iteration else _n_iterations(gc)
-    if T % L == 0:
-        S, b = T // L, int(first.shape[0])
-
-        def stack(x):
-            return jnp.swapaxes(x.reshape(b, S, L, *x.shape[2:]), 0, 1)
-
-        def stack_lbl(x):
-            return (stack(x) if x.ndim == 3
-                    else jnp.broadcast_to(x, (S,) + x.shape))
-
-        scan_step = net._ensure_tbptt_scan_step(single_iteration)
-        with _mon.get_tracer().span("fit/prepare", cat="train"):
-            it0 = jnp.asarray(net.iteration_count, jnp.int32)
-            rng = net._next_rng()
-            streams = (_map_streams(stack, f), _map_streams(stack_lbl, l),
-                       _map_streams(stack, fm), _map_streams(stack, lm))
-            rnn0 = net._init_rnn_state(b)
-        with _mon.step_span(net.iteration_count):
-            (net.params, net.states, net.updater_state, loss) = scan_step(
-                net.params, net.states, net.updater_state, it0, rng,
-                *streams, rnn0)
-        # one iteration per TBPTT segment × iterations(n) applied per
-        # segment (reference increments iterationCount per applied update,
-        # so Adam bias correction and lr schedules see each one)
-        net.iteration_count += S * n_applied
-    else:
-        step = net._ensure_tbptt_step(single_iteration=single_iteration)
-        rnn_state = net._init_rnn_state(int(first.shape[0]))
-        for start in range(0, T, L):
-            sl = slice(start, min(start + L, T))
-            with _mon.get_tracer().span("fit/prepare", cat="train"):
-                it = jnp.asarray(net.iteration_count, jnp.int32)
-                rng = net._next_rng()
-                streams = (
-                    _map_streams(lambda x: x[:, sl], f),
-                    _map_streams(lambda x: x[:, sl] if x.ndim == 3 else x, l),
-                    _map_streams(lambda x: x[:, sl], fm),
-                    _map_streams(lambda x: x[:, sl], lm))
-            with _mon.step_span(net.iteration_count):
-                (net.params, net.states, net.updater_state, loss,
-                 rnn_state) = step(
-                    net.params, net.states, net.updater_state, it, rng,
-                    *streams, rnn_state)
-            net.iteration_count += n_applied
-    net.score_ = loss
-    net._completions.dispatched(loss, int(first.shape[0]))
-
-
-class MultiLayerNetwork:
     def __init__(self, conf: MultiLayerConfiguration):
-        self.conf = conf
-        self.gc = conf.global_conf
+        super().__init__(conf)
         self.impls = None
-        self.params = None          # {"0": {"W": ..., "b": ...}, ...}
-        self.states = None          # non-trainable layer state
-        self.updater = None         # NetworkUpdater
-        self.updater_state = None
-        self.iteration_count = 0
-        self.epoch_count = 0
-        self.listeners: List = []
-        self.score_ = float("nan")
-        self.last_batch_size = 0
-        self.halt_requested = False  # TrainingHealthListener "halt" action
-        self._completions = _mon.StepCompletions(self)   # fit starts its own
-        self._rng = None
-        self._jit_step = None
-        self._jit_tbptt_step = None
         self._jit_output = {}
         self._rnn_state = None      # streaming state for rnn_time_step
 
@@ -390,6 +231,22 @@ class MultiLayerNetwork:
                 return jnp.transpose(f, (0, 2, 3, 1))
         return f
 
+    # --------------------------------- what nn/training.py asks of a container
+    _adapt_inputs = _adapt_input      # the one stream is the inputs
+
+    _batch_streams = staticmethod(_device_arrays)   # bare arrays
+
+    def _layers(self):
+        """``(params key, layer conf, impl)``; stream state is keyed by the
+        impl's integer ``index``, parameters by its string."""
+        return ((str(i), lc, impl) for i, (lc, impl)
+                in enumerate(zip(self.conf.layers, self.impls)))
+
+    def _before_fit(self, iterator):
+        if self.conf.pretrain and not getattr(self, "_pretrained", False):
+            self.pretrain(iterator)
+            self._pretrained = True
+
     def _loss_fn(self, params, states, f, l, fm, lm, train, rng, rnn_state_in=None):
         n = len(self.impls)
         x, new_states, ctx = self._apply_layers(params, states, f, fm, train,
@@ -419,280 +276,6 @@ class MultiLayerNetwork:
         # accumulate in ctx during the forward pass
         aux = ctx.get("aux_loss", 0.0)
         return loss + reg + aux, (new_states, ctx.get("rnn_state_out"))
-
-    # ---------------------------------------------------------- train step
-    def _raw_update_core(self, grads_reduce=None):
-        """Shared step core: loss → AD grads → gradient normalization →
-        updater transform. Returns ``(updates, new_states, new_upd, loss,
-        rnn_out)`` WITHOUT applying the update, so both ``_raw_step`` (apply
-        in-graph) and ``_raw_update_step`` (ship the update through the
-        SHARED_GRADIENTS codec) stay in lock-step by construction.
-
-        ``grads_reduce(grads, loss, new_states) -> (grads, loss,
-        new_states)``: optional cross-device reduction hook applied right
-        after AD, BEFORE the minimize flip / normalization / updater —
-        the seam ``parallel.sequence.sequence_parallel_step`` uses to psum
-        time-sliced gradients while inheriting this core's remat/adapt/aux
-        behavior instead of duplicating it."""
-        gn_mode = self.gc.gradient_normalization
-        gn_thresh = self.gc.gradient_normalization_threshold
-        minimize = self.gc.minimize
-
-        use_remat = remat_enabled(self.gc, self.impls)
-
-        def core(params, states, upd_state, iteration, rng, f, l, fm, lm,
-                 rnn_state_in=None):
-            f = self._adapt_input(f)
-
-            def loss_fn(p):
-                return self._loss_fn(p, states, f, l, fm, lm, True, rng,
-                                     rnn_state_in)
-
-            if use_remat:
-                loss_fn = jax.checkpoint(loss_fn, policy=remat_policy())
-            (loss, (new_states, rnn_out)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            if grads_reduce is not None:
-                grads, loss, new_states = grads_reduce(grads, loss,
-                                                       new_states)
-            with jax.named_scope("updater"):
-                if not minimize:
-                    grads = _tm(lambda g: -g, grads)
-                grads = normalize_gradients(grads, gn_mode, gn_thresh)
-                updates, new_upd = self.updater.apply(upd_state, grads,
-                                                      iteration)
-            return updates, new_states, new_upd, loss, rnn_out
-
-        return core
-
-    def _raw_step(self, with_rnn_state):
-        """The pure (unjitted) train-step function. ``_build_step`` jits it for
-        single-device training; ``deeplearning4j_tpu.parallel`` re-jits it with
-        explicit ``NamedSharding``s over a device mesh (SPMD data parallelism —
-        the reference's ParallelWrapper role, SURVEY.md §2.4/§7 Phase 3)."""
-        core = self._raw_update_core()
-
-        def step(params, states, upd_state, iteration, rng, f, l, fm, lm,
-                 rnn_state_in=None):
-            updates, new_states, new_upd, loss, rnn_out = core(
-                params, states, upd_state, iteration, rng, f, l, fm, lm,
-                rnn_state_in)
-            with jax.named_scope("updater"):
-                new_params = _tm(lambda p, u: p - u.astype(p.dtype), params,
-                                 updates)
-                new_params = self._apply_constraints(new_params)
-            if with_rnn_state:
-                rnn_out = _tm(jax.lax.stop_gradient, rnn_out) if rnn_out else rnn_out
-                return new_params, new_states, new_upd, loss, rnn_out
-            return new_params, new_states, new_upd, loss
-
-        return step
-
-    def _raw_update_step(self, with_rnn_state=False):
-        """Updater-transformed update without application — the
-        SHARED_GRADIENTS wire seam: the reference encodes post-updater updates
-        for peer broadcast (``SymmetricTrainer`` via
-        ``EncodingHandler.java:136``), so the codec must see the update, not
-        the raw gradient. ``with_rnn_state``: thread the detached RNN/KV
-        carry through (TBPTT segments under SHARED_GRADIENTS)."""
-        core = self._raw_update_core()
-
-        def step(params, states, upd_state, iteration, rng, f, l, fm, lm,
-                 rnn_state_in=None):
-            updates, new_states, new_upd, loss, rnn_out = core(
-                params, states, upd_state, iteration, rng, f, l, fm, lm,
-                rnn_state_in)
-            if with_rnn_state:
-                rnn_out = (_tm(jax.lax.stop_gradient, rnn_out)
-                           if rnn_out else rnn_out)
-                return updates, new_states, new_upd, loss, rnn_out
-            return updates, new_states, new_upd, loss
-
-        return step
-
-    def _apply_constraints(self, params):
-        """Per-layer parameter constraints after each update (reference
-        ``BaseConstraint.applyConstraint`` timing)."""
-        from .conf.dropout import apply_constraints
-        out = dict(params)
-        for i, lc in enumerate(self.conf.layers):
-            cons = getattr(lc, "constraints", None) or \
-                getattr(getattr(lc, "inner", None), "constraints", None)
-            if cons:
-                out[str(i)] = apply_constraints(cons, params[str(i)])
-        return out
-
-    def _build_step(self, with_rnn_state, single_iteration=False):
-        step = self._raw_step(with_rnn_state)
-        n_iter = 1 if single_iteration else _n_iterations(self.gc)
-        if n_iter > 1:
-            step = _scan_iterations(step, n_iter, with_rnn_state)
-        return monitored_jit(step, name="mln/step",
-                             donate_argnums=(0, 2))
-
-    def _ensure_step(self, single_iteration=False):
-        if single_iteration and _n_iterations(self.gc) > 1:
-            if getattr(self, "_jit_step_single", None) is None:
-                self._jit_step_single = self._build_step(
-                    with_rnn_state=False, single_iteration=True)
-            return self._jit_step_single
-        if self._jit_step is None:
-            self._jit_step = self._build_step(with_rnn_state=False)
-        return self._jit_step
-
-    def _ensure_tbptt_step(self, single_iteration=False):
-        if single_iteration and _n_iterations(self.gc) > 1:
-            if getattr(self, "_jit_tbptt_step_single", None) is None:
-                self._jit_tbptt_step_single = self._build_step(
-                    with_rnn_state=True, single_iteration=True)
-            return self._jit_tbptt_step_single
-        if self._jit_tbptt_step is None:
-            self._jit_tbptt_step = self._build_step(with_rnn_state=True)
-        return self._jit_tbptt_step
-
-    def _build_tbptt_scan_step(self, single_iteration=False):
-        """The WHOLE TBPTT loop as one jitted program: ``lax.scan`` over
-        stacked segments, carrying params/updater/RNN state (detached between
-        segments by the inner step). One device dispatch per minibatch
-        instead of one per segment: a 200-char/50-TBPTT batch saves 3 of 4
-        dispatches (same move as the ``iterations(n)`` scan, applied to the
-        segment dimension)."""
-        n_iter = 1 if single_iteration else _n_iterations(self.gc)
-        return _build_tbptt_scan(self._raw_step(True), n_iter)
-
-    def _ensure_tbptt_scan_step(self, single_iteration=False):
-        cache = getattr(self, "_jit_tbptt_scan", None)
-        if cache is None:
-            cache = self._jit_tbptt_scan = {}
-        key = bool(single_iteration)
-        if key not in cache:
-            cache[key] = self._build_tbptt_scan_step(single_iteration)
-        return cache[key]
-
-    def _next_rng(self):
-        self._rng, k = jax.random.split(self._rng)
-        return k
-
-    # ----------------------------------------------------------------- fit
-    def fit(self, data, labels=None, epochs=1):
-        """Train (reference ``fit(DataSetIterator)`` :1156). Accepts a DataSet,
-        a DataSetIterator, or (features, labels) arrays.
-
-        .. note:: Timing caution: steps are dispatched asynchronously, so
-           ``fit`` can return before the device has finished. Close a timed
-           window with ``jax.block_until_ready(net.params)`` or a value
-           fetch — e.g. ``float(net.score_)`` — or attach
-           :class:`deeplearning4j_tpu.utils.profiling.StepTimerListener`,
-           which does this for you."""
-        if labels is not None:
-            data = DataSet(np.asarray(data), np.asarray(labels))
-        if isinstance(data, DataSet):
-            data = ListDataSetIterator([data])
-        if self.conf.pretrain and not getattr(self, "_pretrained", False):
-            self.pretrain(data)
-            self._pretrained = True
-        # multi-worker prefetch + device-put-ahead (datasets/prefetch.py):
-        # batch k+1 is transferred while step k computes, so etl_ms
-        # measures a queue pop. DL4J_TPU_PREFETCH_WORKERS=0 restores the
-        # fully synchronous path.
-        it, own_pipeline = wrap_for_training(
-            data, cache_device=self.gc.cache_mode == CacheMode.DEVICE)
-        # a new fit() supersedes a previous health halt — without this, one
-        # halt would silently truncate every later fit to a single batch
-        self.halt_requested = False
-        _mon.get_health().clear_halt()
-        done = self._completions = _mon.StepCompletions(self)
-        try:
-            for epoch in range(epochs):
-                for lst in self.listeners:
-                    lst.on_epoch_start(self, self.epoch_count)
-                with _mon.get_tracer().span("epoch", cat="train",
-                                            epoch=self.epoch_count):
-                    for ds, waited in _mon.spanned(it, "fit/next_batch"):
-                        self._fit_batch(ds, etl_ms=waited * 1e3)
-                        if self.halt_requested:
-                            break
-                    done.drain()
-                for lst in self.listeners:
-                    lst.on_epoch_end(self, self.epoch_count)
-                self.epoch_count += 1
-                if self.halt_requested:
-                    log.warning("fit halted at epoch %d (halt_requested; see "
-                                "TrainingHealthListener)", self.epoch_count)
-                    break
-        except BaseException as e:
-            # error seam: listeners holding process-global resources (an
-            # active ProfilerListener trace window) must release them
-            # before the exception unwinds out of fit
-            from ..optimize.listeners import dispatch_training_error
-            dispatch_training_error(self, self.listeners, e)
-            # the steps dispatched before the failure still count; a fetch
-            # that fails in turn must not hide ``e``
-            with contextlib.suppress(Exception):
-                done.drain()
-            raise
-        finally:
-            if own_pipeline:
-                it.shutdown()   # no prefetch worker outlives its fit
-        return self
-
-    def _fit_batch(self, ds: DataSet, single_iteration=False, etl_ms=None):
-        """One minibatch. ``single_iteration=True`` applies exactly ONE
-        optimizer update even when ``iterations(n)`` scans are configured —
-        the ParallelWrapper tail-batch fallback needs update-count parity
-        with its sharded dispatches (masks and TBPTT routing preserved).
-        ``etl_ms``: what ``fit`` waited for ``ds`` (``fit/next_batch``)."""
-        with _mon.get_tracer().span("fit/prepare", cat="train"):
-            if self.gc.cache_mode == CacheMode.DEVICE:
-                f, l, fm, lm = ds.device_arrays()
-            else:
-                f = jnp.asarray(ds.features)
-                l = jnp.asarray(ds.labels)
-                fm = (None if ds.features_mask is None
-                      else jnp.asarray(ds.features_mask))
-                lm = (None if ds.labels_mask is None
-                      else jnp.asarray(ds.labels_mask))
-            self.last_batch_size = int(f.shape[0])
-            tbptt = (self.conf.backprop_type == BackpropType.TruncatedBPTT
-                     and f.ndim == 3
-                     and f.shape[1] > self.conf.tbptt_fwd_length)
-            if not tbptt:
-                step = self._ensure_step(single_iteration=single_iteration)
-                it = jnp.asarray(self.iteration_count, jnp.int32)
-                rng = self._next_rng()
-        if tbptt:
-            self._fit_tbptt(f, l, fm, lm, single_iteration=single_iteration)
-            return
-        # dispatch only: a span is host time, the fetch is StepCompletions'
-        with _mon.step_span(self.iteration_count):
-            self.params, self.states, self.updater_state, loss = step(
-                self.params, self.states, self.updater_state, it, rng,
-                f, l, fm, lm)
-        self.score_ = loss
-        self.iteration_count += (1 if single_iteration
-                                 else _n_iterations(self.gc))
-        self._completions.dispatched(loss, self.last_batch_size, etl_ms)
-
-    def _fit_tbptt(self, f, l, fm, lm, single_iteration=False):
-        """Truncated BPTT (reference ``doTruncatedBPTT``): split time into
-        chunks of tbptt_fwd_length, carry RNN state (detached) across chunks.
-        Like the reference's practical behavior, the backward truncation equals
-        the forward chunk length; a differing ``tbptt_back_length`` is treated
-        as ``tbptt_fwd_length`` (warned once)."""
-        if (self.conf.tbptt_back_length != self.conf.tbptt_fwd_length
-                and not getattr(self, "_warned_tbptt", False)):
-            log.warning("tbptt_back_length=%d differs from tbptt_fwd_length=%d; "
-                        "backprop truncation uses the forward chunk length",
-                        self.conf.tbptt_back_length, self.conf.tbptt_fwd_length)
-            self._warned_tbptt = True
-        _run_tbptt(self, f, l, fm, lm, single_iteration)
-
-    def _init_rnn_state(self, batch):
-        state = {}
-        for i, impl in enumerate(self.impls):
-            if hasattr(impl, "init_stream_state"):
-                state[i] = impl.init_stream_state(batch)
-        return state
 
     # -------------------------------------------------------------- pretrain
     def pretrain(self, iterator, epochs=1):
@@ -821,50 +404,6 @@ class MultiLayerNetwork:
 
     rnnClearPreviousState = rnn_clear_previous_state
 
-    # ----------------------------------------------------------------- score
-    def score(self, ds: Optional[DataSet] = None, training=False):
-        """Loss (+reg) on a dataset (reference ``score(DataSet)``), or last
-        training score when called without arguments."""
-        if ds is None:
-            return float(self.score_)
-        f = jnp.asarray(ds.features)
-        l = jnp.asarray(ds.labels)
-        fm = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
-        lm = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
-        key = (bool(training), fm is not None, lm is not None)
-        if not hasattr(self, "_jit_score"):
-            self._jit_score = {}
-        if key not in self._jit_score:
-            # jitted: early stopping / evaluative listeners call this every
-            # epoch over the full validation set — eager tracing per batch
-            # would make evaluation the epoch bottleneck on TPU
-            def score_fn(params, states, f, l, fm, lm):
-                f2 = self._adapt_input(f)
-                loss, _ = self._loss_fn(params, states, f2, l, fm, lm,
-                                        training, None)
-                return loss
-            self._jit_score[key] = monitored_jit(score_fn,
-                                                 name="mln/score")
-        loss = self._jit_score[key](self.params, self.states, f, l, fm, lm)
-        return float(loss)
-
-    def compute_gradient_and_score(self, ds: DataSet):
-        """Reference ``computeGradientAndScore`` :2206 — returns (grads, score)
-        without updating params (used by gradient checks and external
-        optimizers)."""
-        f = self._adapt_input(jnp.asarray(ds.features))
-        l = jnp.asarray(ds.labels)
-        fm = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
-        lm = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
-
-        def loss_fn(p):
-            loss, _ = self._loss_fn(p, self.states, f, l, fm, lm, True, None)
-            return loss
-
-        loss, grads = jax.value_and_grad(loss_fn)(self.params)
-        self.score_ = loss
-        return grads, float(loss)
-
     # ------------------------------------------------------------ evaluation
     def evaluate(self, iterator):
         from ..eval.evaluation import Evaluation
@@ -899,11 +438,6 @@ class MultiLayerNetwork:
         i, k = key.split("_", 1)
         return self.params[i][k]
 
-    def num_params(self) -> int:
-        return sum(int(v.size) for v in jax.tree_util.tree_leaves(self.params))
-
-    numParams = num_params
-
     def params_flat(self) -> np.ndarray:
         """Single flattened param vector, layer-major (reference's flattened
         params buffer ``MultiLayerNetwork.java:110``)."""
@@ -929,16 +463,6 @@ class MultiLayerNetwork:
         if pos != vec.size:
             raise ValueError(f"Param vector length {vec.size} != model {pos}")
         self.params = new
-
-    def set_listeners(self, *listeners):
-        self.listeners = list(listeners)
-        return self
-
-    setListeners = set_listeners
-
-    def add_listeners(self, *listeners):
-        self.listeners.extend(listeners)
-        return self
 
     # ------------------------------------------------------------------ misc
     def clone(self):

@@ -253,7 +253,7 @@ def sweep():
 
 def lower_tbptt_batch(net, ds):
     """Lower the ONE program ``fit`` runs for a TBPTT batch whose length is
-    a multiple of the segment (``nn/multilayer.py`` ``_run_tbptt``: the
+    a multiple of the segment (``nn/training.py`` ``_fit_tbptt``: the
     scan over stacked segments). Shapes only — nothing runs."""
     f, l = ds.features, ds.labels
     b, L = f.shape[0], net.conf.tbptt_fwd_length

@@ -688,9 +688,11 @@ class TestBucketingClosesJitSignatures:
         /profile wait block reports HONEST p50/p95 — 99 sub-100µs pops
         plus one 150 ms stall must yield a sub-millisecond median, not
         the one stall the old ms-geometry buckets degenerated to."""
-        from deeplearning4j_tpu.monitor import get_registry
         from deeplearning4j_tpu.monitor.jitwatch import _pipeline_block
-        reg = get_registry()
+        from deeplearning4j_tpu.monitor.registry import MetricsRegistry
+        # a registry of its own: the process's holds every wait an earlier
+        # fit of this file observed, and one over 150 ms would be the max
+        reg = MetricsRegistry()
         h = reg.histogram("input_wait_seconds", unit="s")
         for _ in range(99):
             h.observe(50e-6)

@@ -260,127 +260,3 @@ class TestDropout:
         o1 = np.asarray(net.output(x))
         o2 = np.asarray(net.output(x))
         assert np.allclose(o1, o2)
-
-
-def test_iterations_config_scanned_equals_sequential():
-    """0.9.x ``Builder.iterations(n)``: n optimizer steps per minibatch,
-    compiled as ONE lax.scan program — must match n sequential fits exactly
-    (dropout-free net, same seed)."""
-    import jax
-    import numpy as np
-    from deeplearning4j_tpu import (NeuralNetConfiguration,
-                                    MultiLayerNetwork, DataSet, Sgd)
-    from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
-
-    def build(n_iter):
-        conf = (NeuralNetConfiguration.builder().seed(5)
-                .updater(Sgd(learning_rate=0.1)).activation("tanh")
-                .iterations(n_iter)
-                .list()
-                .layer(DenseLayer(n_in=4, n_out=6))
-                .layer(OutputLayer(n_in=6, n_out=3, activation="softmax",
-                                   loss="mcxent"))
-                .build())
-        return MultiLayerNetwork(conf).init()
-
-    rng = np.random.default_rng(0)
-    ds = DataSet(rng.normal(size=(8, 4)).astype(np.float32),
-                 np.eye(3, dtype=np.float32)[rng.integers(0, 3, 8)])
-    net_scan = build(3)
-    net_seq = build(1)
-    net_scan.fit(ds)
-    for _ in range(3):
-        net_seq.fit(ds)
-    assert net_scan.iteration_count == 3 == net_seq.iteration_count
-    for a, b in zip(jax.tree_util.tree_leaves(net_scan.params),
-                    jax.tree_util.tree_leaves(net_seq.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-7)
-
-
-def test_iterations_config_tbptt_scanned():
-    """iterations(n) on the TBPTT path: n optimizer steps per segment inside
-    one scanned program, equal to the sequential-iteration semantics."""
-    import numpy as np
-    from deeplearning4j_tpu import (NeuralNetConfiguration,
-                                    MultiLayerNetwork, DataSet, Sgd)
-    from deeplearning4j_tpu.nn.conf import BackpropType
-    from deeplearning4j_tpu.nn.conf.layers import SimpleRnn, RnnOutputLayer
-
-    def build(n_iter):
-        conf = (NeuralNetConfiguration.builder().seed(4)
-                .updater(Sgd(learning_rate=0.05)).activation("tanh")
-                .iterations(n_iter)
-                .list()
-                .layer(SimpleRnn(n_in=3, n_out=5))
-                .layer(RnnOutputLayer(n_in=5, n_out=3, activation="softmax",
-                                      loss="mcxent"))
-                .build())
-        conf.backprop_type = BackpropType.TruncatedBPTT
-        conf.tbptt_fwd_length = conf.tbptt_back_length = 4
-        return MultiLayerNetwork(conf).init()
-
-    rng = np.random.default_rng(1)
-    f = rng.normal(size=(2, 8, 3)).astype(np.float32)
-    l = np.eye(3, dtype=np.float32)[rng.integers(0, 3, (2, 8))].astype(
-        np.float32)
-    ds = DataSet(f, l)
-    net = build(2)
-    net.fit(ds)
-    # 2 segments x 2 iterations
-    assert net.iteration_count == 4
-    assert np.isfinite(float(net.score_))
-
-
-def test_tbptt_fused_scan_matches_per_segment_loop():
-    """The fused lax.scan TBPTT path (one dispatch per batch) must produce
-    the same params as dispatching each segment separately (round-4 LSTM
-    dispatch-latency lever; math identical, only the launch granularity
-    changes)."""
-    import jax
-    import jax.numpy as jnp
-    from deeplearning4j_tpu import (NeuralNetConfiguration, MultiLayerNetwork,
-                                    DataSet, Sgd)
-    from deeplearning4j_tpu.nn.conf.layers import LSTM, RnnOutputLayer
-    from deeplearning4j_tpu.nn.conf import BackpropType
-
-    def make():
-        conf = (NeuralNetConfiguration.builder().seed(41)
-                .updater(Sgd(learning_rate=1e-2)).list()
-                .backprop_type(BackpropType.TruncatedBPTT)
-                .t_bptt_forward_length(4).t_bptt_backward_length(4)
-                .layer(LSTM(n_in=3, n_out=8, activation="tanh"))
-                .layer(RnnOutputLayer(n_in=8, n_out=2, activation="softmax",
-                                      loss="mcxent"))
-                .build())
-        return MultiLayerNetwork(conf).init()
-
-    rng = np.random.default_rng(43)
-    T = 12  # 3 equal segments -> fused path
-    f = rng.normal(size=(6, T, 3)).astype(np.float32)
-    l = np.eye(2, dtype=np.float32)[rng.integers(0, 2, (6, T))].astype(
-        np.float32)
-    m = (np.arange(T)[None, :] < rng.integers(6, T + 1, (6, 1))).astype(
-        np.float32)
-
-    fused = make()
-    fused._fit_batch(DataSet(f, l, features_mask=m, labels_mask=m))
-    assert fused.iteration_count == 3
-
-    manual = make()
-    step = manual._ensure_tbptt_step()
-    rnn = manual._init_rnn_state(6)
-    fj, lj, mj = jnp.asarray(f), jnp.asarray(l), jnp.asarray(m)
-    for s in range(3):
-        sl = slice(4 * s, 4 * (s + 1))
-        (manual.params, manual.states, manual.updater_state, loss,
-         rnn) = step(manual.params, manual.states, manual.updater_state,
-                     jnp.asarray(s, jnp.int32), manual._next_rng(),
-                     fj[:, sl], lj[:, sl], mj[:, sl], mj[:, sl], rnn)
-
-    for k in manual.params:
-        for p in manual.params[k]:
-            np.testing.assert_allclose(np.asarray(fused.params[k][p]),
-                                       np.asarray(manual.params[k][p]),
-                                       rtol=1e-5, atol=1e-6,
-                                       err_msg=f"{k}/{p}")

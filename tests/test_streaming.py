@@ -162,17 +162,35 @@ def test_consumer_timeout_raises_not_silent():
         broker.close()
 
 
+def _broker_has(broker, what, topic, n, timeout=10.0):
+    """Wait until the broker has registered ``n`` subscribers (``_subs``) or
+    publishers (``_pubs``) of ``topic``: a hello frame is handled on a
+    thread of the broker's, some time after the client's constructor
+    returns."""
+    deadline = time.monotonic() + timeout
+    while True:
+        with broker._lock:
+            seen = getattr(broker, what).get(topic, 0)
+        if (seen if isinstance(seen, int) else len(seen)) == n:
+            return
+        assert time.monotonic() < deadline, f"{what}[{topic!r}] is {seen!r}"
+        time.sleep(0.002)
+
+
 def test_eos_waits_for_last_publisher():
     """EOS must not end the topic while another publisher still feeds it."""
     broker = StreamingBroker()
     try:
         consumer = NDArrayConsumer(broker.address, "multi", timeout=10.0)
-        time.sleep(0.05)
+        _broker_has(broker, "_subs", "multi", 1)
         p1 = NDArrayPublisher(broker.address, "multi")
         p2 = NDArrayPublisher(broker.address, "multi")
-        time.sleep(0.05)
+        _broker_has(broker, "_pubs", "multi", 2)
         p1.publish(np.ones((1,), np.float32))
         p1.close()                      # EOS from p1 — p2 still open
+        # each publisher has a thread of its own in the broker: p2 speaks
+        # only once p1's EOS has been handled, so the order is the test's
+        _broker_has(broker, "_pubs", "multi", 1)
         p2.publish(np.full((1,), 2, np.float32))
         p2.close()                      # LAST publisher → EOS forwarded
         got = []
