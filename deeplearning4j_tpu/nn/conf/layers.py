@@ -712,7 +712,15 @@ class LoopLMOutputLayer(RnnOutputLayer):
     ``p_t = lambda_t * prod_{j<t}(1 - lambda_j)`` with the last pass taking
     the remainder, and the loss ``sum_t p_t * xent_t - entropy_weight *
     H(p)``, reduced as ``sparse_mcxent`` reduces. Labels are integer ids
-    [b, T]. Inference returns the last pass's softmax."""
+    [b, T]. Inference returns the last pass's softmax.
+
+    One pass's float32 logits [b, T, n_out] are alive at a time, and each is
+    formed once a step: the weights ``p_t`` are computed first, from the
+    states, and the cross-entropy's gradient is built in the forward sweep
+    from the live logits (its own differentiation rule,
+    ``nn.layers.output.weighted_xent``). Kept for the backward sweep: the
+    states' gradient [R, b, T, n_in], the head's [n_in, n_out] and the
+    per-token cross-entropies."""
     loss: str = "sparse_mcxent"
     activation: Optional[str] = "softmax"
     has_bias: bool = False

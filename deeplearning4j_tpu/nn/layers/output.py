@@ -71,14 +71,84 @@ def exit_distribution(gate_logits):
     return jnp.exp(log_p), log_p
 
 
+def _xent(z, labels):
+    """``(logsumexp, logsumexp - picked)`` of the logits ``z`` [..., V]."""
+    lse = jax.nn.logsumexp(z, axis=-1)
+    picked = jnp.take_along_axis(z, labels[..., None], axis=-1)[..., 0]
+    return lse, lse - picked
+
+
+def weighted_xent(impl, head, x, labels, w):
+    """``sum(w * xent)``: the next-token cross-entropy of the states ``x``
+    [R, b, T, d] under ``head`` (``W`` and, where the layer has one, ``b``;
+    the logits as ``impl._logits`` forms them), each token's weighed by ``w``
+    [R, b, T]. A ``lax.scan`` over the passes: one pass's logits are alive at
+    a time.
+
+    Differentiated by a rule of its own, not by AD. The cross-entropy's
+    gradient with respect to the logits, ``w * (softmax - onehot)``, is known
+    the moment the logits and their ``logsumexp`` are, and the total is linear
+    in the one scalar that arrives from above. So the rule's forward sweep
+    forms a pass's logits once and builds the head's and the states'
+    gradients from them while they are alive (the two transposed products AD
+    would run, on AD's operands; the head's summed over the passes in the
+    scan's carry); its residuals are those gradients and ``xent``, and the
+    backward sweep scales them: no product with a vocabulary axis is left
+    there, and none is run twice."""
+    with jax.named_scope("head"):
+        _, xent = jax.lax.scan(
+            lambda _, h: (None, _xent(impl._logits(head, h), labels)[1]),
+            None, x)
+        return jnp.sum(w * xent)
+
+
+def _weighted_xent_fwd(impl, head, x, labels, w):
+    def one_pass(dhead, h_w):
+        h, w_r = h_w
+        z, transposed = jax.vjp(impl._logits, head, h)
+        lse, xent = _xent(z, labels)
+        dz = w_r[..., None] * (jnp.exp(z - lse[..., None]) - jax.nn.one_hot(
+            labels, z.shape[-1], dtype=z.dtype))
+        dhead_r, dh = transposed(dz.astype(z.dtype))
+        return jax.tree_util.tree_map(jnp.add, dhead, dhead_r), (xent, dh)
+
+    # imported here, not at the top: every network's step carries this
+    # file's line numbers (``_OutputBase``) in its op metadata, which the
+    # compile cache's key holds, and a moved line compiles them all again
+    from ...monitor import get_registry
+    get_registry().gauge(
+        "looped_head_logits_per_pass",
+        "Times a pass's [tokens, vocabulary] logits are formed in one "
+        "differentiated step of a looped output layer, set when its rule's "
+        "forward sweep is traced", layer=str(getattr(impl, "index", ""))
+    ).set(1)        # here, and not again in the backward sweep
+    with jax.named_scope("head"):
+        dhead, (xent, dx) = jax.lax.scan(
+            one_pass, jax.tree_util.tree_map(jnp.zeros_like, head), (x, w))
+        return jnp.sum(w * xent), (dhead, dx, xent)
+
+
+def _weighted_xent_bwd(impl, residuals, g):
+    dhead, dx, xent = residuals
+    with jax.named_scope("head"):
+        dhead, dx = jax.tree_util.tree_map(
+            lambda t: (g * t).astype(t.dtype), (dhead, dx))
+        return dhead, dx, None, g * xent      # labels are integers
+
+
+weighted_xent = jax.custom_vjp(weighted_xent, nondiff_argnums=(0,))
+weighted_xent.defvjp(_weighted_xent_fwd, _weighted_xent_bwd)
+
+
 @implements("LoopLMOutputLayer")
 class LoopLMOutputImpl(_OutputBase):
     """Head and exit gate over the [R, b, T, n_in] states of a looped stack
     (see the config class). The logits and the softmax statistics are float32
     whatever the compute dtype (the gemm's operands take the compute dtype,
-    its accumulator is the output); one pass's logits are alive at a time:
-    the per-pass head and cross-entropy run as the checkpointed body of a
-    ``lax.scan`` over the passes."""
+    its accumulator is the output). One pass's logits are alive at a time, in
+    the forward sweep only: the exit gate's weights are computed first, from
+    the states, and :func:`weighted_xent` builds the head's gradients there,
+    from the live logits, under its own differentiation rule."""
 
     def __init__(self, conf, gc, input_type=None):
         super().__init__(conf, gc, input_type)
@@ -114,26 +184,20 @@ class LoopLMOutputImpl(_OutputBase):
     def loss_on(self, params, state, x, labels, mask=None, train=True, rng=None):
         x = self.maybe_dropout(x, train, rng)
         sd = acc_dtype(self.compute_dtype)
-        labels = labels.astype(jnp.int32)
-
-        @jax.checkpoint
-        def pass_xent(h):
-            z = self._logits(params, h)
-            picked = jnp.take_along_axis(z, labels[..., None], axis=-1)
-            return jax.nn.logsumexp(z, axis=-1) - picked[..., 0]
-
-        # the scope is around the scan, so that the backward scan's own ops
-        # (the head gradient's accumulation over the passes) carry it too
-        with jax.named_scope("head"):
-            _, xent = jax.lax.scan(lambda _, h: (None, pass_xent(h)), None, x)
         with jax.named_scope("exit_gate"):
             gate = (jnp.einsum("rbtd,d->rbt", x.astype(sd),
                                params["gate_W"].astype(sd))
                     + params["gate_b"].astype(sd))
             p, log_p = exit_distribution(gate)
-            per_token = jnp.sum(p * xent, axis=0) \
-                + self.conf.entropy_weight * jnp.sum(p * log_p, axis=0)
-        return _reduce(per_token[..., None], mask)
+            # _reduce is linear in the per-token loss: transposed, it gives
+            # the weight a token's loss carries into the mean
+            share, = jax.linear_transpose(
+                lambda per_token: _reduce(per_token[..., None], mask),
+                jax.ShapeDtypeStruct(p.shape[1:], sd))(jnp.ones((), sd))
+            w = p * share
+        head = {k: params[k] for k in ("W", "b") if k in params}
+        return weighted_xent(self, head, x, labels.astype(jnp.int32), w) \
+            + self.conf.entropy_weight * jnp.sum(w * log_p)
 
 
 @implements("LossLayer")

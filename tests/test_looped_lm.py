@@ -28,7 +28,7 @@ from deeplearning4j_tpu.nn.layers import impl_for
 from deeplearning4j_tpu.nn.layers.attention import mha, rope
 from deeplearning4j_tpu.nn.layers.looped import ATTN_KEYS, FFN_KEYS
 from deeplearning4j_tpu.nn.layers.output import exit_distribution
-from deeplearning4j_tpu.nn.losses import get_loss
+from deeplearning4j_tpu.nn.losses import _reduce, get_loss
 from deeplearning4j_tpu.ops import flash_attention as fa
 
 V, D, F, HEADS, HEAD_DIM, L, T = 40, 32, 48, 4, 8, 2, 12
@@ -261,7 +261,7 @@ def test_block_checkpoint_changes_no_number(monkeypatch):
         p, on.states, [jnp.asarray(ds.features)], [jnp.asarray(ds.labels)],
         None, None, True, None)[0])(on.params))
     assert "checkpoint" in text or "remat" in text
-    # and without it (the stack's and the head's) every number is the same
+    # and without it every number is the same
     monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: f)
     off = shaken(ComputationGraph(looped_conf(2)).init())
     text = str(jax.make_jaxpr(lambda p: off._loss_fn(
@@ -377,6 +377,10 @@ def test_trains_through_fit_and_round_trips_through_json():
     gauge = get_registry().snapshot()["looped_block_applications"]
     assert [r["value"] for r in gauge if r["labels"] == {"network": "cg"}] \
         == [2 * L]
+    # the head's rule forms a pass's logits once in a differentiated step
+    gauge = get_registry().snapshot()["looped_head_logits_per_pass"]
+    assert [r["value"] for r in gauge if r["labels"] == {"layer": "out"}] \
+        == [1]
 
 
 def test_the_looped_output_layer_takes_no_other_loss():
@@ -396,6 +400,94 @@ def test_a_sequence_mask_reaches_the_looped_loss():
     # causal: the first half's loss does not see the second half
     assert net.score(masked, training=True) == pytest.approx(
         net.score(half, training=True), rel=1e-5)
+
+
+# ------------------------------------------------- the head's own rule
+def _head(passes, bias, seed=9):
+    """A looped output layer alone, shaken, with states and labels for it."""
+    rng = np.random.default_rng(seed)
+    impl = impl_for(LoopLMOutputLayer(n_in=D, n_out=V, has_bias=bias,
+                                      entropy_weight=0.05),
+                    looped_conf(passes).global_conf)
+    params, _ = impl.init(jax.random.PRNGKey(seed))
+    params = jax.tree_util.tree_map(
+        lambda a: a + 0.3 * jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+        params)
+    x = jnp.asarray(rng.standard_normal((passes, 2, T, D)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, V, (2, T)), jnp.int32)
+    return impl, params, x, labels
+
+
+def plain_looped_loss(params, x, labels, mask, beta=0.05):
+    """The layer's loss written out for plain AD: every pass's logits at
+    once, no scan, no rule."""
+    z = jnp.einsum("rbtd,dv->rbtv", x, params["W"]) + params.get("b", 0.0)
+    xent = jax.nn.logsumexp(z, axis=-1) - jnp.take_along_axis(
+        z, jnp.broadcast_to(labels, z.shape[:-1])[..., None], axis=-1)[..., 0]
+    p, log_p = exit_distribution(
+        jnp.einsum("rbtd,d->rbt", x, params["gate_W"]) + params["gate_b"])
+    per_token = jnp.sum(p * xent, axis=0) + beta * jnp.sum(p * log_p, axis=0)
+    return _reduce(per_token[..., None], mask)
+
+
+def _vocabulary_products(jaxpr):
+    """``dot_general``s with a vocabulary axis among their operands' or
+    their result's, in ``jaxpr`` and every jaxpr below it (a scan's body
+    counts once: per pass)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and any(
+                V in v.aval.shape for v in eqn.invars + eqn.outvars):
+            n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _vocabulary_products(sub)
+    return n
+
+
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
+@pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
+@pytest.mark.parametrize("passes", [1, 4])
+def test_the_heads_rule_gives_what_plain_ad_gives(passes, masked, bias):
+    impl, params, x, labels = _head(passes, bias)
+    mask = None
+    if masked:
+        mask = jnp.asarray(np.random.default_rng(1).random((2, T)) < 0.6,
+                           jnp.float32)
+    assert ("b" in params) == bias
+    loss, grads = jax.value_and_grad(
+        lambda p, x: impl.loss_on(p, {}, x, labels, mask=mask), (0, 1))(
+            params, x)
+    want, want_grads = jax.value_and_grad(
+        lambda p, x: plain_looped_loss(p, x, labels, mask), (0, 1))(params, x)
+    assert float(loss) == pytest.approx(float(want), rel=1e-5)
+    if passes == 1:     # the one pass takes all: the gate moves nothing
+        for g in (grads, want_grads):
+            assert not np.any(g[0].pop("gate_W")) \
+                and not np.any(g[0].pop("gate_b"))
+    assert_trees_close(grads, want_grads, 1e-5)
+    # undifferentiated (``score``) it is the same number
+    assert float(impl.loss_on(params, {}, x, labels, mask=mask,
+                              train=False)) == pytest.approx(float(want),
+                                                             rel=1e-5)
+
+
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
+def test_a_passs_logits_are_formed_once(bias):
+    impl, params, x, labels = _head(4, bias)
+    loss = lambda p, x: impl.loss_on(p, {}, x, labels)
+    # score: the logits' product and nothing of the gradient
+    assert _vocabulary_products(jax.make_jaxpr(loss)(params, x).jaxpr) == 1
+    # differentiated: the logits' product and the two gradient products,
+    # once each in the one scan over the passes ...
+    both = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, x)
+    assert _vocabulary_products(both.jaxpr) == 3
+    assert "checkpoint" not in str(both) and "remat" not in str(both)
+    # ... all three in the forward sweep: the backward one scales
+    _, backward = jax.vjp(loss, params, x)
+    swept = jax.make_jaxpr(backward)(jnp.float32(1.0))
+    assert _vocabulary_products(swept.jaxpr) == 0
+    assert "dot_general" in str(swept) and "while" not in str(swept) \
+        and "scan" not in str(swept)
 
 
 def test_the_scopes_name_the_ops_of_the_step():
@@ -418,6 +510,14 @@ def test_the_scopes_name_the_ops_of_the_step():
         assert named("transpose(jvp(stack))/", "/blocks/",
                      "/rematted_computation/", sub), sub
     assert named("jvp(stack)/", "/final_norm")
-    for sub in ("head", "exit_gate"):
-        assert named("jvp(loss)/", sub) and named("transpose(jvp(loss))/", sub)
-    assert named("transpose(jvp(loss))/", "head/", "/rematted_computation")
+    assert named("jvp(loss)/", "exit_gate")
+    assert named("transpose(jvp(loss))/", "exit_gate")
+    # the head's rule runs both of its sweeps where the loss runs forward:
+    # the logits' product, and the two gradient products under a
+    # ``transpose(`` of their own, which the scope readers take for backward
+    assert named("jvp(loss)/", "head/", "/jvp()/dot_general")
+    assert named("jvp(loss)/", "head/", "/transpose(jvp())/dot_general")
+    # nothing of a pass is formed again, and where the loss's cotangent is
+    # the constant 1 the compiler drops the backward rule's scalings whole
+    assert not named("head/", "rematted_computation")
+    assert not named("transpose(jvp(loss))/", "head/")
