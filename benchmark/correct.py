@@ -35,39 +35,106 @@ def against_reference(net, reference, sample, compute_dtype):
     the network's own seeded weights) against the plain reference, within
     the reference's stated tolerance for ``compute_dtype``. Where the
     reference states no tolerance for gradients (``None``, with its reason),
-    the loss alone is held to it, through ``score(sample, training=True)``."""
+    the loss alone is held to it, through ``score(sample, training=True)``.
+
+    ``net`` is the check's own and is held at its parameters: its updater
+    state, which neither side reads, is deleted first, and one gradient
+    tree is on the device at a time (the system's goes to the host as
+    float32 before the reference runs). The detail ends with the most live
+    device bytes seen at the points between the computations."""
     import jax
     import jax.numpy as jnp
+
+    from benchmark import device
+
+    devices = list(jax.tree_util.tree_leaves(net.params)[0].devices())
+    # init() sends Adam's zeros from the host: a buffer still on its way
+    # is freed only once it has landed, so wait before counting
+    jax.block_until_ready((net.params, net.updater_state))
+    held = [("built", device.bytes_in_use(devices))]
+    device.delete(net.updater_state)
+    held.append(("updater state deleted", device.bytes_in_use(devices)))
 
     tol = reference.TOLERANCE[str(compute_dtype)]
     with_grads = tol["grads"] is not None
     if with_grads:
         grads, loss = net.compute_gradient_and_score(sample)
+        jax.block_until_ready(grads)
+        held.append(("the system's gradients alive",
+                     device.bytes_in_use(devices)))
+        on_host = jax.tree_util.tree_map(
+            lambda g: np.asarray(g, dtype=np.float32), grads)
+        device.delete(grads)
     else:
         loss = net.score(sample, training=True)
     with jax.default_matmul_precision("highest"):
         fn = jax.value_and_grad(reference.loss) if with_grads \
             else reference.loss
-        out = jax.jit(fn)(net.params, jnp.asarray(sample.features),
-                          jnp.asarray(sample.labels))
+        out = jax.block_until_ready(jax.jit(fn)(
+            net.params, jnp.asarray(sample.features),
+            jnp.asarray(sample.labels)))
     ref_loss = float(out[0] if with_grads else out)
     loss_err = abs(loss - ref_loss) / abs(ref_loss)
     ok = bool(np.isfinite(loss) and loss_err <= tol["loss"])
     detail = (f"loss {loss:.6f} vs reference {ref_loss:.6f} "
               f"(rel {loss_err:.2e}, allowed {tol['loss']:.0e})")
-    if not with_grads:
-        return ok, detail + "; gradients not comparable (see the reference)"
+    if with_grads:
+        held.append(("the reference's gradients alive",
+                     device.bytes_in_use(devices)))
+        grad_err, worst, own = grad_distance(on_host, out[1])
+        ok = ok and grad_err <= tol["grads"]
+        detail += (f"; gradients rel L2 {grad_err:.2e} "
+                   f"(allowed {tol['grads']:.0e})")
+        for path, limit in tol.get("leaves", {}).items():
+            if path not in own:
+                raise SystemExit(f"the reference holds leaf {path} to "
+                                 f"{limit}; the gradients have {list(own)}")
+            ok = ok and own[path] <= limit
+            detail += (f"; leaf {path} rel L2 {own[path]:.2e} "
+                       f"(allowed {limit:.0e})")
+        detail += (f"; worst leaf {worst[1]} rel L2 {worst[0]:.2e} (against "
+                   f"its own or the median leaf's norm; not held)")
+    else:
+        detail += "; gradients not comparable (see the reference)"
+    return bool(ok), detail + "; " + _held(held, net.num_params())
 
-    def sq(t):
-        return sum(float(jnp.sum(jnp.square(x.astype(jnp.float32))))
-                   for x in jax.tree_util.tree_leaves(t))
 
-    diff = jax.tree_util.tree_map(
-        lambda a, b: a.astype(jnp.float32) - b, grads, out[1])
-    grad_err = (sq(diff) / sq(out[1])) ** 0.5
-    return ok and grad_err <= tol["grads"], (
-        f"{detail}; gradients rel L2 {grad_err:.2e} "
-        f"(allowed {tol['grads']:.0e})")
+def grad_distance(grads, ref_grads):
+    """Relative L2 of all gradients, ``|g - r| / |r|`` over the whole tree,
+    summed leaf by leaf on the host: one reference leaf is fetched at a
+    time and no tree of differences is built. ``grads``: the system's
+    gradients as host arrays, a tree of the reference's structure. Also the
+    worst leaf, ``(distance, path)``: its ``|g - r|`` against its ``|r|``
+    or the median leaf's, whichever is larger, since a gradient that is
+    nought to rounding in the reference has no relative error; and every
+    leaf's own ``|g - r| / |r|`` by its path (a reference's ``TOLERANCE``
+    may hold some to limits of their own under ``leaves``)."""
+    import jax
+
+    paths, diff, norm = [], [], []
+
+    def leaf(path, r, g):
+        r = np.asarray(r, dtype=np.float32)
+        paths.append(jax.tree_util.keystr(path))
+        diff.append(float(np.sum(np.square(g - r), dtype=np.float64)))
+        norm.append(float(np.sum(np.square(r), dtype=np.float64)))
+
+    jax.tree_util.tree_map_with_path(leaf, ref_grads, grads)
+    diff, norm = np.asarray(diff), np.asarray(norm)
+    against = np.maximum(norm, np.median(norm))
+    each = np.sqrt(diff / np.where(against > 0, against, 1.0))
+    own = np.sqrt(diff / np.where(norm > 0, norm, 1.0))
+    worst = int(np.argmax(each))
+    return (float((diff.sum() / norm.sum()) ** 0.5),
+            (float(each[worst]), paths[worst]),
+            dict(zip(paths, own.tolist())))
+
+
+def _held(held, params):
+    most = max(b for _, b in held)
+    return (f"held at most {most} bytes on the device, "
+            f"{most / params:.2f} a parameter ("
+            + ", ".join(f"{where} {b}" for where, b in held) + ")")
 
 
 def holds_collective(texts):

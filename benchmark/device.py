@@ -2,6 +2,8 @@
 how a result line names the device, and the peak of its memory."""
 from __future__ import annotations
 
+import math
+
 #: published per-chip peaks, keyed by ``jax.devices()[0].device_kind``
 #: (Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM).
 #: Copied from ``bench.DEVICE_PEAKS``: the yardstick may not import from a
@@ -37,6 +39,35 @@ def memory_peak_bytes(devices):
         peak = max(peak, int(stats.get("peak_bytes_in_use", 0))
                    + int(stats.get("peak_bytes_reserved", 0)))
     return peak
+
+
+def bytes_in_use(devices):
+    """Bytes of live buffers on the fullest device, now: the allocator's
+    ``bytes_in_use``, or the sum over ``jax.live_arrays()`` of the shards
+    each device holds where the backend reports none (the CPU). A sample at
+    one moment, between computations: the allocator's *peak* is the
+    window's and cannot tell what a later check held."""
+    import jax
+
+    stats = [d.memory_stats() or {} for d in devices]
+    if all("bytes_in_use" in s for s in stats):
+        return max(int(s["bytes_in_use"]) for s in stats)
+    held = {d: 0 for d in devices}
+    for array in jax.live_arrays():      # by shape: a shard's .data is an
+        shard = array.sharding.shard_shape(array.shape)   # array of its own
+        for d in array.sharding.device_set & held.keys():
+            held[d] += math.prod(shard) * array.dtype.itemsize
+    return max(held.values())
+
+
+def delete(tree):
+    """Frees the device buffers of every array in ``tree`` now, whoever
+    still refers to them."""
+    import jax
+
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.Array) and not leaf.is_deleted():
+            leaf.delete()
 
 
 def largest_program_bytes(devices):

@@ -9,8 +9,8 @@ score and the value product over the visible half), the head once per pass
 (2·d·V); forward once and backward twice, so three times that. The
 embedding is a gather, the norms, the rotation, the gate (2·d a token and
 pass) and the losses are left out: a floor. Nothing recomputed counts: the
-stack's block checkpoint and the head's add about a forward pass of work
-that no algorithm needs.
+stack's block checkpoint adds about a forward pass of the blocks' work that
+no algorithm needs (the head recomputes nothing since PR 31).
 
 Bytes, a floor no program can undercut: what the step is handed and hands
 back. Every parameter and its two Adam moments (float32) are read once and

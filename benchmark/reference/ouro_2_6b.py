@@ -49,31 +49,46 @@ from jax import lax
 from jax.scipy.special import xlogy
 
 #: by the configuration's compute dtype; ``loss`` relative, ``grads``
-#: ||g - g_ref|| / ||g_ref|| over all parameters together. float32 is the
-#: CPU test's bar. bfloat16 is the chip's, from readings on the v5e at the
-#: cell's sizes (one 4096-token sample, published widths; my chip runs,
-#: PR 28; PERF.md §6 has every one):
-#:  - the system, thirty-six seeds over three sessions: loss 2.7e-7 ..
-#:    2.1e-5 (the logits, the softmax statistics and the loss are float32
-#:    on both sides; what differs is the bf16 operands of the gemms below
-#:    them), gradients 9.7e-3 .. 2.09e-2 and one seed at 2.77e-2 (bf16
-#:    operands in 16 block applications of seven gemms and a flash kernel
-#:    each, and in the head's three gemms);
-#:  - control, the head's logits and softmax statistics in bf16 where the
-#:    configuration says float32, two seeds: gradients 9.9e-2 and 1.15e-1,
-#:    loss 2.8e-5 and 2.95e-3 (a bf16 loss near 44,000 moves in steps of 256,
-#:    so its error is anything up to 3e-3: the loss alone does not catch it);
-#:  - control, the residual stream rounded to bf16 after every block, two
-#:    seeds: loss 1.2e-6 and 1.7e-6, gradients 2.05e-2 and 1.08e-2: NOT told
-#:    apart from the system (the sandwich norms keep the stream's rounding,
-#:    0.4 % a time, under the gemms' own), so this check cannot hold the
-#:    configuration to its float32 stream at 4 blocks x 4 passes.
-#: The gradients' limit lies between the system's largest reading and the
-#: bf16-statistics control's smallest, a factor of 1.6 over the one (two
-#: over all seeds but that one) and 2.2 under the other; the loss's five
-#: times over the system's largest.
+#: ||g - g_ref|| / ||g_ref|| over all parameters together, ``leaves`` the
+#: same of single leaves by their path. float32 is the CPU test's bar.
+#: bfloat16 is the chip's, from readings on the v5e at the cell's sizes (one
+#: 4096-token sample, published widths; PERF.md section 6 has them):
+#:  - the system, about eighty seeds (36 in PR 28, 13 in PR 31, 7 and a
+#:    survey of 16 in PR 32): loss 2.7e-7 .. 2.1e-5 (the logits, the softmax
+#:    statistics and the loss are float32 on both sides; what differs is the
+#:    bf16 operands of the gemms below them); gradients 9.3e-3 .. 2.77e-2
+#:    and one seed (3200111) at 4.96e-2, on the parent's tree too: it failed
+#:    PR 28's limit of 0.045. The ratio's denominator swings: the exit
+#:    gate's share of the gradient is a difference of nearly equal per-pass
+#:    losses, and over 16 seeds the reference's norm of ``gate_W``'s
+#:    gradient reads 3.9e2 .. 2.0e3, of the embedding's 5.6e3 .. 1.6e4,
+#:    the distance largest on the seed where the gate's is smallest. The
+#:    head's own leaf is steady: its norm 2.25e3 .. 2.58e3, its distance
+#:    1.05e-2 .. 1.51e-2;
+#:  - control, the reference in the program's place with every product's
+#:    operands rounded to float8 (e4m3's three mantissa bits), the precision
+#:    below the bf16 the configuration states
+#:    (``tests/benchmark/test_benchmark_ouro.py`` has it), four seeds, the
+#:    system's quietest and loudest among them: gradients 1.36e-1, 1.73e-1,
+#:    2.95e-1, 3.07e-1; the head's leaf 1.60e-1 .. 1.97e-1;
+#:  - fault, half of the tokens left out of the system's loss (the sum over
+#:    the rest, and twice it, the mean), the same four seeds: gradients
+#:    5.6e-1 .. 7.8e-1 and 5.4e-1 .. 1.13; the head's leaf 6.9e-1 .. 1.0;
+#:  - NOT held: the head's logits and softmax statistics in bf16 where the
+#:    configuration says float32 (PR 28's control, which set 0.045 from two
+#:    seeds at 9.9e-2 and 1.15e-1): over 16 seeds it reads 1.44e-2 ..
+#:    1.19e-1 in all gradients, under the system's largest on 10 of them,
+#:    and 1.06e-2 .. 1.51e-2 in the head's leaf, the system's own range (the
+#:    head's gemms already take bf16 operands); its loss 3.3e-6 .. 2.0e-4.
+#:    Nor the residual stream rounded to bf16 after every block (two seeds,
+#:    PR 28: gradients 2.05e-2 and 1.08e-2, loss 1.2e-6 and 1.7e-6).
+#: The limits: all gradients 0.09, 1.8 times over the system's largest and
+#: 1.5 under the fp8 control's smallest (6 under the fault's); the head's
+#: leaf 0.05, 3.3 over its largest and 3.2 under the control's smallest;
+#: the loss five times over the system's largest.
 TOLERANCE = {"float32": {"loss": 1e-4, "grads": 1e-4},
-             "bfloat16": {"loss": 1e-4, "grads": 0.045}}
+             "bfloat16": {"loss": 1e-4, "grads": 0.09,
+                          "leaves": {"['out']['W']": 0.05}}}
 
 _HI = lax.Precision.HIGHEST
 #: tokens whose logits are alive at once in the head's cross-entropy

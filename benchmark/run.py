@@ -49,6 +49,7 @@ class Entry:
     def __init__(self, cell, net):
         self.net = net
         self.wrapper = None
+        self.placed = []      # the DataSets train() put on the device itself
         if cell.traffic["entry"] == "parallel_wrapper":
             from deeplearning4j_tpu.parallel import (ParallelWrapper,
                                                      TrainingMode)
@@ -67,6 +68,19 @@ class Entry:
     def barrier(self):
         import jax
         jax.block_until_ready((self.net.params, self.net.score_))
+
+    def release(self):
+        """The run is over: the device arrays it held go, whoever still
+        refers to them: the resident pool's or the wrapper's sharded
+        batches, the network's parameters, layer states, updater state and
+        last loss. The compiled programs stay: the per-layer readers map op
+        names through their text."""
+        from benchmark import device
+        if self.wrapper is not None:
+            self.wrapper.clear_device_cache()
+        net = self.net
+        device.delete(([ds.device_arrays() for ds in self.placed],
+                       net.params, net.states, net.updater_state, net.score_))
 
 
 def train(cell, seed, note):
@@ -87,6 +101,7 @@ def train(cell, seed, note):
     if cell.traffic["feed"] == "resident" and entry.wrapper is None:
         for ds in pool:              # the resident data set goes up once
             ds.device_arrays()
+        entry.placed = pool
     note(f"pool of {len(pool)} batches made"
          f"{' and placed' if cell.traffic['feed'] == 'resident' else ''} in "
          f"{time.perf_counter() - t:.2f}s")
@@ -166,6 +181,7 @@ def one_device(cell, seed, counters, seconds, note):
         cell, chips=1, traffic={**cell.traffic, "entry": "fit"})
     _, entry, feed, losses = train(alone, seed, note)
     win = measure(alone, entry, feed, counters, seconds=seconds)
+    entry.release()              # or its network lingers on device 0
     note(f"one device alone: {win.steps} steps in {win.seconds:.2f}s, "
          f"{win.rate_per_chip:.1f} {cell.config['unit']}/s")
     return losses, win.rate_per_chip
@@ -275,6 +291,13 @@ def run_cell(manifest, root, workload, seed, seconds, trace, rehearse=False,
             sample = cells.make_batches(cell.config, seed + 1, 1,
                                         int(spec["examples"]),
                                         spec.get("seq_len"))[0]
+            # no check below needs the timed network: it leaves the device
+            # before the check's own arrives, so that the check holds one
+            # network at a time (memory_peak is read, the window is over)
+            held = device.bytes_in_use(devices)
+            entry.release()
+            note(f"the timed run's arrays deleted: {held} -> "
+                 f"{device.bytes_in_use(devices)} bytes in use on the device")
             fresh = cells.build_net(cell, seed)   # its own seeded weights
             checks.append(("reference",) + correct.against_reference(
                 fresh, reference, sample, fresh.gc.compute_dtype))

@@ -17,9 +17,18 @@ NAME = re.compile(r"[A-Za-z0-9][A-Za-z0-9_.\-]{0,63}\Z")
 LAYER = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}\Z")
 PERF_MD = open(os.path.join(ROOT, "PERF.md"), encoding="utf-8").read()
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-#: a width may never be listed as reduced
-WIDTH = re.compile(r"hidden|intermediate|latent|state|proj|_dim$|_rank$|head|"
-                   r"expan|experts_per|width")
+#: a width may never be listed as reduced: a hidden, intermediate, latent,
+#: state or projection size, a key that ends in ``_dim`` or ``_rank``, a head
+#: size, an expansion factor, the number of experts per token. A *count*
+#: (layers, heads, routed experts, rows of the vocabulary) may be the chip's
+#: share (``model-configs`` guide, section 4), so ``hidden`` and ``head``
+#: match only as sizes. ``sliding_window`` is covered: the guide lists
+#: "window and state sizes" among the widths that are never cut (a shorter
+#: window is a cheaper attention, not a share of the published one);
+#: ``max_window_layers``, a count of layers, is not.
+WIDTH = re.compile(r"hidden_size|hidden_dim|intermediate|latent|state|proj|"
+                   r"_dim$|_rank$|head_?dim|d_head|head_size|expan|"
+                   r"experts_per|width|sliding_windows?$|window_size")
 
 
 def test_top_level_keys_and_limits():
@@ -53,6 +62,23 @@ def test_configs():
         doc = json.load(open(os.path.join(ROOT, c["file"])))
         assert doc["name"] == c["name"] and doc["reduced"] == c["reduced"]
         assert not [k for k in c["reduced"] if WIDTH.search(k)]
+
+
+@pytest.mark.parametrize("key", [
+    "num_hidden_layers", "n_routed_experts", "vocab_size",
+    "num_attention_heads", "num_key_value_heads", "mamba_n_heads",
+    "max_window_layers"])
+def test_a_count_may_be_reduced(key):
+    assert not WIDTH.search(key)
+
+
+@pytest.mark.parametrize("key", [
+    "hidden_size", "intermediate_size", "moe_intermediate_size",
+    "kv_lora_rank", "mamba_d_state", "mamba_d_head", "num_experts_per_tok",
+    "sliding_window", "sliding_window_size", "head_dim", "mamba_headdim",
+    "mamba_expand", "ffn_hidden_size"])
+def test_a_width_may_not_be_reduced(key):
+    assert WIDTH.search(key)
 
 
 def test_workloads():

@@ -133,10 +133,13 @@ def test_run_cell_rehearsal_of_the_ouro_cell(trace, tmp_path):
 
 def test_bf16_logits_where_the_configuration_says_float32_are_not_correct(
         monkeypatch, tmp_path):
-    """The control that set the gradients' limit on the chip (the head's
-    logits and softmax statistics rounded to bfloat16; PERF.md §6, PR 28),
-    planted in the rehearsal: the same run that is ``correct`` above is not,
-    by the reference check and by it alone."""
+    """PR 28's control (the head's logits and softmax statistics rounded to
+    bfloat16), planted in the rehearsal: the same run that is ``correct``
+    above is not, by the reference check and by it alone. That is float32
+    against float32: on the chip, where the system's own bf16 operands
+    read as much, no limit holds it (16 seeds, PR 32; the readings are in
+    ``reference/ouro_2_6b.py``), and the control the limits stand under is
+    the float8 one below."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.nn.layers.output import LoopLMOutputImpl
 
@@ -153,6 +156,101 @@ def test_bf16_logits_where_the_configuration_says_float32_are_not_correct(
     assert len(failed) == 1 and failed[0].startswith(
         "check reference: FAILED"), notes
     assert result["failed"] == 0         # the state is finite: only wrong
+
+
+def test_half_of_the_tokens_left_out_of_the_loss_is_not_correct(
+        monkeypatch, tmp_path):
+    """The fault read on the chip beside the float8 control (gradients 0.54
+    and more, ``reference/ouro_2_6b.py``), planted under a whole rehearsal:
+    the loss sums over the first half of every sequence only, as if half of
+    the batch had been left out. The run trains, its state is finite, and
+    the reference check alone says that it is wrong."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.layers.output import LoopLMOutputImpl
+
+    sound = LoopLMOutputImpl.loss_on
+
+    def first_half(self, params, state, x, labels, mask=None, **kwargs):
+        keep = jnp.arange(labels.shape[1]) < labels.shape[1] // 2
+        return sound(self, params, state, x, labels,
+                     mask=jnp.broadcast_to(keep, labels.shape).astype(
+                         jnp.float32), **kwargs)
+
+    monkeypatch.setattr(LoopLMOutputImpl, "loss_on", first_half)
+    notes = []
+    result = run.run_cell(MANIFEST, ROOT, CELL, seed=2**31 + 11, seconds=0.2,
+                          trace=False, rehearse=True, note=notes.append,
+                          trace_root=str(tmp_path))
+    assert result["correct"] is False
+    failed = [n for n in notes if n.startswith("check ") and "FAILED" in n]
+    assert len(failed) == 1 and failed[0].startswith(
+        "check reference: FAILED"), notes
+    assert result["failed"] == 0 and result["attempted"] > 0
+
+
+def fp8_operands_reference():
+    """The control that the gradients' limit on the chip stands under (PR
+    32): the plain reference put in the program's place with the operands
+    of every product (the blocks' seven gemms, attention's two, the head's,
+    the gate's) rounded to float8's three mantissa bits (e4m3; the exponent
+    left alone, as a scaled cast would), the precision below the bfloat16
+    the configuration states. Forward operands only: the cotangents pass
+    unrounded, so each backward product has one rounded operand."""
+    import importlib.util
+
+    import jax.numpy as jnp
+    from jax import lax
+
+    def e4m3(x):
+        bits = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+        kept = lax.bitcast_convert_type(
+            (bits + jnp.uint32(0x00080000)) & jnp.uint32(0xFFF00000),
+            jnp.float32)
+        return x + lax.stop_gradient(kept - x)
+
+    class Rounded:
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def dot(a, w, precision=None):
+            return jnp.dot(e4m3(a), e4m3(w), precision=precision)
+
+        @staticmethod
+        def einsum(spec, a, b, precision=None):
+            return jnp.einsum(spec, e4m3(a), e4m3(b), precision=precision)
+
+    spec = importlib.util.find_spec(reference.__name__)
+    control = importlib.util.module_from_spec(spec)    # a second instance
+    spec.loader.exec_module(control)
+    control.jnp = Rounded()
+    return control
+
+
+def test_fp8_operands_where_the_configuration_says_bf16_are_not_correct():
+    """The control at rehearsal size: the reference with float8 operands in
+    the program's place fails the reference check's gradient comparison by
+    the float32 limit and by the chip's (readings on the chip at the
+    cell's size: ``reference/ouro_2_6b.py``)."""
+    import jax
+    import numpy as np
+
+    from benchmark import correct
+    cell = cells.load_cell(MANIFEST, ROOT, CELL, rehearse=True)
+    net = cells.build_net(cell, seed=7)
+    sample, = cells.make_batches(cell.config, 8, 1, 2, 16)
+    args = (net.params, sample.features, sample.labels)
+    ref = jax.grad(reference.loss)(*args)
+    control = jax.grad(fp8_operands_reference().loss)(*args)
+    again = correct.grad_distance(jax.tree_util.tree_map(np.asarray, ref),
+                                  ref)[0]
+    distance, _, own = correct.grad_distance(
+        jax.tree_util.tree_map(np.asarray, control), ref)
+    assert again == 0.0
+    chip = reference.TOLERANCE["bfloat16"]
+    assert distance > chip["grads"] == 0.09
+    assert all(own[path] > limit for path, limit in chip["leaves"].items())
+    assert distance > 100 * reference.TOLERANCE["float32"]["grads"]
 
 
 @pytest.fixture(scope="module")

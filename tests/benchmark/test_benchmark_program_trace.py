@@ -328,7 +328,7 @@ def _rehearsal_manifest():
                 or entry["name"] == "scoped_device_time_share"):
             manifest["per_layer"].append(dict(entry, workloads=[
                 FIXTURE_CELL[w] for w in entry["workloads"]
-                if FIXTURE_CELL[w]]))
+                if FIXTURE_CELL.get(w)]))   # a cell not listed has no twin
     return fixtures, manifest
 
 
