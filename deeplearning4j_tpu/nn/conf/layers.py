@@ -37,7 +37,7 @@ __all__ = [
     "AutoEncoder", "VariationalAutoencoder", "GlobalPoolingLayer",
     "Yolo2OutputLayer", "FrozenLayer", "ConvolutionMode", "SelfAttentionLayer",
     "MoEDenseLayer", "RMSNorm", "GatedDenseLayer", "LoopedBlockStack",
-    "LoopLMOutputLayer",
+    "LoopLMOutputLayer", "Mamba2Layer", "HybridBlockStack",
 ]
 
 
@@ -514,6 +514,9 @@ class EmbeddingSequenceLayer(FeedForwardLayer):
     """Index sequence → vector sequence (added post-0.9 in the reference line;
     included for NLP-model parity)."""
     has_bias: bool = False
+    #: the looked-up vectors are multiplied by it (an embedding multiplier);
+    #: None multiplies nothing
+    scale: Optional[float] = None
 
     def get_output_type(self, index, input_type):
         t = input_type.timeseries_length if isinstance(input_type, InputTypeRecurrent) else None
@@ -640,6 +643,13 @@ class SelfAttentionLayer(BaseRecurrentLayer):
     rope_theta: Optional[float] = None
     #: False drops the output projection's bias (the only bias the layer has)
     has_bias: bool = True
+    #: grouped-query attention: ``Wk`` / ``Wv`` make this many heads and
+    #: query head i reads key-value head ``i // (num_heads // num_kv_heads)``.
+    #: None: as many as ``num_heads``
+    num_kv_heads: Optional[int] = None
+    #: what the scores are multiplied by before the softmax; None is
+    #: ``1 / sqrt(head_dim)``
+    attention_scale: Optional[float] = None
 
 
 @register
@@ -678,6 +688,75 @@ class LoopedBlockStack(BaseRecurrentLayer):
 
 @register
 @dataclasses.dataclass
+class Mamba2Layer(BaseRecurrentLayer):
+    """Selective state-space mixer (Mamba-2; Dao, Gu 2024) over [b, T, n_in],
+    net-new vs the 0.9.x reference. ``num_heads`` heads of ``head_dim``
+    channels (``d_inner = num_heads * head_dim``), a state of ``state_size``
+    per channel, one group (B and C are shared by all heads):
+
+        [z | xBC | dt] = x W_in;  xBC = silu(conv1d(xBC))   depthwise, causal,
+                                                   width ``conv_size``, bias
+        [x | B | C] = xBC;  D_t = softplus(dt + dt_bias);  a_t = exp(D_t * A),
+                                                            A = -exp(A_log)
+        S_t = a_t S_{t-1} + D_t x_t (x) B_t;  y_t = S_t C_t + D x_t
+        out = RMSNorm_gn(y * silu(z)) W_out
+
+    The recurrence is computed in chunks of ``chunk_size`` steps: within a
+    chunk as masked-decay products, between chunks by the carried state (a
+    length that is no multiple of the chunk is padded with steps that leave
+    the state alone). No bias in the two projections."""
+    num_heads: int = 8
+    head_dim: int = 64
+    state_size: int = 128
+    conv_size: int = 4
+    chunk_size: int = 256
+    eps: float = 1e-5
+    activation: Optional[str] = "identity"
+
+    def set_n_in(self, input_type, override=False):
+        super().set_n_in(input_type, override)
+        if self.n_out is None:
+            self.n_out = self.n_in
+
+
+@register
+@dataclasses.dataclass
+class HybridBlockStack(BaseRecurrentLayer):
+    """A stack of pre-normed decoder blocks of two kinds, one per entry of
+    ``layer_types`` (``"mamba"``: a :class:`Mamba2Layer` mixer; ``"attention"``:
+    causal grouped-query attention without positions), each followed by a
+    gated MLP of ``n_hidden`` units:
+
+        u = h + r * Mixer(RMSNorm(h));   h' = u + r * MLP(RMSNorm(u))
+
+    with ``r = residual_multiplier``, and a final RMSNorm after the last
+    block. No biases but the convolution's. Every run of like blocks keeps
+    its weights stacked leaf by leaf ``[n, ...]`` under the keys
+    ``r<run>.<leaf>`` and is one ``lax.scan``; the training step keeps each
+    block's input and recomputes the rest in the backward pass. The stream
+    between blocks is float32 whatever the compute dtype."""
+    layer_types: Optional[List[str]] = None
+    n_hidden: Optional[int] = None
+    eps: float = 1e-5
+    residual_multiplier: float = 1.0
+    num_heads: int = 4
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    attention_scale: Optional[float] = None
+    mamba_heads: int = 8
+    mamba_head_dim: int = 64
+    mamba_state_size: int = 128
+    mamba_conv_size: int = 4
+    mamba_chunk_size: int = 256
+
+    def set_n_in(self, input_type, override=False):
+        super().set_n_in(input_type, override)
+        if self.n_out is None:
+            self.n_out = self.n_in
+
+
+@register
+@dataclasses.dataclass
 class OutputLayer(FeedForwardLayer):
     """Dense + loss (reference ``nn/conf/layers/OutputLayer.java``)."""
     loss: str = "mcxent"
@@ -687,6 +766,15 @@ class OutputLayer(FeedForwardLayer):
 @register
 @dataclasses.dataclass
 class RnnOutputLayer(OutputLayer):
+    #: name of an embedding vertex of the same ``ComputationGraph`` whose
+    #: ``W`` [n_out, n_in] is this layer's head, transposed (tied word
+    #: embeddings): the layer then has no ``W`` of its own, and the one leaf
+    #: is counted, updated, regularised and saved under the embedding's name
+    tied_to: Optional[str] = None
+    #: the logits are divided by it before the loss (a logits scaling); with
+    #: it or the tie the logits are float32 whatever the compute dtype
+    logits_divisor: Optional[float] = None
+
     def get_output_type(self, index, input_type):
         t = input_type.timeseries_length if isinstance(input_type, InputTypeRecurrent) else None
         return InputTypeRecurrent(self.n_out, t)

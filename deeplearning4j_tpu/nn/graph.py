@@ -89,6 +89,8 @@ class ComputationGraph(_TrainingBase):
                 p, s = self.impls[name].init(k)
                 self.params[name] = p
                 self.states[name] = s
+        for name in layer_names:
+            self._check_tie(name)
         layer_updaters = {}
         for name in layer_names:
             u = getattr(conf.vertices[name], "updater", None) or self.gc.updater
@@ -96,6 +98,33 @@ class ComputationGraph(_TrainingBase):
         self.updater = NetworkUpdater(layer_updaters)
         self.updater_state = self.updater.init_state(self.params)
         return self
+
+    # ------------------------------------------------------------ tied leaf
+    def _check_tie(self, name):
+        """A layer tied to another vertex (``RnnOutputLayer.tied_to``) finds
+        there a leaf ``W`` of its own head's shape, transposed."""
+        v = self.conf.vertices[name]
+        src = getattr(v, "tied_to", None)
+        if src is None:
+            return
+        w = self.params.get(src, {}).get("W") if src in self.impls else None
+        if w is None or tuple(w.shape) != (v.n_out, v.n_in):
+            raise ValueError(
+                f"vertex '{name}' is tied to '{src}', which has to be a layer "
+                f"with a leaf W of shape [{v.n_out}, {v.n_in}] (an embedding "
+                f"of the output's classes); found "
+                f"{None if w is None else tuple(w.shape)}")
+
+    def _params_of(self, params, name):
+        """What the layer ``name`` is handed: its own leaves and, where it is
+        tied, the other vertex's ``W`` as ``tied_W``. The leaf lives once, in
+        ``params`` under the other vertex's name, so it is counted, updated,
+        regularised and saved once, and its gradient is the sum over both
+        uses."""
+        src = getattr(self.conf.vertices[name], "tied_to", None)
+        if src is None:
+            return params[name]
+        return {**params[name], "tied_W": params[src]["W"]}
 
     # -------------------------------------------------------------- forward
     def _adapt_inputs(self, inputs):
@@ -144,8 +173,8 @@ class ComputationGraph(_TrainingBase):
                     pre = conf.input_preprocessors.get(name)
                     if pre is not None:
                         x = pre(x, ctx)
-                    p_n = impl.noised_params(params[name], train,
-                                             keys.get(name))
+                    p_n = impl.noised_params(self._params_of(params, name),
+                                             train, keys.get(name))
                     y, ns = impl.forward(p_n, states[name], x, train=train,
                                          rng=keys.get(name), mask=m, ctx=ctx)
                     if impl.save_output:
@@ -192,7 +221,7 @@ class ComputationGraph(_TrainingBase):
             mask = lm if lm is not None else (
                 masks.get(in_name) if x.ndim == 3 or looped else None)
             with jax.named_scope("loss"):
-                total = total + impl.loss_on(params[out_name],
+                total = total + impl.loss_on(self._params_of(params, out_name),
                                              states[out_name], x, lbl,
                                              mask=mask, train=train, rng=rng)
             if hasattr(impl, "update_state"):

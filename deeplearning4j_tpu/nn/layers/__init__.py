@@ -16,4 +16,6 @@ from . import objdetect  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe  # noqa: F401
 from . import looped  # noqa: F401
+from . import mamba  # noqa: F401
+from . import hybrid  # noqa: F401
 from . import wrapper  # noqa: F401

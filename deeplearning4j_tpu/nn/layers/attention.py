@@ -15,10 +15,13 @@ from .base import LayerImpl, implements, acc_dtype, pet_dtype
 
 
 def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
-        key_mask=None):
-    """q,k,v: [b, T, h, d]. Returns [b, T, h, d]. Scaled dot-product attention
-    with f32 softmax accumulation (bf16-safe). ``key_mask``: [b, S] with 1 for
-    real keys, 0 for padding — padded keys are excluded from the softmax.
+        key_mask=None, scale=None):
+    """q: [b, T, h, d], k,v: [b, T, h_kv, d] with ``h_kv`` dividing ``h``
+    (grouped queries: head i reads key-value head ``i // (h // h_kv)``).
+    Returns [b, T, h, d]. Scaled dot-product attention (``scale`` None:
+    ``1 / sqrt(d)``) with f32 softmax accumulation (bf16-safe).
+    ``key_mask``: [b, S] with 1 for real keys, 0 for padding — padded keys
+    are excluded from the softmax.
 
     Long sequences route through the Pallas flash-attention kernel
     (``ops/flash_attention.py``): blockwise online softmax, O(T) memory
@@ -26,12 +29,18 @@ def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
     AND train-time attention dropout included (both run in-kernel; the
     dropout mask is regenerated blockwise from a counter-hash PRNG). The
     dense path below remains the oracle and the fallback (short or
-    non-block-divisible sequences).
+    non-block-divisible sequences) — but not for a long causal call on the
+    TPU, which raises rather than form [b, h, T, T].
+
+    Grouped heads reach the kernels repeated to ``h`` heads (the repeat's
+    transpose sums a group's dk and dv): the kernels' index maps read one
+    head's block per grid row.
     """
     from ...ops import flash_attention as _fa
 
     T, d = q.shape[1], q.shape[-1]
     rate = dropout_rate if (train and rng is not None) else 0.0
+    k, v = _repeat_kv(k, v, q.shape[2])
     if q.shape == k.shape and _fa.supported(T, d, rate, key_mask):
         seed = None
         if rate > 0.0:
@@ -42,8 +51,15 @@ def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
                                       dtype=jnp.int32)
         return _fa.flash_attention(
             q.astype(compute_dtype), k.astype(compute_dtype),
-            v.astype(compute_dtype), causal=causal, key_mask=key_mask,
-            dropout_rate=rate, dropout_seed=seed)
+            v.astype(compute_dtype), causal=causal, scale=scale,
+            key_mask=key_mask, dropout_rate=rate, dropout_seed=seed)
+    if causal and T >= _fa.MIN_SEQ and _fa._on_tpu():
+        raise ValueError(
+            f"mha: a causal call of {T} tokens (head size {d}, keys "
+            f"{k.shape[1]}) does not fit the flash kernels (lengths equal and "
+            f"multiples of {_fa.MIN_BLOCK}, head size <= 256, a [b, T] key "
+            f"mask), and the dense path would form the "
+            f"[{q.shape[0]}, {q.shape[2]}, {T}, {k.shape[1]}] scores")
     visible = None
     if causal:
         T, S = q.shape[1], k.shape[1]
@@ -52,20 +68,35 @@ def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
         km = (key_mask[:, None, None, :] > 0)
         visible = km if visible is None else (visible & km)
     return _dense_attention(q, k, v, visible, compute_dtype,
-                            dropout_rate=dropout_rate, rng=rng, train=train)
+                            dropout_rate=dropout_rate, rng=rng, train=train,
+                            scale=scale)
+
+
+def _repeat_kv(k, v, heads):
+    """``k``, ``v`` [b, S, h_kv, d] with every head repeated so that query
+    head i of ``heads`` finds its key-value head at i; as they are where
+    ``h_kv`` is ``heads``."""
+    group = heads // k.shape[2]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
 
 
 def _dense_attention(q, k, v, visible, compute_dtype, dropout_rate=0.0,
-                     rng=None, train=False):
+                     rng=None, train=False, scale=None):
     """Shared dense scaled-dot-product body (full-sequence AND KV-cache
     streaming paths — one implementation so masking/dropout/numerics cannot
     diverge). ``visible``: broadcastable-to-[b, h, Tq, Tk] bool mask or
-    None."""
+    None. ``k``, ``v`` may hold fewer (grouped) heads than ``q``."""
     d = q.shape[-1]
+    k, v = _repeat_kv(k, v, q.shape[2])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(compute_dtype),
                         k.astype(compute_dtype),
                         preferred_element_type=pet_dtype(compute_dtype))
-    logits = logits / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    if scale is None:
+        logits = logits / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    else:
+        logits = logits * jnp.asarray(scale, jnp.float32)
     if visible is not None:
         logits = jnp.where(visible, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -107,14 +138,23 @@ class SelfAttentionImpl(LayerImpl):
         d = c.head_dim or (c.n_out // h)
         return h, d
 
+    def _kv_heads(self):
+        h = self.conf.num_heads
+        kv = self.conf.num_kv_heads or h
+        if h % kv:
+            raise ValueError(f"SelfAttentionLayer: num_kv_heads={kv} does not "
+                             f"divide num_heads={h}")
+        return kv
+
     def init(self, rng):
         c = self.conf
         h, d = self._dims()
+        kv = self._kv_heads()
         k1, k2, k3, k4 = jax.random.split(rng, 4)
         params = {
             "Wq": self._init_w(k1, (c.n_in, h * d), c.n_in, h * d),
-            "Wk": self._init_w(k2, (c.n_in, h * d), c.n_in, h * d),
-            "Wv": self._init_w(k3, (c.n_in, h * d), c.n_in, h * d),
+            "Wk": self._init_w(k2, (c.n_in, kv * d), c.n_in, kv * d),
+            "Wv": self._init_w(k3, (c.n_in, kv * d), c.n_in, kv * d),
             "Wo": self._init_w(k4, (h * d, c.n_out), h * d, c.n_out),
         }
         if c.has_bias:
@@ -132,7 +172,7 @@ class SelfAttentionImpl(LayerImpl):
         empty/masked — per-example so non-uniform key padding across the
         batch stays exact), and the global token counter."""
         c = self.conf
-        h, d = self._dims()
+        h, d = self._kv_heads(), self._dims()[1]
         L = int(c.stream_max_length)
         cd = self.compute_dtype
         return (jnp.zeros((batch, L, h, d), cd),
@@ -181,7 +221,8 @@ class SelfAttentionImpl(LayerImpl):
             # writes (positions > n + T - 1 - L), matching write-then-attend
             visible = valid & (pos_all[:, None, :] > n + T - 1 - L)
         o = _dense_attention(q, k_all, v_all, visible[:, None], cd,
-                             dropout_rate=dropout_rate, rng=rng, train=train)
+                             dropout_rate=dropout_rate, rng=rng, train=train,
+                             scale=self.conf.attention_scale)
         # now land the chunk's writes (evicting the oldest slots)
         slots = (n + jnp.arange(T)) % L
         k_c = k_c.at[:, slots].set(k.astype(k_c.dtype))
@@ -195,9 +236,10 @@ class SelfAttentionImpl(LayerImpl):
         b, T, _ = x.shape
         x = self.maybe_dropout(x, train, rng)
         cd = self.compute_dtype
+        kv = self._kv_heads()
         q = (x @ params["Wq"].astype(x.dtype)).reshape(b, T, h, d)
-        k = (x @ params["Wk"].astype(x.dtype)).reshape(b, T, h, d)
-        v = (x @ params["Wv"].astype(x.dtype)).reshape(b, T, h, d)
+        k = (x @ params["Wk"].astype(x.dtype)).reshape(b, T, kv, d)
+        v = (x @ params["Wv"].astype(x.dtype)).reshape(b, T, kv, d)
         idx = getattr(self, "index", None)
         carry = (ctx.get("rnn_state_in", {}).get(idx)
                  if ctx is not None and idx is not None else None)
@@ -227,6 +269,11 @@ class SelfAttentionImpl(LayerImpl):
             # path, giving each train step a fresh mask
             from ...parallel.sequence import sp_attend
 
+            if kv != h or c.attention_scale is not None:
+                raise ValueError(
+                    "SelfAttentionLayer: num_kv_heads and attention_scale "
+                    "under a sequence-parallel step are not supported (the "
+                    "ring takes equal heads and 1/sqrt(head_dim))")
             rate = c.dropout_rate if (train and rng is not None) else 0.0
             seed = None
             if rate > 0.0:
@@ -238,7 +285,7 @@ class SelfAttentionImpl(LayerImpl):
                           dropout_seed=seed)
         else:
             o = mha(q, k, v, c.causal, cd, c.dropout_rate, rng, train,
-                    key_mask=mask)
+                    key_mask=mask, scale=c.attention_scale)
         o = o.reshape(b, T, h * d)
         y = o @ params["Wo"].astype(o.dtype)
         if "b" in params:

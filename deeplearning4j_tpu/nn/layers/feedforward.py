@@ -124,6 +124,8 @@ class EmbeddingSequenceImpl(LayerImpl):
             x = x[..., 0]
         idx = x.astype(jnp.int32)
         z = jnp.take(params["W"], idx, axis=0)
+        if getattr(self.conf, "scale", None) is not None:
+            z = z * jnp.asarray(self.conf.scale, z.dtype)
         if "b" in params:
             z = z + params["b"]
         return self.activation(z).astype(self.out_dtype), state

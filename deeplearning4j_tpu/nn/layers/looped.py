@@ -33,6 +33,18 @@ FFN_KEYS = ("Wgate", "Wup", "Wdown")
 GAIN_KEYS = ("g1", "g2", "g3", "g4")
 
 
+def stacked_matrices(impl, subs, names, rng, n):
+    """The matrices ``names`` of ``n`` blocks made of the sub-layers
+    ``subs``, stacked leaf by leaf ``[n, ...]``: one draw per stacked leaf,
+    in ``names``' order, by ``impl``'s weight init; every matrix of a block
+    is [fan_in, fan_out], read off the sub-layers' own ``init``."""
+    block = jax.eval_shape(
+        lambda k: {k_: v for sub in subs for k_, v in sub.init(k)[0].items()},
+        rng)
+    return {k: impl._init_w(key, (n,) + block[k].shape, *block[k].shape)
+            for k, key in zip(names, jax.random.split(rng, len(names)))}
+
+
 @implements("LoopedBlockStack")
 class LoopedBlockStackImpl(LayerImpl):
     def __init__(self, conf, gc, input_type=None):
@@ -53,13 +65,8 @@ class LoopedBlockStackImpl(LayerImpl):
     def init(self, rng):
         c = self.conf
         n = int(c.num_blocks)
-        # one draw per stacked leaf; every matrix of a block is
-        # [fan_in, fan_out], read off the sub-layers' own init
-        block = jax.eval_shape(
-            lambda k: {**self.attn.init(k)[0], **self.ffn.init(k)[0]}, rng)
-        names = ATTN_KEYS + FFN_KEYS
-        params = {k: self._init_w(key, (n,) + block[k].shape, *block[k].shape)
-                  for k, key in zip(names, jax.random.split(rng, len(names)))}
+        params = stacked_matrices(self, (self.attn, self.ffn),
+                                  ATTN_KEYS + FFN_KEYS, rng, n)
         for k in GAIN_KEYS:
             params[k] = host_full((n, c.n_out), 1, self.dtype)
         params["gf"] = host_full((c.n_out,), 1, self.dtype)

@@ -53,8 +53,35 @@ class OutputLayerImpl(_OutputBase):
 @implements("RnnOutputLayer")
 class RnnOutputLayerImpl(_OutputBase):
     """Per-timestep output over [b, T, nIn] (reference ``RnnOutputLayer.java``);
-    loss is mask-aware over [b, T]."""
-    pass
+    loss is mask-aware over [b, T]. With ``tied_to`` the head is another
+    vertex's ``W`` [n_out, n_in], which the graph hands over as ``tied_W``
+    beside the layer's own leaves (a bias at most); with it or with
+    ``logits_divisor`` the gemm's operands take the compute dtype and the
+    logits are its float32 accumulator."""
+
+    def init(self, rng):
+        if self.conf.tied_to is None:
+            return super().init(rng)
+        c = self.conf          # no ``W`` is drawn: the head is the tied leaf
+        return ({"b": self._init_b((c.n_out,))}
+                if getattr(c, "has_bias", True) else {}), {}
+
+    def preout(self, params, x):
+        c = self.conf
+        if c.tied_to is None and c.logits_divisor is None:
+            return super().preout(params, x)
+        cd = self.compute_dtype
+        w, axis = ((params["W"], 0) if c.tied_to is None
+                   else (params["tied_W"], 1))
+        with jax.named_scope("head"):
+            z = jax.lax.dot_general(
+                x.astype(cd), w.astype(cd), (((x.ndim - 1,), (axis,)), ((), ())),
+                preferred_element_type=acc_dtype(cd))
+            if "b" in params:
+                z = z + params["b"].astype(z.dtype)
+            if c.logits_divisor is not None:
+                z = z / jnp.asarray(c.logits_divisor, z.dtype)
+            return z
 
 
 def exit_distribution(gate_logits):
@@ -157,6 +184,9 @@ class LoopLMOutputImpl(_OutputBase):
                 "LoopLMOutputLayer computes the exit-gate-weighted next-token "
                 "cross-entropy over a softmax and no other loss: got loss="
                 f"{conf.loss!r}, activation={self.activation_name!r}")
+        if conf.tied_to is not None or conf.logits_divisor is not None:
+            raise ValueError("LoopLMOutputLayer has a head of its own: "
+                             "tied_to and logits_divisor are RnnOutputLayer's")
 
     def init(self, rng):
         c = self.conf

@@ -62,6 +62,12 @@ class MultiLayerNetwork(_TrainingBase):
         from .conf.layers import FeedForwardLayer, DropoutLayer, LossLayer
         for i, lc in enumerate(layers):
             inner = getattr(lc, "inner", None) or lc
+            if getattr(inner, "tied_to", None) is not None:
+                raise ValueError(
+                    f"Layer {i} ({type(inner).__name__}): tied_to="
+                    f"{inner.tied_to!r} names a vertex, and only a "
+                    f"ComputationGraph has vertices: a MultiLayerNetwork "
+                    f"gives every layer its own parameters")
             if isinstance(inner, (DropoutLayer, LossLayer)):
                 continue  # nIn/nOut not required (pass-through layers)
             if isinstance(inner, FeedForwardLayer):

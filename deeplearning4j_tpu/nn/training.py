@@ -276,6 +276,13 @@ class _TrainingBase:
                 "Block applications per step of the network's looped stacks "
                 "(passes x blocks), set when the step is built",
                 network=self._jit_prefix).set(looped)
+        for _, _, impl in self._layers():
+            for kind, n in getattr(impl, "block_kinds", {}).items():
+                _mon.get_registry().gauge(
+                    "hybrid_blocks",
+                    "Blocks of each kind in the network's hybrid stacks, "
+                    "set when the step is built",
+                    network=self._jit_prefix, kind=kind).set(n)
         return monitored_jit(step, name=f"{self._jit_prefix}/step",
                              donate_argnums=(0, 2))
 

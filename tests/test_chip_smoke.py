@@ -115,6 +115,23 @@ def test_every_kernel_family_lowers_for_tpu_at_bench_shape(monkeypatch):
                          st, st, st, st) == 2
 
 
+def test_grouped_heads_lower_to_the_flash_kernels_at_the_hybrid_cells_shape(
+        monkeypatch):
+    """``mha`` at the granite cell's attention shape (32 query heads over 8
+    key-value heads of 64, T 8192, the configuration's scale), lowered for
+    the TPU from here: the three flash kernels and no dense scores."""
+    from deeplearning4j_tpu.nn.layers.attention import mha
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    sds = jax.ShapeDtypeStruct
+    q = sds((1, 8192, 32, 64), jnp.bfloat16)
+    kv = sds((1, 8192, 8, 64), jnp.bfloat16)
+    attend = lambda q, k, v: mha(q, k, v, True, jnp.bfloat16, scale=0.015625)
+    assert _custom_calls(attend, q, kv, kv) == 3
+    text = jax.jit(attend).trace(q, kv, kv).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "8192x8192" not in text
+
+
 def test_lstm_streams_cross_the_layer_kernel_boundary_narrow_and_time_major(
         monkeypatch):
     """The contract between ``recurrent._BaseLSTMImpl._run`` and

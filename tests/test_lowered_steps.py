@@ -1,0 +1,61 @@
+"""The train steps of the benchmark's configurations, lowered at ``rehearse``
+size on the CPU, against the text they had before the hybrid language model
+came (PR 33): new options of shared layers (``num_kv_heads``,
+``attention_scale``, the embedding's ``scale``, the output layer's ``tied_to``
+and ``logits_divisor``) emit nothing where they are left at their defaults,
+and the tie's lookup in ``ComputationGraph`` adds no op (PR 30's method:
+StableHLO without locations, so a moved line does not count).
+
+A PR that means to change one of these steps replaces its line count and
+digest here, and says so; one that does not and fails here has changed a
+program it did not mean to touch."""
+import hashlib
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark import cells
+
+ROOT = os.path.dirname(cells.HERE)
+#: cell -> (lines, sha256) of its step's lowered text at commit 16227cc
+PARENT = {
+    "resnet50_b256_resident": (
+        11415,
+        "171fe2c5c06046af35f903336e551e011f434ff78c331ad0b902e7300d8c7cf6"),
+    "charrnn_b64_t5000_tbptt50_pool20": (
+        1258,
+        "8b7af6b349c565dcdea9798a6d74f1a5014e6e683580b4439915763419a4bd74"),
+    "ouro_l4_ut4_b2_t4096_resident": (
+        2116,
+        "bc32c2bbb0034d58c85740b9a90e9499dcbc1d435250a3e00541c24639cfa458"),
+}
+
+
+def lowered_step(workload, seed=5):
+    """The text of the cell's raw train step (one truncated-BPTT segment
+    with its carried state where the cell has segments) on its own seeded
+    network and batch."""
+    manifest = cells.load_manifest(ROOT)
+    cell = cells.load_cell(manifest, ROOT, workload, rehearse=True)
+    net = cells.build_net(cell, seed)
+    ds = cells.make_batches(cell.config, seed, 1, cell.batch, cell.seq_len)[0]
+    f, l, fm, lm = net._batch_streams(ds)
+    segments = cell.iterations_per_step > 1
+    args = [net.params, net.states, net.updater_state, jnp.int32(0),
+            net._next_rng(), f, l, fm, lm]
+    if segments:
+        k = int(cell.config["builder_kwargs"]["tbptt"])
+        args[5:7] = [f[:, :k], l[:, :k]]
+        args.append(net._init_rnn_state(int(f.shape[0])))
+    return jax.jit(net._raw_step(segments)).lower(*args).as_text()
+
+
+@pytest.mark.parametrize("workload", sorted(PARENT))
+def test_the_step_is_the_parents_text(workload):
+    text = lowered_step(workload)
+    lines, digest = PARENT[workload]
+    assert len(text.splitlines()) == lines
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
