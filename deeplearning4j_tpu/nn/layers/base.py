@@ -208,6 +208,22 @@ def remat_policy():
                                                          "dl4j_stat")
 
 
+#: the one name the flash kernels give the residuals their backward reads
+#: (``ops/flash_attention.py``, ``_flash_fwd``)
+FLASH_RES = "dl4j_flash_res"
+#: one object for every stack and run: jax caches a checkpoint's partial
+#: evaluation by its policy, and like sub-programs of two runs stay one
+_BLOCK_POLICY = jax.checkpoint_policies.save_only_these_names(FLASH_RES)
+
+
+def block_checkpoint(block):
+    """``block`` under the block stacks' checkpoint: a block application
+    keeps its input and what the flash kernels' backward reads, and
+    recomputes the rest backward. A block with no flash call (the dense
+    path, a state-space block) tags nothing and keeps its input alone."""
+    return jax.checkpoint(block, policy=_BLOCK_POLICY)
+
+
 def acc_dtype(compute_dtype):
     """Accumulator/stats dtype: f32 when computing in a sub-32-bit dtype
     (bf16/f16), otherwise the compute dtype itself — forcing f32 under f64

@@ -6,8 +6,9 @@ Net-new vs the 0.9.x reference, like :mod:`.looped`, and built the same way
 from the layers the package has: every run of like blocks keeps its weights in
 stacked leaves ``[n, ...]`` (keys ``r<run>.<leaf>``) and is one ``lax.scan``
 over them, so the compiled program holds one body per run whatever the depth;
-training checkpoints each block (keeps its input, recomputes the rest
-backward). The residual stream and every norm's statistics are float32
+training checkpoints each block (``base.block_checkpoint``: keeps its input
+and what an attention block's flash kernels read backward, recomputes the
+rest). The residual stream and every norm's statistics are float32
 whatever the compute dtype.
 """
 from __future__ import annotations
@@ -19,7 +20,7 @@ import jax
 from ..conf.layers import GatedDenseLayer, Mamba2Layer, SelfAttentionLayer
 from ..weights import host_full
 from .attention import SelfAttentionImpl
-from .base import LayerImpl, implements, acc_dtype
+from .base import LayerImpl, implements, acc_dtype, block_checkpoint
 from .feedforward import GatedDenseImpl
 from .looped import ATTN_KEYS, FFN_KEYS, stacked_matrices
 from .mamba import Mamba2Impl
@@ -120,8 +121,7 @@ class HybridBlockStackImpl(LayerImpl):
                            if k.startswith(f"r{i}.")}
                 block = lambda p, u, kind=kind: self.block(kind, p, u, mask)
                 if train:
-                    # keep a block's input, recompute the rest backward
-                    block = jax.checkpoint(block)
+                    block = block_checkpoint(block)
                 x, _ = jax.lax.scan(
                     lambda u, p, block=block: (block(p, u), None), x, stacked)
         with jax.named_scope("final_norm"):

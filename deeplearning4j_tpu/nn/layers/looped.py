@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from ..conf.layers import GatedDenseLayer, SelfAttentionLayer
 from ..weights import host_full
 from .attention import SelfAttentionImpl
-from .base import LayerImpl, implements, acc_dtype
+from .base import LayerImpl, implements, acc_dtype, block_checkpoint
 from .feedforward import GatedDenseImpl
 from .normalization import rms_norm
 
@@ -96,8 +96,7 @@ class LoopedBlockStackImpl(LayerImpl):
         stacked = {k: params[k] for k in ATTN_KEYS + FFN_KEYS + GAIN_KEYS}
         block = lambda p, u: self.block(p, u, mask)
         if train:
-            # keep a block application's input, recompute the rest backward
-            block = jax.checkpoint(block)
+            block = block_checkpoint(block)
 
         def one_pass(h, _):
             with jax.named_scope("blocks"):

@@ -35,7 +35,9 @@ throughout; inputs/outputs keep the caller's dtype (bf16 on TPU).
 What engaged is visible: each kernel's ``name=`` carries its edges
 (``flash_fwd_q512_k512``; the device trace's op names), and the registry
 gauge ``flash_grid_steps{kernel}`` holds the steps of its grid per call, set
-when the call is traced.
+when the call is traced. A differentiated call names the residuals its
+backward kernels read (``dl4j_flash_res``; the block stacks' checkpoint keeps
+them) and sets ``flash_residual_bytes{kernel}`` to their bytes.
 
 Used automatically by ``SelfAttentionLayer`` when applicable (TPU backend,
 T divisible by the 128 block; [b, T] key-padding masks AND attention-
@@ -54,6 +56,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -319,6 +322,12 @@ def _split_refs(refs, causal, has_km, has_seed):
     return tabs, lead, km_ref, seed_ref, refs[int(has_km) + int(has_seed):]
 
 
+def _name(kernel, bq, bk):
+    """A kernel's name at its edges: the device trace's op name and the
+    ``kernel`` label of the registry's gauges."""
+    return f"{kernel}_q{bq}_k{bk}"
+
+
 def _launch(kernel, body, bq, bk, bh, n_out, n_in, pairs, specs, operands,
             out_specs, out_shape, scratch):
     """One flash ``pallas_call``; returns the list of its outputs. ``specs``
@@ -337,7 +346,7 @@ def _launch(kernel, body, bq, bk, bh, n_out, n_in, pairs, specs, operands,
                 "inner": lambda i, o, n: (i, n, 0)}
     spec = lambda s: (s if isinstance(s, pl.BlockSpec)
                       else _vspec(s[0], maps[s[1]]))
-    name = f"{kernel}_q{bq}_k{bk}"
+    name = _name(kernel, bq, bk)
     from ..monitor import get_registry     # here: the package imports ops
     get_registry().gauge(
         "flash_grid_steps",
@@ -606,6 +615,7 @@ def rowwise_delta(do, o):
 
 def _bwd(causal, scale, rate, res, g):
     q, k, v, km, seed, o, lse = res
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (8,))
     do = g.astype(q.dtype)
     delta = rowwise_delta(do, o)
     dq = dq_block(q, k, v, km, do, delta, lse, causal, scale, seed, rate)
@@ -647,7 +657,26 @@ def _flash(q, k, v, km, seed, causal, scale, rate):
 
 
 def _flash_fwd(q, k, v, km, seed, causal, scale, rate):
+    # What the backward kernels read carries one name: a ``jax.checkpoint``
+    # whose policy saves it (the block stacks', ``base.block_checkpoint``)
+    # keeps the tuple, and its backward holds neither this call again nor
+    # what made q, k and v. Anywhere else the name is the identity. ``km``
+    # and ``seed`` are the caller's inputs. Of lse's 8 equal lanes one is
+    # kept: the TPU pads a last dimension of 8 to 128, and a kept [bh, T, 8]
+    # would hold as many bytes as q.
+    from ..monitor import get_registry     # here: the package imports ops
+    from ..nn.layers.base import FLASH_RES
     o, lse = _fwd(q, k, v, km, seed, causal, scale, rate)
+    lse = lse[..., 0]
+    q, k, v, o, lse = (checkpoint_name(x, FLASH_RES)
+                       for x in (q, k, v, o, lse))
+    get_registry().gauge(
+        "flash_residual_bytes",
+        "Bytes of the residuals (q, k, v, o, lse) one differentiated "
+        "flash-attention call hands its backward kernels, set when the call "
+        "is traced", kernel=_name("flash_fwd", *pick_blocks(
+            "flash_fwd", q.shape[1], k.shape[1], q.shape[2], q.dtype))
+    ).set(sum(x.size * x.dtype.itemsize for x in (q, k, v, o, lse)))
     return o, (q, k, v, km, seed, o, lse)
 
 

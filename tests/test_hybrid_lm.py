@@ -18,10 +18,10 @@ from deeplearning4j_tpu import Adam, DataSet
 from deeplearning4j_tpu.monitor import get_registry
 from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.layers import (
-    EmbeddingSequenceLayer, HybridBlockStack, Mamba2Layer, RnnOutputLayer,
-    SelfAttentionLayer)
+    EmbeddingSequenceLayer, HybridBlockStack, LoopedBlockStack,
+    LoopLMOutputLayer, Mamba2Layer, RnnOutputLayer, SelfAttentionLayer)
 from deeplearning4j_tpu.nn.graph import ComputationGraph
-from deeplearning4j_tpu.nn.layers import mamba
+from deeplearning4j_tpu.nn.layers import hybrid, looped, mamba
 from deeplearning4j_tpu.nn.layers.attention import mha
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.utils.model_serializer import ModelSerializer
@@ -44,7 +44,7 @@ def _stack(**over):
     return HybridBlockStack(**{**kw, **over})
 
 
-def _lm(tied=True, l2=None, scale=12.0, divisor=8.0):
+def _lm(tied=True, l2=None, scale=12.0, divisor=8.0, **over):
     b = _builder()
     if l2:
         b = b.l2(l2)
@@ -52,7 +52,7 @@ def _lm(tied=True, l2=None, scale=12.0, divisor=8.0):
         b.graph_builder().add_inputs("ids")
         .add_layer("embed", EmbeddingSequenceLayer(n_in=V, n_out=D,
                                                    scale=scale), "ids")
-        .add_layer("stack", _stack(), "embed")
+        .add_layer("stack", _stack(**over), "embed")
         .add_layer("out", RnnOutputLayer(
             n_in=D, n_out=V, loss="sparse_mcxent", activation="softmax",
             has_bias=False, tied_to="embed" if tied else None,
@@ -427,3 +427,107 @@ def test_the_step_carries_the_scopes_and_sets_the_gauges(monkeypatch):
     assert named("jvp(stack)/", "final_norm")
     assert named("jvp(loss)/", "head/", "dot_general")
     assert named("transpose(jvp(loss))/", "head/", "dot_general")
+
+
+# ------------------------------------------------- the block checkpoint
+def _looped_lm():
+    return ComputationGraph(
+        _builder().graph_builder().add_inputs("ids")
+        .add_layer("embed", EmbeddingSequenceLayer(n_in=V, n_out=D), "ids")
+        .add_layer("stack", LoopedBlockStack(
+            n_in=D, n_out=D, num_blocks=2, num_passes=2, num_heads=4,
+            head_dim=8, n_hidden=40, eps=1e-6, rope_theta=1e6), "embed")
+        .add_layer("out", LoopLMOutputLayer(n_in=D, n_out=V,
+                                            entropy_weight=0.05), "stack")
+        .set_outputs("out").build()).init()
+
+
+#: stack -> (its network, the heads of a flash call, their size)
+STACKS = {"looped": (_looped_lm, 4, 8), "hybrid": (_lm, 8, 4)}
+
+
+def _trained(make, T):
+    """(network after one ``fit``, its loss as a function of the
+    parameters on a fresh batch)."""
+    net = make()
+    net.fit(_batch(T=T, seed=1, b=1))
+    ds = _batch(T=T, seed=2, b=1)
+    return net, lambda p: net._loss_fn(
+        p, net.states, [jnp.asarray(ds.features)], [jnp.asarray(ds.labels)],
+        None, None, True, None)[0]
+
+
+def _kernels_by_scan(jaxpr, found):
+    """Appends to ``found`` the sorted names of the Pallas kernels that each
+    ``scan`` of ``jaxpr`` holds outside any scan of its own (none: no
+    entry); returns those that ``jaxpr`` holds outside every scan."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            inner = _kernels_by_scan(sub, found)
+            if eqn.primitive.name != "scan":
+                names += inner
+            elif inner:
+                found.append(sorted(inner))
+    return names
+
+
+def _plain_checkpoint(monkeypatch):
+    """Both stacks' blocks under ``jax.checkpoint`` with no policy."""
+    for module in (looped, hybrid):
+        monkeypatch.setattr(module, "block_checkpoint",
+                            lambda block: jax.checkpoint(block))
+
+
+@pytest.mark.parametrize("stack", sorted(STACKS))
+def test_a_blocks_backward_holds_no_forward_kernel(stack, monkeypatch):
+    """The block checkpoint keeps the flash kernels' residuals: the forward
+    scan over the blocks holds ``flash_fwd``, the backward scan the two
+    backward kernels and no ``flash_fwd``; the gradients are those of a
+    plain ``jax.checkpoint(block)``, whose backward scan runs the forward
+    kernel again; the gauge reads the residuals' bytes."""
+    import deeplearning4j_tpu.monitor.registry as registry
+    monkeypatch.setattr(registry, "_REGISTRY", registry.MetricsRegistry())
+    monkeypatch.setattr(fa, "_FORCE_INTERPRET", True)
+    T = 2 * fa.MIN_BLOCK
+    make, heads, d = STACKS[stack]
+    net, loss = _trained(make, T)
+    fwd, dq, dkv = (f"flash_{k}_q{T}_k{T}" for k in ("fwd", "dq", "dkv"))
+
+    scans = []
+    assert not _kernels_by_scan(
+        jax.make_jaxpr(jax.grad(loss))(net.params).jaxpr, scans)
+    assert scans == [[fwd], [dkv, dq]]
+    got = jax.grad(loss)(net.params)
+
+    row, = get_registry().snapshot()["flash_residual_bytes"]
+    assert row["labels"] == {"kernel": fwd}
+    assert row["value"] == heads * T * (4 * d + 1) * 4   # q k v o, a lane
+
+    _plain_checkpoint(monkeypatch)
+    scans = []
+    _kernels_by_scan(jax.make_jaxpr(jax.grad(loss))(net.params).jaxpr, scans)
+    assert scans == [[fwd], [dkv, dq, fwd]]
+    want = jax.grad(loss)(net.params)
+    # kept and recomputed values are the same bits; the CPU compiler fuses
+    # the two backward bodies apart, which rounds (6e-7 of a leaf's norm)
+    for x, y in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert float(jnp.linalg.norm(x - y)) <= 2e-6 * float(
+            jnp.linalg.norm(y))
+
+
+@pytest.mark.parametrize("stack", ["looped_dense", "hybrid_dense",
+                                   "state_space_only"])
+def test_a_stack_with_no_flash_call_is_the_program_it_was(stack, monkeypatch):
+    """Nothing tagged, nothing kept: on the dense path (off the TPU) and in
+    a stack of state-space blocks the policy changes no line."""
+    make = {"looped_dense": _looped_lm, "hybrid_dense": _lm,
+            "state_space_only": lambda: _lm(layer_types=["mamba"] * 3)}[stack]
+    net, loss = _trained(make, 24)
+    text = jax.jit(jax.grad(loss)).lower(net.params).as_text()
+    assert ("dot_general" in text) and "flash" not in text
+    _plain_checkpoint(monkeypatch)
+    assert jax.jit(jax.grad(loss)).lower(net.params).as_text() == text

@@ -4,7 +4,9 @@ came (PR 33): new options of shared layers (``num_kv_heads``,
 ``attention_scale``, the embedding's ``scale``, the output layer's ``tied_to``
 and ``logits_divisor``) emit nothing where they are left at their defaults,
 and the tie's lookup in ``ComputationGraph`` adds no op (PR 30's method:
-StableHLO without locations, so a moved line does not count).
+StableHLO without locations, so a moved line does not count). The block
+stacks' checkpoint policy (PR 34) emits nothing either where no flash kernel
+runs, as here, off the TPU.
 
 A PR that means to change one of these steps replaces its line count and
 digest here, and says so; one that does not and fails here has changed a
@@ -21,6 +23,7 @@ from benchmark import cells
 
 ROOT = os.path.dirname(cells.HERE)
 #: cell -> (lines, sha256) of its step's lowered text at commit 16227cc
+#: (the hybrid language model's at 8f35757, which brought it)
 PARENT = {
     "resnet50_b256_resident": (
         11415,
@@ -31,6 +34,9 @@ PARENT = {
     "ouro_l4_ut4_b2_t4096_resident": (
         2116,
         "bc32c2bbb0034d58c85740b9a90e9499dcbc1d435250a3e00541c24639cfa458"),
+    "granite_l10_b1_t8192_resident": (
+        5237,
+        "b197bca010107f4d32b8902dff41535d54744965455655adcbbecf09d82d72c4"),
 }
 
 
