@@ -11,6 +11,20 @@ Precision under a bfloat16 compute policy: the products' operands take the
 compute dtype; the step sizes, the decays and their cumulative sums, the
 state carried between chunks, the convolution and the gated norm's
 statistics are float32.
+
+One function carries a differentiation rule of its own (``jax.custom_vjp``):
+:func:`split_conv_silu`, the in-projection's split with the convolution, its
+SiLU and the cast to the compute dtype. It keeps the float32 projection, the
+taps and the bias, and forms the pre-activation again backward; then one
+``dpre``, the input's cotangent as K shifted multiply-adds of it, the taps'
+and the bias's as reductions, and z's, xBC's and dt's cotangents leave as one
+concatenation. Its bodies run under the scopes of the call (``ssm``), trace
+no metric, and forward-mode differentiation of the layer is refused by jax.
+The gated norm, ``rms_norm(y * silu(z), gn)``, is left to autodiff: a rule
+that kept y and z and recomputed the rest compiled to the same three passes
+(PERF.md section 6, PR 35); its output, cast to the compute dtype, passes a
+``lax.optimization_barrier`` on its way to the out-projection, so that it is
+written once and the product reads an array.
 """
 from __future__ import annotations
 
@@ -26,16 +40,71 @@ from .base import LayerImpl, implements, acc_dtype
 from .normalization import rms_norm
 
 
-def causal_conv1d(x, w, b):
-    """Depthwise causal convolution over time: ``x`` [b, T, C], ``w`` [C, K],
-    ``b`` [C] -> ``y_t = sum_k w[:, k] x_{t - K + 1 + k} + b`` (steps before
-    the first read as nought): K shifted multiply-adds, no gemm."""
-    K, T = w.shape[-1], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+def _shifted(x, k):
+    """``x`` [b, T, C] moved ``k`` steps later in time (earlier for a
+    negative ``k``); the steps that enter read as nought."""
+    return jax.lax.pad(x, jnp.zeros((), x.dtype),
+                       ((0, 0, 0), (k, -k, 0), (0, 0, 0)))
+
+
+def _taps(x, K, lo, C):
+    """What the K taps of a causal convolution over channels [lo, lo + C) of
+    ``x`` [b, T, >= lo + C] read: tap k reads ``x_{t - K + 1 + k}``. Moved in
+    time first and cut to the channels after, so that the compiler reads the
+    channels where they lie and keeps no copy of them."""
+    return [_shifted(x, K - 1 - k)[..., lo:lo + C] for k in range(K)]
+
+
+def causal_conv1d(x, w, b, lo=0):
+    """Depthwise causal convolution over time of channels [lo, lo + C) of
+    ``x`` [b, T, >= lo + C], ``w`` [C, K], ``b`` [C] -> ``y_t = sum_k w[:, k]
+    x_{t - K + 1 + k} + b`` [b, T, C] (steps before the first read as
+    nought): K shifted multiply-adds, no gemm."""
     y = b
-    for k in range(K):
-        y = y + padded[:, k:k + T] * w[:, k]
+    for k, tap in enumerate(_taps(x, w.shape[-1], lo, w.shape[0])):
+        y = y + tap * w[:, k]
     return y
+
+
+def split_conv_silu(zxbcdt, w, b, d_inner, out_dtype):
+    """The in-projection's split and the convolution as one function:
+    ``zxbcdt`` [b, T, d_inner + C + H] (z | xBC | dt), ``w`` [C, K], ``b``
+    [C] -> (z, ``silu(causal_conv1d(xBC, w, b))`` cast to ``out_dtype``, dt),
+    the convolution and its SiLU in the dtype of ``zxbcdt``. Differentiated
+    by a rule of its own (below): autodiff's transposition of the K shifted
+    reads writes K arrays of xBC's size and adds them up again, and the split
+    copies xBC out of the projection for it."""
+    C = w.shape[0]
+    return (zxbcdt[..., :d_inner],
+            jax.nn.silu(causal_conv1d(zxbcdt, w, b, d_inner)).astype(out_dtype),
+            zxbcdt[..., d_inner + C:])
+
+
+def _split_conv_silu_fwd(zxbcdt, w, b, d_inner, out_dtype):
+    # kept: the projection, the taps and the bias; the pre-activation is
+    # formed again backward (the compiler may share it with a recomputed
+    # forward that stands in the same program)
+    return _split_conv_silu(zxbcdt, w, b, d_inner, out_dtype), (zxbcdt, w, b)
+
+
+def _split_conv_silu_bwd(d_inner, out_dtype, kept, cotangents):
+    zxbcdt, w, b = kept
+    dz, dy, ddt = cotangents
+    C, K = w.shape
+    pre = causal_conv1d(zxbcdt, w, b, d_inner)
+    gate = jax.nn.sigmoid(pre)
+    dpre = dy.astype(pre.dtype) * (gate * (1 + pre * (1 - gate)))
+    # tap k read x_{t - (K-1-k)}: it hands dpre_{t + (K-1-k)} back to x_t
+    dx = sum(_shifted(dpre, k - (K - 1)) * w[:, k] for k in range(K))
+    dw = jnp.stack([jnp.sum(dpre * tap, axis=(0, 1))
+                    for tap in _taps(zxbcdt, K, d_inner, C)], axis=-1)
+    return (jnp.concatenate([dz, dx, ddt], axis=-1), dw,
+            jnp.sum(dpre, axis=(0, 1)))
+
+
+_split_conv_silu = split_conv_silu
+split_conv_silu = jax.custom_vjp(_split_conv_silu, nondiff_argnums=(3, 4))
+split_conv_silu.defvjp(_split_conv_silu_fwd, _split_conv_silu_bwd)
 
 
 def carried_states(local, decay, first):
@@ -180,11 +249,10 @@ class Mamba2Impl(LayerImpl):
         proj = lambda t, w: jax.lax.dot_general(
             t.astype(cd), w.astype(cd), (((t.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=sd)
-        z, xbc, dt = jnp.split(proj(x, params["W_in"]),
-                               [d_inner, 2 * d_inner + 2 * N], axis=-1)
-        xbc = jax.nn.silu(causal_conv1d(xbc, params["conv_W"].astype(sd),
-                                        params["conv_bias"].astype(sd)))
-        xs, B, C = jnp.split(xbc.astype(cd), [d_inner, d_inner + N], axis=-1)
+        z, xbc, dt = split_conv_silu(
+            proj(x, params["W_in"]), params["conv_W"].astype(sd),
+            params["conv_bias"].astype(sd), d_inner, cd)
+        xs, B, C = jnp.split(xbc, [d_inner, d_inner + N], axis=-1)
         xs = xs.reshape(b, T, H, P)
         with jax.named_scope("ssd"):
             chunk = int(c.chunk_size)
@@ -200,6 +268,10 @@ class Mamba2Impl(LayerImpl):
             y = y + params["D"].astype(sd)[:, None] * xs.astype(sd)
         y = rms_norm(y.reshape(b, T, d_inner) * jax.nn.silu(z), params["gn"],
                      c.eps, sd)
+        # the out-projection's operand stands as an array of its own: fused
+        # into the product as its operand's producer, the norm sets the
+        # product's pace (on the v5e 1.75 ms a block for a 0.70 ms gemm)
+        y = jax.lax.optimization_barrier(y.astype(cd))
         return self.activation(proj(y, params["W_out"])).astype(
             self.out_dtype), state
 

@@ -143,6 +143,113 @@ def test_the_convolution_is_causal_and_reads_its_own_channel():
         rtol=1e-5, atol=1e-5)
 
 
+# -------------------------------------------- the differentiation rule
+def _plain_split_conv_silu(zxbcdt, w, b, d_inner, out_dtype):
+    """What the rule replaces: split, convolution, SiLU, cast, autodiff
+    throughout."""
+    z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + w.shape[0]], axis=-1)
+    return z, jax.nn.silu(mamba.causal_conv1d(xbc, w, b)).astype(out_dtype), dt
+
+
+@pytest.mark.parametrize("out_dtype, tol", [
+    (jnp.float32, 1e-6),
+    # the policy's rounding: xBC leaves, and its cotangent comes, in bfloat16
+    (jnp.bfloat16, 2.0 ** -8),
+], ids=["float32", "bfloat16"])
+def test_the_rule_is_the_gradient_of_what_it_replaces(out_dtype, tol):
+    """Batch 2, a T that is no multiple of any chunk."""
+    rng = np.random.default_rng(3)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    d_inner, C, H = 6, 11, 3
+    args = draw(2, 21, d_inner + C + H), draw(C, 4), draw(C)
+    got = mamba.split_conv_silu(*args, d_inner, out_dtype)
+    want = _plain_split_conv_silu(*args, d_inner, out_dtype)
+    assert [o.dtype for o in got] == [jnp.float32, out_dtype, jnp.float32]
+    for g, wnt in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(wnt, np.float32))
+    weights = [jnp.asarray(rng.normal(size=o.shape), o.dtype) for o in want]
+    scalar = lambda f: lambda *a: sum(
+        jnp.sum((o * wt).astype(jnp.float32))
+        for o, wt in zip(f(*a, d_inner, out_dtype), weights))
+    got = jax.grad(scalar(mamba.split_conv_silu), argnums=(0, 1, 2))(*args)
+    want = jax.grad(scalar(_plain_split_conv_silu), argnums=(0, 1, 2))(*args)
+    for g, wnt in zip(got, want):
+        assert g.shape == wnt.shape and g.dtype == wnt.dtype
+        assert (float(jnp.linalg.norm(g - wnt))
+                <= tol * float(jnp.linalg.norm(wnt)))
+
+
+def _mamba_impl():
+    from deeplearning4j_tpu.nn.layers.base import impl_for
+    conf = (_builder().list().layer(Mamba2Layer(
+        n_in=D, n_out=D, num_heads=4, head_dim=16, state_size=8,
+        chunk_size=8)).build())
+    return impl_for(conf.layers[0], conf.global_conf, None)
+
+
+def test_a_mamba2_layer_is_the_recurrence_in_output_and_every_leaf():
+    """The layer with its rule against the benchmark reference's mixer (the
+    recurrence step by step, autodiff throughout), batch 2, a T that does not
+    fill its last chunk."""
+    impl = _mamba_impl()
+    params, _ = impl.init(jax.random.PRNGKey(3))
+    rng = np.random.default_rng(5)
+    params = {k: v + 0.1 * jnp.asarray(rng.normal(size=v.shape), v.dtype)
+              for k, v in params.items()}      # no leaf at its drawn 0 or 1
+    x = jnp.asarray(rng.normal(size=(2, 21, D)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(2, 21, D)), jnp.float32)
+    ours = lambda p, x: impl.forward(p, {}, x, train=True)[0]
+    theirs = lambda p, x: reference.mamba_mixer(p, x, 1e-5)
+    np.testing.assert_allclose(ours(params, x), theirs(params, x),
+                               rtol=2e-4, atol=2e-5)
+    got = jax.grad(lambda p, x: jnp.sum(w * ours(p, x)), (0, 1))(params, x)
+    want = jax.grad(lambda p, x: jnp.sum(w * theirs(p, x)), (0, 1))(params, x)
+    assert set(got[0]) == {"W_in", "conv_W", "conv_bias", "dt_bias", "A_log",
+                           "D", "gn", "W_out"}
+    for name, g, wnt in [("x", got[1], want[1])] + [
+            (k, got[0][k], want[0][k]) for k in sorted(want[0])]:
+        assert (float(jnp.linalg.norm(g - wnt))
+                <= 1e-4 * float(jnp.linalg.norm(wnt))), name
+
+
+def _padded_products(jaxpr, shape):
+    """The distinct products of ``shape`` that a ``pad`` reads, through every
+    sub-jaxpr: autodiff's transposition of K shifted reads pads K products
+    of the cotangent, one a tap."""
+    found = set()
+
+    def walk(j):
+        made = {}
+        for eqn in j.eqns:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+            if eqn.primitive.name == "mul":
+                made[eqn.outvars[0]] = eqn
+            if (eqn.primitive.name == "pad" and eqn.invars[0] in made
+                    and eqn.invars[0].aval.shape == shape):
+                found.add(eqn.invars[0])
+    walk(jaxpr.jaxpr)
+    return len(found)
+
+
+def test_the_layers_backward_pads_no_product_a_tap():
+    """No four [b, T, d_inner + 2N] products of the cotangent with a tap,
+    each padded and added up: one ``dpre``, shifted."""
+    impl = _mamba_impl()
+    params, _ = impl.init(jax.random.PRNGKey(3))
+    x = jnp.ones((2, 21, D), jnp.float32)
+    shape = (2, 21, 64 + 2 * 8)
+    layer = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(
+        impl.forward(p, {}, x, train=True)[0])))(params)
+    assert _padded_products(layer, shape) == 1
+    cw, cb = params["conv_W"], params["conv_bias"]
+    plain = jax.make_jaxpr(jax.grad(lambda u: sum(map(
+        jnp.sum, _plain_split_conv_silu(u, cw, cb, 64, jnp.float32)))))(
+            jnp.ones((2, 21, 148), jnp.float32))
+    assert _padded_products(plain, shape) == 4     # what the rule replaced
+
+
 def test_a_mamba2_layer_trains_in_a_multilayer_network():
     net = MultiLayerNetwork(
         _builder().list()
