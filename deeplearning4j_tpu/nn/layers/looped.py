@@ -6,20 +6,26 @@ sharing across depth). The blocks are built from the layers the package has
 (``RMSNorm``, ``SelfAttentionLayer`` with rotary positions and no bias,
 ``GatedDenseLayer``), but their weights live in ONE parameter dict of stacked
 leaves ``[num_blocks, ...]``, one leaf per kind of weight, and the forward is
-``lax.scan`` over those leaves inside ``lax.scan`` over the passes: one block
-body in the compiled program whatever the depth and the number of passes, and
-AD sums each weight's gradient over the passes because every pass reads the
-same leaves.
+ONE ``lax.scan`` over the ``num_passes x num_blocks`` block applications:
+application ``n`` takes block ``n % num_blocks``'s leaves, and the last block
+of a pass is followed by the final norm. One block body in the compiled
+program whatever the depth and the number of passes, one stack of kept values
+for the backward sweep (a pass's output is read off it: it is what the next
+pass's first application took), and each weight's gradient summed over the
+passes where it stands (``_take``).
 
 The residual stream and every norm's statistics are float32 whatever the
 compute dtype; the gemms and the attention kernel take the compute dtype.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from ..conf.layers import GatedDenseLayer, SelfAttentionLayer
+from ...monitor import get_registry
 from ..weights import host_full
 from .attention import SelfAttentionImpl
 from .base import LayerImpl, implements, acc_dtype, block_checkpoint
@@ -43,6 +49,75 @@ def stacked_matrices(impl, subs, names, rng, n):
         rng)
     return {k: impl._init_w(key, (n,) + block[k].shape, *block[k].shape)
             for k, key in zip(names, jax.random.split(rng, len(names)))}
+
+
+def _row(leaf, i):
+    return jax.lax.dynamic_index_in_dim(leaf, i, 0, keepdims=False)
+
+
+@jax.custom_vjp
+def _take(leaves, grads, i):
+    """Block ``i``'s row of every stacked leaf, and ``grads`` as it came.
+    Backward, the rows' cotangent is added to row ``i`` of ``grads``'
+    cotangent and nothing is handed to ``leaves``: carried through the scan
+    over the applications, ``grads`` sums every leaf's gradient over the
+    passes one row at a time, in place. AD's own transpose of the index
+    writes each application's rows into a stack of zeros and adds whole
+    stacks."""
+    return jax.tree_util.tree_map(lambda v: _row(v, i), leaves), grads
+
+
+def _take_fwd(leaves, grads, i):
+    return _take(leaves, grads, i), i
+
+
+def _take_bwd(i, cotangents):
+    rows, grads = cotangents
+    return None, jax.tree_util.tree_map(
+        lambda g, r: jax.lax.dynamic_update_index_in_dim(
+            g, _row(g, i) + r.astype(g.dtype), i, 0), grads, rows), None
+
+
+_take.defvjp(_take_fwd, _take_bwd)
+
+
+@jax.custom_vjp
+def _stand_in(zeros, values):
+    """``zeros`` where ``values`` stood, with ``values``' cotangent. Twice in
+    the scan over the applications: for the stacked leaves, so that what
+    ``_take`` has summed by the end of the backward sweep is their gradient;
+    and in every application for the carried ``grads``, which is zeros too
+    but not a constant of the scan: what a checkpointed application takes
+    in it keeps for its backward sweep, a constant once, a carried value
+    once an application."""
+    return zeros
+
+
+_stand_in.defvjp(lambda zeros, values: (zeros, None),
+                 lambda _, cotangent: (None, cotangent))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _norm_if(norm, flag, u, gain):
+    """``norm(u, gain)`` where ``flag`` holds, else ``u``. With a rule of its
+    own because AD through a ``cond`` hands the backward sweep each branch's
+    residuals, zeros from the branch that did not run: three arrays like
+    ``u``, filled and added an application."""
+    return jax.lax.cond(flag, norm, lambda u, gain: u, u, gain)
+
+
+def _norm_if_fwd(norm, flag, u, gain):
+    return _norm_if(norm, flag, u, gain), (flag, u, gain)
+
+
+def _norm_if_bwd(norm, residuals, cotangent):
+    flag, u, gain = residuals
+    return (None,) + jax.lax.cond(
+        flag, lambda c: jax.vjp(norm, u, gain)[1](c),
+        lambda c: (c, jnp.zeros_like(gain)), cotangent)
+
+
+_norm_if.defvjp(_norm_if_fwd, _norm_if_bwd)
 
 
 @implements("LoopedBlockStack")
@@ -91,22 +166,52 @@ class LoopedBlockStackImpl(LayerImpl):
 
     def forward(self, params, state, x, train=False, rng=None, mask=None, ctx=None):
         c = self.conf
+        blocks, passes = int(c.num_blocks), int(c.num_passes)
+        get_registry().gauge(
+            "looped_scan_steps",
+            "Steps of the one scan a looped block stack runs as (passes x "
+            "blocks: one block application each), set when the stack's "
+            "forward is traced",
+            layer=str(getattr(self, "index", ""))).set(passes * blocks)
         x = self.maybe_dropout(x, train, rng).astype(
             acc_dtype(self.compute_dtype))
         stacked = {k: params[k] for k in ATTN_KEYS + FFN_KEYS + GAIN_KEYS}
-        block = lambda p, u: self.block(p, u, mask)
-        if train:
-            block = block_checkpoint(block)
+        # the leaves' values reach a block as constants of the loop; their
+        # gradients travel as the cotangent of ``grads``, which no block reads
+        leaves = jax.lax.stop_gradient(stacked)
+        zeros = jax.tree_util.tree_map(jnp.zeros_like, leaves)
 
-        def one_pass(h, _):
+        def apply(grads, i, ends_pass, u):
             with jax.named_scope("blocks"):
-                u, _ = jax.lax.scan(lambda u, p: (block(p, u), None), h,
-                                    stacked)
+                p, grads = _take(leaves, grads, i)
+                u = self.block(p, u, mask)
             with jax.named_scope("final_norm"):
-                h = self._norm(u, params["gf"])
-            return h, h
+                return _norm_if(self._norm, ends_pass, u, params["gf"]), grads
 
-        _, states = jax.lax.scan(one_pass, x, None, length=int(c.num_passes))
+        if train:
+            apply = block_checkpoint(apply)
+
+        def application(carry, n):
+            u, states, grads = carry
+            i = n % blocks
+            v, grads = apply(_stand_in(zeros, grads), i, i == blocks - 1, u)
+            if not train:
+                # nothing is kept: every application writes its pass's row,
+                # the pass's last one, which ends in the final norm, last
+                states = jax.lax.dynamic_update_index_in_dim(
+                    states, v, n // blocks, 0)
+            return (v, states, grads), (u if train else None)
+
+        states = None if train else jnp.zeros((passes,) + x.shape, x.dtype)
+        (u, states, _), taken = jax.lax.scan(
+            application, (x, states, _stand_in(zeros, stacked)),
+            jnp.arange(passes * blocks, dtype=jnp.int32))
+        if train:
+            # a pass hands its output to the next one's first block, and
+            # what an application took is kept for the backward sweep as it
+            # is: the kept stack holds every pass's output but the last
+            firsts = taken.reshape((passes, blocks) + u.shape)[1:, 0]
+            states = jnp.concatenate([firsts, u[None]])
         return states, state
 
     def regularization(self, params):

@@ -77,8 +77,8 @@ def assert_trees_close(a, b, tol):
     lb, tb = jax.tree_util.tree_flatten_with_path(b)
     assert ta == tb
     for (path, x), (_, y) in zip(la, lb):
-        err = float(jnp.linalg.norm(x - y) / jnp.linalg.norm(y))
-        assert err <= tol, (jax.tree_util.keystr(path), err)
+        err, norm = float(jnp.linalg.norm(x - y)), float(jnp.linalg.norm(y))
+        assert err <= tol * norm, (jax.tree_util.keystr(path), err, norm)
 
 
 # ------------------------------------------------------- against the reference
@@ -250,6 +250,200 @@ def test_scan_over_stacked_leaves_is_a_loop_over_the_blocks():
         assert float(jnp.std(drawn[k])) == pytest.approx(
             (2 / sum(drawn[k].shape[1:])) ** 0.5, rel=0.1), k
         assert not np.allclose(drawn[k][0], drawn[k][1]), k
+
+
+# ------------------------------------- one scan over the block applications
+def stack_conf(blocks, passes, dtype="float32"):
+    """A looped stack alone as a graph's one vertex: 3 blocks at batch 2,
+    T 16 in the tests below."""
+    conf = (NeuralNetConfiguration.builder().seed(7).activation("identity")
+            .graph_builder().add_inputs("x")
+            .add_layer("stack", LoopedBlockStack(
+                n_in=D, n_out=D, num_blocks=blocks, num_passes=passes,
+                num_heads=HEADS, head_dim=HEAD_DIM, n_hidden=F, eps=EPS,
+                rope_theta=THETA), "x")
+            .set_outputs("stack").build())
+    conf.global_conf.compute_dtype = dtype
+    return conf
+
+
+def nested_forward(impl, params, state, x, train=False, rng=None, mask=None,
+                   ctx=None):
+    """The stack as it ran before it was one scan: a scan over the blocks'
+    stacked leaves inside a scan over the passes, the block under a plain
+    ``jax.checkpoint``, every gradient by autodiff."""
+    stacked = {k: v for k, v in params.items() if k != "gf"}
+    block = lambda p, u: impl.block(p, u, mask)
+    if train:
+        block = jax.checkpoint(block)
+
+    def one_pass(h, _):
+        u, _ = jax.lax.scan(lambda u, p: (block(p, u), None), h, stacked)
+        h = impl._norm(u, params["gf"])
+        return h, h
+
+    _, states = jax.lax.scan(one_pass, x.astype(jnp.float32), None,
+                             length=int(impl.conf.num_passes))
+    return states, state
+
+
+def _stack(passes, dtype="float32", blocks=3, t=16):
+    """(the stack's layer, its shaken leaves, an input, a cotangent)."""
+    conf = stack_conf(blocks, passes, dtype)
+    impl = impl_for(conf.vertices["stack"], conf.global_conf, None)
+    rng = np.random.default_rng(11)
+    params = jax.tree_util.tree_map(
+        lambda v: v + 0.2 * jnp.asarray(rng.standard_normal(v.shape), v.dtype),
+        impl.init(jax.random.PRNGKey(3))[0])
+    x = jnp.asarray(rng.standard_normal((2, t, D)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((passes, 2, t, D)), jnp.float32)
+    return impl, params, x, w
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
+                                        ("bfloat16", 2.0 ** -8)])
+@pytest.mark.parametrize("passes", [2, 1])
+def test_the_one_scan_is_the_nested_scans(passes, dtype, tol):
+    impl, params, x, w = _stack(passes, dtype)
+    assert impl.compute_dtype == jnp.dtype(dtype)
+    for train in (False, True):
+        flat, _ = jax.jit(lambda p, x: impl.forward(p, {}, x, train=train))(
+            params, x)
+        nested, _ = jax.jit(lambda p, x: nested_forward(
+            impl, p, {}, x, train=train))(params, x)
+        assert flat.shape == (passes, 2, 16, D) and flat.dtype == jnp.float32
+        np.testing.assert_array_equal(flat, nested)
+    loss = lambda forward: lambda p, x: jnp.sum(
+        forward(p, {}, x, train=True)[0] * w)
+    got = jax.jit(jax.grad(loss(impl.forward), argnums=(0, 1)))(params, x)
+    want = jax.jit(jax.grad(loss(lambda *a, **k: nested_forward(impl, *a, **k)),
+                            argnums=(0, 1)))(params, x)
+    assert all(g.dtype == jnp.float32 for g in jax.tree_util.tree_leaves(got))
+    assert_trees_close(got, want, tol)
+
+
+@pytest.mark.parametrize("passes", [2, 1])
+def test_the_one_scan_through_the_containers_loss(passes, monkeypatch):
+    ds = sample()
+    net = shaken(ComputationGraph(looped_conf(passes)).init())
+    loss = lambda p: net._loss_fn(
+        p, net.states, [jnp.asarray(ds.features)], [jnp.asarray(ds.labels)],
+        None, None, True, None)[0]
+    l_flat, g_flat = jax.jit(jax.value_and_grad(loss))(net.params)
+    monkeypatch.setattr(type(net.impls["stack"]), "forward", nested_forward)
+    l_nested, g_nested = jax.jit(jax.value_and_grad(loss))(net.params)
+    assert float(l_flat) == float(l_nested)
+    assert_trees_close(g_flat, g_nested, 2e-6)
+
+
+def _scans(jaxpr, inside=False, found=None):
+    """``[(scan equation, whether it stands inside another scan)]`` of
+    ``jaxpr``, through every equation that holds a jaxpr of its own."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        scan = eqn.primitive.name == "scan"
+        if scan:
+            found.append((eqn, inside))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _scans(sub, inside or scan, found)
+    return found
+
+
+def _plain_equations(jaxpr):
+    """The equations of ``jaxpr`` that hold no jaxpr of their own, through
+    those that do (a call, a checkpoint, a ``cond``'s branches)."""
+    for eqn in jaxpr.eqns:
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if not subs:
+            yield eqn
+        for sub in subs:
+            yield from _plain_equations(sub)
+
+
+def _stack_grad(impl, w):
+    """The gradient of the training stack's weighted output by its leaves
+    and its input."""
+    return jax.grad(lambda p, x: jnp.sum(
+        impl.forward(p, {}, x, train=True)[0] * w), argnums=(0, 1))
+
+
+@pytest.mark.parametrize("passes", [2, 1])
+def test_the_gradient_is_two_scans_and_adds_a_row_at_a_time(passes):
+    """One forward and one backward scan of passes x blocks steps, neither
+    inside another; in the backward body a value with a whole stacked
+    leaf's shape is written by the row update alone: no stack of zeros, no
+    whole-stack add, no second stack of gradients."""
+    blocks = 3
+    impl, params, x, w = _stack(passes)
+    scans = _scans(jax.make_jaxpr(_stack_grad(impl, w))(params, x).jaxpr)
+    assert [(e.params["length"], e.params["reverse"], inside)
+            for e, inside in scans] == [(passes * blocks, False, False),
+                                        (passes * blocks, True, False)]
+    stacks = {v.shape for k, v in params.items() if k != "gf"}
+    assert len(stacks) == 4         # [3, D], [3, D, D], [3, D, F], [3, F, D]
+    whole = [e for e in _plain_equations(scans[1][0].params["jaxpr"].jaxpr)
+             if any(getattr(v.aval, "shape", None) in stacks
+                    for v in e.outvars)]
+    assert whole and {e.primitive.name for e in whole} == {
+        "dynamic_update_slice"}
+    assert len(whole) == 11         # a row into each of the eleven leaves
+    # and the forward body holds none at all: the leaves are its constants
+    assert not [e for e in _plain_equations(scans[0][0].params["jaxpr"].jaxpr)
+                if any(getattr(v.aval, "shape", None) in stacks
+                       for v in e.outvars)]
+
+
+@pytest.mark.parametrize("passes", [2, 1])
+def test_a_passs_output_is_read_off_the_kept_stack(passes):
+    """Training, a pass's output is what the next pass's first application
+    took, and that is kept for the backward sweep anyway: the compiled
+    forward loop stacks the stream once, passes x blocks rows of it, and
+    carries no result. With nothing kept (inference) the scan carries the
+    passes' rows and stacks nothing."""
+    blocks = 3
+    impl, params, x, w = _stack(passes)
+    stream = (passes * blocks,) + x.shape
+
+    def stacked_by(fn):
+        scan, = [e for e, _ in _scans(jax.make_jaxpr(fn)(params, x).jaxpr)
+                 if not e.params["reverse"]]
+        return scan, [v.aval.shape for v in scan.outvars
+                      if v.aval.shape == stream]
+
+    grad = _stack_grad(impl, w)
+    scan, stacks = stacked_by(grad)
+    # the scan's own output and the checkpoint's kept input are one value:
+    # the compiled loops carry it once (forward written, backward read)
+    assert stacks.count(stream) == 2
+    text = jax.jit(grad).lower(params, x).compile().as_text()
+    carried = [re.findall(r"f32\[%s\]" % ",".join(map(str, stream)), m)
+               for m in re.findall(r" = \((.*?)\) while\(", text)]
+    assert [len(c) for c in carried if c] == [1, 1]
+    assert (passes,) + x.shape not in [v.aval.shape for v in scan.outvars]
+    scan, stacks = stacked_by(lambda p, x: impl.forward(p, {}, x)[0])
+    assert stacks == []
+    assert (passes,) + x.shape in [v.aval.shape for v in scan.outvars]
+
+
+def test_the_scan_steps_gauge_reads_sixteen_at_four_by_four(monkeypatch):
+    import deeplearning4j_tpu.monitor.registry as registry
+    monkeypatch.setattr(registry, "_REGISTRY", registry.MetricsRegistry())
+    conf = (_builder()
+            .add_layer("stack", LoopedBlockStack(
+                n_in=D, n_out=D, num_blocks=4, num_passes=4, num_heads=HEADS,
+                head_dim=HEAD_DIM, n_hidden=F, eps=EPS, rope_theta=THETA),
+                "embed")
+            .add_layer("out", LoopLMOutputLayer(n_in=D, n_out=V,
+                                                entropy_weight=0.05), "stack")
+            .set_outputs("out").build())
+    net = ComputationGraph(conf).init()
+    net.fit(sample())
+    net.fit(sample(seed=2))
+    snap = get_registry().snapshot()
+    assert [(r["labels"], r["value"]) for r in snap["looped_scan_steps"]] \
+        == [({"layer": "stack"}, 16)]
+    assert [r["value"] for r in snap["looped_block_applications"]
+            if r["labels"] == {"network": "cg"}] == [16]
 
 
 def test_block_checkpoint_changes_no_number(monkeypatch):
@@ -504,12 +698,16 @@ def test_the_scopes_name_the_ops_of_the_step():
         return any(pattern.search(n) for n in names)
 
     for sub in ("attn", "ffn"):
-        assert named("jvp(stack)/", "/blocks/", f"/{sub}"), sub
-        assert named("transpose(jvp(stack))/", "/blocks/", f"/{sub}"), sub
+        assert named("jvp(stack)/while/body/", "/blocks/", sub), sub
+        assert named("transpose(jvp(stack))/while/body/", "/blocks/", sub), sub
         # the recomputed forward, inside the backward pass
-        assert named("transpose(jvp(stack))/", "/blocks/",
-                     "/rematted_computation/", sub), sub
+        assert named("transpose(jvp(stack))/while/body/",
+                     "/rematted_computation", "/blocks/", sub), sub
+    # one loop forward and one backward, the scopes inside its body
+    assert not named("jvp(stack)", "/while/", "/while/")
     assert named("jvp(stack)/", "/final_norm")
+    # a weight's gradient is added to its row of the carried sum in `blocks`
+    assert named("transpose(jvp(stack))/", "/blocks/dynamic_update_slice")
     assert named("jvp(loss)/", "exit_gate")
     assert named("transpose(jvp(loss))/", "exit_gate")
     # the head's rule runs both of its sweeps where the loss runs forward:

@@ -9,7 +9,9 @@ stacks' checkpoint policy (PR 34) emits nothing either where no flash kernel
 runs, as here, off the TPU. The state-space mixer's differentiation rule
 and the barrier before its out-projection (PR 35, ``mamba.split_conv_silu``,
 ``Mamba2Impl.forward``) are the hybrid model's alone: its step's text moved
-with them, the other three stayed.
+with them, the other three stayed. The looped stack as one scan over its
+block applications (PR 36, ``looped.LoopedBlockStackImpl.forward``) is the
+looped model's alone: its step's text moved, the other three stayed.
 
 A PR that means to change one of these steps replaces its line count and
 digest here, and says so; one that does not and fails here has changed a
@@ -26,7 +28,8 @@ from benchmark import cells
 
 ROOT = os.path.dirname(cells.HERE)
 #: cell -> (lines, sha256) of its step's lowered text at commit 16227cc
-#: (the hybrid language model's as PR 35 left it: 5237 lines before)
+#: (the hybrid language model's as PR 35 left it: 5237 lines before; the
+#: looped language model's as PR 36 left it: 2116 lines before)
 PARENT = {
     "resnet50_b256_resident": (
         11415,
@@ -35,8 +38,8 @@ PARENT = {
         1258,
         "8b7af6b349c565dcdea9798a6d74f1a5014e6e683580b4439915763419a4bd74"),
     "ouro_l4_ut4_b2_t4096_resident": (
-        2116,
-        "bc32c2bbb0034d58c85740b9a90e9499dcbc1d435250a3e00541c24639cfa458"),
+        2293,
+        "42c1f5cd8f3ab1da9a6c48ab804a90d22cbe91bc9ab122b3c5095444c1e9aeba"),
     "granite_l10_b1_t8192_resident": (
         5376,
         "5aa7ce691b442dfccae6a6db713b75bc64d85db2ff8550cf0ee78ade81ebff43"),
