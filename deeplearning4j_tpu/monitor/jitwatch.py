@@ -34,6 +34,14 @@ both first-class monitor citizens:
 - :func:`profile_report` — the step-anatomy view behind ``GET /profile``
   and ``monitor --profile``: the per-fn jit table, the memory gauges,
   and the step/ETL timing split merged into one JSON+text report.
+- :func:`watch_compile_phases` — one ``jax.monitoring`` listener pair that
+  records every compile of the process, whoever asked for it, by phase:
+  ``jax/trace``, ``jax/lower``, ``jax/backend_compile`` and
+  ``jax/cache_retrieval`` spans (``cat="compile"``, so the tracer keeps
+  them with the monitor off) and the ``jax_compile_phase_seconds_total``
+  / ``jax_compiles_total`` counters. They fire only when something
+  compiles; ``profile_report()["startup"]`` reads them beside the ``init``
+  spans: why a job took as long as it did to reach its first step.
 
 Hot-path cost per monitored call: two counter increments, two
 ``perf_counter`` reads, and one C++-side jit-cache-size probe — all the
@@ -61,7 +69,7 @@ log = logging.getLogger(__name__)
 __all__ = ["monitored_jit", "MonitoredJit", "JitRegistry",
            "get_jit_registry", "sample_device_memory",
            "maybe_sample_device_memory", "wait_cost_captures",
-           "profile_report", "render_profile_text",
+           "profile_report", "render_profile_text", "watch_compile_phases",
            "RETRACE_THRESHOLD", "RETRACE_WINDOW"]
 
 #: compiles of ONE wrapper instance within RETRACE_WINDOW seconds that
@@ -141,6 +149,88 @@ def _abstractify(x):
     if shape is not None and dtype is not None:
         return jax.ShapeDtypeStruct(tuple(shape), dtype)
     return x
+
+
+# --------------------------------------------------------- compile phases
+#: jax.monitoring's time-span events of one compile, by the phase's name here
+_PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile"}
+#: the persistent cache's read, fired as a duration only, on a hit
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: installed once: the offset from ``time.time()`` (jax's clock for the
+#: spans) to ``time.perf_counter()`` (the tracer's), and the counters
+_PHASES: Dict[str, Any] = {}
+_PHASES_LOCK = threading.Lock()
+
+
+def watch_compile_phases():
+    """Install the listener pair (idempotent; every network's ``init`` and
+    every :class:`MonitoredJit` calls it, so it is there before a process's
+    first compile of its own). Each phase of each compile becomes a span
+    recorded after the fact under whatever span is open on the compiling
+    thread. Only the outermost trace is recorded: every ``jnp`` function is
+    a jitted one, so tracing a step fires the event once per op it calls
+    (thousands of records whose time is the outer trace's). A program that
+    compiles while another is traced (a constant computed eagerly) still
+    nests, and the backend's span holds the cache's read where there is
+    one, so a reader that wants seconds takes the union of the intervals
+    per thread."""
+    if _PHASES:
+        return
+    import jax
+    from jax import monitoring
+    from .registry import get_registry
+    reg = get_registry()
+    with _PHASES_LOCK:
+        if _PHASES:
+            return
+        _PHASES.update(
+            seconds={phase: reg.counter(
+                "jax_compile_phase_seconds_total",
+                "seconds in each phase of every jax compile of the process",
+                phase=phase)
+                for phase in (*_PHASE_EVENTS.values(), "cache_retrieval")},
+            compiles=reg.counter(
+                "jax_compiles_total",
+                "programs handed to the backend's compiler or read from "
+                "the persistent cache, the eager one-op programs among them"),
+            offset=time.perf_counter() - time.time(),
+            # the tracing context is per thread; asked when a trace has
+            # ended, it says whether another encloses it
+            top_level=jax.core.trace_ctx.is_top_level)
+    monitoring.register_event_time_span_listener(_on_phase_span)
+    monitoring.register_event_duration_secs_listener(_on_cache_retrieval)
+
+
+def _note_phase(phase, start, dur, **args):
+    try:
+        _PHASES["seconds"][phase].inc(max(dur, 0.0))
+        if phase == "backend_compile":
+            _PHASES["compiles"].inc()
+        from .tracer import get_tracer
+        get_tracer().record_complete(f"jax/{phase}", start, dur,
+                                     cat="compile", **args)
+    except Exception as e:
+        # a listener runs inside jax's compile path: never raise into it
+        log.debug("jitwatch: compile phase %s not recorded: %r", phase, e)
+
+
+def _on_phase_span(event, start_time, end_time, **kw):
+    phase = _PHASE_EVENTS.get(event)
+    if phase == "trace" and not _PHASES["top_level"]():
+        return
+    if phase is not None:
+        _note_phase(phase, start_time + _PHASES["offset"],
+                    end_time - start_time,
+                    fun_name=str(kw.get("fun_name", "")))
+
+
+def _on_cache_retrieval(event, duration, **kw):
+    if event == _RETRIEVAL_EVENT:     # fired right after the read
+        _note_phase("cache_retrieval", time.perf_counter() - duration,
+                    duration)
 
 
 # ------------------------------------------------------------- registry
@@ -326,6 +416,7 @@ class MonitoredJit:
 
     def __init__(self, fn, name: Optional[str] = None, **jit_kwargs):
         import jax
+        watch_compile_phases()
         self._fn = fn
         self.name = name or getattr(fn, "__qualname__",
                                     getattr(fn, "__name__", "jit_fn"))
@@ -459,7 +550,8 @@ class MonitoredJit:
         from .tracer import get_tracer
         get_tracer().record_complete(f"compile/{self.name}", t0, dur,
                                      cat="compile", fn=self.name,
-                                     signature_delta=delta)
+                                     signature_delta=delta,
+                                     persistent_hit=bool(phit))
         sig_key = ";".join(f"{k}={v}" for k, v in sig[0]) if sig else "?"
         reg = get_jit_registry()
         reg.note_compile(self.name, dur, sig_key, delta,
@@ -622,7 +714,11 @@ def _capture_cost_task(jitted, name, sig_key, a_args, a_kwargs):
         # FOREGROUND compile racing this worker would claim it and read
         # as a disk hit it never had) — compilecache.suppress_events
         from ..compilecache.cache import suppress_events
-        with suppress_events():
+        from .tracer import get_tracer
+        # the span is this worker thread's: the second lowering and compile
+        # stay out of any sum over the thread that called the step
+        with suppress_events(), get_tracer().span(
+                "jitwatch/cost_capture", cat="setup", fn=name):
             _capture_cost_now(jitted, name, sig_key, a_args, a_kwargs)
     except Exception as e:
         log.debug("jitwatch: cost capture for %s failed: %r", name, e)
@@ -740,7 +836,9 @@ def profile_report() -> Dict[str, Any]:
     """The step-anatomy report (``GET /profile`` / ``monitor --profile``):
     per-fn jit table + device memory + the step/ETL timing split, merged
     from the monitor registry — one view answering "where does a step's
-    wall-clock actually go: compute, compile, or ETL?"."""
+    wall-clock actually go: compute, compile, or ETL?" — and, from the
+    tracer's kept spans, ``startup``: where the time before the first
+    step went."""
     from .registry import get_registry
     snap = get_registry().snapshot()
 
@@ -766,7 +864,94 @@ def profile_report() -> Dict[str, Any]:
         "locks": _locks_block(),
         "control": _control_block(),
         "trends": _trends_block(),
+        "startup": _startup_block(),
     }
+
+
+def _covered(intervals) -> float:
+    """Seconds the ``(start, end)`` intervals cover, overlaps once."""
+    total, edge = 0.0, float("-inf")
+    for s, e in sorted(intervals):
+        if e > edge:
+            total += e - max(s, edge)
+            edge = e
+    return total
+
+
+def _startup_block() -> Dict[str, Any]:
+    """Why this process took as long as it did to reach its first step,
+    from the tracer's kept spans (``monitor/tracer.py``, the third sink):
+    every ``init`` with what it drew and sent, the phases of every compile
+    (per thread, a trace nested in a trace once; the backend's span holds
+    the cache's read), jitwatch's own cost captures, the model's placements
+    under ``ParallelWrapper``, and the seconds from the first ``init``'s
+    start to the end of the first compile of a step. Empty until a network
+    was initialised or something compiled."""
+    from .tracer import get_tracer
+    tracer = get_tracer()
+    kept = tracer.kept()
+    if not kept:
+        return {}
+    children: Dict[int, List[Dict]] = {}
+    for r in kept:
+        children.setdefault(r["parent_span_id"], []).append(r)
+
+    def seconds(r):
+        return round(r["end"] - r["start"], 6)
+
+    inits = []
+    for r in kept:
+        if r["name"] != "init":
+            continue
+        row = {k: r["args"].get(k)
+               for k in ("network", "leaves", "parameters", "bytes")}
+        row["seconds"] = seconds(r)
+        for child in children.get(r["span_id"], ()):
+            if child["name"] == "init/params":
+                row["params_s"] = seconds(child)
+                row["draw_s"] = round(child["args"].get("draw_s", 0.0), 6)
+                row["place_s"] = round(child["args"].get("place_s", 0.0), 6)
+            elif child["name"] == "init/updater_state":
+                row["updater_state_s"] = seconds(child)
+        inits.append(row)
+    phases = {}
+    for phase in (*_PHASE_EVENTS.values(), "cache_retrieval"):
+        by_thread: Dict[int, List] = {}
+        for r in kept:
+            if r["name"] == f"jax/{phase}":
+                by_thread.setdefault(r["tid"], []).append(
+                    (r["start"], r["end"]))
+        phases[phase] = round(sum(map(_covered, by_thread.values())), 6)
+
+    def named(name):
+        return [r for r in kept if r["name"] == name]
+
+    out: Dict[str, Any] = {
+        "inits": inits,
+        "compile_phase_s": phases,
+        "compiles": len(named("jax/backend_compile")),
+        "cost_capture_s": round(sum(
+            r["end"] - r["start"] for r in named("jitwatch/cost_capture")), 6),
+        "place_model_s": round(sum(
+            r["end"] - r["start"] for r in named("pw/place_model")), 6),
+        "kept": len(kept), "kept_dropped": tracer.kept_dropped,
+    }
+    if inits:
+        first_init = min(r["start"] for r in named("init"))
+        compiled = [r["end"] for r in kept
+                    if r["name"].startswith("compile/")
+                    and _dispatches_step(r["args"].get("fn", ""))
+                    and r["start"] >= first_init]
+        if compiled:
+            out["init_to_first_step_compiled_s"] = round(
+                min(compiled) - first_init, 6)
+    return out
+
+
+def _dispatches_step(fn: str) -> bool:
+    """Whether the monitored function ``fn`` is a train step (``cg/step``,
+    ``mln/step``, ``nn/tbptt_scan``, ``sharding/dp_step``, …)."""
+    return "step" in fn or fn.endswith("_scan")
 
 
 def _mesh_block() -> Dict[str, Any]:
@@ -1165,6 +1350,29 @@ def render_profile_text(report: Dict[str, Any]) -> str:
                          f"outcome={last.get('outcome')} "
                          f"rule={last.get('rule')} "
                          f"exemplar={last.get('exemplar_trace_id')}")
+    startup = report.get("startup") or {}
+    if startup:
+        lines.append("")
+        lines.append("# startup (kept set-up spans — monitor/tracer.py)")
+        for r in startup.get("inits", ()):
+            lines.append(
+                f"init {r.get('network')}: {r['seconds']}s for "
+                f"{r.get('parameters')} parameters in {r.get('leaves')} "
+                f"leaves ({r.get('bytes')} bytes): drawn "
+                f"{r.get('draw_s', '-')}s, placed {r.get('place_s', '-')}s, "
+                f"updater state {r.get('updater_state_s', '-')}s")
+        ph = startup.get("compile_phase_s") or {}
+        lines.append(
+            f"compiles={startup.get('compiles', 0)} "
+            + " ".join(f"{k}={v}s" for k, v in ph.items()))
+        lines.append(
+            f"cost_capture={startup.get('cost_capture_s')}s "
+            f"place_model={startup.get('place_model_s')}s "
+            f"kept={startup.get('kept')} "
+            f"kept_dropped={startup.get('kept_dropped')}")
+        if "init_to_first_step_compiled_s" in startup:
+            lines.append("first init to first step compiled: "
+                         f"{startup['init_to_first_step_compiled_s']}s")
     trends = report.get("trends") or {}
     if trends:
         lines.append("")

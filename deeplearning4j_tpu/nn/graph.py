@@ -33,7 +33,7 @@ from jax.ad_checkpoint import checkpoint_name
 from .layers import impl_for
 from .training import _TrainingBase, _device_arrays
 from ..datasets.dataset import MultiDataSet
-from ..optimize.updater import NetworkUpdater, normalize_gradients
+from ..optimize.updater import normalize_gradients
 
 _tm = jax.tree_util.tree_map
 
@@ -63,7 +63,7 @@ class ComputationGraph(_TrainingBase):
         self._types = None
 
     # ------------------------------------------------------------------ init
-    def init(self, params=None):
+    def _init(self, params):
         conf = self.conf
         # shape inference (idempotent; from_json configs arrive unresolved)
         types = conf.infer_shapes()
@@ -79,25 +79,13 @@ class ComputationGraph(_TrainingBase):
                 it = conf.input_preprocessors[name].get_output_type(it)
             self.impls[name] = impl_for(conf.vertices[name], self.gc, it)
             self.impls[name].index = name
-        if params is not None:
-            self.params = params
-            self.states = {n: self.impls[n].init(k)[1]
-                           for n, k in zip(layer_names, keys)}
-        else:
-            self.params, self.states = {}, {}
-            for name, k in zip(layer_names, keys):
-                p, s = self.impls[name].init(k)
-                self.params[name] = p
-                self.states[name] = s
+        self._init_layers([(n, self.impls[n], k)
+                           for n, k in zip(layer_names, keys)], params)
         for name in layer_names:
             self._check_tie(name)
-        layer_updaters = {}
-        for name in layer_names:
-            u = getattr(conf.vertices[name], "updater", None) or self.gc.updater
-            layer_updaters[name] = u
-        self.updater = NetworkUpdater(layer_updaters)
-        self.updater_state = self.updater.init_state(self.params)
-        return self
+        self._init_updater({
+            name: getattr(conf.vertices[name], "updater", None)
+            or self.gc.updater for name in layer_names})
 
     # ------------------------------------------------------------ tied leaf
     def _check_tie(self, name):

@@ -29,7 +29,6 @@ from jax.ad_checkpoint import checkpoint_name
 
 from .layers import impl_for
 from .training import _TrainingBase, _device_arrays
-from ..optimize.updater import NetworkUpdater
 from ..monitor.jitwatch import monitored_jit
 
 _tm = jax.tree_util.tree_map
@@ -45,8 +44,7 @@ class MultiLayerNetwork(_TrainingBase):
         self._rnn_state = None      # streaming state for rnn_time_step
 
     # ------------------------------------------------------------------ init
-    def init(self, params=None):
-        """Build layer impls and initialize parameters (reference ``init()`` :541)."""
+    def _init(self, params):
         layers = self.conf.layers
         # resolve per-layer input types (best effort; None when unknown)
         input_types = [None] * len(layers)
@@ -86,25 +84,12 @@ class MultiLayerNetwork(_TrainingBase):
             self.impls.append(impl)
         key = jax.random.PRNGKey(self.gc.seed)
         self._rng, *layer_keys = jax.random.split(key, len(layers) + 1)
-        if params is not None:
-            self.params = params
-            self.states = {str(i): impl.init(layer_keys[i])[1]
-                           for i, impl in enumerate(self.impls)}
-        else:
-            self.params = {}
-            self.states = {}
-            for i, impl in enumerate(self.impls):
-                p, s = impl.init(layer_keys[i])
-                self.params[str(i)] = p
-                self.states[str(i)] = s
+        self._init_layers([(str(i), impl, layer_keys[i])
+                           for i, impl in enumerate(self.impls)], params)
         # one updater per layer: per-layer override or global default
-        layer_updaters = {}
-        for i, lc in enumerate(layers):
-            u = getattr(lc, "updater", None) or self.gc.updater
-            layer_updaters[str(i)] = u
-        self.updater = NetworkUpdater(layer_updaters)
-        self.updater_state = self.updater.init_state(self.params)
-        return self
+        self._init_updater({
+            str(i): getattr(lc, "updater", None) or self.gc.updater
+            for i, lc in enumerate(layers)})
 
     # -------------------------------------------------------------- forward
     def _apply_layers(self, params, states, x, fmask, train, rng, upto=None,

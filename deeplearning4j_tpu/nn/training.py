@@ -24,9 +24,10 @@ from .conf.dropout import apply_constraints
 from .layers.base import remat_enabled, remat_policy
 from ..datasets.dataset import DataSet, MultiDataSet, ListDataSetIterator
 from ..datasets.prefetch import wrap_for_training
-from ..optimize.updater import normalize_gradients
+from .weights import read_init_clock, reset_init_clock
+from ..optimize.updater import NetworkUpdater, normalize_gradients
 from .. import monitor as _mon
-from ..monitor.jitwatch import monitored_jit
+from ..monitor.jitwatch import monitored_jit, watch_compile_phases
 
 log = logging.getLogger(__name__)
 
@@ -125,6 +126,20 @@ def _device_arrays(ds, cached=False):
     return _map_streams(jnp.asarray, host)
 
 
+@contextlib.contextmanager
+def _drawing(name):
+    """The set-up span ``name`` around eager initialisers: every array is
+    made on the host and handed to the device by ``nn/weights.py``, which
+    sums its two parts of the time on this thread; the span takes the sums
+    as ``draw_s`` and ``place_s`` over ``leaves`` arrays when it closes (two
+    clock reads an array and a half, and no span per leaf)."""
+    reset_init_clock()
+    span = _mon.get_tracer().span(name, cat="setup")
+    with span:
+        yield
+        span.note(**read_init_clock())
+
+
 class _TrainingBase:
     """What both containers train through. A container supplies:
 
@@ -164,6 +179,41 @@ class _TrainingBase:
     def _before_fit(self, iterator):
         """Called by ``fit`` with the data as an iterator, before the
         prefetch pipeline wraps it."""
+
+    # ----------------------------------------------------------------- init
+    def init(self, params=None):
+        """Build the layers' implementations and initialise parameters, layer
+        state and updater state (reference ``init()``,
+        ``MultiLayerNetwork.java`` :541, ``ComputationGraph.java`` :394);
+        ``params`` given, they are taken as they are. The call is the
+        ``init`` span (``cat="setup"``: kept by the tracer whatever the
+        monitor switch says, docs/OBSERVABILITY.md "Start-up"), with
+        ``init/params`` and ``init/updater_state`` below it."""
+        watch_compile_phases()
+        span = _mon.get_tracer().span("init", cat="setup",
+                                      network=self._jit_prefix)
+        with span:
+            self._init(params)
+            leaves = jax.tree_util.tree_leaves(self.params)
+            span.note(leaves=len(leaves), parameters=self.num_params(),
+                      bytes=sum(int(v.nbytes) for v in leaves))
+        return self
+
+    def _init_layers(self, keyed, params):
+        """``params`` and ``states`` from the ``(params key, impl, rng)``
+        triples ``keyed``, under the ``init/params`` span."""
+        with _drawing("init/params"):
+            made = {key: impl.init(rng) for key, impl, rng in keyed}
+        self.params = (params if params is not None
+                       else {key: p for key, (p, _) in made.items()})
+        self.states = {key: s for key, (_, s) in made.items()}
+
+    def _init_updater(self, layer_updaters):
+        """One updater per layer, and its state for the parameters (Adam's
+        zeros), under the ``init/updater_state`` span."""
+        self.updater = NetworkUpdater(layer_updaters)
+        with _drawing("init/updater_state"):
+            self.updater_state = self.updater.init_state(self.params)
 
     # ---------------------------------------------------------- train step
     def _raw_update_core(self, grads_reduce=None):

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import threading
+import time
 from typing import Optional
 
 import jax
@@ -37,6 +39,43 @@ def _np_rng(rng):
     return np.random.default_rng([int(x) for x in arr])
 
 
+class _InitClock(threading.local):
+    """What the eager initialisers below spent on this thread since
+    :func:`reset_init_clock`: seconds making arrays on the host
+    (``draw_s``: seeding, sampling, filling), seconds in the
+    ``jnp.asarray`` that hands each to the device (``place_s``), and how
+    many arrays (``leaves``). A network's ``init()`` resets it and reads
+    it into its ``init/params`` span (``nn/training.py``): two sums, and
+    no span per leaf (ResNet50 has some five hundred)."""
+    draw_s = 0.0
+    place_s = 0.0
+    leaves = 0
+
+
+_CLOCK = _InitClock()
+
+
+def reset_init_clock():
+    _CLOCK.draw_s, _CLOCK.place_s, _CLOCK.leaves = 0.0, 0.0, 0
+
+
+def read_init_clock():
+    return {"draw_s": _CLOCK.draw_s, "place_s": _CLOCK.place_s,
+            "leaves": _CLOCK.leaves}
+
+
+def _place(out, t0):
+    """``jnp.asarray(out)`` for a host array made since ``t0``, both parts
+    of the time added to this thread's :class:`_InitClock`."""
+    t1 = time.perf_counter()
+    arr = jnp.asarray(out)
+    t2 = time.perf_counter()
+    _CLOCK.draw_s += t1 - t0
+    _CLOCK.place_s += t2 - t1
+    _CLOCK.leaves += 1
+    return arr
+
+
 #: elements ``_normal`` samples at a time (1 MB of float64)
 _CHUNK = 1 << 17
 
@@ -45,6 +84,7 @@ def _normal(rng, shape, dtype, scale=1.0, shift=0.0):
     """Sampling, scaling and shifting all happen host-side in the eager path:
     an eager device multiply/add would compile one tiny program per distinct
     shape, re-creating the init blowup _np_rng exists to kill."""
+    t0 = time.perf_counter()
     g = _np_rng(rng)
     if g is None:
         return jax.random.normal(rng, shape, dtype) * scale + shift
@@ -62,21 +102,23 @@ def _normal(rng, shape, dtype, scale=1.0, shift=0.0):
         draw *= scale
         draw += shift
         part[...] = draw
-    return jnp.asarray(out)
+    return _place(out, t0)
 
 
 def _uniform(rng, shape, dtype, lo, hi):
+    t0 = time.perf_counter()
     g = _np_rng(rng)
     if g is None:
         return jax.random.uniform(rng, shape, dtype, lo, hi)
-    return jnp.asarray(g.uniform(lo, hi, size=shape).astype(dtype))
+    return _place(g.uniform(lo, hi, size=shape).astype(dtype), t0)
 
 
 def host_full(shape, value, dtype):
     """Eager constant init without an XLA compile: numpy fill + device_put.
     (Eager ``jnp.full``/``jnp.zeros`` compiles a tiny program per distinct
     shape — see ``_np_rng``.)"""
-    return jnp.asarray(np.full(shape, value, dtype=np.dtype(dtype)))
+    t0 = time.perf_counter()
+    return _place(np.full(shape, value, dtype=np.dtype(dtype)), t0)
 
 
 class WeightInit:

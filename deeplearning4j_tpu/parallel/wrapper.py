@@ -548,10 +548,15 @@ class ParallelWrapper:
         """Place params/updater-state with EXACTLY the specs the jitted
         step was built with (``composed_specs`` is the single source of
         truth for both) — TP rules claim the model axis, ZeRO flags layer
-        the data axis; everything else replicates."""
+        the data axis; everything else replicates. A set-up span (kept by
+        the tracer whatever the monitor switch says): one per ``fit``."""
         net = self.net
         put = lambda t: _tm(lambda x: put_replicated(x, self.mesh), t)
-        with get_tracer().span("pw/place_model", cat="train"):
+        leaves = jax.tree_util.tree_leaves(
+            (net.params, net.states, net.updater_state))
+        with get_tracer().span("pw/place_model", cat="setup",
+                               leaves=len(leaves),
+                               bytes=sum(int(x.nbytes) for x in leaves)):
             par, upd = composed_specs(
                 net, self.mesh, tp_rules=self.tp_rules,
                 shard_update=self.weight_update_sharding,

@@ -4,6 +4,7 @@ detector (shape churn trips it, padded shapes don't), device-memory
 gauges, the /profile step-anatomy report, and the ProfilerListener
 close-on-error regression."""
 import json
+import threading
 import urllib.request
 
 import numpy as np
@@ -48,6 +49,125 @@ def _net(seed=1):
 def _ds(batch, rng):
     return DataSet(rng.normal(size=(batch, 4)).astype(np.float32),
                    np.eye(3, dtype=np.float32)[rng.integers(0, 3, batch)])
+
+
+# ---------------------------------------------------------- compile phases
+def _kept(prefix, since=0, thread=None):
+    return [r for r in get_tracer().kept()[since:]
+            if r["name"].startswith(prefix)
+            and thread in (None, r["tid"])]
+
+
+class TestCompilePhases:
+    """``watch_compile_phases``: every jax compile by phase, as kept spans
+    (docs/OBSERVABILITY.md "Start-up")."""
+
+    @pytest.fixture(autouse=True)
+    def _monitor_off(self):
+        import deeplearning4j_tpu.monitor as monitor
+        monitor.set_enabled(False)
+        get_tracer().clear()
+        yield
+        monitor.set_enabled(True)
+
+    def test_first_call_leaves_its_phases_under_compile_and_a_second_none(
+            self):
+        f = monitored_jit(lambda x: jnp.tanh(x) @ x.T, name="test/phases")
+        f(jnp.ones((3, 5)))
+        (compile_,) = _kept("compile/test/phases")
+        assert compile_["cat"] == "compile" and compile_["args"][
+            "persistent_hit"] is False
+        assert len(get_tracer()) == 0          # the ring obeys the switch
+        inside = [r for r in _kept("jax/")
+                  if r["tid"] == compile_["tid"]
+                  and compile_["start"] <= r["start"]
+                  and r["end"] <= compile_["end"] + 1e-3]
+        assert {r["name"] for r in inside} == {
+            "jax/trace", "jax/lower", "jax/backend_compile"}
+        assert all(r["cat"] == "compile" and r["args"]["fun_name"]
+                   for r in inside)
+        assert sum(r["end"] - r["start"] for r in inside) <= \
+            compile_["end"] - compile_["start"] + 1e-3
+        # spans made after the fact are siblings under the caller's span
+        assert {r["parent_span_id"] for r in inside} == {
+            compile_["parent_span_id"]}
+        before = len(get_tracer().kept())
+        f(jnp.ones((3, 5)))
+        assert get_tracer().kept()[before:] == []
+
+    def test_a_function_jitted_inside_a_jitted_function_is_traced_once(self):
+        import jax
+        inner = jax.jit(lambda x: jnp.sin(x) * 2)
+        outer = monitored_jit(lambda x: inner(x) + jnp.cos(x),
+                              name="test/nested")
+        x = jnp.ones((7,))
+        since = len(get_tracer().kept())
+        outer(x)
+        # not the inner function's trace, nor one per jnp op it calls
+        me = threading.get_ident()   # the cost worker traces it once more
+        (trace,) = _kept("jax/trace", since, me)
+        (compile_,) = _kept("compile/test/nested", since)
+        assert compile_["start"] <= trace["start"] < trace["end"] \
+            <= compile_["end"]
+        assert len(_kept("jax/backend_compile", since, me)) == 1
+
+    def test_counters_move_only_with_a_compile(self, monkeypatch):
+        from deeplearning4j_tpu.monitor import jitwatch
+        monkeypatch.setattr(jitwatch, "_COST_CAPTURE", False)  # its own compile
+        reg = get_registry()
+        compiles = reg.counter("jax_compiles_total")
+        seconds = {p: reg.counter("jax_compile_phase_seconds_total", phase=p)
+                   for p in ("trace", "lower", "backend_compile")}
+        f = monitored_jit(lambda x: x * 5 - 1, name="test/phase_counters")
+        x = jnp.ones((9,))
+        n, was = compiles.value, {p: c.value for p, c in seconds.items()}
+        f(x)
+        assert compiles.value == n + 1
+        assert all(seconds[p].value > was[p] for p in seconds)
+        n, was = compiles.value, {p: c.value for p, c in seconds.items()}
+        f(x)
+        assert compiles.value == n
+        assert all(seconds[p].value == was[p] for p in seconds)
+
+    def test_cost_capture_is_a_span_of_the_worker_thread(self):
+        from deeplearning4j_tpu.monitor.jitwatch import wait_cost_captures
+        f = monitored_jit(lambda x: x @ x, name="test/cost_span")
+        f(jnp.ones((4, 4)))
+        assert wait_cost_captures(30.0)
+        (capture,) = _kept("jitwatch/cost_capture")
+        assert capture["cat"] == "setup" and capture["args"] == {
+            "fn": "test/cost_span"}
+        assert capture["tid"] != threading.get_ident()
+        # what it lowers again is on its thread and under its span
+        again = [r for r in _kept("jax/") if r["tid"] == capture["tid"]]
+        assert again and all(r["parent_span_id"] == capture["span_id"]
+                             for r in again)
+
+    def test_profile_startup_block_names_all_of_it(self):
+        from deeplearning4j_tpu.monitor.jitwatch import wait_cost_captures
+        rng = np.random.default_rng(0)
+        dropped = get_tracer().kept_dropped   # the process's: clear() keeps it
+        net = _net()
+        net.fit(_ds(8, rng))
+        assert wait_cost_captures(30.0)
+        startup = profile_report()["startup"]
+        (row,) = startup["inits"]
+        assert row["network"] == "mln" and row["parameters"] == \
+            net.num_params() and row["leaves"] == 4
+        assert 0 < row["draw_s"] + row["place_s"] <= row["params_s"] + 1e-5
+        assert row["params_s"] + row["updater_state_s"] <= row["seconds"]
+        assert set(startup["compile_phase_s"]) == {
+            "trace", "lower", "backend_compile", "cache_retrieval"}
+        assert startup["compile_phase_s"]["trace"] > 0
+        assert startup["compiles"] >= 1 and startup["cost_capture_s"] > 0
+        assert startup["kept"] == len(get_tracer().kept())
+        assert startup["kept_dropped"] == dropped
+        assert startup["init_to_first_step_compiled_s"] >= row["seconds"]
+        text = render_profile_text(profile_report())
+        assert "# startup" in text and "init mln:" in text
+        assert "first init to first step compiled" in text
+        get_tracer().clear()
+        assert profile_report()["startup"] == {}
 
 
 # ------------------------------------------------------------ monitored_jit

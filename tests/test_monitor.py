@@ -186,15 +186,96 @@ class TestTracer:
         assert handle["args"]["trace_id"] == rpc["args"]["trace_id"]
         assert handle["args"]["parent_span_id"] == rpc["args"]["span_id"]
 
-    def test_decorator(self):
+    @pytest.mark.parametrize("cat,kept", [("setup", True), ("compile", True),
+                                          ("train", False), ("host", False)])
+    def test_setup_and_compile_spans_are_kept_with_the_monitor_off(
+            self, cat, kept, monkeypatch):
+        """The third sink: the ring obeys the switch, the kept list only
+        the span's category."""
+        from deeplearning4j_tpu.monitor import tracer as tracer_mod
+        monkeypatch.setattr(tracer_mod, "_ENABLED", False)
         tr = Tracer()
+        with tr.span("work", cat=cat, n=3):
+            pass
+        tr.record_complete("after/the/fact", time.perf_counter() - 0.5, 0.5,
+                           cat=cat)
+        assert tr.events() == []
+        names = [r["name"] for r in tr.kept()]
+        assert names == (["work", "after/the/fact"] if kept else [])
 
-        @tr.trace(cat="test")
-        def add(a, b):
-            return a + b
+    def test_kept_record_is_on_perf_counter_under_the_open_span(self):
+        tr = Tracer()
+        t0 = time.perf_counter()
+        outer = tr.span("init", cat="setup", network="mln")
+        with outer as ctx:
+            with tr.span("init/params", cat="setup"):
+                time.sleep(0.002)
+            tr.record_complete("jax/trace", time.perf_counter() - 0.001,
+                               0.001, cat="compile", fun_name="f")
+            outer.note(leaves=4)
+        t1 = time.perf_counter()
+        child, phase, parent = tr.kept()
+        assert parent["name"] == "init" and parent["parent_span_id"] == 0
+        assert parent["args"] == {"network": "mln", "leaves": 4}
+        assert t0 <= parent["start"] <= child["start"] < child["end"] \
+            <= parent["end"] <= t1
+        assert child["end"] - child["start"] >= 0.002
+        for r in (child, phase):
+            assert r["parent_span_id"] == parent["span_id"] == ctx.span_id
+            assert r["trace_id"] == parent["trace_id"] == ctx.trace_id
+            assert r["tid"] == threading.get_ident()
+        assert phase["args"] == {"fun_name": "f"} and phase["cat"] == "compile"
 
-        assert add(1, 2) == 3
-        assert tr.export()["traceEvents"][0]["name"].endswith("add")
+    def test_kept_list_is_bounded_keeps_the_first_and_counts_the_rest(self):
+        tr = Tracer(kept_capacity=4)
+        for i in range(7):
+            with tr.span(f"s{i}", cat="setup"):
+                pass
+        assert [r["name"] for r in tr.kept()] == ["s0", "s1", "s2", "s3"]
+        assert tr.kept_dropped == 3
+        assert len(tr) == 7               # the ring took them all
+
+    def test_export_carries_a_kept_span_once_and_clear_clears_both(self):
+        tr = Tracer(capacity=2)
+        with tr.span("init", cat="setup"):
+            pass
+        both = tr.export()["traceEvents"]     # in the ring and kept: once
+        assert [e["name"] for e in both] == ["init"]
+        for i in range(3):                    # the ring wraps, start-up stays
+            with tr.span(f"step{i}", cat="train"):
+                pass
+        events = tr.export()["traceEvents"]
+        assert [e["name"] for e in events] == ["init", "step1", "step2"]
+        first = events[0]
+        assert first == both[0]               # the ring's ts, ids in hex
+        assert first["cat"] == "setup" and first["ph"] == "X"
+        assert int(first["args"]["span_id"], 16) == tr.kept()[0]["span_id"]
+        tr.clear()
+        assert tr.kept() == [] and tr.export()["traceEvents"] == []
+
+    def test_a_kept_span_costs_microseconds(self, monkeypatch):
+        """What the always-written sink costs beside a plain span, monitor
+        off (the benchmark's state): a lock and a dict per kept span,
+        nothing per plain one. Printed for PERF.md; held to a loose bound."""
+        from deeplearning4j_tpu.monitor import tracer as tracer_mod
+        monkeypatch.setattr(tracer_mod, "_ENABLED", False)
+        n = 2000
+
+        def per_span_us(cat):
+            tr = Tracer(kept_capacity=n)
+            t = time.perf_counter()
+            for _ in range(n):
+                with tr.span("s", cat=cat):
+                    pass
+            return (time.perf_counter() - t) / n * 1e6, tr
+
+        per_span_us("train")                  # warm the annotation's import
+        plain = min(per_span_us("train")[0] for _ in range(3))
+        kept, tr = min((per_span_us("setup") for _ in range(3)),
+                       key=lambda found: found[0])
+        assert len(tr.kept()) == n and tr.kept_dropped == 0
+        print(f"plain span {plain:.2f} us, kept span {kept:.2f} us")
+        assert kept - plain < 100.0
 
     def test_fit_produces_nested_step_spans(self):
         tracer = get_tracer()

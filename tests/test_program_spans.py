@@ -3,7 +3,9 @@ docs/OBSERVABILITY.md "Span Tracer"): with the monitor off they reach a
 profiler trace and not the ring buffer, with it on the ring holds them under
 ``epoch``; and the completion fetch that left the ``step`` span
 (``monitor.StepCompletions``): never the newest loss without a listener,
-eager with one, the counters whole when ``fit`` returns."""
+eager with one, the counters whole when ``fit`` returns; and the set-up
+spans (``init`` and its two children, ``pw/place_model``), which the tracer
+keeps whatever the switch says."""
 import glob
 
 import jax
@@ -35,11 +37,11 @@ def _builder():
             .updater(Sgd(learning_rate=0.1)).activation("tanh"))
 
 
-def _mln():
+def _mln(params=None):
     return MultiLayerNetwork(
         _builder().list().layer(DenseLayer(n_in=4, n_out=8))
         .layer(OutputLayer(n_in=8, n_out=3, activation="softmax",
-                           loss="mcxent")).build()).init()
+                           loss="mcxent")).build()).init(params)
 
 
 def _graph():
@@ -328,3 +330,63 @@ def test_ready_losses_resolve_without_waiting_and_errors_still_drain():
     with pytest.raises(RuntimeError, match="iterator broke"):
         net.fit(boom())
     assert _iterations() - before == 3
+
+
+# ----------------------------------------------------------- set-up spans
+def _kept(name):
+    return [r for r in get_tracer().kept() if r["name"] == name]
+
+
+@pytest.mark.parametrize("build,network", [(_mln, "mln"), (_graph, "cg")],
+                         ids=["multilayer", "graph"])
+def test_init_is_a_kept_span_over_its_params_and_updater_state(
+        build, network, monitor_off):
+    get_tracer().clear()
+    net = build()
+    assert len(get_tracer()) == 0               # the ring obeys the switch
+    (init,), (params,), (state,) = (_kept("init"), _kept("init/params"),
+                                    _kept("init/updater_state"))
+    leaves = jax.tree_util.tree_leaves(net.params)
+    assert init["cat"] == "setup" and init["args"] == {
+        "network": network, "leaves": len(leaves),
+        "parameters": net.num_params(),
+        "bytes": sum(x.nbytes for x in leaves)}
+    for child in (params, state):
+        assert child["parent_span_id"] == init["span_id"]
+        assert init["start"] <= child["start"] <= child["end"] <= init["end"]
+    assert params["end"] <= state["start"]
+    seconds = lambda r: r["end"] - r["start"]
+    assert seconds(init) > seconds(params) + seconds(state)
+    # what nn/weights.py summed into each: host drawing and the hand-over
+    # (Sgd keeps no state: the updater's span is there and made nothing)
+    assert params["args"]["leaves"] == len(leaves)
+    assert state["args"] == {"draw_s": 0.0, "place_s": 0.0, "leaves": 0}
+    args = params["args"]
+    assert set(args) == {"draw_s", "place_s", "leaves"}
+    assert args["draw_s"] > 0 and args["place_s"] > 0
+    assert args["draw_s"] + args["place_s"] <= seconds(params)
+
+
+def test_init_with_given_parameters_takes_them_and_draws_the_state():
+    given = _mln().params
+    get_tracer().clear()
+    net = _mln(given)
+    assert net.params is given and set(net.states) == set(given)
+    assert _kept("init")[0]["args"]["parameters"] == net.num_params()
+
+
+def test_parallel_wrapper_places_the_model_once_per_fit(monitor_off):
+    get_tracer().clear()
+    net = _mln()
+    wrapper = (ParallelWrapper.Builder(net).workers(4)
+               .training_mode(TrainingMode.AVERAGING).averaging_frequency(1)
+               .build())
+    state = jax.tree_util.tree_leaves(
+        (net.params, net.states, net.updater_state))
+    for calls in (1, 2):
+        wrapper.fit(ListDataSetIterator(_batches(n=8)))
+        placed = _kept("pw/place_model")
+        assert len(placed) == calls
+    assert all(r["cat"] == "setup" and r["args"] == {
+        "leaves": len(state), "bytes": sum(x.nbytes for x in state)}
+        for r in placed)
