@@ -211,16 +211,24 @@ def remat_policy():
 #: the one name the flash kernels give the residuals their backward reads
 #: (``ops/flash_attention.py``, ``_flash_fwd``)
 FLASH_RES = "dl4j_flash_res"
+#: the name a block gives a sub-layer's output where a norm reads it: a
+#: norm's backward reads the norm's input, and nothing else in the backward
+#: sweep reads that output (``looped.LoopedBlockStackImpl.block``)
+NORM_IN = "dl4j_norm_in"
 #: one object for every stack and run: jax caches a checkpoint's partial
 #: evaluation by its policy, and like sub-programs of two runs stay one
-_BLOCK_POLICY = jax.checkpoint_policies.save_only_these_names(FLASH_RES)
+_BLOCK_POLICY = jax.checkpoint_policies.save_only_these_names(FLASH_RES,
+                                                              NORM_IN)
 
 
 def block_checkpoint(block):
     """``block`` under the block stacks' checkpoint: a block application
-    keeps its input and what the flash kernels' backward reads, and
-    recomputes the rest backward. A block with no flash call (the dense
-    path, a state-space block) tags nothing and keeps its input alone."""
+    keeps its input, what the flash kernels' backward reads and what a
+    post-norm reads (a sub-layer's output that the block named
+    ``NORM_IN``: the projection that made it is not run again for the
+    norm's backward alone), and recomputes the rest backward. A block with
+    no flash call (the dense path, a state-space block) and no post-norm
+    (the hybrid stack's) tags nothing and keeps its input alone."""
     return jax.checkpoint(block, policy=_BLOCK_POLICY)
 
 

@@ -12,7 +12,9 @@ of a pass is followed by the final norm. One block body in the compiled
 program whatever the depth and the number of passes, one stack of kept values
 for the backward sweep (a pass's output is read off it: it is what the next
 pass's first application took), and each weight's gradient summed over the
-passes where it stands (``_take``).
+passes where it stands (``_take``). Training, an application keeps its input,
+the flash kernels' residuals and the FFN's output, which the block's last
+norm reads (``base.block_checkpoint``), and recomputes the rest backward.
 
 The residual stream and every norm's statistics are float32 whatever the
 compute dtype; the gemms and the attention kernel take the compute dtype.
@@ -23,12 +25,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..conf.layers import GatedDenseLayer, SelfAttentionLayer
 from ...monitor import get_registry
 from ..weights import host_full
 from .attention import SelfAttentionImpl
-from .base import LayerImpl, implements, acc_dtype, block_checkpoint
+from .base import (LayerImpl, implements, acc_dtype, block_checkpoint,
+                   NORM_IN)
 from .feedforward import GatedDenseImpl
 from .normalization import rms_norm
 
@@ -152,7 +156,13 @@ class LoopedBlockStackImpl(LayerImpl):
 
     def block(self, p, x, mask=None):
         """One block on the float32 stream ``x`` [b, T, d] with one block's
-        leaves ``p``."""
+        leaves ``p``. A sandwich block: each sub-layer's output passes a norm
+        of its own before it joins the stream, and that norm's backward
+        reads the output. The FFN's carries the checkpoint's name for such a
+        value, so the down-projection is not run again backward for the norm
+        alone; the attention's does not (kept too, the compiler lays the
+        backward sweep's stream out anew and the head after it: PERF.md
+        section 7)."""
         cd = self.compute_dtype
         with jax.named_scope("attn"):
             o, _ = self.attn.forward({k: p[k] for k in ATTN_KEYS}, {},
@@ -162,7 +172,7 @@ class LoopedBlockStackImpl(LayerImpl):
         with jax.named_scope("ffn"):
             f, _ = self.ffn.forward({k: p[k] for k in FFN_KEYS}, {},
                                     self._norm(a, p["g3"]).astype(cd))
-            return a + self._norm(f, p["g4"])
+            return a + self._norm(checkpoint_name(f, NORM_IN), p["g4"])
 
     def forward(self, params, state, x, train=False, rng=None, mask=None, ctx=None):
         c = self.conf
@@ -175,6 +185,17 @@ class LoopedBlockStackImpl(LayerImpl):
             layer=str(getattr(self, "index", ""))).set(passes * blocks)
         x = self.maybe_dropout(x, train, rng).astype(
             acc_dtype(self.compute_dtype))
+        if train:
+            # ``block`` names one value like the stream an application, in
+            # the compute dtype: the FFN's output
+            get_registry().gauge(
+                "looped_kept_norm_input_bytes",
+                "Bytes of post-norm inputs (sub-layer outputs a block named "
+                "for its checkpoint) that one training step of a looped "
+                "block stack keeps for its backward sweep, set when the "
+                "stack's forward is traced",
+                layer=str(getattr(self, "index", ""))).set(
+                    passes * blocks * x.size * self.compute_dtype.itemsize)
         stacked = {k: params[k] for k in ATTN_KEYS + FFN_KEYS + GAIN_KEYS}
         # the leaves' values reach a block as constants of the loop; their
         # gradients travel as the cotangent of ``grads``, which no block reads

@@ -23,6 +23,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.nn.layers import hybrid, looped, mamba
 from deeplearning4j_tpu.nn.layers.attention import mha
+from deeplearning4j_tpu.nn.layers.base import FLASH_RES, NORM_IN
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.utils.model_serializer import ModelSerializer
 
@@ -588,6 +589,15 @@ def _plain_checkpoint(monkeypatch):
                             lambda block: jax.checkpoint(block))
 
 
+def _checkpoint_keeping(monkeypatch, *names):
+    """Both stacks' blocks under a checkpoint whose policy keeps ``names``."""
+    policy = jax.checkpoint_policies.save_only_these_names(*names)
+    for module in (looped, hybrid):
+        monkeypatch.setattr(
+            module, "block_checkpoint",
+            lambda block: jax.checkpoint(block, policy=policy))
+
+
 @pytest.mark.parametrize("stack", sorted(STACKS))
 def test_a_blocks_backward_holds_no_forward_kernel(stack, monkeypatch):
     """The block checkpoint keeps the flash kernels' residuals: the forward
@@ -630,11 +640,30 @@ def test_a_blocks_backward_holds_no_forward_kernel(stack, monkeypatch):
                                    "state_space_only"])
 def test_a_stack_with_no_flash_call_is_the_program_it_was(stack, monkeypatch):
     """Nothing tagged, nothing kept: on the dense path (off the TPU) and in
-    a stack of state-space blocks the policy changes no line."""
+    a stack of state-space blocks the policy changes no line. The looped
+    block names its last norm's input whatever the path (PR 38): there the
+    flash kernels' name changes no line."""
     make = {"looped_dense": _looped_lm, "hybrid_dense": _lm,
             "state_space_only": lambda: _lm(layer_types=["mamba"] * 3)}[stack]
     net, loss = _trained(make, 24)
     text = jax.jit(jax.grad(loss)).lower(net.params).as_text()
     assert ("dot_general" in text) and "flash" not in text
-    _plain_checkpoint(monkeypatch)
+    if stack == "looped_dense":
+        _checkpoint_keeping(monkeypatch, NORM_IN)
+    else:
+        _plain_checkpoint(monkeypatch)
+    assert jax.jit(jax.grad(loss)).lower(net.params).as_text() == text
+
+
+def test_the_hybrid_block_names_no_norm_input(monkeypatch):
+    """The hybrid block has no norm after a sub-layer: it names nothing for
+    the checkpoint, and its step under the one policy, which also keeps what
+    a looped block names (PR 38), is its step under the flash kernels' name
+    alone, kernels and all."""
+    monkeypatch.setattr(fa, "_FORCE_INTERPRET", True)
+    net, loss = _trained(_lm, 2 * fa.MIN_BLOCK)
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss))(net.params))
+    assert FLASH_RES in jaxpr and NORM_IN not in jaxpr
+    text = jax.jit(jax.grad(loss)).lower(net.params).as_text()
+    _checkpoint_keeping(monkeypatch, FLASH_RES)
     assert jax.jit(jax.grad(loss)).lower(net.params).as_text() == text

@@ -24,8 +24,9 @@ from deeplearning4j_tpu.nn.conf.layers import (EmbeddingSequenceLayer,
                                                RnnOutputLayer,
                                                SelfAttentionLayer)
 from deeplearning4j_tpu.nn.graph import ComputationGraph
-from deeplearning4j_tpu.nn.layers import impl_for
+from deeplearning4j_tpu.nn.layers import impl_for, looped
 from deeplearning4j_tpu.nn.layers.attention import mha, rope
+from deeplearning4j_tpu.nn.layers.base import FLASH_RES, NORM_IN
 from deeplearning4j_tpu.nn.layers.looped import ATTN_KEYS, FFN_KEYS
 from deeplearning4j_tpu.nn.layers.output import exit_distribution
 from deeplearning4j_tpu.nn.losses import _reduce, get_loss
@@ -413,16 +414,83 @@ def test_a_passs_output_is_read_off_the_kept_stack(passes):
     grad = _stack_grad(impl, w)
     scan, stacks = stacked_by(grad)
     # the scan's own output and the checkpoint's kept input are one value:
-    # the compiled loops carry it once (forward written, backward read)
-    assert stacks.count(stream) == 2
+    # the compiled loops carry it once (forward written, backward read),
+    # beside the FFN's output, kept for the norm that reads it and, in
+    # float32, as large
+    assert stacks.count(stream) == 3
     text = jax.jit(grad).lower(params, x).compile().as_text()
     carried = [re.findall(r"f32\[%s\]" % ",".join(map(str, stream)), m)
                for m in re.findall(r" = \((.*?)\) while\(", text)]
-    assert [len(c) for c in carried if c] == [1, 1]
+    assert [len(c) for c in carried if c] == [2, 2]
     assert (passes,) + x.shape not in [v.aval.shape for v in scan.outvars]
     scan, stacks = stacked_by(lambda p, x: impl.forward(p, {}, x)[0])
     assert stacks == []
     assert (passes,) + x.shape in [v.aval.shape for v in scan.outvars]
+
+
+def _keep_the_flash_residuals_alone(monkeypatch):
+    """The stack's blocks under the checkpoint as it was before a norm's
+    input had a name: the policy keeps the flash kernels' residuals only."""
+    policy = jax.checkpoint_policies.save_only_these_names(FLASH_RES)
+    monkeypatch.setattr(looped, "block_checkpoint",
+                        lambda block: jax.checkpoint(block, policy=policy))
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
+                                        ("bfloat16", 2.0 ** -8)])
+def test_the_checkpoint_keeps_what_the_last_norm_reads(dtype, tol,
+                                                       monkeypatch):
+    """A block names the FFN's output, which its last norm reads: the
+    forward scan stacks one more value like the stream, in the compute
+    dtype, and the backward scan's body holds one product fewer (the
+    down-projection, which nothing but that norm's backward would read)
+    than under a policy without the name; the gradients are the same."""
+    blocks, passes = 3, 2
+    impl, params, x, w = _stack(passes, dtype)
+    stream = (passes * blocks,) + x.shape
+
+    def read():
+        grad = _stack_grad(impl, w)
+        forward, backward = (e for e, _ in _scans(
+            jax.make_jaxpr(grad)(params, x).jaxpr))
+        products = sum(e.primitive.name == "dot_general" for e in
+                       _plain_equations(backward.params["jaxpr"].jaxpr))
+        kept = sorted(str(v.aval.dtype) for v in forward.outvars
+                      if v.aval.shape == stream)
+        return products, kept, jax.jit(grad)(params, x)
+
+    products, kept, got = read()
+    _keep_the_flash_residuals_alone(monkeypatch)
+    products_before, kept_before, want = read()
+    assert products == products_before - 1
+    assert kept_before == ["float32", "float32"]    # scan's output, input
+    assert kept == sorted(kept_before + [dtype])
+    assert_trees_close(got, want, tol)
+
+
+@pytest.mark.parametrize("flash", [False, True], ids=["dense", "flash"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_an_eager_gradient_keeps_three_things_an_application(dtype, flash,
+                                                             monkeypatch):
+    """Differentiated eagerly (the benchmark's reference check does), nothing
+    is dropped as dead code: what the stack keeps once an application is its
+    input, the named input of the last norm and, where a kernel runs, the
+    flash kernels' residuals; every other residual is a constant of the
+    scan, kept once (no carried value once an application)."""
+    monkeypatch.setattr(fa, "_FORCE_INTERPRET", flash)
+    blocks, passes, t = 3, 2, 2 * fa.MIN_BLOCK if flash else 16
+    impl, params, x, _ = _stack(passes, dtype, t=t)
+    _, back = jax.vjp(lambda p, x: impl.forward(p, {}, x, train=True)[0],
+                      params, x)
+    n = passes * blocks
+    kept = sorted((str(v.dtype), v.shape[1:])
+                  for v in jax.tree_util.tree_leaves(back)
+                  if getattr(v, "ndim", 0) > 1 and v.shape[0] == n)
+    heads = (x.shape[0] * HEADS, t)
+    want = [("float32", x.shape), (dtype, x.shape)]
+    if flash:       # q, k, v, o and a lane of the log-sum-exp
+        want += [(dtype, heads + (HEAD_DIM,))] * 4 + [("float32", heads)]
+    assert kept == sorted(want)
 
 
 def test_the_scan_steps_gauge_reads_sixteen_at_four_by_four(monkeypatch):
@@ -444,6 +512,10 @@ def test_the_scan_steps_gauge_reads_sixteen_at_four_by_four(monkeypatch):
         == [({"layer": "stack"}, 16)]
     assert [r["value"] for r in snap["looped_block_applications"]
             if r["labels"] == {"network": "cg"}] == [16]
+    # one named norm input an application, like the stream in float32
+    assert [(r["labels"], r["value"])
+            for r in snap["looped_kept_norm_input_bytes"]] \
+        == [({"layer": "stack"}, 16 * 2 * T * D * 4)]
 
 
 def test_block_checkpoint_changes_no_number(monkeypatch):
@@ -455,6 +527,7 @@ def test_block_checkpoint_changes_no_number(monkeypatch):
         p, on.states, [jnp.asarray(ds.features)], [jnp.asarray(ds.labels)],
         None, None, True, None)[0])(on.params))
     assert "checkpoint" in text or "remat" in text
+    assert NORM_IN in text          # and a block named its last norm's input
     # and without it every number is the same
     monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: f)
     off = shaken(ComputationGraph(looped_conf(2)).init())
