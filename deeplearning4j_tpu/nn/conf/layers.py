@@ -205,6 +205,51 @@ class MoEDenseLayer(FeedForwardLayer):
 
 @register
 @dataclasses.dataclass
+class RoutedExpertsLayer(FeedForwardLayer):
+    """Routed gated-expert feed-forward layer with a shared expert, of which
+    this chip holds its share (net-new vs the 0.9.x reference; a class
+    beside :class:`MoEDenseLayer`, whose experts are single projections
+    routed by a softmax with a capacity). ``num_experts`` is the published
+    count, the router's width; ``experts_held`` the ids whose weights live
+    here (None: all of them). Over [b, T, n_in] or [b, n_in], per position:
+
+        s   = sigmoid(x Wr)                           [num_experts], float32
+        top = the top_k largest of s + b              b: state, choice only
+        w_e = routed_scaling_factor * s_e / (sum_{j in top} s_j + 1e-20)
+                                                      (``renormalize``)
+        y   = Shared(x) + sum_{e in top and held} w_e Expert_e(x)
+
+    with ``Expert_e(x) = (silu(x Wgate_e) * (x Wup_e)) Wdown_e`` of
+    ``n_hidden`` units and ``Shared`` the same of ``shared_hidden`` (0:
+    none). The sum over ``top`` is the published layer; over the held ones
+    it is this chip's part, and nothing stands in for the chips that hold
+    the others. No choice is dropped whatever the routing: the held choices
+    are sorted by expert, each expert's rows padded to whole tiles, and one
+    loop runs an expert's three products tile by tile: over a standing number
+    of tiles, twice what uniform routing sends here, so that a router that
+    leans costs what a level one does, and over the tiles in use where those
+    are more (``nn/layers/moe.py: tile_plan`` derives both from the tokens,
+    ``top_k`` and the two expert counts)."""
+    num_experts: int = 8
+    experts_held: Optional[List[int]] = None
+    top_k: int = 2
+    n_hidden: Optional[int] = None
+    shared_hidden: Optional[int] = None
+    renormalize: bool = True
+    routed_scaling_factor: float = 1.0
+    activation: Optional[str] = "identity"
+
+    def get_output_type(self, index, input_type):
+        if isinstance(input_type, InputTypeRecurrent):
+            return InputTypeRecurrent(self.n_out, input_type.timeseries_length)
+        return InputTypeFeedForward(self.n_out)
+
+    def preprocessor_for(self, input_type):
+        return None
+
+
+@register
+@dataclasses.dataclass
 class ConvolutionLayer(FeedForwardLayer):
     """2-D convolution (reference ``nn/conf/layers/ConvolutionLayer.java``).
 
@@ -650,6 +695,22 @@ class SelfAttentionLayer(BaseRecurrentLayer):
     #: what the scores are multiplied by before the softmax; None is
     #: ``1 / sqrt(head_dim)``
     attention_scale: Optional[float] = None
+    #: the latent layout (multi-head latent attention), a layout of this
+    #: layer and no other attention: with a rank here, keys and values come
+    #: through one latent of that width,
+    #: ``[c | kr] = x Wkv_a``, ``[kn | v] = RMSNorm_gc(c) Wkv_b`` per head,
+    #: ``k = [kn | kr]`` with the ``qk_rope_head_dim`` channels ``kr`` shared
+    #: by all heads, so the query and key heads are ``qk_nope_head_dim +
+    #: qk_rope_head_dim`` wide and the value heads ``v_head_dim``. Leaves
+    #: ``Wq``, ``Wkv_a`` [n_in, rank + qk_rope_head_dim], ``gc`` [rank],
+    #: ``Wkv_b`` [rank, heads * (qk_nope_head_dim + v_head_dim)], ``Wo``
+    #: [heads * v_head_dim, n_out]. ``rope_theta`` rotates whole heads as
+    #: ever; left None the shared channels are carried as they are.
+    kv_latent_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    latent_norm_eps: float = 1e-5
 
 
 @register
@@ -721,21 +782,63 @@ class Mamba2Layer(BaseRecurrentLayer):
 
 @register
 @dataclasses.dataclass
-class HybridBlockStack(BaseRecurrentLayer):
-    """A stack of pre-normed decoder blocks of two kinds, one per entry of
-    ``layer_types`` (``"mamba"``: a :class:`Mamba2Layer` mixer; ``"attention"``:
-    causal grouped-query attention without positions), each followed by a
-    gated MLP of ``n_hidden`` units:
+class KimiDeltaAttentionLayer(BaseRecurrentLayer):
+    """Kimi Delta Attention mixer (Kimi Linear; Moonshot AI 2025) over
+    [b, T, n_in], net-new vs the 0.9.x reference: a gated delta rule with one
+    decay a key channel. ``num_heads`` heads H of ``head_dim`` channels K for
+    keys and values alike:
 
-        u = h + r * Mixer(RMSNorm(h));   h' = u + r * MLP(RMSNorm(u))
+        q, k, v = silu(conv1d(x Wq)), silu(conv1d(x Wk)), silu(conv1d(x Wv))
+                          depthwise, causal, width ``conv_size``, no bias
+        q, k    = q / ||q|| * K^-0.5,  k / ||k||       per head (eps 1e-6,
+                                                       under the root)
+        g_t     = -exp(A_log[h]) softplus((x W_fa W_fb)_t + dt_bias)  [H, K]
+        beta_t  = sigmoid(x W_b)_t                                    [H]
+        S_t     = Diag(exp(g_t)) S_{t-1};  S_t += beta_t k_t (v_t - S_t^T k_t)^T
+        o_t     = S_t^T q_t                            S [K, K] a head, S_0 = 0
+        out     = (RMSNorm_gn(o) * sigmoid(x W_ga W_gb)) Wo    gn [K], a head
+
+    The rule is computed in chunks of ``chunk_size`` steps, a unit-lower-
+    triangular solve a chunk and head and one state carried across each
+    boundary (``nn/layers/kda.py``); a length that is no multiple of the
+    chunk is padded with steps that leave the state alone. No bias."""
+    num_heads: int = 4
+    head_dim: int = 64
+    conv_size: int = 4
+    chunk_size: int = 64
+    eps: float = 1e-5
+    activation: Optional[str] = "identity"
+
+    def set_n_in(self, input_type, override=False):
+        super().set_n_in(input_type, override)
+        if self.n_out is None:
+            self.n_out = self.n_in
+
+
+@register
+@dataclasses.dataclass
+class HybridBlockStack(BaseRecurrentLayer):
+    """A stack of pre-normed decoder blocks, one per entry of
+    ``layer_types`` (``"mamba"``: a :class:`Mamba2Layer` mixer; ``"attention"``:
+    causal grouped-query attention without positions; ``"kda"``: a
+    :class:`KimiDeltaAttentionLayer` mixer; ``"mla"``: causal attention in
+    :class:`SelfAttentionLayer`'s latent layout, without positions), each
+    followed by what its entry of ``ffn_types`` names (``"dense"``, the
+    default: a gated MLP of ``n_hidden`` units; ``"experts"``: a
+    :class:`RoutedExpertsLayer`):
+
+        u = h + r * Mixer(RMSNorm(h));   h' = u + r * FFN(RMSNorm(u))
 
     with ``r = residual_multiplier``, and a final RMSNorm after the last
-    block. No biases but the convolution's. Every run of like blocks keeps
-    its weights stacked leaf by leaf ``[n, ...]`` under the keys
-    ``r<run>.<leaf>`` and is one ``lax.scan``; the training step keeps each
-    block's input and recomputes the rest in the backward pass. The stream
-    between blocks is float32 whatever the compute dtype."""
+    block. No biases but the state-space convolution's. Every run of blocks
+    alike in mixer and feed-forward keeps its weights stacked leaf by leaf
+    ``[n, ...]`` under the keys ``r<run>.<leaf>`` and is one ``lax.scan``;
+    the training step keeps each block's input and recomputes the rest in
+    the backward pass. The stream between blocks is float32 whatever the
+    compute dtype. The experts' score-correction bias is the stack's state
+    (``r<run>.b``), which no optimizer moves."""
     layer_types: Optional[List[str]] = None
+    ffn_types: Optional[List[str]] = None
     n_hidden: Optional[int] = None
     eps: float = 1e-5
     residual_multiplier: float = 1.0
@@ -748,6 +851,23 @@ class HybridBlockStack(BaseRecurrentLayer):
     mamba_state_size: int = 128
     mamba_conv_size: int = 4
     mamba_chunk_size: int = 256
+    kda_heads: int = 4
+    kda_head_dim: int = 64
+    kda_conv_size: int = 4
+    kda_chunk_size: int = 64
+    #: the ``"mla"`` blocks' latent layout (``num_heads`` heads)
+    kv_latent_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: the ``"experts"`` blocks' :class:`RoutedExpertsLayer`
+    num_experts: int = 8
+    experts_held: Optional[List[int]] = None
+    experts_per_token: int = 2
+    expert_hidden: Optional[int] = None
+    shared_hidden: Optional[int] = None
+    renormalize: bool = True
+    routed_scaling_factor: float = 1.0
 
     def set_n_in(self, input_type, override=False):
         super().set_n_in(input_type, override)

@@ -15,6 +15,7 @@ from . import variational  # noqa: F401
 from . import objdetect  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe  # noqa: F401
+from . import kda  # noqa: F401
 from . import looped  # noqa: F401
 from . import mamba  # noqa: F401
 from . import hybrid  # noqa: F401

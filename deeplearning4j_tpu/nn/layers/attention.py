@@ -11,14 +11,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..weights import host_full
 from .base import LayerImpl, implements, acc_dtype, pet_dtype
+from .normalization import rms_norm
 
 
 def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
         key_mask=None, scale=None):
-    """q: [b, T, h, d], k,v: [b, T, h_kv, d] with ``h_kv`` dividing ``h``
-    (grouped queries: head i reads key-value head ``i // (h // h_kv)``).
-    Returns [b, T, h, d]. Scaled dot-product attention (``scale`` None:
+    """q: [b, T, h, d], k: [b, T, h_kv, d], v: [b, T, h_kv, d_v] with
+    ``h_kv`` dividing ``h`` (grouped queries: head i reads key-value head
+    ``i // (h // h_kv)``; the value heads may have a size of their own).
+    Returns [b, T, h, d_v]. Scaled dot-product attention (``scale`` None:
     ``1 / sqrt(d)``) with f32 softmax accumulation (bf16-safe).
     ``key_mask``: [b, S] with 1 for real keys, 0 for padding — padded keys
     are excluded from the softmax.
@@ -41,7 +44,8 @@ def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
     T, d = q.shape[1], q.shape[-1]
     rate = dropout_rate if (train and rng is not None) else 0.0
     k, v = _repeat_kv(k, v, q.shape[2])
-    if q.shape == k.shape and _fa.supported(T, d, rate, key_mask):
+    if q.shape == k.shape and _fa.supported(T, d, rate, key_mask,
+                                            v.shape[-1]):
         seed = None
         if rate > 0.0:
             # per-step scalar seed for the in-kernel counter-hash dropout
@@ -57,7 +61,7 @@ def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
         raise ValueError(
             f"mha: a causal call of {T} tokens (head size {d}, keys "
             f"{k.shape[1]}) does not fit the flash kernels (lengths equal and "
-            f"multiples of {_fa.MIN_BLOCK}, head size <= 256, a [b, T] key "
+            f"multiples of {_fa.MIN_BLOCK}, head sizes <= 256, a [b, T] key "
             f"mask), and the dense path would form the "
             f"[{q.shape[0]}, {q.shape[2]}, {T}, {k.shape[1]}] scores")
     visible = None
@@ -135,6 +139,8 @@ class SelfAttentionImpl(LayerImpl):
     def _dims(self):
         c = self.conf
         h = c.num_heads
+        if c.kv_latent_rank is not None:
+            return h, int(c.qk_nope_head_dim + c.qk_rope_head_dim)
         d = c.head_dim or (c.n_out // h)
         return h, d
 
@@ -146,20 +152,77 @@ class SelfAttentionImpl(LayerImpl):
                              f"divide num_heads={h}")
         return kv
 
+    def _latent(self):
+        """(latent rank, the keys' part made from the latent, the keys' part
+        all heads share, the value head size) of the latent layout, or None:
+        see the config class."""
+        c = self.conf
+        if c.kv_latent_rank is None:
+            return None
+        if c.num_kv_heads not in (None, c.num_heads) or c.head_dim not in (
+                None, c.qk_nope_head_dim + c.qk_rope_head_dim):
+            raise ValueError(
+                "SelfAttentionLayer: the latent layout makes num_heads key "
+                "and value heads from one latent; its head size is "
+                "qk_nope_head_dim + qk_rope_head_dim")
+        return (int(c.kv_latent_rank), int(c.qk_nope_head_dim),
+                int(c.qk_rope_head_dim), int(c.v_head_dim))
+
+    def _init_latent(self, rng):
+        c = self.conf
+        h = c.num_heads
+        rank, nope, shared, d_v = self._latent()
+        w = lambda key, n_in, n_out: self._init_w(key, (n_in, n_out), n_in,
+                                                  n_out)
+        k1, k2, k3, k4 = jax.random.split(rng, 4)
+        return {"Wq": w(k1, c.n_in, h * (nope + shared)),
+                "Wkv_a": w(k2, c.n_in, rank + shared),
+                "gc": host_full((rank,), 1, self.dtype),
+                "Wkv_b": w(k3, rank, h * (nope + d_v)),
+                "Wo": w(k4, h * d_v, c.n_out)}
+
     def init(self, rng):
         c = self.conf
-        h, d = self._dims()
-        kv = self._kv_heads()
-        k1, k2, k3, k4 = jax.random.split(rng, 4)
-        params = {
-            "Wq": self._init_w(k1, (c.n_in, h * d), c.n_in, h * d),
-            "Wk": self._init_w(k2, (c.n_in, kv * d), c.n_in, kv * d),
-            "Wv": self._init_w(k3, (c.n_in, kv * d), c.n_in, kv * d),
-            "Wo": self._init_w(k4, (h * d, c.n_out), h * d, c.n_out),
-        }
+        if self._latent():
+            params = self._init_latent(rng)
+        else:
+            h, d = self._dims()
+            kv = self._kv_heads()
+            k1, k2, k3, k4 = jax.random.split(rng, 4)
+            params = {
+                "Wq": self._init_w(k1, (c.n_in, h * d), c.n_in, h * d),
+                "Wk": self._init_w(k2, (c.n_in, kv * d), c.n_in, kv * d),
+                "Wv": self._init_w(k3, (c.n_in, kv * d), c.n_in, kv * d),
+                "Wo": self._init_w(k4, (h * d, c.n_out), h * d, c.n_out),
+            }
         if c.has_bias:
             params["b"] = self._init_b((c.n_out,))
         return params, {}
+
+    def _qkv(self, params, x):
+        """q [b, T, h, d], k [b, T, h_kv, d], v [b, T, h_kv, d_v] of ``x``
+        [b, T, n_in]. The latent layout: keys and values come through one
+        normed latent, and every head's key ends in the channels that
+        ``Wkv_a`` makes beside the latent, one set for all heads."""
+        c = self.conf
+        b, T, _ = x.shape
+        h = c.num_heads
+        proj = lambda t, w: t @ params[w].astype(t.dtype)
+        if not self._latent():
+            d, kv = self._dims()[1], self._kv_heads()
+            return (proj(x, "Wq").reshape(b, T, h, d),
+                    proj(x, "Wk").reshape(b, T, kv, d),
+                    proj(x, "Wv").reshape(b, T, kv, d))
+        rank, nope, shared, d_v = self._latent()
+        q = proj(x, "Wq").reshape(b, T, h, nope + shared)
+        latent = proj(x, "Wkv_a")
+        normed = rms_norm(latent[..., :rank], params["gc"],
+                          c.latent_norm_eps, acc_dtype(self.compute_dtype))
+        kv = proj(normed.astype(x.dtype), "Wkv_b").reshape(b, T, h, nope + d_v)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(
+                latent[:, :, None, rank:], (b, T, h, shared))], axis=-1)
+        return q, k, kv[..., nope:]
 
     #: training forward is scan-free — the stream state must not disable
     #: the conv-net remat policy the way true RNN carries do (base.py)
@@ -173,10 +236,11 @@ class SelfAttentionImpl(LayerImpl):
         batch stays exact), and the global token counter."""
         c = self.conf
         h, d = self._kv_heads(), self._dims()[1]
+        d_v = self._latent()[3] if self._latent() else d
         L = int(c.stream_max_length)
         cd = self.compute_dtype
         return (jnp.zeros((batch, L, h, d), cd),
-                jnp.zeros((batch, L, h, d), cd),
+                jnp.zeros((batch, L, h, d_v), cd),
                 jnp.full((batch, L), -1, jnp.int32),
                 jnp.zeros((), jnp.int32))
 
@@ -232,14 +296,12 @@ class SelfAttentionImpl(LayerImpl):
 
     def forward(self, params, state, x, train=False, rng=None, mask=None, ctx=None):
         c = self.conf
-        h, d = self._dims()
+        h = c.num_heads
         b, T, _ = x.shape
         x = self.maybe_dropout(x, train, rng)
         cd = self.compute_dtype
         kv = self._kv_heads()
-        q = (x @ params["Wq"].astype(x.dtype)).reshape(b, T, h, d)
-        k = (x @ params["Wk"].astype(x.dtype)).reshape(b, T, kv, d)
-        v = (x @ params["Wv"].astype(x.dtype)).reshape(b, T, kv, d)
+        q, k, v = self._qkv(params, x)
         idx = getattr(self, "index", None)
         carry = (ctx.get("rnn_state_in", {}).get(idx)
                  if ctx is not None and idx is not None else None)
@@ -286,7 +348,7 @@ class SelfAttentionImpl(LayerImpl):
         else:
             o = mha(q, k, v, c.causal, cd, c.dropout_rate, rng, train,
                     key_mask=mask, scale=c.attention_scale)
-        o = o.reshape(b, T, h * d)
+        o = o.reshape(b, T, h * v.shape[-1])
         y = o @ params["Wo"].astype(o.dtype)
         if "b" in params:
             y = y + params["b"].astype(o.dtype)

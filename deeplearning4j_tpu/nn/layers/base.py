@@ -215,18 +215,24 @@ FLASH_RES = "dl4j_flash_res"
 #: norm's backward reads the norm's input, and nothing else in the backward
 #: sweep reads that output (``looped.LoopedBlockStackImpl.block``)
 NORM_IN = "dl4j_norm_in"
+#: the name a chunked scan gives the state that one of its checkpointed
+#: segments is handed (``kda.delta_rule_chunked``): with it and the segments'
+#: read-out kept, a block's backward runs no segment's forward but the one
+#: the segment's own checkpoint asks for
+SCAN_CARRY = "dl4j_scan_carry"
 #: one object for every stack and run: jax caches a checkpoint's partial
 #: evaluation by its policy, and like sub-programs of two runs stay one
-_BLOCK_POLICY = jax.checkpoint_policies.save_only_these_names(FLASH_RES,
-                                                              NORM_IN)
+_BLOCK_POLICY = jax.checkpoint_policies.save_only_these_names(
+    FLASH_RES, NORM_IN, SCAN_CARRY)
 
 
 def block_checkpoint(block):
     """``block`` under the block stacks' checkpoint: a block application
-    keeps its input, what the flash kernels' backward reads and what a
+    keeps its input, what the flash kernels' backward reads, what a
     post-norm reads (a sub-layer's output that the block named
     ``NORM_IN``: the projection that made it is not run again for the
-    norm's backward alone), and recomputes the rest backward. A block with
+    norm's backward alone) and the states a chunked scan's segments were
+    handed (``SCAN_CARRY``), and recomputes the rest backward. A block with
     no flash call (the dense path, a state-space block) and no post-norm
     (the hybrid stack's) tags nothing and keeps its input alone."""
     return jax.checkpoint(block, policy=_BLOCK_POLICY)

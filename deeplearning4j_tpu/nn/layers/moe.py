@@ -12,13 +12,22 @@ The Switch-Transformer load-balancing auxiliary loss (num_experts × Σ_e
 fraction_of_tokens_routed_to_e × mean_gate_prob_e) accumulates through the
 forward ``ctx`` into the training objective (``nn/multilayer.py`` /
 ``nn/graph.py`` add ``ctx['aux_loss']`` to loss+reg).
+
+:class:`RoutedExpertsImpl` is the other expert layer, a class of its own
+beside the one above: gated expert FFNs with a shared expert, routed over all
+the published experts without a drop, of which this chip holds the ones it
+is told, their products grouped over the rows that were routed to them
+(:func:`grouped_ffn`).
 """
 from __future__ import annotations
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .base import LayerImpl, implements, pet_dtype
+from ...monitor import get_registry
+from ..weights import host_full
+from .base import LayerImpl, implements, acc_dtype, pet_dtype
 
 
 @implements("MoEDenseLayer")
@@ -179,3 +188,278 @@ class MoEDenseImpl(LayerImpl):
             ctx["aux_loss"] = ctx.get("aux_loss", 0.0) + aux
 
         return self.activation(y).astype(self.out_dtype), state
+
+
+# ------------------------------------------------------- routed gated experts
+#: rows of a product that fill a side of the MXU: a tile is a multiple of it
+MXU_ROWS = 128
+#: the grouped products' standing walk, over the rows that uniform routing
+#: sends here: a router that leans up to this far costs what a level one does
+PROVISION = 2
+
+
+def tile_plan(n, k, held, experts):
+    """(rows a tile, tiles the grouped products always walk) of ``n`` tokens
+    with ``k`` choices each among ``experts``, ``held`` of them here, from
+    what the layer can see. A tile is the rows uniform routing sends one
+    expert (``n k / experts``) to the next multiple of :data:`MXU_ROWS`, four
+    of them at most: a tile reads its expert's three matrices whole, so
+    smaller tiles read them more often, and larger ones pad more. The
+    standing walk is :data:`PROVISION` times the rows uniform routing sends
+    here and a tile of padding for every held expert: the buffers of a
+    capacity factor without its drops, what lies beyond being walked as well
+    (:func:`grouped_ffn`)."""
+    mean = n * k / experts
+    tile = MXU_ROWS * min(max(1, -(-int(mean) // MXU_ROWS)), 4)
+    return tile, int(-(-PROVISION * mean * held // tile)) + held
+
+
+def routing_tables(local, weights, held, tile):
+    """The rows of the grouped products, expert by expert: ``local`` [n, k]
+    holds, for each of a token's k choices, the held expert's index in
+    [0, ``held``) or ``held`` where the chosen expert lives elsewhere;
+    ``weights`` [n, k] the choices' weights. The choices that landed here
+    are put in the order of their experts (a stable sort, so a group's
+    tokens stay in order), every expert's group is padded to whole tiles of
+    ``tile`` rows, and the tables are sized for the worst routing,
+    ``n min(k, held)`` rows and the groups' padding: no choice is dropped.
+
+    -> (``row_token`` [R] the token a row reads and adds to, a padding row
+    one of ``tile`` rows past the last token; ``row_weight`` [R], nought on
+    padding; ``tile_expert`` [R / tile]; the tiles in use, a traced scalar)."""
+    n, k = local.shape
+    rows = -(-(n * min(k, held) + held * (tile - 1)) // tile) * tile
+    key = local.reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.bincount(key, length=held + 1)[:held]
+    padded = -(-counts // tile) * tile
+    ends = jnp.cumsum(padded)
+    r = jnp.arange(rows)
+    expert = jnp.minimum(jnp.searchsorted(ends, r, side="right"), held - 1)
+    rank = r - (ends - padded)[expert]
+    valid = (rank < counts[expert]) & (r < ends[-1])
+    choice = order[jnp.clip((jnp.cumsum(counts) - counts)[expert] + rank, 0,
+                            n * k - 1)]
+    row_token = jnp.where(valid, choice // k, n + r % tile).astype(jnp.int32)
+    row_weight = jnp.where(valid, weights.reshape(-1)[choice], 0)
+    return (row_token, row_weight, expert[::tile].astype(jnp.int32),
+            (ends[-1] // tile).astype(jnp.int32))
+
+
+def _tile(i, tile, *tables):
+    return tuple(jax.lax.dynamic_slice_in_dim(t, i * tile, tile)
+                 for t in tables)
+
+
+def _expert(leaves, e, compute_dtype):
+    return tuple(jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
+                 .astype(compute_dtype) for w in leaves)
+
+
+def _dots(compute_dtype):
+    sd = acc_dtype(compute_dtype)
+    dot = lambda a, b, dims: jax.lax.dot_general(
+        a.astype(compute_dtype), b.astype(compute_dtype), (dims, ((), ())),
+        preferred_element_type=sd)
+    return (lambda a, b: dot(a, b, ((1,), (0,))),       # a b
+            lambda a, b: dot(a, b, ((1,), (1,))),       # a b^T
+            lambda a, b: dot(a, b, ((0,), (0,))))       # a^T b
+
+
+def grouped_ffn(x, w_gate, w_up, w_down, row_token, row_weight, tile_expert,
+                tiles, tile, compute_dtype):
+    """``y[t] = sum over the rows r of token t of row_weight[r] *
+    Expert_{e(r)}(x[t])`` with ``Expert_e(x) = (silu(x Wgate_e) * (x Wup_e))
+    Wdown_e``: ``x`` [n, d], the held experts' leaves ``w_gate``, ``w_up``
+    [E, d, f], ``w_down`` [E, f, d], and :func:`routing_tables`' tables ->
+    [n, d] in the accumulator dtype. One loop over the first ``tiles`` tiles,
+    a traced count that is at least the tiles in use (the layer walks its
+    standing provision when fewer are, :func:`tile_plan`, and the tiles in
+    use when more: the work never follows the tables' size, the worst
+    routing's; a tile past those in use holds padding rows of weight nought
+    and adds nothing); a turn gathers a tile's rows, runs one expert's three
+    products on them and adds the weighted result to its tokens' rows.
+    Differentiated by a rule of its own (a loop of a traced length has no
+    transpose): the same walk again, recomputing a tile's hidden state."""
+    n, d = x.shape
+    ab, _, _ = _dots(compute_dtype)
+
+    def turn(i, y):
+        with jax.named_scope("dispatch"):
+            rows, weight = _tile(i, tile, row_token, row_weight)
+            xt = x[rows]
+        with jax.named_scope("experts"):
+            wg, wu, wd = _expert((w_gate, w_up, w_down), tile_expert[i],
+                                 compute_dtype)
+            yt = ab(jax.nn.silu(ab(xt, wg)) * ab(xt, wu), wd)
+        with jax.named_scope("dispatch"):
+            return y.at[rows].add(weight[:, None].astype(yt.dtype) * yt,
+                                  unique_indices=True, indices_are_sorted=True)
+
+    y = jnp.zeros((n + tile, d), acc_dtype(compute_dtype))
+    return jax.lax.fori_loop(0, tiles, turn, y)[:n]
+
+
+def _grouped_ffn_fwd(x, w_gate, w_up, w_down, row_token, row_weight,
+                     tile_expert, tiles, tile, compute_dtype):
+    args = (x, w_gate, w_up, w_down, row_token, row_weight, tile_expert, tiles)
+    return _grouped_ffn(*args, tile, compute_dtype), args
+
+
+def _grouped_ffn_bwd(tile, compute_dtype, kept, dy):
+    x, w_gate, w_up, w_down, row_token, row_weight, tile_expert, tiles = kept
+    n, d = x.shape
+    sd = acc_dtype(compute_dtype)
+    ab, abt, atb = _dots(compute_dtype)
+    add_row = lambda acc, e, part: jax.lax.dynamic_update_index_in_dim(
+        acc, jax.lax.dynamic_index_in_dim(acc, e, 0) + part[None], e, 0)
+
+    def turn(i, carry):
+        dx, dwg, dwu, dwd, dweight = carry
+        e = tile_expert[i]
+        with jax.named_scope("dispatch"):
+            rows, weight = _tile(i, tile, row_token, row_weight)
+            xt, dyt = x[rows], dy[rows].astype(sd)
+        with jax.named_scope("experts"):
+            wg, wu, wd = _expert((w_gate, w_up, w_down), e, compute_dtype)
+            g, u = ab(xt, wg), ab(xt, wu)
+            gate = jax.nn.sigmoid(g)
+            act = g * gate
+            h = act * u
+            dweight_t = jnp.sum(dyt * ab(h, wd), axis=-1)
+            dyt = weight[:, None].astype(sd) * dyt
+            dh = abt(dyt, wd)
+            du, dg = dh * act, dh * u * (gate * (1 + g * (1 - gate)))
+            dxt = abt(dg, wg) + abt(du, wu)
+            dwg, dwu, dwd = (add_row(dwg, e, atb(xt, dg)),
+                             add_row(dwu, e, atb(xt, du)),
+                             add_row(dwd, e, atb(h, dyt)))
+        with jax.named_scope("dispatch"):
+            dx = dx.at[rows].add(dxt, unique_indices=True,
+                                 indices_are_sorted=True)
+            dweight = jax.lax.dynamic_update_slice_in_dim(
+                dweight, dweight_t.astype(dweight.dtype), i * tile, 0)
+        return dx, dwg, dwu, dwd, dweight
+
+    zeros = lambda like: jnp.zeros(like.shape, sd)
+    dx, dwg, dwu, dwd, dweight = jax.lax.fori_loop(0, tiles, turn, (
+        jnp.zeros((n + tile, d), sd), zeros(w_gate), zeros(w_up),
+        zeros(w_down), jnp.zeros(row_weight.shape, row_weight.dtype)))
+    no = lambda t: np.zeros(t.shape, jax.dtypes.float0)
+    return (dx[:n].astype(x.dtype), dwg.astype(w_gate.dtype),
+            dwu.astype(w_up.dtype), dwd.astype(w_down.dtype), no(row_token),
+            dweight, no(tile_expert), no(tiles))
+
+
+_grouped_ffn = grouped_ffn
+grouped_ffn = jax.custom_vjp(_grouped_ffn, nondiff_argnums=(8, 9))
+grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
+
+
+@implements("RoutedExpertsLayer")
+class RoutedExpertsImpl(LayerImpl):
+    """See the config class. Leaves: ``Wr`` [n_in, num_experts] (the
+    router, over every published expert), the held experts' ``We_gate``,
+    ``We_up`` [held, n_in, n_hidden], ``We_down`` [held, n_hidden, n_out],
+    the shared expert's ``Ws_gate``, ``Ws_up`` [n_in, shared_hidden],
+    ``Ws_down`` [shared_hidden, n_out]. State: ``b`` [num_experts], the
+    score-correction bias, which enters the choice only and which no
+    gradient reaches."""
+
+    EXPERT_KEYS = ("We_gate", "We_up", "We_down")
+    SHARED_KEYS = ("Ws_gate", "Ws_up", "Ws_down")
+
+    def __init__(self, conf, gc, input_type=None):
+        super().__init__(conf, gc, input_type)
+        c = conf
+        held = list(c.experts_held if c.experts_held is not None
+                    else range(c.num_experts))
+        if (not held or len(set(held)) != len(held)
+                or not all(0 <= e < c.num_experts for e in held)):
+            raise ValueError(
+                f"RoutedExpertsLayer: experts_held lists distinct ids of the "
+                f"{c.num_experts} published experts; got {held}")
+        if not 1 <= c.top_k <= c.num_experts:
+            raise ValueError(f"RoutedExpertsLayer needs 1 <= top_k <= "
+                             f"num_experts (got {c.top_k}, {c.num_experts})")
+        self.held = held
+
+    def init(self, rng, lead=()):
+        """``lead``: leading dimensions of every leaf (a stack of layers)."""
+        c = self.conf
+        E, f, fs = len(self.held), int(c.n_hidden), int(c.shared_hidden or 0)
+        shapes = {"Wr": (c.n_in, c.num_experts),
+                  "We_gate": (E, c.n_in, f), "We_up": (E, c.n_in, f),
+                  "We_down": (E, f, c.n_out)}
+        if fs:
+            shapes.update({"Ws_gate": (c.n_in, fs), "Ws_up": (c.n_in, fs),
+                           "Ws_down": (fs, c.n_out)})
+        params = {name: self._init_w(key, lead + shape, *shape[-2:])
+                  for (name, shape), key in zip(
+                      shapes.items(), jax.random.split(rng, len(shapes)))}
+        return params, {"b": host_full(lead + (c.num_experts,), 0, self.dtype)}
+
+    def route(self, x, w_router, bias):
+        """(the k chosen experts [n, k], their weights [n, k]) of ``x``
+        [n, n_in], at least float32 and with the product at full precision:
+        a choice is a comparison of scores."""
+        c = self.conf
+        rdt = jnp.promote_types(jnp.float32, self.dtype)
+        logits = jnp.dot(x.astype(rdt), w_router.astype(rdt),
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(rdt)), int(c.top_k))
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if c.renormalize:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                 + 1e-20)
+        return chosen, weights * c.routed_scaling_factor
+
+    def forward(self, params, state, x, train=False, rng=None, mask=None,
+                ctx=None):
+        c = self.conf
+        cd = self.compute_dtype
+        x = self.maybe_dropout(x, train, rng)
+        flat = x.reshape(-1, x.shape[-1])
+        held = len(self.held)
+        tile, standing = tile_plan(flat.shape[0], int(c.top_k), held,
+                                   c.num_experts)
+        with jax.named_scope("router"):
+            chosen, weights = self.route(flat, params["Wr"], state["b"])
+        with jax.named_scope("dispatch"):
+            local = np.full((c.num_experts,), held, np.int32)
+            local[self.held] = np.arange(held)
+            *tables, in_use = routing_tables(jnp.asarray(local)[chosen],
+                                             weights, held, tile)
+            standing = min(standing, tables[0].shape[0] // tile)
+            tables.append(jnp.maximum(in_use, standing))
+            layer = str(getattr(self, "index", ""))
+            gauge = get_registry().gauge
+            for which, count in (("held", held),
+                                 ("published", c.num_experts)):
+                gauge("moe_experts",
+                      "Experts of a routed expert layer: held on this chip "
+                      "and published (the router's width), set when the "
+                      "layer is traced", layer=layer, which=which).set(count)
+            gauge("moe_rows_sized",
+                  "Rows the tables of a routed expert layer's grouped "
+                  "products are sized for (the worst routing; the products "
+                  "walk the standing rows, or the tiles in use where those "
+                  "are more), set when the layer is traced",
+                  layer=layer).set(int(tables[0].shape[0]))
+            gauge("moe_rows_standing",
+                  "Rows a routed expert layer's grouped products walk "
+                  "whatever the routing (twice what uniform routing sends "
+                  "here and a tile for every held expert), set when the "
+                  "layer is traced", layer=layer).set(standing * tile)
+        xc = flat.astype(cd)
+        y = grouped_ffn(xc, *(params[k] for k in self.EXPERT_KEYS), *tables,
+                        tile, cd)
+        if "Ws_gate" in params:
+            with jax.named_scope("shared"):
+                ab = _dots(cd)[0]
+                y = y + ab(jax.nn.silu(ab(xc, params["Ws_gate"]))
+                           * ab(xc, params["Ws_up"]), params["Ws_down"])
+        return self.activation(y.reshape(x.shape[:-1] + (c.n_out,))).astype(
+            self.out_dtype), state

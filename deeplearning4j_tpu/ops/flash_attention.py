@@ -82,11 +82,13 @@ _EDGES = {"flash_fwd": (1024, 1024), "flash_dq": (1024, 1024),
           "flash_dkv": (1024, 1024)}
 
 
-def vmem_bytes(kernel: str, block_q: int, block_k: int, d: int, dtype) -> int:
+def vmem_bytes(kernel: str, block_q: int, block_k: int, d: int, dtype,
+               d_v: int | None = None) -> int:
     """VMEM one grid step of ``kernel`` holds at these edges, reckoned from
     its tiles as Mosaic lays them out (last dimension padded to 128 lanes;
     every streamed tile twice, for the pipeline's double buffer): the
-    [block, d] operand and output tiles, the [block, 8] f32 statistics, the
+    [block, d] operand and output tiles (q, k, dq and dk at the head size
+    ``d``; v, o, do and dv at the value head size ``d_v``, ``d`` where None), the [block, 8] f32 statistics, the
     f32 accumulators in scratch, and the [block_q, block_k] intermediates the
     body names — f32 ones (s, p; dp, ds too in the backward) plus the copies
     cast to the operand dtype for the MXU. An upper bound: Mosaic streams the
@@ -96,19 +98,23 @@ def vmem_bytes(kernel: str, block_q: int, block_k: int, d: int, dtype) -> int:
     24 / 27)."""
     item = jnp.dtype(dtype).itemsize
     lanes = -(-d // 128) * 128
+    lanes_v = lanes if d_v is None else -(-d_v // 128) * 128
     row, col = block_q * lanes, block_k * lanes       # elements of a tile
+    row_v, col_v = block_q * lanes_v, block_k * lanes_v
     stat_q, stat_k = block_q * 128 * 4, block_k * 128 * 4
-    if kernel == "flash_fwd":
-        streamed = (2 * row + 2 * col) * item + stat_k + stat_q  # q o k v km lse
-        scratch = 2 * stat_q + row * 4                           # m l acc
+    if kernel == "flash_fwd":                          # q o k v km lse
+        streamed = (row + row_v + col + col_v) * item + stat_k + stat_q
+        scratch = 2 * stat_q + row_v * 4                         # m l acc
         n_f32, casts = 2, 1                                      # s p | p
-    elif kernel == "flash_dq":
-        streamed = (3 * row + 2 * col) * item + stat_k + 2 * stat_q
+    elif kernel == "flash_dq":                         # q do dq k v
+        streamed = ((2 * row + row_v + col + col_v) * item + stat_k
+                    + 2 * stat_q)
         scratch = row * 4                                        # dq
         n_f32, casts = 4, 1                                      # s p dp ds | ds
-    else:
-        streamed = (2 * row + 4 * col) * item + stat_k + 2 * stat_q
-        scratch = 2 * col * 4                                    # dk dv
+    else:                                              # q do k v dk dv
+        streamed = ((row + row_v + 2 * col + 2 * col_v) * item + stat_k
+                    + 2 * stat_q)
+        scratch = (col + col_v) * 4                              # dk dv
         n_f32, casts = 4, 2                                      # | pd ds
     return (2 * streamed + scratch
             + block_q * block_k * (4 * n_f32 + item * casts))
@@ -120,13 +126,15 @@ def _divisors(T: int, cap: int):
                              -MIN_BLOCK) if T % b == 0]
 
 
-def pick_blocks(kernel: str, Tq: int, Tk: int, d: int, dtype):
+def pick_blocks(kernel: str, Tq: int, Tk: int, d: int, dtype,
+                d_v: int | None = None):
     """(block_q, block_k) for one call of ``kernel`` (``flash_fwd``,
     ``flash_dq``, ``flash_dkv``) from what the call can see: among the
     128-multiples that divide ``Tq`` and ``Tk`` and are no larger than the
     edges the sweep found best for the kernel (:data:`_EDGES`), the pair of
     the largest tile whose step fits :data:`VMEM_LIMIT` by
-    :func:`vmem_bytes` (of two tiles of one area, the one with more query
+    :func:`vmem_bytes` at the head size ``d`` of q and k and the value head
+    size ``d_v`` (``d`` where None) (of two tiles of one area, the one with more query
     rows). The two lengths are tiled apart, and a length that no larger edge
     divides falls back to smaller ones down to 128, so every 128-multiple
     takes the flash path. Dropout coordinates hash GLOBAL positions, so the
@@ -134,17 +142,17 @@ def pick_blocks(kernel: str, Tq: int, Tk: int, d: int, dtype):
     cap_q, cap_k = _EDGES[kernel]
     fits = [(bq, bk) for bq in _divisors(Tq, cap_q)
             for bk in _divisors(Tk, cap_k)
-            if vmem_bytes(kernel, bq, bk, d, dtype) <= VMEM_LIMIT]
+            if vmem_bytes(kernel, bq, bk, d, dtype, d_v) <= VMEM_LIMIT]
     return max(fits, key=lambda e: (e[0] * e[1], e[0]),
                default=(MIN_BLOCK, MIN_BLOCK))
 
 
-def _edges(kernel, Tq, Tk, d, dtype, block_q, block_k):
+def _edges(kernel, Tq, Tk, d, dtype, block_q, block_k, d_v=None):
     """The chooser's edges, each overridden where the caller gave one (the
     sweep of ``perf_flash_check.py`` and the tests' multi-block grids)."""
     bq, bk = block_q, block_k
     if not (bq and bk):
-        pq, pk = pick_blocks(kernel, Tq, Tk, d, dtype)
+        pq, pk = pick_blocks(kernel, Tq, Tk, d, dtype, d_v)
         bq, bk = bq or pq, bk or pk
     if Tq % bq or Tk % bk or bq % MIN_BLOCK or bk % MIN_BLOCK:
         raise ValueError(f"{kernel}: edges ({bq}, {bk}) must be multiples of "
@@ -372,9 +380,9 @@ def _operands(q_side, k_side, bq, bk, q, k, v, km, seed, rate, bwd=()):
     them: q, k, v, the key mask and the dropout seed where there are any,
     then ``bwd`` = (do, delta, lse) for the backward kernels. q-side tiles
     follow ``q_side`` of the walk ("outer" / "inner"), k-side ``k_side``."""
-    d = q.shape[-1]
+    d, d_v = q.shape[-1], v.shape[-1]
     row, col = ((1, bq, d), q_side), ((1, bk, d), k_side)
-    specs, operands = [row, col, col], [q, k, v]
+    specs, operands = [row, col, ((1, bk, d_v), k_side)], [q, k, v]
     if km is not None:
         specs.append(((1, bk, 8), k_side))
         operands.append(km)
@@ -383,7 +391,7 @@ def _operands(q_side, k_side, bq, bk, q, k, v, km, seed, rate, bwd=()):
         operands.append(seed)
     if bwd:
         stat = ((1, bq, 8), q_side)
-        specs += [row, stat, stat]
+        specs += [((1, bq, d_v), q_side), stat, stat]
         operands += list(bwd)
     return specs, operands
 
@@ -441,13 +449,14 @@ def _fwd_kernel(*refs, causal, scale, nk, rate, has_km):
 
 
 def _fwd(q, k, v, km, seed, causal, scale, rate, block_q=None, block_k=None):
-    """q: [bh, Tq, d], k/v: [bh, Tk, d], km: [bh, Tk, 8] key mask or None,
-    seed: [3] i32 (seed, q_off, k_off — :func:`seed3`) or None (rate > 0) →
-    (o [bh, Tq, d], lse [bh, Tq, 8]). ``block_q`` / ``block_k`` override
+    """q: [bh, Tq, d], k: [bh, Tk, d], v: [bh, Tk, d_v] (a value head size
+    of its own), km: [bh, Tk, 8] key mask or None, seed: [3] i32 (seed,
+    q_off, k_off — :func:`seed3`) or None (rate > 0) →
+    (o [bh, Tq, d_v], lse [bh, Tq, 8]). ``block_q`` / ``block_k`` override
     :func:`pick_blocks`."""
     bh, Tq, d = q.shape
-    Tk = k.shape[1]
-    bq, bk = _edges("flash_fwd", Tq, Tk, d, q.dtype, block_q, block_k)
+    Tk, d_v = k.shape[1], v.shape[2]
+    bq, bk = _edges("flash_fwd", Tq, Tk, d, q.dtype, block_q, block_k, d_v)
     nq, nk = Tq // bq, Tk // bk
     kern = functools.partial(_fwd_kernel, causal=causal, scale=scale, nk=nk,
                              rate=rate, has_km=km is not None)
@@ -460,10 +469,10 @@ def _fwd(q, k, v, km, seed, causal, scale, rate, block_q=None, block_k=None):
         "flash_fwd", kern, bq, bk, bh, nq, nk,
         causal_pairs(nq, nk, bq, bk) if causal else (),
         specs, operands,
-        out_specs=[((1, bq, d), "outer"), ((1, bq, 8), "outer")],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=[((1, bq, d_v), "outer"), ((1, bq, 8), "outer")],
+        out_shape=[jax.ShapeDtypeStruct((bh, Tq, d_v), q.dtype),
                    jax.ShapeDtypeStruct((bh, Tq, 8), jnp.float32)],
-        scratch=[_scratch((bq, 8)), _scratch((bq, 8)), _scratch((bq, d))])
+        scratch=[_scratch((bq, 8)), _scratch((bq, 8)), _scratch((bq, d_v))])
 
 
 # ----------------------------------------------------------------- backward
@@ -559,7 +568,8 @@ def _dkv_kernel(*refs, causal, scale, nq, rate, has_km):
 
 def dq_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
              rate=0.0, block_q=None, block_k=None):
-    """dq for one q-shard against one k/v block ([bh, Tq, d] × [bh, Tk, d]).
+    """dq for one q-shard against one k/v block ([bh, Tq, d] × [bh, Tk, d];
+    ``v`` and ``do`` at the value head size).
     ``delta``/``lse`` are the GLOBAL rowwise Δ and log-sum-exp ([bh, Tq, 8]
     lane-padded) — with them, per-block probabilities recompute exactly, so
     per-block gradients sum to the full-attention gradient. Used by the
@@ -568,7 +578,8 @@ def dq_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
     override :func:`pick_blocks`; the two lengths are tiled apart."""
     bh, Tq, d = q.shape
     Tk = k.shape[1]
-    bq, bk = _edges("flash_dq", Tq, Tk, d, q.dtype, block_q, block_k)
+    bq, bk = _edges("flash_dq", Tq, Tk, d, q.dtype, block_q, block_k,
+                    v.shape[2])
     nq, nk = Tq // bq, Tk // bk
     kern = functools.partial(_dq_kernel, causal=causal, scale=scale, nk=nk,
                              rate=rate, has_km=km is not None)
@@ -589,22 +600,21 @@ def dkv_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
     """(dk, dv) for one k/v block against one q-shard; see :func:`dq_block`
     for the global-``lse``/``delta`` contract and the overrides."""
     bh, Tk, d = k.shape
-    Tq = q.shape[1]
-    bq, bk = _edges("flash_dkv", Tq, Tk, d, q.dtype, block_q, block_k)
+    Tq, d_v = q.shape[1], v.shape[2]
+    bq, bk = _edges("flash_dkv", Tq, Tk, d, q.dtype, block_q, block_k, d_v)
     nq, nk = Tq // bq, Tk // bk
     kern = functools.partial(_dkv_kernel, causal=causal, scale=scale, nq=nq,
                              rate=rate, has_km=km is not None)
     specs, operands = _operands("inner", "outer", bq, bk, q, k, v, km, seed,
                                 rate, bwd=(do, delta, lse))
-    col = ((1, bk, d), "outer")
     return _launch(
         "flash_dkv", kern, bq, bk, bh, nk, nq,
         causal_pairs(nq, nk, bq, bk, k_major=True) if causal else (),
         specs, operands,
-        out_specs=[col, col],
+        out_specs=[((1, bk, d), "outer"), ((1, bk, d_v), "outer")],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch=[_scratch((bk, d)), _scratch((bk, d))])
+        scratch=[_scratch((bk, d)), _scratch((bk, d_v))])
 
 
 def rowwise_delta(do, o):
@@ -675,7 +685,8 @@ def _flash_fwd(q, k, v, km, seed, causal, scale, rate):
         "Bytes of the residuals (q, k, v, o, lse) one differentiated "
         "flash-attention call hands its backward kernels, set when the call "
         "is traced", kernel=_name("flash_fwd", *pick_blocks(
-            "flash_fwd", q.shape[1], k.shape[1], q.shape[2], q.dtype))
+            "flash_fwd", q.shape[1], k.shape[1], q.shape[2], q.dtype,
+            v.shape[2]))
     ).set(sum(x.size * x.dtype.itemsize for x in (q, k, v, o, lse)))
     return o, (q, k, v, km, seed, o, lse)
 
@@ -717,8 +728,10 @@ def _interpret() -> bool:
 MIN_SEQ = 4096
 
 
-def supported(T: int, d: int, dropout_rate: float, key_mask) -> bool:
-    """Whether the flash path applies: TPU backend (the interpreter would be
+def supported(T: int, d: int, dropout_rate: float, key_mask,
+              d_v: int | None = None) -> bool:
+    """Whether the flash path applies (``d`` the head size of q and k,
+    ``d_v`` the values', ``d`` where None): TPU backend (the interpreter would be
     far slower than the dense einsum — except under the tests' forced
     interpret mode), block-divisible sequence long enough to beat the dense
     path, head dim within VMEM tiling. Both [b, T] key-padding masks
@@ -730,14 +743,15 @@ def supported(T: int, d: int, dropout_rate: float, key_mask) -> bool:
         return False
     if key_mask is not None and getattr(key_mask, "ndim", None) != 2:
         return False
-    return (T % MIN_BLOCK == 0 and T >= min_seq and d <= 256
+    return (T % MIN_BLOCK == 0 and T >= min_seq and max(d, d_v or d) <= 256
             and 0.0 <= dropout_rate < 1.0)
 
 
 def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
                     key_mask=None, dropout_rate: float = 0.0,
                     dropout_seed=None):
-    """Blockwise attention. q/k/v: [b, T, h, d] → [b, T, h, d].
+    """Blockwise attention. q, k: [b, T, h, d], v: [b, T, h, d_v] (the
+    value heads may have a size of their own) → [b, T, h, d_v].
     ``key_mask``: optional [b, T] (1 = real key, 0 = padding) — masked keys
     are excluded from the softmax inside the kernels (no dense fallback).
     ``dropout_rate`` > 0 applies dropout to the normalized attention
@@ -756,7 +770,7 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
         seed = seed3(dropout_seed)
 
     def to_bh(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, T, d)
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, T, x.shape[-1])
 
     km = None
     if key_mask is not None:
@@ -765,4 +779,5 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
         km = jnp.broadcast_to(km[..., None], (b * h, T, 8))
     o = _flash(to_bh(q), to_bh(k), to_bh(v), km, seed, bool(causal),
                float(scale), rate)
-    return jnp.transpose(o.reshape(b, h, T, d), (0, 2, 1, 3)).astype(out_dtype)
+    return jnp.transpose(o.reshape(b, h, T, v.shape[-1]),
+                         (0, 2, 1, 3)).astype(out_dtype)

@@ -1144,3 +1144,158 @@ def test_flash_bf16_matches_dense_bf16():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    rtol=6e-2, atol=6e-2)
+
+
+# ------------------------------------------- a value head size of its own
+def _qk_v(b, T, h, d, d_v, seed):
+    rng = np.random.default_rng(seed)
+    q, k = (jnp.asarray(rng.normal(size=(b, T, h, d)), jnp.float32)
+            for _ in range(2))
+    return q, k, jnp.asarray(rng.normal(size=(b, T, h, d_v)), jnp.float32)
+
+
+@pytest.mark.parametrize("variant", ["plain", "key_mask"])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d,d_v", [(48, 32), (24, 40)])
+def test_value_heads_of_their_own_size_match_the_dense_oracle(d, d_v, causal,
+                                                              variant):
+    """q and k at one head size, v (and o, do, dv) at another, through the
+    three kernels at multi-block grids against ``_dense_attention``: loss
+    and every gradient (latent attention's 192 / 128 is such a pair)."""
+    from deeplearning4j_tpu.nn.layers.attention import _dense_attention
+    b, T, h = 1, 256, 2
+    q, k, v = _qk_v(b, T, h, d, d_v, seed=11)
+    key_mask = None
+    if variant == "key_mask":
+        key_mask = jnp.asarray(np.r_[np.ones(200), np.zeros(56)][None],
+                               jnp.float32)
+    w = jnp.asarray(np.random.default_rng(12).normal(size=(b, T, h, d_v)),
+                    jnp.float32)
+    visible = jnp.tril(jnp.ones((T, T), bool))[None, None] if causal else None
+    if key_mask is not None:
+        km = key_mask[:, None, None, :] > 0
+        visible = km if visible is None else visible & km
+
+    def through_kernels(q, k, v):
+        o = fa.flash_attention(q, k, v, causal=causal, key_mask=key_mask)
+        assert o.shape == (b, T, h, d_v)
+        return jnp.sum(o * w)
+
+    def dense(q, k, v):
+        return jnp.sum(_dense_attention(q, k, v, visible, jnp.float32) * w)
+
+    got, grads = jax.value_and_grad(through_kernels, (0, 1, 2))(q, k, v)
+    want, ref = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-4)
+    for a, r in zip(grads, ref):
+        assert a.shape == r.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=2e-3,
+                                   atol=2e-4)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (128, 256),
+                                             (256, 128)])
+def test_backward_blocks_take_a_value_head_size_of_their_own(block_q,
+                                                             block_k):
+    """``dq_block`` / ``dkv_block`` at forced edges: dq and dk come at the
+    head size of q and k, dv at the values'."""
+    bh, T, d, d_v = 2, 256, 48, 32
+    rng = np.random.default_rng(3)
+    q, k = (jnp.asarray(rng.normal(size=(bh, T, d)), jnp.float32)
+            for _ in range(2))
+    v, do = (jnp.asarray(rng.normal(size=(bh, T, d_v)), jnp.float32)
+             for _ in range(2))
+    scale = d ** -0.5
+
+    def dense(q, k, v):
+        s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+        return jnp.sum(jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v)
+                       * do)
+
+    o, lse = fa._fwd(q, k, v, None, None, True, scale, 0.0, block_q=block_q,
+                     block_k=block_k)
+    assert o.shape == (bh, T, d_v)
+    delta = fa.rowwise_delta(do, o)
+    dq = fa.dq_block(q, k, v, None, do, delta, lse, True, scale,
+                     block_q=block_q, block_k=block_k)
+    dk, dv = fa.dkv_block(q, k, v, None, do, delta, lse, True, scale,
+                          block_q=block_q, block_k=block_k)
+    for got, want in zip((dq, dk, dv), jax.grad(dense, (0, 1, 2))(q, k, v)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-3, atol=2e-4)
+
+
+def test_the_chooser_and_the_gate_reckon_with_the_value_head_size():
+    """``vmem_bytes`` at ``d_v`` None or equal to ``d`` is what it was; a
+    wider value head takes more, a narrower one less; ``supported`` holds
+    both sizes to 256; the kernels keep their names."""
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        same = fa.vmem_bytes(kernel, 1024, 1024, 128, jnp.bfloat16)
+        assert fa.vmem_bytes(kernel, 1024, 1024, 128, jnp.bfloat16, 128) == same
+        assert fa.vmem_bytes(kernel, 1024, 1024, 128, jnp.bfloat16, 256) > same
+        assert fa.vmem_bytes(kernel, 1024, 1024, 256, jnp.bfloat16, 128) \
+            < fa.vmem_bytes(kernel, 1024, 1024, 256, jnp.bfloat16)
+        bq, bk = fa.pick_blocks(kernel, 8192, 8192, 192, jnp.bfloat16, 128)
+        assert fa.vmem_bytes(kernel, bq, bk, 192, jnp.bfloat16, 128) \
+            <= fa.VMEM_LIMIT
+    assert fa.supported(256, 192, 0.0, None, 128)
+    assert not fa.supported(256, 192, 0.0, None, 384)
+    assert not fa.supported(256, 384, 0.0, None, 128)
+    q, k, v = _qk_v(1, 256, 1, 48, 32, seed=1)
+    from deeplearning4j_tpu.monitor import get_registry
+    fa.flash_attention(q, k, v)
+    kernels = {row["labels"]["kernel"]
+               for row in get_registry().snapshot()["flash_grid_steps"]}
+    assert any(name.startswith("flash_fwd_q") for name in kernels)
+
+
+def test_latent_attention_is_a_layout_of_the_one_layer():
+    """``SelfAttentionLayer`` with a latent rank: keys and values through one
+    normed latent, the shared key channels broadcast over the heads, value
+    heads of their own size, into the one ``mha``; against the equations
+    written out."""
+    from deeplearning4j_tpu import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    from deeplearning4j_tpu.nn.layers.base import impl_for
+    conf = NeuralNetConfiguration.builder().seed(3).list().build()
+    h, N, R, Dv, rank, d = 2, 16, 8, 12, 10, 20
+    layer = impl_for(SelfAttentionLayer(
+        n_in=d, n_out=d, num_heads=h, kv_latent_rank=rank,
+        qk_nope_head_dim=N, qk_rope_head_dim=R, v_head_dim=Dv,
+        has_bias=False, activation="identity"), conf.global_conf)
+    params, _ = layer.init(jax.random.PRNGKey(0))
+    assert {k: v.shape for k, v in params.items()} == {
+        "Wq": (d, h * (N + R)), "Wkv_a": (d, rank + R), "gc": (rank,),
+        "Wkv_b": (rank, h * (N + Dv)), "Wo": (h * Dv, d)}
+    params["gc"] = params["gc"] * 1.3
+    b, T = 2, 256                                   # the flash path
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(b, T, d)),
+                    jnp.float32)
+
+    def written_out(p, x):
+        q = (x @ p["Wq"]).reshape(b, T, h, N + R)
+        latent = x @ p["Wkv_a"]
+        c = latent[..., :rank]
+        c = c / jnp.sqrt(jnp.mean(c * c, -1, keepdims=True) + 1e-5) * p["gc"]
+        kv = (c @ p["Wkv_b"]).reshape(b, T, h, N + Dv)
+        k = jnp.concatenate([kv[..., :N], jnp.broadcast_to(
+            latent[:, :, None, rank:], (b, T, h, R))], -1)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (N + R) ** -0.5
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), kv[..., N:])
+        return o.reshape(b, T, h * Dv) @ p["Wo"]
+
+    loss = lambda f: lambda p, x: jnp.sum(jnp.sin(f(p, x)))
+    got, grads = jax.value_and_grad(loss(
+        lambda p, x: layer.forward(p, {}, x)[0]), (0, 1))(params, x)
+    want, ref = jax.value_and_grad(loss(written_out), (0, 1))(params, x)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-4)
+    for a, r in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=2e-3,
+                                   atol=2e-4)
+    # the streaming state holds keys and values at their own sizes
+    k_c, v_c, _, _ = layer.init_stream_state(1)
+    assert k_c.shape[-1] == N + R and v_c.shape[-1] == Dv

@@ -1,0 +1,266 @@
+"""The routed gated-expert layer (``nn/layers/moe.py: RoutedExpertsImpl``):
+against a plain masked loop over the held experts (output and every gradient)
+under ordinary and extreme routings, without a dropped choice; the share test
+(all shares' routed parts and the shared expert once add up to the uncut
+layer); the tables the grouped products walk and how far they are walked."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu import NeuralNetConfiguration
+from deeplearning4j_tpu.nn.conf.layers import RoutedExpertsLayer
+from deeplearning4j_tpu.nn.layers import moe
+from deeplearning4j_tpu.nn.layers.base import impl_for
+
+D, F, E, K = 16, 12, 32, 8
+
+
+@pytest.fixture(autouse=True)
+def _toy_tiles(monkeypatch):
+    """Tiles in multiples of 8 rows, not of the MXU's 128: a few dozen
+    tokens fill several."""
+    monkeypatch.setattr(moe, "MXU_ROWS", 8)
+
+
+def _layer(held, top_k=K, shared=F, **kw):
+    conf = NeuralNetConfiguration.builder().seed(3).list().build()
+    return impl_for(RoutedExpertsLayer(
+        n_in=D, n_out=D, num_experts=E, experts_held=held, top_k=top_k,
+        n_hidden=F, shared_hidden=shared, routed_scaling_factor=2.446,
+        **kw), conf.global_conf)
+
+
+def gated(x, w_gate, w_up, w_down):
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def masked_loop(params, x, held, top_k=K, scaling=2.446, bias=None):
+    """The layer's equations, every held expert on every token and masked."""
+    scores = jax.nn.sigmoid(x @ params["Wr"])
+    _, top = jax.lax.top_k(scores if bias is None else scores + bias, top_k)
+    chosen = jnp.take_along_axis(scores, top, axis=-1)
+    weight = scaling * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    y = 0.0
+    if "Ws_gate" in params:
+        y = gated(x, params["Ws_gate"], params["Ws_up"], params["Ws_down"])
+    for i, e in enumerate(held):
+        w_e = jnp.sum(jnp.where(top == e, weight, 0.0), axis=-1)
+        y = y + w_e[:, None] * gated(x, params["We_gate"][i],
+                                     params["We_up"][i], params["We_down"][i])
+    return y
+
+
+def _agree(layer, params, state, x, held, **kw):
+    w = jnp.asarray(np.random.default_rng(9).normal(size=x.shape), jnp.float32)
+    got, grads = jax.value_and_grad(
+        lambda p, x: jnp.sum(layer.forward(p, state, x)[0] * w), (0, 1))(
+            params, x)
+    with jax.default_matmul_precision("highest"):
+        want, ref = jax.value_and_grad(
+            lambda p, x: jnp.sum(masked_loop(p, x, held, **kw) * w), (0, 1))(
+                params, x)
+    assert float(got) == pytest.approx(float(want), rel=1e-5, abs=1e-5)
+    for (path, a), r in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(ref)):
+        scale = max(float(jnp.linalg.norm(r)), 1e-6)
+        assert float(jnp.linalg.norm(a - r)) / scale < 1e-4, path
+    return grads
+
+
+def _x(n=40, seed=0):
+    return jnp.asarray(np.random.default_rng(seed).normal(size=(n, D)),
+                       jnp.float32)
+
+
+def test_the_layer_is_the_masked_loop_over_its_held_experts():
+    held = [3, 4, 9, 17, 18, 19, 30, 31]
+    layer = _layer(held)
+    params, state = layer.init(jax.random.PRNGKey(0))
+    assert {k: v.shape for k, v in params.items()} == {
+        "Wr": (D, E), "We_gate": (8, D, F), "We_up": (8, D, F),
+        "We_down": (8, F, D), "Ws_gate": (D, F), "Ws_up": (D, F),
+        "Ws_down": (F, D)}
+    assert state["b"].shape == (E,) and not np.asarray(state["b"]).any()
+    _agree(layer, params, state, _x(), held)
+    # [b, T, d] passes through as it is
+    x3 = _x(24).reshape(2, 12, D)
+    y3, _ = layer.forward(params, state, x3)
+    flat, _ = layer.forward(params, state, x3.reshape(24, D))
+    assert y3.shape == (2, 12, D)
+    np.testing.assert_allclose(np.asarray(y3).reshape(24, D),
+                               np.asarray(flat), rtol=1e-6, atol=1e-6)
+
+
+def _steered(params, experts, strength=30.0):
+    """The router pulled to ``experts`` whatever the token."""
+    pull = np.full((E,), -strength, np.float32)
+    pull[list(experts)] = strength
+    x_dir = np.ones((D,), np.float32) / D
+    return {**params, "Wr": params["Wr"] * 0.01 + jnp.asarray(
+        np.outer(x_dir, pull))}
+
+
+@pytest.mark.parametrize("mxu_rows", [4, 32])
+@pytest.mark.parametrize("case", ["one_held_expert_takes_every_token",
+                                  "all_choices_held", "none_routed_here"])
+def test_routing_edges_are_exact_and_drop_nothing(case, mxu_rows,
+                                                  monkeypatch):
+    """The worst routings for a capacity: every token's choices on this
+    chip, every token to one held expert, no token here at all; at tiles
+    that an expert's rows fill several times over (12 rows) and at tiles
+    they do not fill (32). Each is the masked loop exactly, gradients too;
+    the products walk their standing tiles, and the tiles in use where the
+    routing passes them."""
+    monkeypatch.setattr(moe, "MXU_ROWS", mxu_rows)
+    walked = []
+    grouped = moe.grouped_ffn
+    monkeypatch.setattr(moe, "grouped_ffn", lambda *args: (
+        walked.append(int(args[7])), grouped(*args))[1])
+    held = list(range(8))
+    layer = _layer(held)
+    params, state = layer.init(jax.random.PRNGKey(1))
+    x = jnp.abs(_x(48, seed=4)) + 0.1              # x . ones > 0
+    if case == "one_held_expert_takes_every_token":
+        params = _steered(params, [5] + list(range(20, 27)))
+    elif case == "all_choices_held":
+        params = _steered(params, held)
+    else:
+        params = _steered(params, range(16, 24))
+    grads = _agree(layer, params, state, x, held)
+    _, top = jax.lax.top_k(jax.nn.sigmoid(x @ params["Wr"]), K)
+    here = np.isin(np.asarray(top), held).sum()
+    assert here == {"one_held_expert_takes_every_token": 48,
+                    "all_choices_held": 48 * 8, "none_routed_here": 0}[case]
+    if case == "none_routed_here":
+        assert not np.asarray(grads[0]["We_gate"]).any()
+    if case == "one_held_expert_takes_every_token":
+        assert np.asarray(grads[0]["We_gate"][5]).any()
+        assert not np.asarray(grads[0]["We_gate"][4]).any()
+    # 48 tokens of 8 choices among 32: uniform routing sends an expert 12
+    tile, standing = moe.tile_plan(48, K, 8, E)
+    assert (tile, standing) == {4: (12, 24), 32: (32, 14)}[mxu_rows]
+    layer.forward(params, state, x)
+    in_use = {"one_held_expert_takes_every_token": -(-48 // tile),
+              "all_choices_held": 8 * -(-48 // tile), "none_routed_here": 0}
+    assert walked[-1] == max(in_use[case], standing)
+    assert (in_use[case] > standing) == (case == "all_choices_held")
+
+
+def test_the_walk_is_planned_from_what_the_layer_sees(monkeypatch):
+    """``tile_plan``: a tile is what uniform routing sends one expert, to a
+    multiple of the MXU's rows and four of them at most; the standing walk
+    is twice what it sends here and a tile for every held expert."""
+    monkeypatch.setattr(moe, "MXU_ROWS", 128)
+    assert moe.tile_plan(8192, 8, 8, 256) == (256, 24)       # the kimi cell
+    assert moe.tile_plan(8192, 8, 256, 256) == (256, 768)    # all held
+    assert moe.tile_plan(80, 8, 8, 32) == (128, 11)          # its rehearsal
+    assert moe.tile_plan(65536, 8, 8, 64) == (512, 264)      # 8192 an expert
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The share test (``model-configs`` guide, section 4): the routed parts
+    that all four shares of 8 experts give, with the shared expert, which
+    every chip computes alike, counted once, add up to what the uncut
+    reference gives for the whole layer of 32."""
+    from benchmark.reference import kimi_linear_48b_a3b as reference
+    whole = _layer(None)
+    params, state = whole.init(jax.random.PRNGKey(2))
+    x = _x(64, seed=5)
+    with jax.default_matmul_precision("highest"):
+        uncut = reference.experts_ffn(params, x, K, 2.446)
+        shared = reference.gated(x, params["Ws_gate"], params["Ws_up"],
+                                 params["Ws_down"])
+    total = shared
+    for first in range(0, E, 8):
+        held = list(range(first, first + 8))
+        share = _layer(held)
+        part = {k: (v[first:first + 8] if k in share.EXPERT_KEYS else v)
+                for k, v in params.items()}
+        y, _ = share.forward(part, state, x)
+        total = total + (y - shared)
+        # and the reference, given the same share, gives the same part
+        with jax.default_matmul_precision("highest"):
+            part_ref = masked_loop(part, x, held)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(part_ref),
+                                   rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
+                               rtol=1e-4, atol=1e-5)
+    # the uncut layer through the program too
+    y, _ = whole.forward(params, state, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(uncut), rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_the_bias_enters_the_choice_only_and_no_gradient_reaches_it():
+    held = list(range(8))
+    layer = _layer(held)
+    params, state = layer.init(jax.random.PRNGKey(3))
+    x = _x(32, seed=6)
+    bias = jnp.asarray(np.random.default_rng(1).normal(size=(E,)) * 0.3,
+                       jnp.float32)
+    biased = {"b": bias}
+    _agree(layer, params, biased, x, held, bias=bias)
+    moved, _ = layer.forward(params, biased, x)
+    plain, _ = layer.forward(params, state, x)
+    assert not np.allclose(np.asarray(moved), np.asarray(plain))
+    grad = jax.grad(lambda s: jnp.sum(layer.forward(params, s, x)[0]))(biased)
+    assert not np.asarray(grad["b"]).any()
+
+
+def test_the_tables_hold_every_choice_once_and_whole_tiles():
+    """``routing_tables``: each held choice is one row, in its expert's
+    group, groups padded to whole tiles; the tables are sized for the worst
+    routing and the tiles in use follow the routing."""
+    rng = np.random.default_rng(0)
+    n, k, held, tile = 50, 4, 3, 8
+    local = np.stack([rng.permutation(10)[:k] for _ in range(n)])
+    local = np.where(local < held, local, held).astype(np.int32)
+    weights = rng.uniform(size=(n, k)).astype(np.float32)
+    row_token, row_weight, tile_expert, tiles = (np.asarray(t) for t in (
+        moe.routing_tables(jnp.asarray(local), jnp.asarray(weights), held,
+                           tile)))
+    rows = len(row_token)
+    assert rows == -(-(n * min(k, held) + held * (tile - 1)) // tile) * tile
+    counts = [(local == e).sum() for e in range(held)]
+    assert tiles == sum(-(-c // tile) for c in counts)
+    real = row_token < n
+    assert real.sum() == sum(counts) and not real[tiles * tile:].any()
+    assert not row_weight[~real].any()
+    for t in range(int(tiles)):
+        e = tile_expert[t]
+        for r in range(t * tile, (t + 1) * tile):
+            if real[r]:
+                slot = np.flatnonzero(local[row_token[r]] == e)
+                assert len(slot) == 1
+                assert row_weight[r] == weights[row_token[r], slot[0]]
+        tokens = row_token[t * tile:(t + 1) * tile]
+        assert (np.diff(tokens) > 0).all()          # sorted and distinct
+    # every (token, held choice) pair is there exactly once
+    pairs = {(int(row_token[r]), int(tile_expert[r // tile]))
+             for r in np.flatnonzero(real)}
+    assert pairs == {(t, int(e)) for t in range(n) for e in local[t]
+                     if e < held}
+
+
+def test_the_layer_refuses_what_it_cannot_mean():
+    with pytest.raises(ValueError, match="experts_held"):
+        _layer([1, 1])
+    with pytest.raises(ValueError, match="experts_held"):
+        _layer([E])
+    with pytest.raises(ValueError, match="top_k"):
+        _layer([0], top_k=E + 1)
+    # no shared expert: the routed sum alone; the scores as they are
+    bare = _layer(list(range(8)), shared=None, renormalize=False)
+    params, state = bare.init(jax.random.PRNGKey(4))
+    assert "Ws_gate" not in params
+    y, _ = bare.forward(params, state, _x(16))
+    assert y.shape == (16, D) and np.isfinite(np.asarray(y)).all()
+    from deeplearning4j_tpu.monitor import get_registry
+    snap = get_registry().snapshot()
+    assert {row["labels"]["which"]: row["value"]
+            for row in snap["moe_experts"]} == {"held": 8, "published": E}
+    assert snap["moe_rows_sized"][0]["value"] == 16 * 8 + 8 * 7   # 184 rows
+    # uniform routing sends the eight 32 rows: twice that and a tile each
+    assert snap["moe_rows_standing"][0]["value"] == (8 + 8) * 8
