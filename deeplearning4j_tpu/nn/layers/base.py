@@ -220,10 +220,15 @@ NORM_IN = "dl4j_norm_in"
 #: read-out kept, a block's backward runs no segment's forward but the one
 #: the segment's own checkpoint asks for
 SCAN_CARRY = "dl4j_scan_carry"
+#: the name a chunked scan gives the values of a chunk that are costly to
+#: form and small to hold (``kda.delta_rule_segment``: the delta rule's two
+#: decayed products and its triangular inverse): a backward that finds them
+#: kept forms none of them a second time
+CHUNK_MATS = "dl4j_chunk_mats"
 #: one object for every stack and run: jax caches a checkpoint's partial
 #: evaluation by its policy, and like sub-programs of two runs stay one
 _BLOCK_POLICY = jax.checkpoint_policies.save_only_these_names(
-    FLASH_RES, NORM_IN, SCAN_CARRY)
+    FLASH_RES, NORM_IN, SCAN_CARRY, CHUNK_MATS)
 
 
 def block_checkpoint(block):
@@ -231,10 +236,13 @@ def block_checkpoint(block):
     keeps its input, what the flash kernels' backward reads, what a
     post-norm reads (a sub-layer's output that the block named
     ``NORM_IN``: the projection that made it is not run again for the
-    norm's backward alone) and the states a chunked scan's segments were
-    handed (``SCAN_CARRY``), and recomputes the rest backward. A block with
-    no flash call (the dense path, a state-space block) and no post-norm
-    (the hybrid stack's) tags nothing and keeps its input alone."""
+    norm's backward alone), the states a chunked scan's segments were
+    handed (``SCAN_CARRY``) and the in-chunk matrices such a scan named
+    ``CHUNK_MATS`` (the delta rule's ``A``, ``B`` and triangular inverse:
+    its backward forms none of them again), and recomputes the rest
+    backward. A block with no flash call (the dense path, a state-space
+    block) and no post-norm (the hybrid stack's) tags nothing and keeps its
+    input alone."""
     return jax.checkpoint(block, policy=_BLOCK_POLICY)
 
 

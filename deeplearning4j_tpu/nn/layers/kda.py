@@ -20,6 +20,9 @@ so one unit-lower-triangular inverse a chunk and head gives ``T`` with
 ``o = (q e^G) S_0 + tril(B) U`` with ``B`` as ``A`` with q for k_t, and the
 state leaves as ``Diag(e^{G_L}) S_0 + (K e^{G_L - G})^T U``. One state a
 chunk crosses the boundary, carried by a ``lax.scan`` over the chunks.
+``A``, ``B`` and ``T`` are what is costly to form and small to hold of a
+chunk: they carry a name (``base.CHUNK_MATS``) under which the checkpoints
+they sit under keep them, so that no backward forms them a second time.
 
 ``exp(-G_s)`` overflows float32 inside a chunk once the decays are strong
 (at a log-decay of -4 a step, e^{256} after 64), so no product is formed from
@@ -46,7 +49,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ...monitor import get_registry
 from ..weights import _uniform, host_full
-from .base import LayerImpl, implements, acc_dtype, NORM_IN, SCAN_CARRY
+from .base import (LayerImpl, implements, acc_dtype, NORM_IN, SCAN_CARRY,
+                   CHUNK_MATS)
 from .mamba import split_conv_silu
 from .normalization import rms_norm
 
@@ -55,8 +59,13 @@ from .normalization import rms_norm
 SUB = 16
 #: chunks whose in-chunk matrices are alive at once (``mamba.SEGMENT_CHUNKS``'
 #: reason): a longer sequence is walked segment by segment under a
-#: checkpoint that keeps each segment's inputs and the state it was handed
+#: checkpoint that keeps each segment's inputs, the state it was handed and
+#: its in-chunk matrices (``_SEGMENT_POLICY``)
 SEGMENT_CHUNKS = 16
+#: what a segment's own checkpoint keeps beside its inputs: the values named
+#: ``CHUNK_MATS``. A name inside nested checkpoints is kept only where every
+#: policy on the way lists it: the block stacks' one does too
+_SEGMENT_POLICY = jax.checkpoint_policies.save_only_these_names(CHUNK_MATS)
 
 
 def _weights(G):
@@ -136,15 +145,26 @@ def _unit_lower_inverse(N):
     return done
 
 
+def _kept(t):
+    """``t`` [..., L, L] under the name ``CHUNK_MATS``, which it carries as
+    rows of L L: where a checkpoint keeps it, a float32 [64, 64] tile would
+    stand padded to 128 lanes."""
+    return checkpoint_name(t.reshape(t.shape[:-2] + (-1,)),
+                           CHUNK_MATS).reshape(t.shape)
+
+
 @jax.custom_vjp
 def unit_lower_inverse(N):
     """:func:`_unit_lower_inverse`; its cotangent is ``-T^T dT T^T`` with
-    the inverse ``T`` it kept."""
+    the inverse ``T`` it kept, which carries the name ``CHUNK_MATS``: the
+    name stands on the value the rule hands its backward (one given to the
+    call's result names another value, and the substitution is run again
+    for the one the rule reads)."""
     return _unit_lower_inverse(N)
 
 
 def _unit_lower_inverse_fwd(N):
-    T = _unit_lower_inverse(N)
+    T = _kept(_unit_lower_inverse(N))
     return T, T
 
 
@@ -215,18 +235,32 @@ def carried_states(S, wk_q, w_v, k_end, decay, compute_dtype):
     return jax.lax.scan(step, S, (wk_q, w_v, k_end, decay))
 
 
+def _padded_chunks(T, chunk):
+    """(chunks, segments) that ``T`` steps are walked in: whole segments of
+    like length, at most ``SEGMENT_CHUNKS`` chunks each."""
+    chunks = -(-T // chunk)
+    segments = -(-chunks // SEGMENT_CHUNKS)
+    return segments * -(-chunks // segments), segments
+
+
 def delta_rule_segment(S, q, k, v, g, beta, compute_dtype):
     """One segment, all of its chunks: ``S`` [b, H, K, V] enters; ``q``,
     ``k``, ``g`` [b, c, L, H, K], ``v`` [b, c, L, H, V], ``beta``
     [b, c, L, H] -> (the state that leaves, ``o`` [b, c, L, H, V]), both in
-    the accumulator dtype."""
+    the accumulator dtype. The in-chunk matrices carry the name
+    ``CHUNK_MATS`` as the backward reads them: ``A`` (the cotangents of
+    ``beta`` and of the products) and ``T`` (named by the inverse's own
+    rule, which reads it) in the accumulator dtype, ``B`` in the compute
+    dtype its one reader takes it in; no value is rounded for it. A
+    checkpoint that keeps them recomputes no ``within_block``, no
+    off-diagonal product and no inverse."""
     cd, sd = compute_dtype, acc_dtype(compute_dtype)
-    L = k.shape[2]
     g, beta = g.astype(sd), beta.astype(sd)
     G = jnp.cumsum(g, axis=2)
     A, B = _decayed_products(q, k, G, g, cd)
     by_head = lambda t: jnp.moveaxis(t, 3, 2)            # [b, c, H, L, ...]
     beta_h = by_head(beta)
+    A, B = _kept(A), _kept(B.astype(cd))
     T = unit_lower_inverse(beta_h[..., None] * A)
     ks, grow = by_head(k.astype(sd)), by_head(jnp.exp(G))
     to_end = by_head(jnp.exp(G[:, :, -1:] - G))
@@ -243,7 +277,7 @@ def delta_rule_segment(S, q, k, v, g, beta, compute_dtype):
         chunk_major(w[..., :V]), chunk_major((ks * to_end).astype(cd)),
         chunk_major(jnp.exp(G[:, :, -1])), cd)
     o = jnp.moveaxis(read, 0, 1) + jnp.einsum(
-        "bchts,bchsv->bchtv", B.astype(cd),
+        "bchts,bchsv->bchtv", B,
         jnp.moveaxis(U, 0, 1).astype(cd), preferred_element_type=sd)
     return S, jnp.moveaxis(o, 2, 3)
 
@@ -256,24 +290,24 @@ def delta_rule_chunked(q, k, v, g, beta, chunk, compute_dtype):
     last chunk (or segment of chunks) is padded with steps of ``g`` 0,
     ``beta`` 0 and ``k`` 0, which leave the state alone, and cut again."""
     b, T, H, K = k.shape
-    chunks = -(-T // chunk)
-    segments = -(-chunks // SEGMENT_CHUNKS)
-    per = -(-chunks // segments)                 # chunks a segment
-    pad = segments * per * chunk - T
+    chunks, segments = _padded_chunks(T, chunk)
+    pad = chunks * chunk - T
     parts = (q, k, v, g, beta)
     if pad:
         parts = tuple(jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                       for t in parts)
     # segment-major: [segments, b, chunks a segment, chunk, ...]
     parts = tuple(jnp.moveaxis(
-        t.reshape(b, segments, per, chunk, *t.shape[2:]), 1, 0) for t in parts)
+        t.reshape(b, segments, -1, chunk, *t.shape[2:]), 1, 0) for t in parts)
     segment = jax.checkpoint(
-        lambda S, xs: delta_rule_segment(S, *xs, compute_dtype))
+        lambda S, xs: delta_rule_segment(S, *xs, compute_dtype),
+        policy=_SEGMENT_POLICY)
 
     def walk(S, xs):
-        # a checkpoint around the layer whose policy keeps these names (the
-        # block stacks') holds the state every segment was handed and what
-        # it read out, and runs no segment again but for its own backward
+        # a checkpoint around the layer whose policy keeps these names and
+        # the in-chunk matrices' (the block stacks') holds the state every
+        # segment was handed and what it read out, and runs no segment
+        # again but for its own backward, which forms no in-chunk matrix
         S, o = segment(checkpoint_name(S, SCAN_CARRY), xs)
         return S, checkpoint_name(o, NORM_IN)
 
@@ -372,6 +406,15 @@ class KimiDeltaAttentionImpl(LayerImpl):
                 "(the carried state crosses one boundary fewer), set when "
                 "the layer is traced",
                 layer=str(getattr(self, "index", ""))).set(-(-T // chunk))
+            get_registry().gauge(
+                "kda_kept_bytes",
+                "Bytes of in-chunk matrices (A, B and the triangular "
+                "inverse of every chunk and head) that one differentiated "
+                "layer hands its backward by name, set when the layer is "
+                "traced",
+                layer=str(getattr(self, "index", ""))).set(
+                    b * _padded_chunks(T, chunk)[0] * H * chunk * chunk
+                    * (2 * jnp.dtype(sd).itemsize + jnp.dtype(cd).itemsize))
             o = delta_rule_chunked(q, k, v, g, beta, chunk, cd)
         gate = jax.nn.sigmoid(proj(proj(x, "W_ga"), "W_gb"))
         y = (rms_norm(o, params["gn"], c.eps, sd).reshape(b, T, d) * gate)

@@ -1,7 +1,9 @@
 """The Kimi Delta Attention mixer (``nn/layers/kda.py``): the chunked delta
 rule against the recurrence token by token (loss and every gradient, several
 chunk lengths, lengths that fill no chunk, strong decays, several segments),
-and the layer against its equations written out."""
+the in-chunk matrices kept by name across the block stacks' checkpoint (the
+same gradients bit for bit, one formation of the weights and one of the
+inverse fewer), and the layer against its equations written out."""
 import numpy as np
 import pytest
 
@@ -92,6 +94,149 @@ def test_a_state_that_is_not_carried_shows(monkeypatch):
     assert float(jnp.linalg.norm(cut - sound) / jnp.linalg.norm(sound)) > 0.1
     assert np.allclose(np.asarray(cut[:, :16]), np.asarray(sound[:, :16]),
                        atol=1e-5)
+
+
+def _under_the_block_checkpoint(chunk, T, decay, compute_dtype=jnp.float32):
+    """(the rule's loss and gradients on its five inputs through
+    ``base.block_checkpoint``, as a function of them; the inputs). A fresh
+    function a call: jax caches a function's trace, and the policy it was
+    traced under with it."""
+    from deeplearning4j_tpu.nn.layers.base import block_checkpoint
+    *args, w = _inputs(T, decay)
+    rule = lambda *a: kda.delta_rule_chunked(*a, chunk, compute_dtype)
+    loss = lambda *a: jnp.sum(block_checkpoint(rule)(*a) * w)
+    return jax.value_and_grad(loss, (0, 1, 2, 3, 4)), args
+
+
+def _strike_the_name(monkeypatch):
+    """Both policies the in-chunk matrices sit under as they were before
+    they listed the matrices' name: the block stacks' one, and the
+    segment's own, which kept its inputs alone."""
+    from deeplearning4j_tpu.nn.layers import base
+    monkeypatch.setattr(
+        base, "_BLOCK_POLICY", jax.checkpoint_policies.save_only_these_names(
+            base.FLASH_RES, base.NORM_IN, base.SCAN_CARRY))
+    monkeypatch.setattr(kda, "_SEGMENT_POLICY",
+                        jax.checkpoint_policies.nothing_saveable)
+
+
+@pytest.mark.parametrize("chunk,T,decay,per_segment,compute_dtype", [
+    (8, 40, 1.0, None, jnp.float32), (16, 50, 0.05, None, jnp.float32),
+    (32, 96, 1.0, None, jnp.float32), (64, 150, 1.0, None, jnp.float32),
+    (64, 128, 6.0, None, jnp.float32), (48, 100, 1.0, None, jnp.float32),
+    # five chunks in segments of two: the last is padded
+    (16, 70, 1.0, 2, jnp.float32),
+    # the cell's policy: the B that is kept is the rounded one its reader
+    # takes, and the inverse is rounded after it is kept
+    (64, 150, 1.0, None, jnp.bfloat16), (16, 70, 1.0, 2, jnp.bfloat16),
+])
+def test_keeping_the_chunk_matrices_changes_no_bit(
+        chunk, T, decay, per_segment, compute_dtype, monkeypatch):
+    """Under the block stacks' checkpoint the rule's loss and its gradients
+    on all five inputs are, bit for bit, those of the same program with the
+    name struck from both policies: what is kept is what would be formed
+    again."""
+    if per_segment:
+        monkeypatch.setattr(kda, "SEGMENT_CHUNKS", per_segment)
+    grad, args = _under_the_block_checkpoint(chunk, T, decay, compute_dtype)
+    kept = jax.jit(grad)(*args)
+    _strike_the_name(monkeypatch)
+    struck = jax.jit(_under_the_block_checkpoint(
+        chunk, T, decay, compute_dtype)[0])(*args)
+    for a, b in zip(jax.tree_util.tree_leaves(kept),
+                    jax.tree_util.tree_leaves(struck)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def _equations(jaxpr, trips=1):
+    """(equation, times it runs) of ``jaxpr`` and of every jaxpr inside it:
+    a ``scan``'s body runs its ``length`` times."""
+    for eqn in jaxpr.eqns:
+        yield eqn, trips
+        inside = trips * eqn.params.get("length", 1)
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner, inside)
+
+
+def _formed(grad, args):
+    """What the differentiated rule forms, by equations in its program:
+    (``exp`` over the [SUB, SUB, K] weights of a diagonal block, ``neg``
+    over a [SUB, i SUB] block row of the inverse's substitution: one a
+    block row below the first, formed nowhere else)."""
+    K, chunk = args[1].shape[-1], 64
+    rows = [(kda.SUB, i * kda.SUB) for i in range(1, chunk // kda.SUB)]
+    weights = inverse = 0
+    for eqn, _ in _equations(jax.make_jaxpr(grad)(*args).jaxpr):
+        if eqn.primitive.name not in ("exp", "neg"):
+            continue
+        shape = tuple(eqn.outvars[0].aval.shape)
+        weights += (eqn.primitive.name == "exp"
+                    and shape[-3:] == (kda.SUB, kda.SUB, K))
+        inverse += eqn.primitive.name == "neg" and shape[-2:] in rows
+    return weights, inverse
+
+
+def test_the_backward_forms_no_chunk_matrix_again(monkeypatch):
+    """The differentiated rule forms the [SUB, SUB, K] weights of the
+    diagonal blocks in two passes, each of two calls (q against k, k against
+    k): forward, and for the products' cotangents; and it runs the inverse's
+    substitution (three block rows a chunk of 64) once, forward. With the
+    name struck the segment's recomputed forward is a third pass over the
+    weights and a second substitution; struck from the block stacks' policy
+    alone it is too (a name inside nested checkpoints is kept only where
+    both list it)."""
+    formed = lambda: _formed(*_under_the_block_checkpoint(64, 256, 1.0))
+    assert formed() == (2 * 2, 3)
+    from deeplearning4j_tpu.nn.layers import base
+    monkeypatch.setattr(
+        base, "_BLOCK_POLICY", jax.checkpoint_policies.save_only_these_names(
+            base.FLASH_RES, base.NORM_IN, base.SCAN_CARRY))
+    assert formed() == (3 * 2, 2 * 3)
+    _strike_the_name(monkeypatch)
+    assert formed() == (3 * 2, 2 * 3)
+
+
+def test_the_name_on_the_inverses_result_keeps_another_value(monkeypatch):
+    """The inverse's rule reads the ``T`` its forward handed it: a name
+    given to the call's result instead stands on another value, and the
+    substitution runs a second time for the one the rule reads."""
+    monkeypatch.setattr(kda, "_unit_lower_inverse_fwd",
+                        lambda N: (kda._unit_lower_inverse(N),) * 2)
+    renamed = jax.custom_vjp(kda._unit_lower_inverse)
+    renamed.defvjp(kda._unit_lower_inverse_fwd, kda._unit_lower_inverse_bwd)
+    monkeypatch.setattr(kda, "unit_lower_inverse",
+                        lambda N: kda._kept(renamed(N)))
+    assert _formed(*_under_the_block_checkpoint(64, 256, 1.0)) == (2 * 2,
+                                                                   2 * 3)
+
+
+def test_the_kept_bytes_are_those_the_program_names():
+    """``kda_kept_bytes`` reads what the layer's differentiated program
+    gives the name ``CHUNK_MATS``, over all of its segments: 40 steps in
+    chunks of 16 are three chunks, the last padded; ``A`` and the inverse in
+    float32, ``B`` in the compute dtype."""
+    from deeplearning4j_tpu.monitor import get_registry
+    from deeplearning4j_tpu.nn.layers.base import CHUNK_MATS, block_checkpoint
+    layer = _layer()
+    layer.index = "kept"
+    params, _ = layer.init(jax.random.PRNGKey(1))
+    loss = lambda p, x: jnp.sum(block_checkpoint(
+        lambda p, x: layer.forward(p, {}, x)[0])(p, x))
+    program = jax.make_jaxpr(jax.grad(loss))(
+        params, jnp.zeros((2, 40, 24), jnp.float32))
+    named = sum(trips * eqn.outvars[0].aval.size
+                * eqn.outvars[0].aval.dtype.itemsize
+                for eqn, trips in _equations(program.jaxpr)
+                if eqn.primitive.name == "name"
+                and eqn.params["name"] == CHUNK_MATS)
+    rows = {row["labels"]["layer"]: row["value"]
+            for row in get_registry().snapshot()["kda_kept_bytes"]}
+    cd = jnp.dtype(layer.compute_dtype).itemsize
+    assert rows["kept"] == named == 2 * 3 * 2 * 16 * 16 * (4 + 4 + cd)
 
 
 def _layer(n_in=24, H=2, K=8, chunk=16):
