@@ -237,6 +237,10 @@ class RoutedExpertsLayer(FeedForwardLayer):
     shared_hidden: Optional[int] = None
     renormalize: bool = True
     routed_scaling_factor: float = 1.0
+    #: ``"sigmoid"`` (above, with the score-correction bias) or
+    #: ``"softmax"``: ``s = softmax(x Wr)`` over all ``num_experts`` in
+    #: float32 and no bias, the rest as above
+    score: str = "sigmoid"
     activation: Optional[str] = "identity"
 
     def get_output_type(self, index, input_type):
@@ -711,6 +715,15 @@ class SelfAttentionLayer(BaseRecurrentLayer):
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     latent_norm_eps: float = 1e-5
+    #: causal only: query i sees the keys i - window < j <= i (a sliding
+    #: window of ``window`` keys, itself among them); None sees every
+    #: earlier key. The flash kernels walk such a call as a band of blocks
+    window: Optional[int] = None
+    #: a published ``rope_parameters`` block that scales ``rope_theta``'s
+    #: rotation (``rope_type`` "yarn": ``factor``,
+    #: ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    #: ``attention_factor``); None rotates by the plain frequencies
+    rope_scaling: Optional[dict] = None
 
 
 @register
@@ -820,7 +833,8 @@ class KimiDeltaAttentionLayer(BaseRecurrentLayer):
 class HybridBlockStack(BaseRecurrentLayer):
     """A stack of pre-normed decoder blocks, one per entry of
     ``layer_types`` (``"mamba"``: a :class:`Mamba2Layer` mixer; ``"attention"``:
-    causal grouped-query attention without positions; ``"kda"``: a
+    causal grouped-query attention, rotary where ``rope_theta`` is given;
+    ``"window"``: the same in a sliding window of ``window`` keys; ``"kda"``: a
     :class:`KimiDeltaAttentionLayer` mixer; ``"mla"``: causal attention in
     :class:`SelfAttentionLayer`'s latent layout, without positions), each
     followed by what its entry of ``ffn_types`` names (``"dense"``, the
@@ -835,8 +849,9 @@ class HybridBlockStack(BaseRecurrentLayer):
     ``[n, ...]`` under the keys ``r<run>.<leaf>`` and is one ``lax.scan``;
     the training step keeps each block's input and recomputes the rest in
     the backward pass. The stream between blocks is float32 whatever the
-    compute dtype. The experts' score-correction bias is the stack's state
-    (``r<run>.b``), which no optimizer moves."""
+    compute dtype. The experts' score-correction bias of sigmoid scores
+    (``expert_score``) is the stack's state (``r<run>.b``), which no
+    optimizer moves; softmax scores have none."""
     layer_types: Optional[List[str]] = None
     ffn_types: Optional[List[str]] = None
     n_hidden: Optional[int] = None
@@ -860,6 +875,14 @@ class HybridBlockStack(BaseRecurrentLayer):
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    #: rotary positions of the ``"attention"`` blocks (None: none) and
+    #: their scaling (:class:`SelfAttentionLayer`'s ``rope_scaling``)
+    rope_theta: Optional[float] = None
+    rope_scaling: Optional[dict] = None
+    #: the ``"window"`` blocks: grouped-query attention of ``num_heads``
+    #: over ``num_kv_heads`` in a sliding window of ``window`` keys, rotary
+    #: by ``rope_theta`` unscaled
+    window: Optional[int] = None
     #: the ``"experts"`` blocks' :class:`RoutedExpertsLayer`
     num_experts: int = 8
     experts_held: Optional[List[int]] = None
@@ -868,6 +891,7 @@ class HybridBlockStack(BaseRecurrentLayer):
     shared_hidden: Optional[int] = None
     renormalize: bool = True
     routed_scaling_factor: float = 1.0
+    expert_score: str = "sigmoid"
 
     def set_n_in(self, input_type, override=False):
         super().set_n_in(input_type, override)

@@ -8,8 +8,11 @@ across a mesh 'sp' axis (see ``deeplearning4j_tpu/parallel/sequence.py``).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..weights import host_full
 from .base import LayerImpl, implements, acc_dtype, pet_dtype
@@ -17,14 +20,16 @@ from .normalization import rms_norm
 
 
 def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
-        key_mask=None, scale=None):
+        key_mask=None, scale=None, window=None):
     """q: [b, T, h, d], k: [b, T, h_kv, d], v: [b, T, h_kv, d_v] with
     ``h_kv`` dividing ``h`` (grouped queries: head i reads key-value head
     ``i // (h // h_kv)``; the value heads may have a size of their own).
     Returns [b, T, h, d_v]. Scaled dot-product attention (``scale`` None:
     ``1 / sqrt(d)``) with f32 softmax accumulation (bf16-safe).
     ``key_mask``: [b, S] with 1 for real keys, 0 for padding — padded keys
-    are excluded from the softmax.
+    are excluded from the softmax. ``window`` (causal only): query i sees
+    the keys i - window < j <= i (a sliding window of ``window`` keys,
+    itself among them), which the flash kernels walk as a band of blocks.
 
     Long sequences route through the Pallas flash-attention kernel
     (``ops/flash_attention.py``): blockwise online softmax, O(T) memory
@@ -41,6 +46,8 @@ def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
     """
     from ...ops import flash_attention as _fa
 
+    if window is not None and not causal:
+        raise ValueError("mha: a window bands causal attention only")
     T, d = q.shape[1], q.shape[-1]
     rate = dropout_rate if (train and rng is not None) else 0.0
     k, v = _repeat_kv(k, v, q.shape[2])
@@ -56,7 +63,8 @@ def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
         return _fa.flash_attention(
             q.astype(compute_dtype), k.astype(compute_dtype),
             v.astype(compute_dtype), causal=causal, scale=scale,
-            key_mask=key_mask, dropout_rate=rate, dropout_seed=seed)
+            key_mask=key_mask, dropout_rate=rate, dropout_seed=seed,
+            window=window)
     if causal and T >= _fa.MIN_SEQ and _fa._on_tpu():
         raise ValueError(
             f"mha: a causal call of {T} tokens (head size {d}, keys "
@@ -68,6 +76,8 @@ def mha(q, k, v, causal, compute_dtype, dropout_rate=0.0, rng=None, train=False,
     if causal:
         T, S = q.shape[1], k.shape[1]
         visible = jnp.tril(jnp.ones((T, S), bool))[None, None]
+        if window is not None:
+            visible &= ~jnp.tril(jnp.ones((T, S), bool), -window)[None, None]
     if key_mask is not None:
         km = (key_mask[:, None, None, :] > 0)
         visible = km if visible is None else (visible & km)
@@ -119,16 +129,52 @@ def _dense_attention(q, k, v, visible, compute_dtype, dropout_rate=0.0,
                       preferred_element_type=pet_dtype(compute_dtype))
 
 
-def rope(x, theta, start=0):
+def yarn(theta, half, factor, original_max_position_embeddings, beta_fast=32,
+         beta_slow=1, attention_factor=None, **_):
+    """(inv_freq [half], attention factor) of YaRN's rotary scaling (Peng et
+    al. 2023; ``_compute_yarn_parameters`` of transformers), on the host in
+    float64: channel i keeps its frequency ``theta ** (-i / half)`` below
+    the channel whose wavelength fits ``beta_fast`` times into the original
+    context, is divided by ``factor`` above the one where ``beta_slow``
+    does, and is blended linearly between them; the cosine and sine are
+    multiplied by ``attention_factor`` (None: ``0.1 ln(factor) + 1``)."""
+    dim, ctx = 2 * half, float(original_max_position_embeddings)
+    edge = lambda turns: (dim * math.log(ctx / (turns * 2 * math.pi))
+                          / (2 * math.log(theta)))
+    low = max(math.floor(edge(beta_fast)), 0)
+    high = min(math.ceil(edge(beta_slow)), dim - 1)
+    if high == low:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0, 1)
+    extrapolated = float(theta) ** (-np.arange(half) / half)
+    inv_freq = extrapolated / factor * ramp + extrapolated * (1 - ramp)
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return inv_freq, float(attention_factor)
+
+
+def rope(x, theta, start=0, scaling=None):
     """Rotary position embedding of ``x`` [b, T, h, d] at positions
     ``start`` .. ``start + T - 1``: the pair (i, i + d/2) of every head is
     rotated by ``position * theta ** (-2 i / d)`` (the rotate-half pairing).
-    Angles and rotation in float32; returned in ``x``'s dtype."""
+    ``scaling``: a published ``rope_parameters`` block whose ``rope_type``
+    is ``"yarn"`` (:func:`yarn`'s frequencies, the rotation scaled by its
+    attention factor), or None. Angles and rotation in float32; returned in
+    ``x``'s dtype."""
     T, half = x.shape[1], x.shape[-1] // 2
-    inv_freq = 1.0 / (float(theta) ** (jnp.arange(half, dtype=jnp.float32)
-                                       / half))
+    if scaling is None:
+        inv_freq = 1.0 / (float(theta) ** (jnp.arange(half, dtype=jnp.float32)
+                                           / half))
+    else:
+        if scaling.get("rope_type") != "yarn":
+            raise ValueError(f"rope: scaling {scaling.get('rope_type')!r} "
+                             f"is not supported (yarn is)")
+        inv_freq, factor = yarn(float(theta), half, **scaling)
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
     angle = (start + jnp.arange(T, dtype=jnp.float32))[:, None] * inv_freq
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if scaling is not None:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
@@ -279,7 +325,8 @@ class SelfAttentionImpl(LayerImpl):
             # window (p - L, p]: eviction emulated per query, not per chunk
             visible = (valid
                        & (pos_all[:, None, :] <= qpos[None, :, None])
-                       & (pos_all[:, None, :] > qpos[None, :, None] - L))
+                       & (pos_all[:, None, :] > qpos[None, :, None]
+                          - min(L, self.conf.window or L)))
         else:
             # non-causal streaming: every key retained after this chunk's
             # writes (positions > n + T - 1 - L), matching write-then-attend
@@ -314,7 +361,8 @@ class SelfAttentionImpl(LayerImpl):
                     "step is not supported (a shard does not know its "
                     "global positions)")
             start = 0 if carry is None else carry[3]
-            q, k = rope(q, c.rope_theta, start), rope(k, c.rope_theta, start)
+            q, k = (rope(t, c.rope_theta, start, c.rope_scaling)
+                    for t in (q, k))
         if carry is not None:
             o, new_carry = self._cached_attention(
                 q, k, v, carry, cd, key_mask=mask,
@@ -331,11 +379,12 @@ class SelfAttentionImpl(LayerImpl):
             # path, giving each train step a fresh mask
             from ...parallel.sequence import sp_attend
 
-            if kv != h or c.attention_scale is not None:
+            if kv != h or c.attention_scale is not None or c.window:
                 raise ValueError(
-                    "SelfAttentionLayer: num_kv_heads and attention_scale "
-                    "under a sequence-parallel step are not supported (the "
-                    "ring takes equal heads and 1/sqrt(head_dim))")
+                    "SelfAttentionLayer: num_kv_heads, attention_scale and "
+                    "window under a sequence-parallel step are not supported "
+                    "(the ring takes equal heads, 1/sqrt(head_dim) and the "
+                    "whole causal triangle)")
             rate = c.dropout_rate if (train and rng is not None) else 0.0
             seed = None
             if rate > 0.0:
@@ -347,7 +396,7 @@ class SelfAttentionImpl(LayerImpl):
                           dropout_seed=seed)
         else:
             o = mha(q, k, v, c.causal, cd, c.dropout_rate, rng, train,
-                    key_mask=mask, scale=c.attention_scale)
+                    key_mask=mask, scale=c.attention_scale, window=c.window)
         o = o.reshape(b, T, h * v.shape[-1])
         y = o @ params["Wo"].astype(o.dtype)
         if "b" in params:

@@ -1,9 +1,10 @@
-"""Hybrid block stack: pre-normed decoder blocks whose mixer is one of four
-kinds, state-space (``Mamba2Layer``), grouped-query attention without
-positions, a gated delta rule (``KimiDeltaAttentionLayer``) or latent
-attention (``SelfAttentionLayer`` in its latent layout), each with a gated
-MLP or routed experts (``RoutedExpertsLayer``), in the order ``layer_types``
-and ``ffn_types`` give.
+"""Hybrid block stack: pre-normed decoder blocks whose mixer is one of five
+kinds, state-space (``Mamba2Layer``), grouped-query attention (rotary or
+without positions), the same in a sliding window, a gated delta rule
+(``KimiDeltaAttentionLayer``) or latent attention (``SelfAttentionLayer`` in
+its latent layout), each with a gated MLP or routed experts
+(``RoutedExpertsLayer``), in the order ``layer_types`` and ``ffn_types``
+give.
 
 Net-new vs the 0.9.x reference, like :mod:`.looped`, and built the same way
 from the layers the package has: every run of like blocks keeps its weights in
@@ -23,6 +24,7 @@ import jax
 
 from ..conf.layers import (GatedDenseLayer, KimiDeltaAttentionLayer,
                            Mamba2Layer, RoutedExpertsLayer, SelfAttentionLayer)
+from ...monitor import get_registry
 from ..weights import host_full
 from .attention import SelfAttentionImpl
 from .base import LayerImpl, implements, acc_dtype, block_checkpoint
@@ -33,12 +35,13 @@ from .mamba import Mamba2Impl
 from .moe import RoutedExpertsImpl
 from .normalization import rms_norm
 
-KINDS = ("mamba", "attention", "kda", "mla")
+KINDS = ("mamba", "attention", "kda", "mla", "window")
 FFN_KINDS = ("dense", "experts")
 #: the latent layout's matrices (``gc``, its norm's gain, is no matrix)
 MLA_KEYS = ("Wq", "Wkv_a", "Wkv_b", "Wo")
 #: the scope a block's mixer half runs under, by kind
-MIXER_SCOPE = {"mamba": "ssm", "attention": "attn", "kda": "kda", "mla": "mla"}
+MIXER_SCOPE = {"mamba": "ssm", "attention": "attn", "kda": "kda", "mla": "mla",
+               "window": "swa"}
 
 
 @implements("HybridBlockStack")
@@ -60,15 +63,24 @@ class HybridBlockStackImpl(LayerImpl):
                          attention_scale=c.attention_scale, causal=True,
                          rope_theta=None, has_bias=False,
                          activation="identity", **init)
+        grouped = dict(attention, num_kv_heads=c.num_kv_heads,
+                       head_dim=c.head_dim)
         self.mixers = {
-            "attention": SelfAttentionImpl(SelfAttentionLayer(
-                num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
-                **attention), gc),
+            "attention": SelfAttentionImpl(SelfAttentionLayer(**dict(
+                grouped, rope_theta=c.rope_theta,
+                rope_scaling=c.rope_scaling)), gc),
             "mamba": Mamba2Impl(Mamba2Layer(
                 n_in=c.n_in, n_out=c.n_out, num_heads=c.mamba_heads,
                 head_dim=c.mamba_head_dim, state_size=c.mamba_state_size,
                 conv_size=c.mamba_conv_size, chunk_size=c.mamba_chunk_size,
                 eps=c.eps, **init), gc)}
+        if "window" in c.layer_types:
+            if not c.window:
+                raise ValueError("HybridBlockStack: window blocks need a "
+                                 "window")
+            self.mixers["window"] = SelfAttentionImpl(SelfAttentionLayer(
+                **dict(grouped, rope_theta=c.rope_theta,
+                       window=c.window)), gc)
         if "kda" in c.layer_types:
             self.mixers["kda"] = KimiDeltaAttentionImpl(
                 KimiDeltaAttentionLayer(
@@ -90,7 +102,8 @@ class HybridBlockStackImpl(LayerImpl):
                 experts_held=c.experts_held, top_k=c.experts_per_token,
                 n_hidden=c.expert_hidden, shared_hidden=c.shared_hidden,
                 renormalize=c.renormalize,
-                routed_scaling_factor=c.routed_scaling_factor, **init), gc)
+                routed_scaling_factor=c.routed_scaling_factor,
+                score=c.expert_score, **init), gc)
         groups = [(pair, len(list(group))) for pair, group in
                   itertools.groupby(zip(c.layer_types, ffn_types))]
         #: (mixer, blocks) of every run of like blocks, in order
@@ -108,10 +121,10 @@ class HybridBlockStackImpl(LayerImpl):
                 self.runs, self.run_ffns,
                 jax.random.split(rng, len(self.runs)))):
             k_mixer, k_ffn = jax.random.split(key)
-            if kind in ("attention", "mla"):
+            if kind in ("attention", "mla", "window"):
                 run = stacked_matrices(
                     self, (self.mixers[kind],),
-                    ATTN_KEYS if kind == "attention" else MLA_KEYS, k_mixer, n)
+                    MLA_KEYS if kind == "mla" else ATTN_KEYS, k_mixer, n)
             else:
                 run = self.mixers[kind].init(k_mixer, lead=(n,))[0]
             if kind == "mla":
@@ -181,6 +194,12 @@ class HybridBlockStackImpl(LayerImpl):
         for sub in (*self.mixers.values(), getattr(self, "experts", None)):
             if sub is not None:
                 sub.index = getattr(self, "index", "")
+        if "window" in self.mixers:
+            get_registry().gauge(
+                "attention_window",
+                "Keys a query of a hybrid stack's sliding-window blocks sees "
+                "(itself among them), set when the stack is traced",
+                layer=str(getattr(self, "index", ""))).set(self.conf.window)
         of_run = lambda leaves, i: {
             k.partition(".")[2]: v for k, v in leaves.items()
             if k.startswith(f"r{i}.")}

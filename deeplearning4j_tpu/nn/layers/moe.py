@@ -362,9 +362,9 @@ class RoutedExpertsImpl(LayerImpl):
     router, over every published expert), the held experts' ``We_gate``,
     ``We_up`` [held, n_in, n_hidden], ``We_down`` [held, n_hidden, n_out],
     the shared expert's ``Ws_gate``, ``Ws_up`` [n_in, shared_hidden],
-    ``Ws_down`` [shared_hidden, n_out]. State: ``b`` [num_experts], the
-    score-correction bias, which enters the choice only and which no
-    gradient reaches."""
+    ``Ws_down`` [shared_hidden, n_out]. State, of sigmoid scores only: ``b``
+    [num_experts], the score-correction bias, which enters the choice only
+    and which no gradient reaches."""
 
     EXPERT_KEYS = ("We_gate", "We_up", "We_down")
     SHARED_KEYS = ("Ws_gate", "Ws_up", "Ws_down")
@@ -382,6 +382,9 @@ class RoutedExpertsImpl(LayerImpl):
         if not 1 <= c.top_k <= c.num_experts:
             raise ValueError(f"RoutedExpertsLayer needs 1 <= top_k <= "
                              f"num_experts (got {c.top_k}, {c.num_experts})")
+        if c.score not in ("sigmoid", "softmax"):
+            raise ValueError(f"RoutedExpertsLayer: score is 'sigmoid' or "
+                             f"'softmax'; got {c.score!r}")
         self.held = held
 
     def init(self, rng, lead=()):
@@ -397,19 +400,27 @@ class RoutedExpertsImpl(LayerImpl):
         params = {name: self._init_w(key, lead + shape, *shape[-2:])
                   for (name, shape), key in zip(
                       shapes.items(), jax.random.split(rng, len(shapes)))}
+        if c.score == "softmax":
+            return params, {}
         return params, {"b": host_full(lead + (c.num_experts,), 0, self.dtype)}
 
     def route(self, x, w_router, bias):
         """(the k chosen experts [n, k], their weights [n, k]) of ``x``
         [n, n_in], at least float32 and with the product at full precision:
-        a choice is a comparison of scores."""
+        a choice is a comparison of scores. ``bias``: the sigmoid scores'
+        correction, None for softmax scores."""
         c = self.conf
         rdt = jnp.promote_types(jnp.float32, self.dtype)
         logits = jnp.dot(x.astype(rdt), w_router.astype(rdt),
                          precision=jax.lax.Precision.HIGHEST)
-        scores = jax.nn.sigmoid(logits)
-        _, chosen = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(bias.astype(rdt)), int(c.top_k))
+        if c.score == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, chosen = jax.lax.top_k(scores, int(c.top_k))
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, chosen = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(bias.astype(rdt)),
+                int(c.top_k))
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if c.renormalize:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
@@ -426,7 +437,7 @@ class RoutedExpertsImpl(LayerImpl):
         tile, standing = tile_plan(flat.shape[0], int(c.top_k), held,
                                    c.num_experts)
         with jax.named_scope("router"):
-            chosen, weights = self.route(flat, params["Wr"], state["b"])
+            chosen, weights = self.route(flat, params["Wr"], state.get("b"))
         with jax.named_scope("dispatch"):
             local = np.full((c.num_experts,), held, np.int32)
             local[self.held] = np.arange(held)

@@ -21,7 +21,10 @@ Causal, they collapse into ONE dimension over the list of visible (q block,
 k block) pairs (:func:`causal_pairs`), whose tables arrive by scalar
 prefetch and drive the index maps: a block above the diagonal is never a
 grid step, and only a block that straddles the diagonal builds the causal
-mask (blocks wholly below it take the unmasked body). The BlockSpec index
+mask (blocks wholly below it take the unmasked body). A causal call with a
+``window`` (a sliding window of keys) walks the band alone: a block below
+the band is no grid step either, and one that straddles its lower edge
+masks that edge too. The BlockSpec index
 maps stage one block of each operand into VMEM per step (no full-sequence
 VMEM residency — T is bounded by HBM, not VMEM), and the running
 accumulators (m/l/acc, dq, dk/dv) live in VMEM scratch that persists across
@@ -80,6 +83,16 @@ VMEM_LIMIT = 32 * 2 ** 20
 #: more of the walk is diagonal blocks, whose hidden cells are computed.
 _EDGES = {"flash_fwd": (1024, 1024), "flash_dq": (1024, 1024),
           "flash_dkv": (1024, 1024)}
+#: per kernel: the largest edge a banded call takes, as a share of its
+#: window. Swept on the v5e at bh 32, T 8192, d 128, a window of 1024, bf16
+#: (``perf_flash_check.py blocksweep 1024``; the table is in PERF.md): the
+#: backward kernels walk half the window best, where a 1024 × 1024 pair is
+#: cells of both edges of the band (dq 2.75 ms a call at 512 × 512 against
+#: 3.06 at 1024 × 1024, dk/dv 3.65 against 3.70); the forward keeps the
+#: causal edges (2.47 against 3.11 at 512 × 512: its accumulator is
+#: rescaled every step, and a third of its steps more cost more than the
+#: cells they skip)
+_WINDOW_SHARE = {"flash_fwd": 1.0, "flash_dq": 0.5, "flash_dkv": 0.5}
 
 
 def vmem_bytes(kernel: str, block_q: int, block_k: int, d: int, dtype,
@@ -127,7 +140,7 @@ def _divisors(T: int, cap: int):
 
 
 def pick_blocks(kernel: str, Tq: int, Tk: int, d: int, dtype,
-                d_v: int | None = None):
+                d_v: int | None = None, window: int | None = None):
     """(block_q, block_k) for one call of ``kernel`` (``flash_fwd``,
     ``flash_dq``, ``flash_dkv``) from what the call can see: among the
     128-multiples that divide ``Tq`` and ``Tk`` and are no larger than the
@@ -137,9 +150,14 @@ def pick_blocks(kernel: str, Tq: int, Tk: int, d: int, dtype,
     size ``d_v`` (``d`` where None) (of two tiles of one area, the one with more query
     rows). The two lengths are tiled apart, and a length that no larger edge
     divides falls back to smaller ones down to 128, so every 128-multiple
-    takes the flash path. Dropout coordinates hash GLOBAL positions, so the
-    three kernels may pick different edges without changing any decision."""
+    takes the flash path. A banded call (``window``) caps both edges at
+    :data:`_WINDOW_SHARE` of its window. Dropout coordinates hash GLOBAL
+    positions, so the three kernels may pick different edges without
+    changing any decision."""
     cap_q, cap_k = _EDGES[kernel]
+    if window is not None:
+        cap = max(MIN_BLOCK, int(window * _WINDOW_SHARE[kernel]))
+        cap_q, cap_k = min(cap_q, cap), min(cap_k, cap)
     fits = [(bq, bk) for bq in _divisors(Tq, cap_q)
             for bk in _divisors(Tk, cap_k)
             if vmem_bytes(kernel, bq, bk, d, dtype, d_v) <= VMEM_LIMIT]
@@ -147,12 +165,13 @@ def pick_blocks(kernel: str, Tq: int, Tk: int, d: int, dtype,
                default=(MIN_BLOCK, MIN_BLOCK))
 
 
-def _edges(kernel, Tq, Tk, d, dtype, block_q, block_k, d_v=None):
+def _edges(kernel, Tq, Tk, d, dtype, block_q, block_k, d_v=None,
+           window=None):
     """The chooser's edges, each overridden where the caller gave one (the
     sweep of ``perf_flash_check.py`` and the tests' multi-block grids)."""
     bq, bk = block_q, block_k
     if not (bq and bk):
-        pq, pk = pick_blocks(kernel, Tq, Tk, d, dtype, d_v)
+        pq, pk = pick_blocks(kernel, Tq, Tk, d, dtype, d_v, window)
         bq, bk = bq or pq, bk or pk
     if Tq % bq or Tk % bk or bq % MIN_BLOCK or bk % MIN_BLOCK:
         raise ValueError(f"{kernel}: edges ({bq}, {bk}) must be multiples of "
@@ -160,18 +179,34 @@ def _edges(kernel, Tq, Tk, d, dtype, block_q, block_k, d_v=None):
     return bq, bk
 
 
+def _window(causal, window):
+    """``window`` as a static int, or None; a window bands a causal walk
+    only."""
+    if window is None:
+        return None
+    if not causal or int(window) < 1:
+        raise ValueError(f"flash attention: a window ({window}) needs a "
+                         f"causal call and at least one key")
+    return int(window)
+
+
 def causal_pairs(nq: int, nk: int, block_q: int, block_k: int,
-                 k_major: bool = False):
+                 k_major: bool = False, window: int | None = None):
     """The causal walk as four int32 tables, one entry per grid step:
     (outer block, inner block, first of its row?, last of its row?), rows in
     order. A (q block, k block) pair is visible where the k block starts no
-    later than the q block ends. ``k_major`` False: a row is a q block and
-    its visible k blocks (forward, dq); True: a k block and its visible q
-    blocks (dk/dv). A k block past the last query (``Tk`` > ``Tq``) sees
-    none; it keeps the last q block as its one step, where the diagonal's
-    mask hides every cell, so that its zeros are still written."""
-    vis = (np.arange(nk)[None, :] * block_k
-           <= (np.arange(nq)[:, None] + 1) * block_q - 1)
+    later than the q block ends and, with a ``window`` (query i sees keys
+    i - window < j <= i), where it ends later than its first query's window
+    begins: the banded walk, in which a pair outside the band is never a
+    grid step. ``k_major`` False: a row is a q block and its visible k
+    blocks (forward, dq); True: a k block and its visible q blocks (dk/dv).
+    A k block past the last query (``Tk`` > ``Tq``) sees none; it keeps the
+    last q block as its one step, where the diagonal's mask hides every
+    cell, so that its zeros are still written."""
+    q_start = np.arange(nq)[:, None] * block_q
+    vis = np.arange(nk)[None, :] * block_k <= q_start + block_q - 1
+    if window is not None:
+        vis &= (np.arange(nk)[None, :] + 1) * block_k - 1 > q_start - window
     if k_major:
         vis = vis.T.copy()
         vis[~vis.any(axis=1), -1] = True
@@ -271,37 +306,43 @@ def _scratch(shape, dtype=jnp.float32):
     return pltpu.VMEM(shape, dtype)
 
 
-def _causal_mask(s, qi, kj, block_q, block_k):
+def _causal_mask(s, qi, kj, block_q, block_k, window=None):
     qpos = qi * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
     kpos = kj * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(kpos <= qpos, s, _NEG)
+    visible = kpos <= qpos
+    if window is not None:
+        visible &= kpos > qpos - window
+    return jnp.where(visible, s, _NEG)
 
 
-def _scores(q, k, km_ref, scale, masked, qi, kj):
+def _scores(q, k, km_ref, scale, masked, qi, kj, window=None):
     """f32 [block_q, block_k] logits of one block pair. The matmul runs in
     the SOURCE dtype (bf16 → native MXU pass) with f32 accumulation; the
     scale moves after the dot so bf16 q is not pre-rounded by it. ``masked``
-    (static) applies the causal mask: asked for only on blocks that
-    straddle the diagonal."""
+    (static) applies the causal mask, and the window's lower edge where
+    there is one: asked for only on blocks that straddle an edge."""
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale
     if masked:
-        s = _causal_mask(s, qi, kj, q.shape[0], k.shape[0])
+        s = _causal_mask(s, qi, kj, q.shape[0], k.shape[0], window)
     if km_ref is not None:
         s = jnp.where(km_ref[0, :, 0][None, :] > 0, s, _NEG)
     return s
 
 
-def _on_pair(causal, qi, kj, block_q, block_k, body):
+def _on_pair(causal, qi, kj, block_q, block_k, body, window=None):
     """Run ``body(masked)`` on block pair (qi, kj). Every pair the grid
     visits is visible; causal, the one that straddles the diagonal (its last
-    key lies past its first query) takes the masked body, one wholly below
-    it the unmasked: no iota, compare and select for a mask that hides
-    nothing."""
+    key lies past its first query) or, with a ``window``, the band's lower
+    edge (its first key lies at or before its last query's window begins)
+    takes the masked body, one wholly inside the band the unmasked: no
+    iota, compare and select for a mask that hides nothing."""
     if not causal:
         body(False)
         return
     straddles = (kj + 1) * block_k - 1 > qi * block_q
+    if window is not None:
+        straddles |= kj * block_k <= (qi + 1) * block_q - 1 - window
     pl.when(straddles)(lambda: body(True))
     pl.when(jnp.logical_not(straddles))(lambda: body(False))
 
@@ -330,14 +371,15 @@ def _split_refs(refs, causal, has_km, has_seed):
     return tabs, lead, km_ref, seed_ref, refs[int(has_km) + int(has_seed):]
 
 
-def _name(kernel, bq, bk):
-    """A kernel's name at its edges: the device trace's op name and the
+def _name(kernel, bq, bk, window=None):
+    """A kernel's name at its edges, and its window where it has one
+    (``flash_fwd_q1024_k1024_w1024``): the device trace's op name and the
     ``kernel`` label of the registry's gauges."""
-    return f"{kernel}_q{bq}_k{bk}"
+    return f"{kernel}_q{bq}_k{bk}" + ("" if window is None else f"_w{window}")
 
 
 def _launch(kernel, body, bq, bk, bh, n_out, n_in, pairs, specs, operands,
-            out_specs, out_shape, scratch):
+            out_specs, out_shape, scratch, window=None):
     """One flash ``pallas_call``; returns the list of its outputs. ``specs``
     / ``out_specs`` list (block shape, side) pairs with side "outer" or
     "inner" (or a finished BlockSpec): the index maps follow the
@@ -354,7 +396,7 @@ def _launch(kernel, body, bq, bk, bh, n_out, n_in, pairs, specs, operands,
                 "inner": lambda i, o, n: (i, n, 0)}
     spec = lambda s: (s if isinstance(s, pl.BlockSpec)
                       else _vspec(s[0], maps[s[1]]))
-    name = _name(kernel, bq, bk)
+    name = _name(kernel, bq, bk, window)
     from ..monitor import get_registry     # here: the package imports ops
     get_registry().gauge(
         "flash_grid_steps",
@@ -397,7 +439,7 @@ def _operands(q_side, k_side, bq, bk, q, k, v, km, seed, rate, bwd=()):
 
 
 # ------------------------------------------------------------------ forward
-def _fwd_kernel(*refs, causal, scale, nk, rate, has_km):
+def _fwd_kernel(*refs, causal, scale, nk, rate, has_km, window=None):
     tabs, (q_ref, k_ref, v_ref), km_ref, seed_ref, rest = _split_refs(
         refs, causal, has_km, rate > 0.0)
     o_ref, lse_ref, m_s, l_s, acc_s = rest
@@ -412,10 +454,16 @@ def _fwd_kernel(*refs, causal, scale, nk, rate, has_km):
 
     def _compute(masked):
         v = v_ref[0]
-        s = _scores(q_ref[0], k_ref[0], km_ref, scale, masked, qi, kj)
+        s = _scores(q_ref[0], k_ref[0], km_ref, scale, masked, qi, kj, window)
         m = m_s[:, 0]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))       # [Bq]
         p = jnp.exp(s - m_new[:, None])
+        if masked and window is not None:
+            # a row whose first visited block lies wholly outside its window
+            # sees only _NEG there, and exp(_NEG - _NEG) = 1 would count
+            # every hidden cell until a visible key's correction wipes them;
+            # hidden cells count nought instead, whatever the order
+            p = jnp.where(s > _NEG * 0.5, p, 0.0)
         alpha = jnp.exp(m - m_new)
         # softmax denominator accumulates UNDROPPED p — dropout applies to
         # the normalized probabilities (out = drop(softmax(s)) @ v), and
@@ -429,7 +477,7 @@ def _fwd_kernel(*refs, causal, scale, nk, rate, has_km):
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _on_pair(causal, qi, kj, bq, bk, _compute)
+    _on_pair(causal, qi, kj, bq, bk, _compute, window)
 
     @pl.when(last)
     def _():
@@ -448,18 +496,22 @@ def _fwd_kernel(*refs, causal, scale, nk, rate, has_km):
             lse_ref.shape[1:])
 
 
-def _fwd(q, k, v, km, seed, causal, scale, rate, block_q=None, block_k=None):
+def _fwd(q, k, v, km, seed, causal, scale, rate, block_q=None, block_k=None,
+         window=None):
     """q: [bh, Tq, d], k: [bh, Tk, d], v: [bh, Tk, d_v] (a value head size
     of its own), km: [bh, Tk, 8] key mask or None, seed: [3] i32 (seed,
     q_off, k_off — :func:`seed3`) or None (rate > 0) →
     (o [bh, Tq, d_v], lse [bh, Tq, 8]). ``block_q`` / ``block_k`` override
-    :func:`pick_blocks`."""
+    :func:`pick_blocks`; ``window`` (causal only) bands the walk."""
     bh, Tq, d = q.shape
     Tk, d_v = k.shape[1], v.shape[2]
-    bq, bk = _edges("flash_fwd", Tq, Tk, d, q.dtype, block_q, block_k, d_v)
+    window = _window(causal, window)
+    bq, bk = _edges("flash_fwd", Tq, Tk, d, q.dtype, block_q, block_k, d_v,
+                    window)
     nq, nk = Tq // bq, Tk // bk
     kern = functools.partial(_fwd_kernel, causal=causal, scale=scale, nk=nk,
-                             rate=rate, has_km=km is not None)
+                             rate=rate, has_km=km is not None,
+                             window=window)
     # lse is lane-padded to [bh, T, 8]: TPU block shapes need their last two
     # dims (8·k, 128·m) or full-dim; a (1, blk) slice of [bh, T] is
     # unlowerable. 8 f32 lanes per position is noise next to q/k/v
@@ -467,16 +519,17 @@ def _fwd(q, k, v, km, seed, causal, scale, rate, block_q=None, block_k=None):
                                 rate)
     return _launch(
         "flash_fwd", kern, bq, bk, bh, nq, nk,
-        causal_pairs(nq, nk, bq, bk) if causal else (),
+        causal_pairs(nq, nk, bq, bk, window=window) if causal else (),
         specs, operands,
         out_specs=[((1, bq, d_v), "outer"), ((1, bq, 8), "outer")],
         out_shape=[jax.ShapeDtypeStruct((bh, Tq, d_v), q.dtype),
                    jax.ShapeDtypeStruct((bh, Tq, 8), jnp.float32)],
-        scratch=[_scratch((bq, 8)), _scratch((bq, 8)), _scratch((bq, d_v))])
+        scratch=[_scratch((bq, 8)), _scratch((bq, 8)), _scratch((bq, d_v))],
+        window=window)
 
 
 # ----------------------------------------------------------------- backward
-def _dq_kernel(*refs, causal, scale, nk, rate, has_km):
+def _dq_kernel(*refs, causal, scale, nk, rate, has_km, window=None):
     tabs, (q_ref, k_ref, v_ref), km_ref, seed_ref, rest = _split_refs(
         refs, causal, has_km, rate > 0.0)
     do_ref, delta_ref, lse_ref, dq_ref, dq_s = rest
@@ -494,7 +547,7 @@ def _dq_kernel(*refs, causal, scale, nk, rate, has_km):
         lse = lse_ref[0, :, 0]
         delta = delta_ref[0, :, 0]
         k = k_ref[0]
-        s = _scores(q_ref[0], k, km_ref, scale, masked, qi, kj)
+        s = _scores(q_ref[0], k, km_ref, scale, masked, qi, kj, window)
         # s-guard: masked cells get p = 0 even on fully-masked rows, where
         # lse is the _NEG sentinel and exp(s - lse) would be exp(0) = 1
         p = jnp.where(s > _NEG * 0.5, jnp.exp(s - lse[:, None]), 0.0)
@@ -510,14 +563,14 @@ def _dq_kernel(*refs, causal, scale, nk, rate, has_km):
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _on_pair(causal, qi, kj, bq, bk, _compute)
+    _on_pair(causal, qi, kj, bq, bk, _compute, window)
 
     @pl.when(last)
     def _():
         dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, causal, scale, nq, rate, has_km):
+def _dkv_kernel(*refs, causal, scale, nq, rate, has_km, window=None):
     tabs, (q_ref, k_ref, v_ref), km_ref, seed_ref, rest = _split_refs(
         refs, causal, has_km, rate > 0.0)
     do_ref, delta_ref, lse_ref, dk_ref, dv_ref, dk_s, dv_s = rest
@@ -536,7 +589,7 @@ def _dkv_kernel(*refs, causal, scale, nq, rate, has_km):
         do = do_ref[0]
         lse = lse_ref[0, :, 0]
         delta = delta_ref[0, :, 0]
-        s = _scores(q, k_ref[0], km_ref, scale, masked, qj, ki)
+        s = _scores(q, k_ref[0], km_ref, scale, masked, qj, ki, window)
         # same s-guard as _dq_kernel (fully-masked rows: lse = _NEG)
         p = jnp.where(s > _NEG * 0.5,
                       jnp.exp(s - lse[:, None]), 0.0)    # [Bq, Bk]
@@ -558,7 +611,7 @@ def _dkv_kernel(*refs, causal, scale, nq, rate, has_km):
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _on_pair(causal, qj, ki, bq, bk, _compute)
+    _on_pair(causal, qj, ki, bq, bk, _compute, window)
 
     @pl.when(last)
     def _():
@@ -567,7 +620,7 @@ def _dkv_kernel(*refs, causal, scale, nq, rate, has_km):
 
 
 def dq_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
-             rate=0.0, block_q=None, block_k=None):
+             rate=0.0, block_q=None, block_k=None, window=None):
     """dq for one q-shard against one k/v block ([bh, Tq, d] × [bh, Tk, d];
     ``v`` and ``do`` at the value head size).
     ``delta``/``lse`` are the GLOBAL rowwise Δ and log-sum-exp ([bh, Tq, 8]
@@ -575,46 +628,54 @@ def dq_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
     per-block gradients sum to the full-attention gradient. Used by the
     in-kernel backward below AND per ring step by
     ``parallel.sequence.ring_flash_attention``. ``block_q`` / ``block_k``
-    override :func:`pick_blocks`; the two lengths are tiled apart."""
+    override :func:`pick_blocks`; the two lengths are tiled apart.
+    ``window`` as in :func:`_fwd`."""
     bh, Tq, d = q.shape
     Tk = k.shape[1]
+    window = _window(causal, window)
     bq, bk = _edges("flash_dq", Tq, Tk, d, q.dtype, block_q, block_k,
-                    v.shape[2])
+                    v.shape[2], window)
     nq, nk = Tq // bq, Tk // bk
     kern = functools.partial(_dq_kernel, causal=causal, scale=scale, nk=nk,
-                             rate=rate, has_km=km is not None)
+                             rate=rate, has_km=km is not None,
+                             window=window)
     specs, operands = _operands("outer", "inner", bq, bk, q, k, v, km, seed,
                                 rate, bwd=(do, delta, lse))
     dq, = _launch(
         "flash_dq", kern, bq, bk, bh, nq, nk,
-        causal_pairs(nq, nk, bq, bk) if causal else (),
+        causal_pairs(nq, nk, bq, bk, window=window) if causal else (),
         specs, operands,
         out_specs=[((1, bq, d), "outer")],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
-        scratch=[_scratch((bq, d))])
+        scratch=[_scratch((bq, d))], window=window)
     return dq
 
 
 def dkv_block(q, k, v, km, do, delta, lse, causal, scale, seed=None,
-              rate=0.0, block_q=None, block_k=None):
+              rate=0.0, block_q=None, block_k=None, window=None):
     """(dk, dv) for one k/v block against one q-shard; see :func:`dq_block`
-    for the global-``lse``/``delta`` contract and the overrides."""
+    for the global-``lse``/``delta`` contract, the overrides and
+    ``window``."""
     bh, Tk, d = k.shape
     Tq, d_v = q.shape[1], v.shape[2]
-    bq, bk = _edges("flash_dkv", Tq, Tk, d, q.dtype, block_q, block_k, d_v)
+    window = _window(causal, window)
+    bq, bk = _edges("flash_dkv", Tq, Tk, d, q.dtype, block_q, block_k, d_v,
+                    window)
     nq, nk = Tq // bq, Tk // bk
     kern = functools.partial(_dkv_kernel, causal=causal, scale=scale, nq=nq,
-                             rate=rate, has_km=km is not None)
+                             rate=rate, has_km=km is not None,
+                             window=window)
     specs, operands = _operands("inner", "outer", bq, bk, q, k, v, km, seed,
                                 rate, bwd=(do, delta, lse))
     return _launch(
         "flash_dkv", kern, bq, bk, bh, nk, nq,
-        causal_pairs(nq, nk, bq, bk, k_major=True) if causal else (),
+        causal_pairs(nq, nk, bq, bk, k_major=True, window=window)
+        if causal else (),
         specs, operands,
         out_specs=[((1, bk, d), "outer"), ((1, bk, d_v), "outer")],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch=[_scratch((bk, d)), _scratch((bk, d_v))])
+        scratch=[_scratch((bk, d)), _scratch((bk, d_v))], window=window)
 
 
 def rowwise_delta(do, o):
@@ -623,14 +684,15 @@ def rowwise_delta(do, o):
     return jnp.broadcast_to(delta[..., None], delta.shape + (8,))
 
 
-def _bwd(causal, scale, rate, res, g):
+def _bwd(causal, scale, rate, window, res, g):
     q, k, v, km, seed, o, lse = res
     lse = jnp.broadcast_to(lse[..., None], lse.shape + (8,))
     do = g.astype(q.dtype)
     delta = rowwise_delta(do, o)
-    dq = dq_block(q, k, v, km, do, delta, lse, causal, scale, seed, rate)
+    dq = dq_block(q, k, v, km, do, delta, lse, causal, scale, seed, rate,
+                  window=window)
     dk, dv = dkv_block(q, k, v, km, do, delta, lse, causal, scale, seed,
-                       rate)
+                       rate, window=window)
     dkm = None if km is None else jnp.zeros_like(km)
     # int32 primal → float0 cotangent (the JAX convention for non-float args)
     dseed = (None if seed is None
@@ -660,13 +722,13 @@ def normalize_operand_dtypes(q, k, v):
     return (q.astype(common), k.astype(common), v.astype(common), out_dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash(q, k, v, km, seed, causal, scale, rate):
-    o, _ = _fwd(q, k, v, km, seed, causal, scale, rate)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, km, seed, causal, scale, rate, window):
+    o, _ = _fwd(q, k, v, km, seed, causal, scale, rate, window=window)
     return o
 
 
-def _flash_fwd(q, k, v, km, seed, causal, scale, rate):
+def _flash_fwd(q, k, v, km, seed, causal, scale, rate, window):
     # What the backward kernels read carries one name: a ``jax.checkpoint``
     # whose policy saves it (the block stacks', ``base.block_checkpoint``)
     # keeps the tuple, and its backward holds neither this call again nor
@@ -676,7 +738,7 @@ def _flash_fwd(q, k, v, km, seed, causal, scale, rate):
     # would hold as many bytes as q.
     from ..monitor import get_registry     # here: the package imports ops
     from ..nn.layers.base import FLASH_RES
-    o, lse = _fwd(q, k, v, km, seed, causal, scale, rate)
+    o, lse = _fwd(q, k, v, km, seed, causal, scale, rate, window=window)
     lse = lse[..., 0]
     q, k, v, o, lse = (checkpoint_name(x, FLASH_RES)
                        for x in (q, k, v, o, lse))
@@ -686,7 +748,7 @@ def _flash_fwd(q, k, v, km, seed, causal, scale, rate):
         "flash-attention call hands its backward kernels, set when the call "
         "is traced", kernel=_name("flash_fwd", *pick_blocks(
             "flash_fwd", q.shape[1], k.shape[1], q.shape[2], q.dtype,
-            v.shape[2]))
+            v.shape[2], window), window)
     ).set(sum(x.size * x.dtype.itemsize for x in (q, k, v, o, lse)))
     return o, (q, k, v, km, seed, o, lse)
 
@@ -749,9 +811,12 @@ def supported(T: int, d: int, dropout_rate: float, key_mask,
 
 def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
                     key_mask=None, dropout_rate: float = 0.0,
-                    dropout_seed=None):
+                    dropout_seed=None, window: int | None = None):
     """Blockwise attention. q, k: [b, T, h, d], v: [b, T, h, d_v] (the
     value heads may have a size of their own) → [b, T, h, d_v].
+    ``window`` (causal only): query i sees keys i - window < j <= i, and
+    the kernels walk only the pairs of blocks that meet that band; a window
+    of T or more is the causal call itself.
     ``key_mask``: optional [b, T] (1 = real key, 0 = padding) — masked keys
     are excluded from the softmax inside the kernels (no dense fallback).
     ``dropout_rate`` > 0 applies dropout to the normalized attention
@@ -777,7 +842,9 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
         km = jnp.broadcast_to(jnp.asarray(key_mask, jnp.float32)[:, None, :],
                               (b, h, T)).reshape(b * h, T)
         km = jnp.broadcast_to(km[..., None], (b * h, T, 8))
+    if window is not None and window >= T:
+        window = None
     o = _flash(to_bh(q), to_bh(k), to_bh(v), km, seed, bool(causal),
-               float(scale), rate)
+               float(scale), rate, _window(causal, window))
     return jnp.transpose(o.reshape(b, h, T, v.shape[-1]),
                          (0, 2, 1, 3)).astype(out_dtype)

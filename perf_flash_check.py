@@ -8,6 +8,7 @@ leaves some query rows with no visible key, and with in-kernel dropout.
 
     python perf_flash_check.py             # the transformer bench shape
     python perf_flash_check.py blocksweep  # ms per call over (block_q, block_k)
+    python perf_flash_check.py blocksweep 1024  # banded by a window
     python perf_flash_check.py picks       # the chooser's edges and the dense path
 """
 import os
@@ -130,6 +131,9 @@ def check(b=4, T=8192, h=8, d=64, oracle_heads=2, rate=0.3, seed=1234,
 SWEEP = (((32, 4096, 128), (128, 256, 512, 1024), (128, 256, 512, 1024, 2048)),
          ((32, 4096, 64), (256, 512, 1024), (256, 512, 1024, 2048)),
          ((32, 8192, 64), (256, 512, 1024), (256, 512, 1024, 2048)))
+#: the banded walk's shape and edges: the Mellum2 cell's windowed layers
+#: (PR 41, a window of 1024)
+WINDOW_SWEEP = (((32, 8192, 128), (256, 512, 1024), (256, 512, 1024)),)
 
 
 def _ms_per_call(fn, *args, iters=20):
@@ -144,16 +148,17 @@ def _ms_per_call(fn, *args, iters=20):
     return (time.perf_counter() - t0) / iters * 1e3
 
 
-def _time_kernels(bh, T, d, block_q=None, block_k=None):
+def _time_kernels(bh, T, d, block_q=None, block_k=None, window=None):
     """ms per call of flash_fwd, flash_dq, flash_dkv (causal, bf16, no mask,
-    no dropout) at [bh, T, d] with these edges (None: the chooser's)."""
+    no dropout) at [bh, T, d] with these edges (None: the chooser's), banded
+    by ``window`` where it is given."""
     import deeplearning4j_tpu.ops.flash_attention as fa
 
     rng = np.random.default_rng(0)
     q, k, v, do = (jnp.asarray(rng.normal(size=(bh, T, d)), jnp.bfloat16)
                    for _ in range(4))
     scale = 1.0 / float(np.sqrt(d))
-    kw = dict(block_q=block_q, block_k=block_k)
+    kw = dict(block_q=block_q, block_k=block_k, window=window)
     fwd = jax.jit(lambda q, k, v: fa._fwd(q, k, v, None, None, True, scale,
                                           0.0, **kw))
     o, lse = fwd(q, k, v)
@@ -184,17 +189,19 @@ def _time_dense(b, T, h, d):
             "dense_fwdbwd_ms": _ms_per_call(g, q, k, v, iters=5)}
 
 
-def blocksweep(grid=True,
+def blocksweep(grid=True, window=None,
                out_path=os.path.join("chiprun_out", "flash_sweep.jsonl")):
     """The table :func:`ops.flash_attention.pick_blocks` is made from, in
     one process: per shape and pair of edges the ms per call of each kernel
-    and the TFLOP/s of the causal half's products it runs (2 forward, 3 in
-    dq, 4 in dk/dv, each T²·d FLOP a head), every row also appended to
-    ``out_path``; edges that do not divide T or that :func:`vmem_bytes`
-    puts past ``VMEM_LIMIT`` are left out, a pair Mosaic refuses is printed
-    as such. Then the chooser's own pick per shape and at T 2048, and the
-    dense path at the cell's shape and at T 2048 beside them (``grid``
-    False: these last rows alone)."""
+    and the TFLOP/s of the products it needs (2 forward, 3 in dq, 4 in
+    dk/dv, each 2·d FLOP a visible (query, key) pair a head: the causal
+    half, or with ``window`` the band's ``WINDOW_SWEEP`` walks), every row
+    also appended to ``out_path``; edges that do not divide T or that
+    :func:`vmem_bytes` puts past ``VMEM_LIMIT`` are left out, a pair Mosaic
+    refuses is printed as such. Then the chooser's own pick per shape (and
+    the causal walk at its edges beside a banded one), and without a window
+    at T 2048 and the dense path at the cell's shape and at T 2048 beside
+    them (``grid`` False: these last rows alone)."""
     import json
 
     import deeplearning4j_tpu.ops.flash_attention as fa
@@ -202,29 +209,32 @@ def blocksweep(grid=True,
     os.makedirs(os.path.dirname(out_path), exist_ok=True)
     products = {"fwd": 2, "dq": 3, "dkv": 4}
 
-    def row(bh, T, d, bq, bk):
-        rec = {"bh": bh, "T": T, "d": d, "block_q": bq, "block_k": bk}
+    def row(bh, T, d, bq, bk, w):
+        rec = {"bh": bh, "T": T, "d": d, "window": w, "block_q": bq,
+               "block_k": bk}
         try:
-            rec.update(_time_kernels(bh, T, d, bq, bk))
+            rec.update(_time_kernels(bh, T, d, bq, bk, w))
         except Exception as e:  # noqa: BLE001 - Mosaic's refusal is the row
             rec["refused"] = f"{type(e).__name__}: {str(e)[:200]}"
+        pairs = (w * (w + 1) // 2 + (T - w) * w) if w else T * T / 2
         for n, c in products.items():
             if f"{n}_ms" in rec:
-                rec[f"{n}_tflops"] = (c * bh * T * T * d
+                rec[f"{n}_tflops"] = (2 * c * bh * pairs * d
                                       / (rec[f"{n}_ms"] * 1e-3) / 1e12)
         with open(out_path, "a") as f:
             f.write(json.dumps(rec) + "\n")
         if "refused" in rec:
-            print(f"{bh:>3} {T:>5} {d:>4} {bq!s:>5} {bk!s:>5} "
+            print(f"{bh:>3} {T:>5} {d:>4} {bq!s:>5} {bk!s:>5} {w!s:>5} "
                   f"{rec['refused']}", flush=True)
             return
-        print(f"{bh:>3} {T:>5} {d:>4} {bq!s:>5} {bk!s:>5} " + " ".join(
-            f"{rec[f'{n}_ms']:>8.3f} {rec[f'{n}_tflops']:>6.1f}"
-            for n in products), flush=True)
+        print(f"{bh:>3} {T:>5} {d:>4} {bq!s:>5} {bk!s:>5} {w!s:>5} "
+              + " ".join(f"{rec[f'{n}_ms']:>8.3f} {rec[f'{n}_tflops']:>6.1f}"
+                         for n in products), flush=True)
 
-    print(f"{'bh':>3} {'T':>5} {'d':>4} {'bq':>5} {'bk':>5} "
+    sweep = WINDOW_SWEEP if window else SWEEP
+    print(f"{'bh':>3} {'T':>5} {'d':>4} {'bq':>5} {'bk':>5} {'w':>5} "
           + " ".join(f"{n + '_ms':>8} {'TF/s':>6}" for n in products))
-    for (bh, T, d), edges_q, edges_k in SWEEP if grid else ():
+    for (bh, T, d), edges_q, edges_k in sweep if grid else ():
         for bq in edges_q:
             for bk in edges_k:
                 if T % bq or T % bk or max(
@@ -232,13 +242,16 @@ def blocksweep(grid=True,
                         for kern in ("flash_fwd", "flash_dq", "flash_dkv")
                         ) > fa.VMEM_LIMIT:
                     continue
-                row(bh, T, d, bq, bk)
+                row(bh, T, d, bq, bk, window)
     print("the chooser's own edges (block columns None):")
-    for bh, T, d in [shape for shape, _, _ in SWEEP] + [(32, 2048, 128)]:
-        print({kern: fa.pick_blocks(kern, T, T, d, jnp.bfloat16)
+    shapes = [shape for shape, _, _ in sweep]
+    for bh, T, d in shapes + ([] if window else [(32, 2048, 128)]):
+        print({kern: fa.pick_blocks(kern, T, T, d, jnp.bfloat16, window=window)
                for kern in ("flash_fwd", "flash_dq", "flash_dkv")})
-        row(bh, T, d, None, None)
-    for T in (4096, 2048):
+        row(bh, T, d, None, None, window)
+        if window:
+            row(bh, T, d, None, None, None)
+    for T in () if window else (4096, 2048):
         rec = {"b": 2, "T": T, "h": 16, "d": 128}
         try:
             rec.update(_time_dense(2, T, 16, 128))
@@ -257,7 +270,8 @@ if __name__ == "__main__":
               jax.devices()[0].device_kind)
         if jax.default_backend() != "tpu":
             raise SystemExit("the sweep times the chip: no TPU here")
-        blocksweep(grid=cmd == "blocksweep")
+        blocksweep(grid=cmd == "blocksweep",
+                   window=int(sys.argv[2]) if len(sys.argv) > 2 else None)
     else:
         print("backend:", jax.default_backend())
         check()

@@ -134,10 +134,12 @@ def test_supported_routing_contract():
         fa._FORCE_INTERPRET = True
 
 
-def test_self_attention_rnn_time_step_kv_cache_matches_full():
+@pytest.mark.parametrize("window", [None, 4])
+def test_self_attention_rnn_time_step_kv_cache_matches_full(window):
     """Streaming rnn_time_step with the KV cache must reproduce the full-
     sequence causal forward, token by token (the attention analogue of the
-    reference's rnnTimeStep-vs-full consistency checks)."""
+    reference's rnnTimeStep-vs-full consistency checks); with a window of 4
+    keys, both sides see the same 4."""
     from deeplearning4j_tpu import NeuralNetConfiguration, MultiLayerNetwork, Adam
     from deeplearning4j_tpu.nn.conf.layers import (SelfAttentionLayer,
                                                    RnnOutputLayer)
@@ -146,7 +148,7 @@ def test_self_attention_rnn_time_step_kv_cache_matches_full():
             .updater(Adam(learning_rate=1e-3)).activation("identity")
             .list()
             .layer(SelfAttentionLayer(n_in=12, n_out=12, num_heads=3,
-                                      stream_max_length=32))
+                                      stream_max_length=32, window=window))
             .layer(RnnOutputLayer(n_in=12, n_out=5, activation="softmax",
                                   loss="mcxent"))
             .build())

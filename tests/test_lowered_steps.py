@@ -23,7 +23,12 @@ more name in the same policy) are the linear-attention expert model's alone:
 its step's text moved (17063 lines before, 16090 since: the segments'
 recompute lost the weights and the inverse's substitution; it had no pin,
 and has one since), the other four, of which the looped and the hybrid model share the
-policy and give nothing that name, stayed.
+policy and give nothing that name, stayed. The sliding window, the rotary scaling, the softmax score and the
+window mixer (PR 41: ``flash_attention.causal_pairs(window=)``,
+``attention.rope(scaling=)``, ``RoutedExpertsLayer.score``, the ``window``
+kind of ``HybridBlockStack``) emit nothing at their defaults: the five steps
+above kept their text, and the sliding-window expert model's step has a pin
+of its own.
 
 A PR that means to change one of these steps replaces its line count and
 digest here, and says so; one that does not and fails here has changed a
@@ -59,10 +64,14 @@ PARENT = {
 }
 
 
-#: the linear-attention expert language model's step as PR 40 left it
+#: the linear-attention expert language model's step as PR 40 left it, and
+#: the sliding-window expert language model's as PR 41 brought it
 PINNED = dict(PARENT, kimi_linear_l5_e8_b1_t8192_resident=(
     16090,
-    "fb75bddf88a97f70ae3eb149a483fb4f6a4ed481463b5f19fe091b50ad7d3846"))
+    "fb75bddf88a97f70ae3eb149a483fb4f6a4ed481463b5f19fe091b50ad7d3846"),
+    mellum2_l4_e16_b1_t8192_resident=(
+    4443,
+    "b53dfc657e476cdf953324cb2a2c6c359fd78d677e135ddb7700c93b3cd42f10"))
 
 
 def lowered_step(workload, seed=5):

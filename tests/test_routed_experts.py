@@ -24,11 +24,11 @@ def _toy_tiles(monkeypatch):
     monkeypatch.setattr(moe, "MXU_ROWS", 8)
 
 
-def _layer(held, top_k=K, shared=F, **kw):
+def _layer(held, top_k=K, shared=F, experts=E, scaling=2.446, **kw):
     conf = NeuralNetConfiguration.builder().seed(3).list().build()
     return impl_for(RoutedExpertsLayer(
-        n_in=D, n_out=D, num_experts=E, experts_held=held, top_k=top_k,
-        n_hidden=F, shared_hidden=shared, routed_scaling_factor=2.446,
+        n_in=D, n_out=D, num_experts=experts, experts_held=held, top_k=top_k,
+        n_hidden=F, shared_hidden=shared, routed_scaling_factor=scaling,
         **kw), conf.global_conf)
 
 
@@ -36,9 +36,11 @@ def gated(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
-def masked_loop(params, x, held, top_k=K, scaling=2.446, bias=None):
+def masked_loop(params, x, held, top_k=K, scaling=2.446, bias=None,
+                score="sigmoid"):
     """The layer's equations, every held expert on every token and masked."""
-    scores = jax.nn.sigmoid(x @ params["Wr"])
+    scores = (jax.nn.softmax(x @ params["Wr"], axis=-1) if score == "softmax"
+              else jax.nn.sigmoid(x @ params["Wr"]))
     _, top = jax.lax.top_k(scores if bias is None else scores + bias, top_k)
     chosen = jnp.take_along_axis(scores, top, axis=-1)
     weight = scaling * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
@@ -159,30 +161,45 @@ def test_the_walk_is_planned_from_what_the_layer_sees(monkeypatch):
     assert moe.tile_plan(65536, 8, 8, 64) == (512, 264)      # 8192 an expert
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+#: (score, published experts, held a share, the shared expert's width, the
+#: routed scaling): the Kimi Linear layer's kind at 32 over four shares of 8,
+#: the Mellum2 layer's at 64 over four shares of 16 (ids 0-15 .. 48-63)
+SHARES = {"sigmoid": (E, 8, F, 2.446), "softmax": (64, 16, None, 1.0)}
+
+
+@pytest.mark.parametrize("score", sorted(SHARES))
+def test_the_shares_add_up_to_the_uncut_layer(score):
     """The share test (``model-configs`` guide, section 4): the routed parts
-    that all four shares of 8 experts give, with the shared expert, which
-    every chip computes alike, counted once, add up to what the uncut
-    reference gives for the whole layer of 32."""
-    from benchmark.reference import kimi_linear_48b_a3b as reference
-    whole = _layer(None)
+    that all four shares give, with a shared expert, which every chip
+    computes alike, counted once where there is one, add up to what the
+    uncut reference of the configuration gives for the whole layer."""
+    from benchmark.reference import kimi_linear_48b_a3b, mellum2_12b_a2_5b
+    experts, size, fs, scaling = SHARES[score]
+    kw = dict(shared=fs, experts=experts, scaling=scaling, score=score)
+    whole = _layer(None, **kw)
     params, state = whole.init(jax.random.PRNGKey(2))
+    assert ("b" in state) == (score == "sigmoid")
     x = _x(64, seed=5)
     with jax.default_matmul_precision("highest"):
-        uncut = reference.experts_ffn(params, x, K, 2.446)
-        shared = reference.gated(x, params["Ws_gate"], params["Ws_up"],
-                                 params["Ws_down"])
+        if score == "sigmoid":
+            uncut = kimi_linear_48b_a3b.experts_ffn(params, x, K, scaling)
+            shared = kimi_linear_48b_a3b.gated(
+                x, params["Ws_gate"], params["Ws_up"], params["Ws_down"])
+        else:
+            uncut = mellum2_12b_a2_5b.experts_ffn(params, x, K)
+            shared = 0.0
     total = shared
-    for first in range(0, E, 8):
-        held = list(range(first, first + 8))
-        share = _layer(held)
-        part = {k: (v[first:first + 8] if k in share.EXPERT_KEYS else v)
+    for first in range(0, experts, size):
+        held = list(range(first, first + size))
+        share = _layer(held, **kw)
+        part = {k: (v[first:first + size] if k in share.EXPERT_KEYS else v)
                 for k, v in params.items()}
         y, _ = share.forward(part, state, x)
         total = total + (y - shared)
         # and the reference, given the same share, gives the same part
         with jax.default_matmul_precision("highest"):
-            part_ref = masked_loop(part, x, held)
+            part_ref = masked_loop(part, x, held, scaling=scaling,
+                                   score=score)
         np.testing.assert_allclose(np.asarray(y), np.asarray(part_ref),
                                    rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
@@ -191,6 +208,30 @@ def test_the_shares_add_up_to_the_uncut_layer():
     y, _ = whole.forward(params, state, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(uncut), rtol=1e-4,
                                atol=1e-5)
+
+
+def test_softmax_scores_against_a_hand_computation():
+    """``score="softmax"``: probabilities over all the published experts in
+    float32 (float64 here), the 8 largest, renormalised over the 8; the
+    layer holds no bias state and is the masked loop, gradients too."""
+    held = list(range(16, 24))
+    layer = _layer(held, shared=None, scaling=1.0, score="softmax")
+    params, state = layer.init(jax.random.PRNGKey(7))
+    assert state == {}
+    x = _x(24, seed=8)
+    chosen, weights = layer.route(x, params["Wr"], None)
+    logits = np.asarray(x, np.float64) @ np.asarray(params["Wr"], np.float64)
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    for t in range(24):
+        top = np.argsort(-p[t])[:K]
+        assert set(np.asarray(chosen[t]).tolist()) == set(top.tolist())
+        want = p[t, np.asarray(chosen[t])] / p[t, top].sum()
+        np.testing.assert_allclose(np.asarray(weights[t]), want, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.0, rtol=1e-6)
+    _agree(layer, params, state, x, held, scaling=1.0, score="softmax")
+    with pytest.raises(ValueError, match="score"):
+        _layer(held, score="relu")
 
 
 def test_the_bias_enters_the_choice_only_and_no_gradient_reaches_it():
