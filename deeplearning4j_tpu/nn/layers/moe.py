@@ -214,6 +214,26 @@ def tile_plan(n, k, held, experts):
     return tile, int(-(-PROVISION * mean * held // tile)) + held
 
 
+#: choices :func:`token_rows` counts by one product with a triangle of ones
+COUNT_BLOCK = 256
+#: where a layer's slots (``n min(k, held)``, the rows the combining gather
+#: reads) are at most this many times the rows it walks, the walk writes its
+#: tiles in row order and gathers each token's rows after it; where they are
+#: more, a turn adds its tile to its tokens by a scatter-add. On the v5e a
+#: scattered row cost 0.54 to 0.93 us a pass and a combined slot 0.075 us,
+#: the row buffer's fill and copies about 0.04 more: even near 5 (at 1.6
+#: slots a walked row the combine took 150 ms off a 474 ms step, at 10.7 it
+#: added 3 ms to a 659 ms one)
+COMBINE_PER_WALKED_ROW = 4
+
+
+def _rows_sized(n, k, held, tile):
+    """Rows of the tables of ``n`` tokens' ``k`` choices among ``held``
+    experts in tiles of ``tile``: the worst routing and every group's
+    padding to a tile."""
+    return -(-(n * min(k, held) + held * (tile - 1)) // tile) * tile
+
+
 def routing_tables(local, weights, held, tile):
     """The rows of the grouped products, expert by expert: ``local`` [n, k]
     holds, for each of a token's k choices, the held expert's index in
@@ -228,7 +248,7 @@ def routing_tables(local, weights, held, tile):
     one of ``tile`` rows past the last token; ``row_weight`` [R], nought on
     padding; ``tile_expert`` [R / tile]; the tiles in use, a traced scalar)."""
     n, k = local.shape
-    rows = -(-(n * min(k, held) + held * (tile - 1)) // tile) * tile
+    rows = _rows_sized(n, k, held, tile)
     key = local.reshape(-1)
     order = jnp.argsort(key, stable=True)
     counts = jnp.bincount(key, length=held + 1)[:held]
@@ -244,6 +264,38 @@ def routing_tables(local, weights, held, tile):
     row_weight = jnp.where(valid, weights.reshape(-1)[choice], 0)
     return (row_token, row_weight, expert[::tile].astype(jnp.int32),
             (ends[-1] // tile).astype(jnp.int32))
+
+
+def token_rows(local, held, tile):
+    """The rows of :func:`routing_tables` that each token's choices landed
+    on, from the same ``local`` [n, k], ``held`` and ``tile`` -> [n, min(k,
+    held)] int32 (the most rows a token can have here); a slot that no
+    choice filled points at the row one past the tables, which the walk
+    never writes and which holds nought. The tables put the r-th choice of
+    held expert e (in the order of the choices) on row r of e's group; that
+    r is counted here by products with a triangle of ones, a block of
+    :data:`COUNT_BLOCK` choices at a time, so no sort, scatter or gather of
+    single numbers runs (on the v5e, at 8192 tokens of 8 choices, 0.65 ms
+    where a stable sort of the tables' rows by token took 2.4)."""
+    n, k = local.shape
+    rows = _rows_sized(n, k, held, tile)
+    flat = local.reshape(-1)
+    block = min(COUNT_BLOCK, flat.shape[0])
+    pad = -flat.shape[0] % block
+    hit = (jnp.pad(flat, (0, pad), constant_values=held)[:, None]
+           == jnp.arange(held)).astype(jnp.float32)
+    within = jnp.einsum("ij,bjh->bih", jnp.tril(jnp.ones((block, block))),
+                        hit.reshape(-1, block, held),
+                        precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+    counted = jnp.cumsum(within[:, -1], axis=0)
+    rank = (within + (counted - within[:, -1])[:, None]).reshape(-1, held)
+    padded = -(-counted[-1] // tile) * tile
+    first = jnp.cumsum(padded) - padded
+    row = jnp.sum(jnp.where(hit > 0, rank - 1 + first, 0), axis=-1)
+    row = jnp.where(flat < held, row[:flat.shape[0]], rows).reshape(n, k)
+    if held < k:
+        row = jnp.sort(row, axis=-1)[:, :held]
+    return row.astype(jnp.int32)
 
 
 def _tile(i, tile, *tables):
@@ -266,25 +318,64 @@ def _dots(compute_dtype):
             lambda a, b: dot(a, b, ((0,), (0,))))       # a^T b
 
 
+def _combine(row_out, token_rows):
+    """``out[t] = sum_j row_out[token_rows[t, j]]``: one gather a slot,
+    added in ``row_out``'s dtype."""
+    out = row_out[token_rows[:, 0]]
+    for j in range(1, token_rows.shape[1]):
+        out = out + row_out[token_rows[:, j]]
+    return out
+
+
+def _sink(n, d, tile, rows, token_rows, dtype):
+    """The buffer a walk writes its tiles into: in token order, ``n +
+    tile`` rows (a padding row adds to one of the tile's rows past the last
+    token), where ``token_rows`` is None; else in row order, a row of the
+    tables and one past them, which stays nought."""
+    return jnp.zeros((n + tile if token_rows is None else rows + 1, d), dtype)
+
+
+def _put(out, i, tile, rows, part, token_rows):
+    """Tile ``i``'s ``part`` into the walk's buffer: added to its tokens'
+    rows, or written to its own rows."""
+    if token_rows is None:
+        return out.at[rows].add(part, unique_indices=True,
+                                indices_are_sorted=True)
+    return jax.lax.dynamic_update_slice_in_dim(out, part, i * tile, 0)
+
+
+def _drain(out, n, token_rows):
+    """[n, d] of the walk's buffer: its first ``n`` rows, or each token's
+    rows combined."""
+    if token_rows is None:
+        return out[:n]
+    with jax.named_scope("dispatch"):
+        return _combine(out, token_rows)
+
+
 def grouped_ffn(x, w_gate, w_up, w_down, row_token, row_weight, tile_expert,
-                tiles, tile, compute_dtype):
+                tiles, token_rows, tile, compute_dtype):
     """``y[t] = sum over the rows r of token t of row_weight[r] *
     Expert_{e(r)}(x[t])`` with ``Expert_e(x) = (silu(x Wgate_e) * (x Wup_e))
     Wdown_e``: ``x`` [n, d], the held experts' leaves ``w_gate``, ``w_up``
-    [E, d, f], ``w_down`` [E, f, d], and :func:`routing_tables`' tables ->
-    [n, d] in the accumulator dtype. One loop over the first ``tiles`` tiles,
-    a traced count that is at least the tiles in use (the layer walks its
-    standing provision when fewer are, :func:`tile_plan`, and the tiles in
-    use when more: the work never follows the tables' size, the worst
-    routing's; a tile past those in use holds padding rows of weight nought
-    and adds nothing); a turn gathers a tile's rows, runs one expert's three
-    products on them and adds the weighted result to its tokens' rows.
-    Differentiated by a rule of its own (a loop of a traced length has no
-    transpose): the same walk again, recomputing a tile's hidden state."""
+    [E, d, f], ``w_down`` [E, f, d], :func:`routing_tables`' tables and
+    :func:`token_rows`' (or None) -> [n, d] in the accumulator dtype. One
+    loop over the first ``tiles`` tiles, a traced count that is at least the
+    tiles in use (the layer walks its standing provision when fewer are,
+    :func:`tile_plan`, and the tiles in use when more: the work never
+    follows the tables' size, the worst routing's; a tile past those in use
+    holds padding rows of weight nought and adds nothing); a turn gathers a
+    tile's rows, runs one expert's three products on them and writes the
+    weighted result to the tile's own rows of a buffer in row order, which
+    one gather a slot turns into each token's sum after the loop; without
+    ``token_rows`` a turn adds the result to its tokens' rows instead
+    (:data:`COMBINE_PER_WALKED_ROW`). Differentiated by a rule of its own (a
+    loop of a traced length has no transpose): the same walk again,
+    recomputing a tile's hidden state."""
     n, d = x.shape
     ab, _, _ = _dots(compute_dtype)
 
-    def turn(i, y):
+    def turn(i, out):
         with jax.named_scope("dispatch"):
             rows, weight = _tile(i, tile, row_token, row_weight)
             xt = x[rows]
@@ -293,21 +384,24 @@ def grouped_ffn(x, w_gate, w_up, w_down, row_token, row_weight, tile_expert,
                                  compute_dtype)
             yt = ab(jax.nn.silu(ab(xt, wg)) * ab(xt, wu), wd)
         with jax.named_scope("dispatch"):
-            return y.at[rows].add(weight[:, None].astype(yt.dtype) * yt,
-                                  unique_indices=True, indices_are_sorted=True)
+            return _put(out, i, tile, rows,
+                        weight[:, None].astype(yt.dtype) * yt, token_rows)
 
-    y = jnp.zeros((n + tile, d), acc_dtype(compute_dtype))
-    return jax.lax.fori_loop(0, tiles, turn, y)[:n]
+    out = _sink(n, d, tile, row_token.shape[0], token_rows,
+                acc_dtype(compute_dtype))
+    return _drain(jax.lax.fori_loop(0, tiles, turn, out), n, token_rows)
 
 
 def _grouped_ffn_fwd(x, w_gate, w_up, w_down, row_token, row_weight,
-                     tile_expert, tiles, tile, compute_dtype):
-    args = (x, w_gate, w_up, w_down, row_token, row_weight, tile_expert, tiles)
+                     tile_expert, tiles, token_rows, tile, compute_dtype):
+    args = (x, w_gate, w_up, w_down, row_token, row_weight, tile_expert, tiles,
+            token_rows)
     return _grouped_ffn(*args, tile, compute_dtype), args
 
 
 def _grouped_ffn_bwd(tile, compute_dtype, kept, dy):
-    x, w_gate, w_up, w_down, row_token, row_weight, tile_expert, tiles = kept
+    (x, w_gate, w_up, w_down, row_token, row_weight, tile_expert, tiles,
+     token_rows) = kept
     n, d = x.shape
     sd = acc_dtype(compute_dtype)
     ab, abt, atb = _dots(compute_dtype)
@@ -335,24 +429,25 @@ def _grouped_ffn_bwd(tile, compute_dtype, kept, dy):
                              add_row(dwu, e, atb(xt, du)),
                              add_row(dwd, e, atb(h, dyt)))
         with jax.named_scope("dispatch"):
-            dx = dx.at[rows].add(dxt, unique_indices=True,
-                                 indices_are_sorted=True)
+            dx = _put(dx, i, tile, rows, dxt, token_rows)
             dweight = jax.lax.dynamic_update_slice_in_dim(
                 dweight, dweight_t.astype(dweight.dtype), i * tile, 0)
         return dx, dwg, dwu, dwd, dweight
 
     zeros = lambda like: jnp.zeros(like.shape, sd)
     dx, dwg, dwu, dwd, dweight = jax.lax.fori_loop(0, tiles, turn, (
-        jnp.zeros((n + tile, d), sd), zeros(w_gate), zeros(w_up),
-        zeros(w_down), jnp.zeros(row_weight.shape, row_weight.dtype)))
-    no = lambda t: np.zeros(t.shape, jax.dtypes.float0)
-    return (dx[:n].astype(x.dtype), dwg.astype(w_gate.dtype),
-            dwu.astype(w_up.dtype), dwd.astype(w_down.dtype), no(row_token),
-            dweight, no(tile_expert), no(tiles))
+        _sink(n, d, tile, row_token.shape[0], token_rows, sd), zeros(w_gate),
+        zeros(w_up), zeros(w_down),
+        jnp.zeros(row_weight.shape, row_weight.dtype)))
+    no = lambda t: None if t is None else np.zeros(t.shape, jax.dtypes.float0)
+    return (_drain(dx, n, token_rows).astype(x.dtype),
+            dwg.astype(w_gate.dtype), dwu.astype(w_up.dtype),
+            dwd.astype(w_down.dtype), no(row_token), dweight,
+            no(tile_expert), no(tiles), no(token_rows))
 
 
 _grouped_ffn = grouped_ffn
-grouped_ffn = jax.custom_vjp(_grouped_ffn, nondiff_argnums=(8, 9))
+grouped_ffn = jax.custom_vjp(_grouped_ffn, nondiff_argnums=(9, 10))
 grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
 
 
@@ -441,10 +536,13 @@ class RoutedExpertsImpl(LayerImpl):
         with jax.named_scope("dispatch"):
             local = np.full((c.num_experts,), held, np.int32)
             local[self.held] = np.arange(held)
-            *tables, in_use = routing_tables(jnp.asarray(local)[chosen],
-                                             weights, held, tile)
+            local = jnp.asarray(local)[chosen]
+            *tables, in_use = routing_tables(local, weights, held, tile)
             standing = min(standing, tables[0].shape[0] // tile)
-            tables.append(jnp.maximum(in_use, standing))
+            slots = flat.shape[0] * min(int(c.top_k), held)
+            combine = slots <= COMBINE_PER_WALKED_ROW * standing * tile
+            tables += [jnp.maximum(in_use, standing),
+                       token_rows(local, held, tile) if combine else None]
             layer = str(getattr(self, "index", ""))
             gauge = get_registry().gauge
             for which, count in (("held", held),
@@ -464,6 +562,12 @@ class RoutedExpertsImpl(LayerImpl):
                   "whatever the routing (twice what uniform routing sends "
                   "here and a tile for every held expert), set when the "
                   "layer is traced", layer=layer).set(standing * tile)
+            gauge("moe_combine_slots",
+                  "Rows the gather that adds up each token's rows of a "
+                  "routed expert layer reads a pass (min(k, held) a token; "
+                  "0 where the layer adds its rows by a scatter-add), set "
+                  "when the layer is traced",
+                  layer=layer).set(slots if combine else 0)
         xc = flat.astype(cd)
         y = grouped_ffn(xc, *(params[k] for k in self.EXPERT_KEYS), *tables,
                         tile, cd)

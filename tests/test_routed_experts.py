@@ -2,7 +2,9 @@
 against a plain masked loop over the held experts (output and every gradient)
 under ordinary and extreme routings, without a dropped choice; the share test
 (all shares' routed parts and the shared expert once add up to the uncut
-layer); the tables the grouped products walk and how far they are walked."""
+layer); the tables the grouped products walk and how far they are walked;
+the rows each token's choices landed on, and the two ways the walk hands its
+tiles back."""
 import numpy as np
 import pytest
 
@@ -104,18 +106,51 @@ def _steered(params, experts, strength=30.0):
         np.outer(x_dir, pull))}
 
 
+def _rows_read_once(local, held, tile):
+    """``token_rows`` against ``routing_tables``: every choice that landed
+    here is read exactly once, on a row of its token in a tile of its
+    expert, and every other slot reads the row past the tables, which the
+    walk never reaches."""
+    local = jnp.asarray(local)
+    n, k = local.shape
+    row_token, _, tile_expert, tiles = (np.asarray(t) for t in (
+        moe.routing_tables(local, jnp.ones(local.shape), held, tile)))
+    rows = np.asarray(moe.token_rows(local, held, tile))
+    R = len(row_token)
+    assert rows.shape == (n, min(k, held)) and rows.dtype == np.int32
+    assert tiles * tile <= R                 # row R is never written
+    landed = np.asarray(local) < held
+    for t in range(n):
+        mine = rows[t][rows[t] < R]
+        assert len(mine) == landed[t].sum() and (rows[t][len(mine):] == R).all()
+        assert (row_token[mine] == t).all()
+        assert sorted(tile_expert[mine // tile].tolist()) == sorted(
+            np.asarray(local)[t][landed[t]].tolist())
+    read = rows[rows < R]
+    assert len(set(read.tolist())) == len(read) == (row_token < n).sum()
+
+
+#: the two ways the walk hands its tiles back: combined by one gather a
+#: slot (a layer whose slots are few against its walk), added by a scatter
+FORMS = {"combine": 10 ** 6, "scatter": 0}
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
 @pytest.mark.parametrize("mxu_rows", [4, 32])
 @pytest.mark.parametrize("case", ["one_held_expert_takes_every_token",
                                   "all_choices_held", "none_routed_here"])
-def test_routing_edges_are_exact_and_drop_nothing(case, mxu_rows,
+def test_routing_edges_are_exact_and_drop_nothing(case, mxu_rows, form,
                                                   monkeypatch):
     """The worst routings for a capacity: every token's choices on this
     chip, every token to one held expert, no token here at all; at tiles
-    that an expert's rows fill several times over (12 rows) and at tiles
-    they do not fill (32). Each is the masked loop exactly, gradients too;
+    that an expert's rows fill several times over (12 rows: the groups end
+    on a tile's edge) and at tiles they do not fill (32); the walk's tiles
+    combined by one gather a slot and added by a scatter. Each is the
+    masked loop exactly, gradients too; each landed choice is read once;
     the products walk their standing tiles, and the tiles in use where the
     routing passes them."""
     monkeypatch.setattr(moe, "MXU_ROWS", mxu_rows)
+    monkeypatch.setattr(moe, "COMBINE_PER_WALKED_ROW", FORMS[form])
     walked = []
     grouped = moe.grouped_ffn
     monkeypatch.setattr(moe, "grouped_ffn", lambda *args: (
@@ -133,6 +168,8 @@ def test_routing_edges_are_exact_and_drop_nothing(case, mxu_rows,
     grads = _agree(layer, params, state, x, held)
     _, top = jax.lax.top_k(jax.nn.sigmoid(x @ params["Wr"]), K)
     here = np.isin(np.asarray(top), held).sum()
+    tile, standing = moe.tile_plan(48, K, 8, E)
+    _rows_read_once(np.where(np.asarray(top) < 8, top, 8), 8, tile)
     assert here == {"one_held_expert_takes_every_token": 48,
                     "all_choices_held": 48 * 8, "none_routed_here": 0}[case]
     if case == "none_routed_here":
@@ -141,7 +178,6 @@ def test_routing_edges_are_exact_and_drop_nothing(case, mxu_rows,
         assert np.asarray(grads[0]["We_gate"][5]).any()
         assert not np.asarray(grads[0]["We_gate"][4]).any()
     # 48 tokens of 8 choices among 32: uniform routing sends an expert 12
-    tile, standing = moe.tile_plan(48, K, 8, E)
     assert (tile, standing) == {4: (12, 24), 32: (32, 14)}[mxu_rows]
     layer.forward(params, state, x)
     in_use = {"one_held_expert_takes_every_token": -(-48 // tile),
@@ -283,6 +319,53 @@ def test_the_tables_hold_every_choice_once_and_whole_tiles():
              for r in np.flatnonzero(real)}
     assert pairs == {(t, int(e)) for t in range(n) for e in local[t]
                      if e < held}
+    # and each token's rows are found again: 3 held of 4 choices, so a
+    # token has 3 slots at most
+    _rows_read_once(local, held, tile)
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_the_walk_lowers_to_a_scatter_only_where_it_adds(form):
+    """The jitted forward and backward of ``grouped_ffn``: with the tokens'
+    rows combined by gathers no scatter is left in either; without them the
+    tiles are added by a scatter-add."""
+    rng = np.random.default_rng(2)
+    n, held, tile = 40, 4, 8
+    local = jnp.asarray(rng.integers(0, held + 2, size=(n, K)).clip(0, held),
+                        jnp.int32)
+    tables = moe.routing_tables(local, jnp.ones((n, K)), held, tile)
+    rows = moe.token_rows(local, held, tile) if form == "combine" else None
+    x = jnp.ones((n, D))
+    w = [jnp.ones((held, D, F)), jnp.ones((held, D, F)),
+         jnp.ones((held, F, D))]
+
+    def loss(x, w, weight):
+        return jnp.sum(moe.grouped_ffn(x, *w, tables[0], weight, tables[2],
+                                       tables[3], rows, tile, jnp.float32))
+    for f in (loss, jax.grad(loss, (0, 1, 2))):
+        text = jax.jit(f).lower(x, w, tables[1]).as_text()
+        assert ("scatter" in text) == (form == "scatter")
+
+
+@pytest.mark.parametrize("cell, experts, held, slots", [
+    ("kimi", 256, 8, 0), ("mellum2", 64, 16, 8192 * 8)])
+def test_the_cells_shapes_choose_the_form(cell, experts, held, slots,
+                                          monkeypatch):
+    """At the expert cells' 8192 tokens, top 8 and tiles of the MXU's 128:
+    the kimi layer walks 24 tiles of 256 for 65,536 slots (10.7 a walked
+    row) and adds by a scatter, the Mellum2 layer 80 tiles of 512 (1.6)
+    and combines; ``moe_combine_slots`` says which."""
+    monkeypatch.setattr(moe, "MXU_ROWS", 128)
+    layer = _layer(list(range(held)), experts=experts, shared=None)
+    params, state = layer.init(jax.random.PRNGKey(5))
+    walked = []
+    grouped = moe.grouped_ffn
+    monkeypatch.setattr(moe, "grouped_ffn", lambda *args: (
+        walked.append(args[8] is None), grouped(*args))[1])
+    layer.forward(params, state, _x(8192, seed=9))
+    assert walked == [slots == 0]
+    from deeplearning4j_tpu.monitor import get_registry
+    assert get_registry().snapshot()["moe_combine_slots"][-1]["value"] == slots
 
 
 def test_the_layer_refuses_what_it_cannot_mean():
@@ -305,3 +388,5 @@ def test_the_layer_refuses_what_it_cannot_mean():
     assert snap["moe_rows_sized"][0]["value"] == 16 * 8 + 8 * 7   # 184 rows
     # uniform routing sends the eight 32 rows: twice that and a tile each
     assert snap["moe_rows_standing"][0]["value"] == (8 + 8) * 8
+    # 16 tokens' 8 slots against those 128 walked rows: combined
+    assert snap["moe_combine_slots"][0]["value"] == 16 * 8
