@@ -30,8 +30,12 @@ they sit under keep them, so that no backward forms them a second time.
 ``SUB``-step blocks: a block against an earlier one as a product of
 ``k_t e^{G_t - G_ref}`` and ``k_s e^{G_ref - G_s}`` with ``G_ref`` the sum
 at the later block's start (both exponents at most nought), and a block
-against itself from the [SUB, SUB, K] differences directly, which are at
-most nought wherever ``s <= t``.
+against itself from the differences directly, which are at most nought
+wherever ``s <= t``: its first half of rows against its first half of
+columns and its second half against all of them (:func:`within_block`),
+so that the quarter above the diagonal that only a mask would read is not
+formed; backward one pass over ``exp(-|G_t - G_u|)`` gives both operands'
+cotangents.
 
 Precision under a bfloat16 compute policy: the products' operands take the
 compute dtype; the log-decays, their sums and exponentials, the key and
@@ -68,32 +72,68 @@ SEGMENT_CHUNKS = 16
 _SEGMENT_POLICY = jax.checkpoint_policies.save_only_these_names(CHUNK_MATS)
 
 
-def _weights(G):
-    """``exp(G[t, c] - G[s, c])`` for ``s <= t`` and nought above the
-    diagonal: ``G`` [..., m, K] -> [..., m, m, K]. The exponents it forms
-    are at most nought."""
-    m = G.shape[-2]
-    seen = jnp.tril(jnp.ones((m, m), bool))[:, :, None]
-    return jnp.exp(jnp.where(
-        seen, G[..., :, None, :] - G[..., None, :, :], -jnp.inf))
+def _steps_major(x):
+    """``x`` [..., m, K] -> [m, K, n]: a block's steps lead, its channels
+    follow, and every block and head of ``x`` (``n`` of them) lies along the
+    last axis, so that a pair of steps is a slice of the leading axes and a
+    sum over the channels adds whole rows."""
+    m, K = x.shape[-2:]
+    return jnp.moveaxis(x.reshape(-1, m, K), 0, -1)
+
+
+def _blocks_major(x, lead):
+    """[a, b, n] -> ``lead`` + [a, b]: :func:`_steps_major` undone."""
+    return jnp.moveaxis(x, -1, 0).reshape(lead + x.shape[:-1])
+
+
+def _bands(m):
+    """(first, past the last) row of each band of a block of ``m`` steps
+    (a power of two): its two halves."""
+    half = max(m // 2, 1)
+    return [(lo, lo + half) for lo in range(0, m, half)]
+
+
+def block_pairs(m):
+    """Pairs of steps (t, s) whose weights :func:`within_block`'s forward
+    forms for a block of ``m`` steps, a head and a channel: each band of
+    rows against the columns up to its last row, three quarters of the
+    block (192 of 256 at m = 16; the lower triangle holds 136)."""
+    return sum((hi - lo) * hi for lo, hi in _bands(m))
 
 
 def _within_block(left, right, G):
-    return jnp.sum(left[..., :, None, :] * right[..., None, :, :]
-                   * _weights(G), axis=-1)
+    lead, m = G.shape[:-2], G.shape[-2]
+    l, r, g = _steps_major(left), _steps_major(right), _steps_major(G)
+    rows = []
+    for lo, hi in _bands(m):
+        t, s = np.arange(lo, hi), np.arange(hi)
+        seen = (t[:, None] >= s[None, :])[:, :, None, None]
+        weights = jnp.exp(jnp.where(seen, g[lo:hi][:, None] - g[:hi][None],
+                                    -jnp.inf))       # exponents <= 0
+        band = jnp.sum(l[lo:hi][:, None] * r[:hi][None] * weights, axis=-2)
+        rows.append(jnp.pad(band, ((0, 0), (0, m - hi), (0, 0))))
+    return _blocks_major(jnp.concatenate(rows), lead)
 
 
 @jax.custom_vjp
 def within_block(left, right, G):
     """``out[t, s] = sum_c left[t, c] right[s, c] exp(G[t, c] - G[s, c])``
     for ``s <= t`` and nought above the diagonal: ``left``, ``right``, ``G``
-    [..., m, K] -> [..., m, m]. Differentiated by a rule of its own: the
-    [m, m, K] weights are formed again backward and two reductions over them
-    give ``left``'s and ``right``'s cotangents, from which ``G``'s follow
-    (``left * dleft - right * dright``); autodiff keeps the weights and runs
-    four. (One call for q and k against k, the weights formed once for both,
-    wrote them out as an array and lost 50 ms a step on the v5e: PERF.md
-    section 6, PR 39.)"""
+    [..., m, K] -> [..., m, m]. Every block and head lies along the last
+    axis and the channels are summed across rows (:func:`_steps_major`).
+    Forward, the weights are formed band by band (:func:`_bands`): the
+    first half of the rows against the first half of the columns, the
+    second half against all of them, so the quarter of the block above the
+    diagonal that no band reaches is never formed (:func:`block_pairs`).
+    Differentiated by a rule of its own that keeps nothing but the three
+    operands and makes one pass a call over ``exp(-|G_t - G_u|)``, which
+    below the diagonal is the weight of the pair (t, u) and above it the
+    weight of (u, t): one sum over ``u`` gives ``left``'s cotangent from
+    the one half and ``right``'s from the other, each pair's weight formed
+    once for each cotangent that reads it, where the masked form formed all
+    m x m weights twice and discarded half of each. (One call for q and k
+    against k, the weights formed once for both, wrote them out as an
+    array and lost 50 ms a step on the v5e: PERF.md section 6.)"""
     return _within_block(left, right, G)
 
 
@@ -103,9 +143,20 @@ def _within_block_fwd(left, right, G):
 
 def _within_block_bwd(kept, d_out):
     left, right, G = kept
-    weighed = d_out[..., None] * _weights(G)
-    d_left = jnp.sum(weighed * right[..., None, :, :], axis=-2)
-    d_right = jnp.sum(weighed * left[..., :, None, :], axis=-3)
+    lead, m = G.shape[:-2], G.shape[-2]
+    l, r, g = _steps_major(left), _steps_major(right), _steps_major(G)
+    mirrored = jnp.exp(-jnp.abs(g[:, None] - g[None]))          # [t, u, K, n]
+    d = jnp.moveaxis(d_out.reshape(-1, m, m), 0, -1)              # [t, u, n]
+    low = np.tril(np.ones((m, m), bool))[:, :, None]
+    by_row = jnp.where(low, d, 0)[:, :, None]                     # d[t, u], u <= t
+    by_col = jnp.where(np.swapaxes(low, 0, 1), jnp.swapaxes(d, 0, 1),
+                       0)[:, :, None]                             # d[u, t], u >= t
+    # both sums in one reduction, so that the weights are formed once
+    zero = np.zeros((), mirrored.dtype)
+    d_left, d_right = jax.lax.reduce(
+        (by_row * mirrored * r[None], by_col * mirrored * l[None]),
+        (zero, zero), lambda a, b: (a[0] + b[0], a[1] + b[1]), (1,))
+    d_left, d_right = _blocks_major(d_left, lead), _blocks_major(d_right, lead)
     return d_left, d_right, left * d_left - right * d_right
 
 
@@ -406,6 +457,14 @@ class KimiDeltaAttentionImpl(LayerImpl):
                 "(the carried state crosses one boundary fewer), set when "
                 "the layer is traced",
                 layer=str(getattr(self, "index", ""))).set(-(-T // chunk))
+            get_registry().gauge(
+                "kda_block_pairs",
+                "Pairs of steps whose decayed weights the delta rule's "
+                "forward forms for one diagonal block, head and key channel "
+                "(the block's lower triangle and what its bands hold above "
+                "it), set when the layer is traced",
+                layer=str(getattr(self, "index", ""))).set(
+                    block_pairs(math.gcd(chunk, SUB)))
             get_registry().gauge(
                 "kda_kept_bytes",
                 "Bytes of in-chunk matrices (A, B and the triangular "

@@ -96,6 +96,73 @@ def test_a_state_that_is_not_carried_shows(monkeypatch):
                        atol=1e-5)
 
 
+def dense_within_block(left, right, G):
+    """The diagonal blocks as they were formed before the bands: all m x m
+    pairs of a block for every channel, the ones above the diagonal sent
+    through ``exp(-inf)`` to nought."""
+    m = G.shape[-2]
+    seen = jnp.tril(jnp.ones((m, m), bool))[:, :, None]
+    weights = jnp.exp(jnp.where(
+        seen, G[..., :, None, :] - G[..., None, :, :], -jnp.inf))
+    return jnp.sum(left[..., :, None, :] * right[..., None, :, :] * weights,
+                   axis=-1)
+
+
+def _block_inputs(m, decay, seed=4, lead=(2, 3), K=32):
+    rng = np.random.default_rng(seed)
+    left, right = (rng.normal(size=lead + (m, K)) for _ in range(2))
+    G = np.cumsum(-decay * rng.uniform(size=lead + (m, K)), axis=-2)
+    d_out = rng.normal(size=lead + (m, m))          # the upper triangle too
+    return tuple(jnp.asarray(t, jnp.float32) for t in (left, right, G, d_out))
+
+
+@pytest.mark.parametrize("m", [8, 16])
+@pytest.mark.parametrize("decay", [1.0, 6.0])
+def test_the_bands_are_the_dense_masked_form(m, decay):
+    """``within_block`` against every pair formed and the upper triangle
+    masked: the output bit for bit, the three cotangents to float32
+    rounding, and a cotangent above the diagonal changes nothing."""
+    left, right, G, d_out = _block_inputs(m, decay)
+    got, vjp = jax.vjp(kda.within_block, left, right, G)
+    want, ref_vjp = jax.vjp(dense_within_block, left, right, G)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    grads = vjp(d_out)
+    for a, r in zip(grads, ref_vjp(d_out)):
+        scale = float(jnp.max(jnp.abs(r)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-5,
+                                   atol=1e-6 * scale)
+    below = vjp(jnp.tril(d_out))
+    for a, b in zip(grads, below):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("m", [8, 16])
+def test_no_weights_above_the_bands_are_formed(m):
+    """No value of ``within_block``'s forward or of its rule has the dense
+    form's trailing [m, m, K]; forward, every ``exp`` together forms the
+    weights of ``block_pairs(m)`` pairs a block and channel, three quarters
+    of the block; the rule adds one pass over all m x m, in which each pair
+    below the diagonal is formed once for each cotangent that reads it
+    (the masked form formed all of them twice more)."""
+    left, right, G, d_out = _block_inputs(m, 1.0)
+    lead, K = G.shape[:-2], G.shape[-1]
+    forward = jax.make_jaxpr(kda.within_block)(left, right, G)
+    backward = jax.make_jaxpr(
+        lambda *a: jax.vjp(kda.within_block, *a[:3])[1](a[3]))(
+        left, right, G, d_out)
+    n = K * int(np.prod(lead))
+    for program, pairs in ((forward, kda.block_pairs(m)),
+                           (backward, kda.block_pairs(m) + m * m)):
+        formed = 0
+        for eqn, trips in _equations(program.jaxpr):
+            for var in eqn.outvars:
+                assert tuple(var.aval.shape[-3:]) != (m, m, K), eqn
+            if eqn.primitive.name == "exp":
+                formed += trips * eqn.outvars[0].aval.size
+        assert formed == pairs * n
+    assert kda.block_pairs(16) == 192 and kda.block_pairs(8) == 48
+
+
 def _under_the_block_checkpoint(chunk, T, decay, compute_dtype=jnp.float32):
     """(the rule's loss and gradients on its five inputs through
     ``base.block_checkpoint``, as a function of them; the inputs). A fresh
@@ -164,31 +231,35 @@ def _equations(jaxpr, trips=1):
 
 def _formed(grad, args):
     """What the differentiated rule forms, by equations in its program:
-    (``exp`` over the [SUB, SUB, K] weights of a diagonal block, ``neg``
-    over a [SUB, i SUB] block row of the inverse's substitution: one a
-    block row below the first, formed nowhere else)."""
+    (passes over the diagonal blocks' weights: forward one ``exp`` over the
+    [rows, columns, K, n] pairs of each band of a block of ``SUB`` steps,
+    backward one over the whole [SUB, SUB, K, n]; ``neg`` over a
+    [SUB, i SUB] block row of the inverse's substitution: one a block row
+    below the first, formed nowhere else)."""
     K, chunk = args[1].shape[-1], 64
     rows = [(kda.SUB, i * kda.SUB) for i in range(1, chunk // kda.SUB)]
-    weights = inverse = 0
+    bands = squares = inverse = 0
     for eqn, _ in _equations(jax.make_jaxpr(grad)(*args).jaxpr):
         if eqn.primitive.name not in ("exp", "neg"):
             continue
         shape = tuple(eqn.outvars[0].aval.shape)
-        weights += (eqn.primitive.name == "exp"
-                    and shape[-3:] == (kda.SUB, kda.SUB, K))
+        if eqn.primitive.name == "exp" and len(shape) == 4 and shape[2] == K:
+            square = shape[:2] == (kda.SUB, kda.SUB)
+            squares += square
+            bands += not square
         inverse += eqn.primitive.name == "neg" and shape[-2:] in rows
-    return weights, inverse
+    return bands // len(kda._bands(kda.SUB)) + squares, inverse
 
 
 def test_the_backward_forms_no_chunk_matrix_again(monkeypatch):
-    """The differentiated rule forms the [SUB, SUB, K] weights of the
-    diagonal blocks in two passes, each of two calls (q against k, k against
-    k): forward, and for the products' cotangents; and it runs the inverse's
-    substitution (three block rows a chunk of 64) once, forward. With the
-    name struck the segment's recomputed forward is a third pass over the
-    weights and a second substitution; struck from the block stacks' policy
-    alone it is too (a name inside nested checkpoints is kept only where
-    both list it)."""
+    """The differentiated rule forms the weights of the diagonal blocks in
+    two passes, each of two calls (q against k, k against k): forward, and
+    for the products' cotangents; and it runs the inverse's substitution
+    (three block rows a chunk of 64) once, forward. With the name struck
+    the segment's recomputed forward is a third pass over the weights and a
+    second substitution; struck from the block stacks' policy alone it is
+    too (a name inside nested checkpoints is kept only where both list
+    it)."""
     formed = lambda: _formed(*_under_the_block_checkpoint(64, 256, 1.0))
     assert formed() == (2 * 2, 3)
     from deeplearning4j_tpu.nn.layers import base
@@ -299,3 +370,19 @@ def test_the_layer_stacks_its_leaves_and_refuses_what_it_cannot_do():
     rows = {row["labels"]["layer"]: row["value"]
             for row in get_registry().snapshot()["kda_chunks"]}
     assert rows["probe"] == 3                                  # 40 / 16
+
+
+def test_the_block_pairs_gauge_reads_what_a_block_forms():
+    """``kda_block_pairs`` is set where the layer is traced: chunks of 16
+    steps are one diagonal block of 16, whose two bands form 192 pairs a
+    head and channel forward (the lower triangle 136 of them, the dense
+    form 256); chunks of 8, 48 of 64."""
+    from deeplearning4j_tpu.monitor import get_registry
+    for chunk, pairs in ((16, 192), (8, 48)):
+        layer = _layer(chunk=chunk)
+        layer.index = f"pairs{chunk}"
+        params, _ = layer.init(jax.random.PRNGKey(1))
+        layer.forward(params, {}, jnp.zeros((1, 40, 24), jnp.float32))
+        rows = {row["labels"]["layer"]: row["value"]
+                for row in get_registry().snapshot()["kda_block_pairs"]}
+        assert rows[f"pairs{chunk}"] == pairs == kda.block_pairs(chunk)

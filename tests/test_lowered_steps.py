@@ -34,7 +34,11 @@ rows added by one gather a slot (``moe.grouped_ffn``,
 routed experts: at this size both layers combine (the kimi layer at its
 full size has too many slots for its walk and keeps the scatter-add, and
 its full-size step lowers to the parent's text), and the four steps above
-kept their text.
+kept their text. The delta rule's diagonal blocks formed in two bands of
+rows forward and in one pass over the mirrored weights backward
+(``kda.within_block`` and its rule) moved the linear-attention expert
+model's step alone (17249 lines before): no other cell runs that mixer,
+and the five other steps kept their text.
 
 A PR that means to change one of these steps replaces its line count and
 digest here, and says so; one that does not and fails here has changed a
@@ -71,10 +75,11 @@ PARENT = {
 
 
 #: the two expert language models' steps with the experts' walk in row
-#: order (16090 and 4443 lines before)
+#: order (16090 and 4443 lines before), the linear-attention one's with its
+#: diagonal blocks formed in bands (17249 lines before)
 PINNED = dict(PARENT, kimi_linear_l5_e8_b1_t8192_resident=(
-    17249,
-    "ca9838c670a21d029ef4d65f74eff2678c75e5ff31e59815d1fde1aaecc0ca7d"),
+    17596,
+    "6c410e25d686bf1a39a8089b89c3d3979b76001f30b6b685198ec9adc5a66afd"),
     mellum2_l4_e16_b1_t8192_resident=(
     5244,
     "3cf29bb1873483d3efe91ebb05ad48d6bc08fc7576419bb358f81444a27251d7"))
