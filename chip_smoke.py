@@ -44,7 +44,7 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-#: the bench shapes (bench.py) the legs run at
+#: the shapes the legs run at
 SHAPES = {
     "resnet50": {"batch": 256},
     "flash": {"b": 4, "T": 8192, "h": 8, "d": 64},
@@ -187,6 +187,29 @@ def leg_a(steps=8):
 
 
 # ------------------------------------------------------------------- leg B
+def transformer_lm_net(vocab, embed, heads, blocks):
+    """Decoder-only TransformerLM (pre-LN residual CG), bf16 compute."""
+    from deeplearning4j_tpu.models import TransformerLM
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    conf = TransformerLM(vocab_size=vocab, embed_dim=embed, num_heads=heads,
+                         num_blocks=blocks, seed=1).conf()
+    conf.global_conf.compute_dtype = "bfloat16"
+    return ComputationGraph(conf).init()
+
+
+def token_batch(batch, seq_len, vocab):
+    """Seeded token ids [b, T] (f32-encoded) and one-hot next-token labels
+    [b, T, vocab]."""
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, vocab, size=(batch, seq_len)).astype(np.float32)
+    labels = np.eye(vocab, dtype=np.float32)[
+        rng.integers(0, vocab, size=(batch, seq_len))]
+    return ids, labels
+
+
 def _fit_holds_custom_call(name, net, ds, lowered_for):
     """One public ``fit`` of a bench config, then the text of the program
     it compiled: the Mosaic custom call must be in it."""
@@ -208,7 +231,6 @@ def _fit_holds_custom_call(name, net, ds, lowered_for):
 def leg_b():
     import jax
     import jax.numpy as jnp
-    import bench
     import perf_flash_check
     import perf_lstm
     from deeplearning4j_tpu.datasets.dataset import DataSet
@@ -217,11 +239,11 @@ def leg_b():
            "lstm": perf_lstm.kernel_check(**SHAPES["lstm"])}
 
     lm = SHAPES["transformer_lm"]
-    ids, labels = bench.token_batch(lm["batch"], lm["seq_len"], lm["vocab"])
+    ids, labels = token_batch(lm["batch"], lm["seq_len"], lm["vocab"])
     out["transformer_lm"] = _fit_holds_custom_call(
         "transformer_lm",
-        bench.transformer_lm_net(lm["vocab"], lm["embed"], lm["heads"],
-                                 lm["blocks"]),
+        transformer_lm_net(lm["vocab"], lm["embed"], lm["heads"],
+                           lm["blocks"]),
         DataSet(ids, labels),
         # lowering reads shapes only: nothing is transferred or run
         lambda net: net._ensure_step().lower(
@@ -232,10 +254,11 @@ def leg_b():
     gc.collect()
 
     rnn = SHAPES["graves_lstm"]
-    ds = bench.char_stream(rnn["batch"], rnn["seq_len"])
+    ds = perf_lstm.char_stream(rnn["batch"], rnn["seq_len"])
     out["graves_lstm"] = _fit_holds_custom_call(
         "graves_lstm",
-        bench.graves_lstm_net(width=rnn["width"], tbptt=rnn["tbptt"]), ds,
+        perf_lstm.graves_lstm_net(width=rnn["width"], tbptt=rnn["tbptt"]),
+        ds,
         lambda net: perf_lstm.lower_tbptt_batch(net, ds))
     return out
 

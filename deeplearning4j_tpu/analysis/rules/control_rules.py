@@ -38,15 +38,14 @@ class ActuatorOutsideControlPlane(Rule):
         "surface shows it, no cooldown bounds it, and a flapping caller "
         "can shred the fleet. Route automated actions through a "
         "ControlPolicy; manual/runbook invocations belong in the "
-        "paramserver package itself, tests, or bench harnesses (all "
-        "exempt, as are self.* forwards — the definition pattern, e.g. "
+        "paramserver package itself or tests (both exempt, as are self.* "
+        "forwards — the definition pattern, e.g. "
         "ServedModel.set_admission delegating to its own batcher).")
 
     def check(self, tree, lines, path) -> Iterator:
         p = path.replace("\\", "/")
         parts = p.split("/")
-        if "tests" in parts or "control" in parts \
-                or "paramserver" in parts or parts[-1].startswith("bench"):
+        if "tests" in parts or "control" in parts or "paramserver" in parts:
             return
         for node in ast.walk(tree):
             if not isinstance(node, ast.Call):

@@ -27,8 +27,8 @@ convention the package settled on:
   ``_hits``/``_misses`` — ISSUE 11's ``serving_cache_*`` response-cache
   series, any future cache): they must be counters ending ``_total`` — a
   gauge or histogram spelling would break the hit-rate math every
-  consumer (the /profile ``cache`` column, the bench's
-  ``cache_hit_rate``) derives from windowed counter deltas.
+  consumer (the /profile ``cache`` column) derives from windowed
+  counter deltas.
 
 The rule fires on direct registry-handle creations — ``X.counter("name",
 ...)`` / ``X.gauge`` / ``X.histogram`` with a literal (or

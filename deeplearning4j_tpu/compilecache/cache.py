@@ -11,9 +11,9 @@ read, but it is off by default; this module is the package's one switch:
   so cache hits/misses are observable. Where the cache lives, in order:
   ``JAX_COMPILATION_CACHE_DIR`` (jax's own variable — when it is set the
   cache is placed from outside and no code here sets another directory),
-  the directory the caller passes (``chip_smoke.py`` and ``bench.py
-  --one`` pass the fixed ``<checkout>/.jax_cache``), then the
-  ``DL4J_TPU_COMPILE_CACHE_DIR`` fleet dial.
+  the directory the caller passes (``chip_smoke.py`` passes the fixed
+  ``<checkout>/.jax_cache``), then the ``DL4J_TPU_COMPILE_CACHE_DIR``
+  fleet dial.
 - :func:`maybe_enable` is the fleet seam: a no-op unless one of the two
   variables is set (tier-1 runs with both unset, so the cache is off by
   default), called from ``ModelRegistry.register`` (serving replicas) and

@@ -271,8 +271,8 @@ class TelemetryCollector:
         events locally, then samples the merged fleet dump into the
         collector's history ring and evaluates the fleet-scope alert
         engine. Returns a per-tick summary (labels scraped, per-target
-        scrape ms, errors) so tests and the bench latch exact numbers
-        instead of diffing process-global counters."""
+        scrape ms, errors) so tests latch exact numbers instead of
+        diffing process-global counters."""
         from .flightrec import get_flight_recorder
         t_tick0 = time.perf_counter()
         now = float(now) if now is not None else time.time()

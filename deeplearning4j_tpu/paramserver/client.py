@@ -128,8 +128,8 @@ class ParameterServerClient:
     ``shard``: which shard of a fleet this client talks to — metrics
     labeling only (``paramserver_wire_bytes_total{shard=}``).
     ``push_delay_s``: artificial per-push latency added before the wire
-    round — a fault-injection dial for benchmarks/tests that need a slow
-    transport (the overlap bench drives sync vs overlap against it).
+    round — a fault-injection dial for tests that need a slow transport
+    (the overlap tests drive sync vs overlap against it).
     """
 
     def __init__(self, address: str, staleness: int = 0,

@@ -50,7 +50,7 @@ The flush data plane is built for raw speed (ISSUE 11, docs/SERVING.md
   rows on device and crosses device→host in ONE transfer. The split is
   observable: ``serving/pad`` and ``serving/transfer`` spans nest under
   ``serving/flush``, and ``serving_pad_ms``/``serving_transfer_ms``
-  histograms carry the same numbers for /profile and the bench.
+  histograms carry the same numbers for /profile.
 - **precision.** ``precision="bf16"`` casts inputs to bfloat16 at submit
   (halving host→device bytes) and serves the forward in bf16; responses
   are cast back to float32 on the host side of the single transfer.

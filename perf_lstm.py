@@ -5,10 +5,11 @@
   python perf_lstm.py profile DIR      # jax.profiler trace of steady state
   python perf_lstm.py check            # kernels vs the lax.scan path
 
-Dispatch is asynchronous: every timed window closes on a value fetch
-(``bench._sync``). The modes that A/B a trace-time knob (``unroll``,
-``stream``) run one child per value; their parent never initialises a
-backend, because the chip belongs to one process at a time.
+Dispatch is asynchronous: every timed window closes on
+``jax.block_until_ready`` of what its last call returned (:func:`timed`).
+The modes that A/B a trace-time knob (``unroll``, ``stream``) run one
+child per value; their parent never initialises a backend, because the
+chip belongs to one process at a time.
 """
 import contextlib
 import os
@@ -19,7 +20,42 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bench import _sync, char_stream, device_peaks, graves_lstm_net
+from benchmark import device
+
+
+def graves_lstm_net(vocab=80, width=512, tbptt=50):
+    """2×GravesLSTM(width) char-RNN with TBPTT (the reference
+    CudnnLSTMHelper's showcase config), bf16 compute, the data cached on
+    the device after its first transfer."""
+    from deeplearning4j_tpu import Adam
+    from deeplearning4j_tpu.nn.conf import BackpropType, NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf.layers import GravesLSTM, RnnOutputLayer
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    conf = (NeuralNetConfiguration.builder().seed(1)
+            .updater(Adam(learning_rate=1e-3)).activation("tanh")
+            .compute_dtype("bfloat16").cache_mode("device")
+            .list()
+            .layer(GravesLSTM(n_in=vocab, n_out=width))
+            .layer(GravesLSTM(n_in=width, n_out=width))
+            .layer(RnnOutputLayer(n_in=width, n_out=vocab,
+                                  activation="softmax", loss="mcxent"))
+            .build())
+    conf.backprop_type = BackpropType.TruncatedBPTT
+    conf.tbptt_fwd_length = tbptt
+    conf.tbptt_back_length = tbptt
+    return MultiLayerNetwork(conf).init()
+
+
+def char_stream(batch, seq_len, vocab=80):
+    """Seeded one-hot character stream with next-character labels."""
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, vocab, size=(batch, seq_len))
+    f = np.eye(vocab, dtype=np.float32)[ids]          # [b, T, vocab]
+    l = np.eye(vocab, dtype=np.float32)[np.roll(ids, -1, axis=1)]
+    return DataSet(f, l)
 
 
 def _charrnn(batch, width, tbptt, seq_len, vocab=80):
@@ -27,16 +63,28 @@ def _charrnn(batch, width, tbptt, seq_len, vocab=80):
             char_stream(batch, seq_len, vocab))
 
 
+def timed(fn, *args, iters):
+    """Mean seconds per call of ``fn(*args)`` over ``iters`` calls, after
+    one untimed call that compiles and warms it."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    out = None
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters
+
+
+def _fit(net, ds):
+    """One ``fit``; returns what the step leaves behind, the parameters
+    and the loss, for a window to close on."""
+    net.fit(ds)
+    return net.params, net.score_
+
+
 def measure(batch=64, width=512, tbptt=50, seq_len=200, fits=3):
     net, ds = _charrnn(batch, width, tbptt, seq_len)
-    net.fit(ds)                      # compile + warm
-    _sync(net.score_)
-    t0 = time.perf_counter()
-    for _ in range(fits):
-        net.fit(ds)
-    _sync(net.score_)
-    dt = time.perf_counter() - t0
-    return batch * seq_len * fits / dt
+    return batch * seq_len / timed(_fit, net, ds, iters=fits)
 
 
 def kernel_ab(batch=64, width=512, tbptt=50, seq_len=200):
@@ -80,7 +128,6 @@ def micro(batch=64, width=512, tbptt=50):
     Each leg prints ms per call and µs per timestep, so the residual
     between (a)-(d) attributes the 33 µs/step directly."""
     from jax.experimental import pallas as pl
-    from bench import _warm_time
     import deeplearning4j_tpu.ops.lstm_cell as lc
 
     b, H, T = batch, width, tbptt
@@ -94,7 +141,7 @@ def micro(batch=64, width=512, tbptt=50):
     c0 = jnp.zeros((b, H), jnp.float32)
 
     def timeit(fn, *args):
-        return _warm_time(fn, *args, iters=20)
+        return timed(fn, *args, iters=20)
 
     # a. empty kernel on the same grid (streams the same xp blocks so the
     # DMA pattern matches; compute body is a single copy)
@@ -272,7 +319,7 @@ def roofline(batch=64, width=512, tbptt=50, seq_len=200):
     """XLA cost model of one TBPTT batch + bound analysis at the device's
     published peaks. (utils.profiling.step_cost covers the plain step;
     this lowers the FUSED tbptt scan, which it cannot.)"""
-    peaks = device_peaks()
+    peaks = device.peaks(jax.devices()[0].device_kind)
     net, ds = _charrnn(batch, width, tbptt, seq_len)
     ca = lower_tbptt_batch(net, ds).compile().cost_analysis()
     flops = float(ca.get("flops", 0.0))
@@ -372,11 +419,9 @@ def kernel_check(batch=64, width=512, tbptt=50, vocab=80,
 
 def profile(log_dir, batch=64, width=512):
     net, ds = _charrnn(batch, width, 50, 200)
-    net.fit(ds)
-    _sync(net.score_)
+    jax.block_until_ready(_fit(net, ds))
     jax.profiler.start_trace(log_dir)
-    net.fit(ds)
-    _sync(net.score_)       # value fetch BEFORE stop: trace must be complete
+    jax.block_until_ready(_fit(net, ds))    # the trace must be complete
     jax.profiler.stop_trace()
     print("trace written to", log_dir)
 

@@ -425,13 +425,16 @@ def test_ctl001_flags_actuator_calls_outside_control_plane():
     hit = {f.message.split("actuator call ")[1].split("(")[0]
            for f in fs}
     assert hit == {".scale_to", ".remap", ".restart", ".set_admission"}
+    # a script at the root of the checkout is held to it like the package
+    assert rule_ids(lint_src(_CTL_SRC, path="bench_drill.py")) == \
+        ["CTL001"] * 4
 
 
 def test_ctl001_exempts_sanctioned_packages_and_self_forwards():
-    # the control plane, the paramserver package (manual runbook paths),
-    # tests, and bench harnesses all legitimately actuate
+    # the control plane, the paramserver package (manual runbook paths)
+    # and tests all legitimately actuate
     for path in ("pkg/control/policies.py", "pkg/paramserver/training.py",
-                 "tests/test_x.py", "bench.py"):
+                 "tests/test_x.py"):
         assert lint_src(_CTL_SRC, path=path) == []
     # self.* forward: the definition pattern (ServedModel.set_admission
     # delegating to its own batcher), not an automated action
@@ -920,8 +923,8 @@ def test_mon001_metric_name_unit_suffix_convention():
 def test_mon001_serving_cache_series_must_be_counters():
     """ISSUE 11 satellite: the response-cache hit/miss series are
     monotonic events — any non-counter spelling (or a counter without
-    _total) breaks the hit-rate math /profile and the bench derive from
-    counter deltas."""
+    _total) breaks the hit-rate math /profile derives from counter
+    deltas."""
     bad = lint_src("""
         reg.gauge("serving_cache_hits")
         reg.histogram("serving_cache_misses_ms")

@@ -46,6 +46,35 @@ def test_result_line_holds_exactly_ok_and_device():
         "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}}
 
 
+def test_perf_scripts_refuse_an_unknown_device_kind():
+    """A roofline against another chip's peaks is wrong: ``perf_lstm``
+    reads them from the benchmark's table, which has none for the CPU."""
+    from benchmark import device
+    sys.path.insert(0, ROOT)
+    try:
+        import perf_lstm
+    finally:
+        sys.path.remove(ROOT)
+    assert "TPU v5 lite" in device.PEAKS
+    with pytest.raises(SystemExit) as exc:
+        perf_lstm.roofline(batch=2, width=8, tbptt=4, seq_len=8)
+    assert "'cpu'" in str(exc.value.code)
+
+
+def test_perf_script_parents_initialise_no_backend():
+    """A sweep's parent leaves the chip to its children: importing the
+    perf scripts initialises no jax backend and imports no other harness."""
+    p = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, perf_lstm, perf_flash_check\n"
+         "from jax._src import xla_bridge\n"
+         "sys.exit(xla_bridge.backends_are_initialized()\n"
+         "         or 'bench' in sys.modules)"],
+        cwd=ROOT, capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode == 0, p.stderr[-2000:]
+
+
 def test_kernel_call_off_chip_without_forced_interpret_raises():
     assert not fa._FORCE_INTERPRET
     q = jnp.zeros((1, 256, 2, 16), jnp.float32)
@@ -80,7 +109,7 @@ def _custom_calls(fn, *avals):
 def test_every_kernel_family_lowers_for_tpu_at_bench_shape(monkeypatch):
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     sds = jax.ShapeDtypeStruct
-    # flash at bench_transformer_lm's attention shape: fwd + dq + dkv
+    # flash at the smoke TransformerLM's attention shape: fwd + dq + dkv
     qkv = [sds((4, 8192, 8, 64), jnp.bfloat16)] * 3
     km = np.ones((4, 8192), np.float32)
     assert fa.supported(8192, 64, 0.3, km)
@@ -94,7 +123,7 @@ def test_every_kernel_family_lowers_for_tpu_at_bench_shape(monkeypatch):
         lambda q, k, v: fa.flash_attention(q, k, v, causal=False,
                                            dropout_rate=0.3,
                                            dropout_seed=7), *qkv) == 3
-    # the LSTM kernels at bench_graves_lstm's segment shape, bf16 weights:
+    # the LSTM kernels at the char-RNN's segment shape, bf16 weights:
     # lstm_cell with its streams in both dtypes the layer hands it (bf16
     # under the bf16 policy, f32 under f32 compute), over both reserves
     b, T, H = 64, 50, 512

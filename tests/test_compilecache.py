@@ -99,7 +99,7 @@ def test_maybe_enable_is_noop_without_the_dial(monkeypatch):
 
 def test_cache_directory_resolution_order(monkeypatch, tmp_path):
     """Where the cache lives: JAX_COMPILATION_CACHE_DIR, then the
-    directory the caller passes (chip_smoke.py / bench.py --one: the fixed
+    directory the caller passes (chip_smoke.py: the fixed
     <checkout>/.jax_cache), then the DL4J_TPU_COMPILE_CACHE_DIR dial. With
     jax's own variable set the cache is placed from outside: no code
     updates ``jax_compilation_cache_dir`` at all. jax's config and latch

@@ -22,6 +22,7 @@ from deeplearning4j_tpu.monitor import (TrainingHealthListener,
                                         get_tracer, monitored_jit,
                                         profile_report, render_profile_text,
                                         sample_device_memory)
+from deeplearning4j_tpu.monitor.jitwatch import wait_cost_captures
 
 
 @pytest.fixture(autouse=True)
@@ -66,6 +67,9 @@ class TestCompilePhases:
     def _monitor_off(self):
         import deeplearning4j_tpu.monitor as monitor
         monitor.set_enabled(False)
+        # an earlier test's cost capture still queued on the worker would
+        # land its span in this test's kept list
+        assert wait_cost_captures(60.0)
         get_tracer().clear()
         yield
         monitor.set_enabled(True)
@@ -91,6 +95,9 @@ class TestCompilePhases:
         # spans made after the fact are siblings under the caller's span
         assert {r["parent_span_id"] for r in inside} == {
             compile_["parent_span_id"]}
+        # the first call's cost capture is the worker's span, not the
+        # second call's: let it land before counting
+        assert wait_cost_captures(60.0)
         before = len(get_tracer().kept())
         f(jnp.ones((3, 5)))
         assert get_tracer().kept()[before:] == []

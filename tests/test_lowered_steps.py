@@ -1,44 +1,16 @@
 """The train steps of the benchmark's configurations, lowered at ``rehearse``
-size on the CPU, against the text they had before the hybrid language model
-came (PR 33): new options of shared layers (``num_kv_heads``,
-``attention_scale``, the embedding's ``scale``, the output layer's ``tied_to``
-and ``logits_divisor``) emit nothing where they are left at their defaults,
-and the tie's lookup in ``ComputationGraph`` adds no op (PR 30's method:
-StableHLO without locations, so a moved line does not count). The block
-stacks' checkpoint policy (PR 34) emits nothing either where no flash kernel
-runs, as here, off the TPU. The state-space mixer's differentiation rule
-and the barrier before its out-projection (PR 35, ``mamba.split_conv_silu``,
-``Mamba2Impl.forward``) are the hybrid model's alone: its step's text moved
-with them, the other three stayed. The looped stack as one scan over its
-block applications (PR 36, ``looped.LoopedBlockStackImpl.forward``) is the
-looped model's alone: its step's text moved, the other three stayed. The
-looped block's name for what its last norm reads (PR 38,
-``looped.LoopedBlockStackImpl.block``, kept by the one policy of
-``base.block_checkpoint``) is the looped model's alone too: its step's text
-moved (one more stack kept forward, one product fewer backward), and the
-hybrid model's, which shares the policy and names nothing, stayed with the
-other two. The delta rule's in-chunk matrices kept by name across its
-segments' checkpoint and the blocks' (PR 40, ``kda.delta_rule_segment``, one
-more name in the same policy) are the linear-attention expert model's alone:
-its step's text moved (17063 lines before, 16090 since: the segments'
-recompute lost the weights and the inverse's substitution; it had no pin,
-and has one since), the other four, of which the looped and the hybrid model share the
-policy and give nothing that name, stayed. The sliding window, the rotary scaling, the softmax score and the
-window mixer (PR 41: ``flash_attention.causal_pairs(window=)``,
-``attention.rope(scaling=)``, ``RoutedExpertsLayer.score``, the ``window``
-kind of ``HybridBlockStack``) emit nothing at their defaults: the five steps
-above kept their text, and the sliding-window expert model's step has a pin
-of its own. The routed experts' walk written in row order and each token's
-rows added by one gather a slot (``moe.grouped_ffn``,
-``moe.token_rows``) moved both expert models' steps, the only ones with
-routed experts: at this size both layers combine (the kimi layer at its
-full size has too many slots for its walk and keeps the scatter-add, and
-its full-size step lowers to the parent's text), and the four steps above
-kept their text. The delta rule's diagonal blocks formed in two bands of
-rows forward and in one pass over the mirrored weights backward
-(``kda.within_block`` and its rule) moved the linear-attention expert
-model's step alone (17249 lines before): no other cell runs that mixer,
-and the five other steps kept their text.
+size on the CPU, against the text each had when a change last moved it on
+purpose: a line count and a digest of the StableHLO, printed without
+locations so that a moved source line does not count. ``PARENT`` holds the
+steps as they were before the hybrid language model came, ``PINNED`` adds
+the steps moved since and the configurations that came later.
+
+Why it holds: options of shared layers left at their defaults must emit
+nothing, and a mixer, a layer kind or a name in the block checkpoint's
+policy that one model alone uses must leave every other model's step as it
+was. Off the TPU no flash kernel runs, so what a policy keeps for the
+kernels emits nothing here. Which change moved which step, and by how many
+lines, is in ``CHANGES.md``.
 
 A PR that means to change one of these steps replaces its line count and
 digest here, and says so; one that does not and fails here has changed a

@@ -186,6 +186,49 @@ def test_http_predict_listing_and_error_codes():
         srv.stop()
 
 
+@pytest.mark.parametrize("qps", [30.0, 90.0])
+def test_open_loop_load_answers_every_request(qps):
+    """An open-loop load generator: requests leave on a fixed schedule
+    whether or not earlier ones came back, for one second at ``qps``. Every
+    one is answered with the model's own output for its own input, and the
+    batcher counts exactly the requests sent."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    net = _net(seed=5)
+    name = f"open_loop_{int(qps)}"
+    srv = InferenceServer()
+    srv.register(name, net, batch_buckets=(1, 2, 4, 8, 16, 32),
+                 linger_ms=3.0, max_queue_examples=256,
+                 default_deadline_ms=30000.0, input_shape=(6,),
+                 warmup=True)
+    port = srv.start(port=0)
+    url = f"http://127.0.0.1:{port}/v1/models/{name}/predict"
+    answered = get_registry().counter("serving_requests_total", model=name,
+                                      outcome="ok")
+    before = answered.value
+    n = int(qps)
+    xs = np.random.default_rng(3).normal(size=(n, 1, 6)).astype(np.float32)
+    try:
+        with ThreadPoolExecutor(max_workers=32) as pool:
+            t0 = time.perf_counter()
+            futures = []
+            for i in range(n):
+                delay = t0 + i / qps - time.perf_counter()
+                if delay > 0:
+                    time.sleep(delay)
+                futures.append(pool.submit(_post, url,
+                                           {"inputs": xs[i].tolist()}))
+            replies = [f.result() for f in futures]
+    finally:
+        srv.stop()          # joins the scheduler: its counts are all in
+    for x, (code, doc) in zip(xs, replies):
+        assert code == 200, doc
+        np.testing.assert_allclose(np.asarray(doc["outputs"], np.float32),
+                                   np.asarray(net.output(x)),
+                                   rtol=1e-6, atol=1e-7)
+    assert answered.value - before == n
+
+
 def test_http_429_under_saturation_with_retry_after():
     """Tiny queue + slow model: concurrent clients overflow admission and
     get typed 429s while accepted requests still complete."""

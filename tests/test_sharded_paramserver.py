@@ -183,8 +183,8 @@ def test_sharded_delta_training_bit_equivalent_and_2x_fewer_wire_bytes():
     BIT-EQUIVALENT final params, with the fleet run moving >= 2x fewer
     wire bytes per step (proven via paramserver_wire_bytes_total). The net
     is sized so a full vector dwarfs a sparse frame (a ~1.5k-param toy
-    with threshold 1e-2 encodes ~sparse updates; on production nets the
-    gap is the bench's 14x)."""
+    with threshold 1e-2 encodes ~sparse updates; a larger net widens the
+    gap)."""
     def _net():
         conf = (NeuralNetConfiguration.builder().seed(21)
                 .updater(Sgd(learning_rate=5e-2)).activation("tanh").list()
@@ -531,13 +531,33 @@ def test_single_server_parallel_shard_pulls_share_fanout_path():
     """Satellite: per-shard pulls parallelize even against ONE server —
     pull_sharded rides the same Fanout/connection-pool machinery as the
     fleet client and reassembles bit-exactly; the pool really held
-    multiple live sockets (the parallelism evidence)."""
+    multiple live sockets (the parallelism evidence). No shard pull hands
+    its socket back before all four hold one, so the overlap does not
+    depend on thread timing: a fan-out that ran the pulls one after
+    another would break the barrier instead."""
     rng = np.random.default_rng(4)
     vec = rng.normal(size=205).astype(np.float32)
     with ParameterServer(port=0, num_shards=4) as srv:
         with ParameterServerClient(srv.address, pool_size=4,
                                    max_retries=1, backoff=0.01) as c:
             c.set_params(vec)
+            together = threading.Barrier(4, timeout=30)
+            in_pull = threading.local()
+            pull, checkin = c.pull, c._checkin
+
+            def pull_holding(shard=-1):
+                in_pull.on = True
+                try:
+                    return pull(shard=shard)
+                finally:
+                    in_pull.on = False
+
+            def checkin_together(s):
+                if getattr(in_pull, "on", False):
+                    together.wait()
+                checkin(s)
+
+            c.pull, c._checkin = pull_holding, checkin_together
             version, out = c.pull_sharded()
             np.testing.assert_array_equal(out, vec)
             assert version == c.server_version()[0]
